@@ -164,7 +164,7 @@ class ServeSettings(S):
                 "(JAX_PLATFORMS in the fleet parent's environment — cpu "
                 "under the test/dev rings, unset on a TPU host so "
                 "replicas see the real chips); 'cpu' forces the dev-ring "
-                "behavior (fake devices, remote plugin disabled); any "
+                "behavior (virtual devices); any "
                 "other value pins that platform; '' = never pin")
     hang_timeout_s: float = _(10.0, "per-replica hang watchdog: a replica "
                                     "whose beacons freeze this long is "

@@ -89,10 +89,11 @@ class GeneralSettings(S):
                               "enough for CI runs")
     compilation_cache_dir: str = _(
         "auto", "persistent XLA compilation-cache directory: 'auto' = "
-                "<run_dir>/compile_cache (restarts/resumes of the run "
-                "recompile nothing), 'off' disables, else an explicit dir "
-                "shared across runs; exported to spawned workers as "
-                "JAX_COMPILATION_CACHE_DIR")
+                "one fixed git-ignored directory in the checkout "
+                "(.compile_cache — every run, restart and server start "
+                "shares it), 'off' disables, else an explicit dir; "
+                "JAX_COMPILATION_CACHE_DIR, when set, is used instead of "
+                "either and nothing else is set")
     prefetch_depth: int = _(
         2, "device-side input prefetch depth: keep N batches already "
            "device_put onto the mesh (with the compiled step's sharding) "

@@ -38,10 +38,10 @@ def _pin_batch(x: jnp.ndarray) -> jnp.ndarray:
     rematerialization" on every LayerNorm broadcast (dp x fsdp x tp meshes).
     Pinning the stream keeps activations batch-sharded and turns the weight
     shards into per-layer all-gathers instead. No-op without a mesh."""
-    from jax.interpreters import pxla
+    from ..parallel.ring import current_mesh
 
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh.empty or "data" not in mesh.shape or "fsdp" not in mesh.shape:
+    mesh = current_mesh()
+    if mesh is None or "data" not in mesh.shape or "fsdp" not in mesh.shape:
         return x
     spec = jax.sharding.PartitionSpec(("data", "fsdp"))
     return jax.lax.with_sharding_constraint(x, spec)

@@ -64,7 +64,7 @@ class PipelineDriver:
                  hang_startup_timeout_s: float = 0.0,
                  step_timeout_s: float = 300.0,
                  ready_timeout_s: float = 300.0,
-                 worker_platform: str = "cpu",
+                 worker_platform: Optional[str] = None,
                  launch_fn: Optional[Callable[..., int]] = None,
                  trace_armed: Optional[bool] = None) -> None:
         self.run_dir = run_dir
@@ -74,6 +74,11 @@ class PipelineDriver:
             raise ValueError("an MPMD pipeline needs >= 2 stages")
         self.step_timeout_s = step_timeout_s
         self.ready_timeout_s = ready_timeout_s
+        # stages are processes and a chip belongs to one process: only an
+        # explicit cpu platform (virtual devices) can hold several
+        from ..parallel.launcher import require_workers_fit_host
+        require_workers_fit_host(self.n_stages, worker_platform,
+                                 f"{self.n_stages} MPMD stages")
         if launch_fn is None:
             # deferred: pulling the launcher imports the parallel package
             # (and with it the jax MODULE — no backend init, but real

@@ -218,9 +218,9 @@ def device_bandwidths(device_kind: str = "cpu") -> Dict[str, float]:
     for key, hbm, ici in _BANDWIDTHS:
         if key in kind:
             return {"hbm_bytes_per_s": hbm, "ici_bytes_per_s": ici}
-    if "tpu" in kind:  # unknown TPU generation: assume v4-class
-        return {"hbm_bytes_per_s": 1.2e12, "ici_bytes_per_s": 2.4e11}
-    return {"hbm_bytes_per_s": 2.0e10, "ici_bytes_per_s": 1.0e10}
+    # not in the table is an error, not a default (as utils/perf.py's peaks)
+    raise ValueError(f"no HBM/ICI bandwidth known for device kind {kind!r}: "
+                     f"add it to obs/ledger.py::_BANDWIDTHS with its source")
 
 
 def roofline_attribution(*, tokens_per_s: float, flops_per_token: float,

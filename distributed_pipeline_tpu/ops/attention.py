@@ -28,7 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["dot_product_attention", "make_attention_bias", "causal_bias"]
+__all__ = ["dot_product_attention", "resolve_attention_impl",
+           "make_attention_bias", "causal_bias"]
 
 NEG_INF = -1e9  # large-negative in bf16-safe range; -inf would NaN the softmax
 # on fully-masked rows
@@ -69,6 +70,50 @@ def _xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+def resolve_attention_impl(impl: str, seq_len: int, mesh=None) -> str:
+    """The arm ``impl`` runs at this sequence length on this mesh — the ONE
+    rule, shared by the dispatcher below and by whoever reports the arm
+    (trainer evidence, chip_smoke.py). ``auto``: ring when the mesh has a
+    sequence axis > 1, else the pallas flash kernel on TPU from ~1k
+    context up (O(L) instead of O(L^2) HBM in both directions; below that
+    the dense XLA path keeps its small [L, L] logits), else XLA."""
+    if impl != "auto":
+        return impl
+    if mesh is not None and mesh.shape.get("sequence", 1) > 1:
+        return "ring"  # sequence-parallel mesh: attention must ring
+    on_tpu = jax.default_backend() == "tpu"
+    return "pallas" if (on_tpu and seq_len >= 1024) else "xla"
+
+
+def _flash_on_mesh(q, k, v, pad_mask, causal, mesh):
+    """The flash kernel on a mesh of more than one device. Mosaic kernels
+    cannot be partitioned by GSPMD ("wrap the call in a shard_map"), so
+    this is that shard_map: batch and heads split as ring attention
+    splits them, the sequence whole on every device."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.ring import batch_and_head_axes
+    from ..utils.jax_compat import shard_map
+    from .flash_attention import flash_attention
+
+    if mesh.shape.get("sequence", 1) > 1:
+        raise ValueError(
+            "attention_impl 'pallas' keeps the whole sequence on each "
+            "device and cannot run on a mesh with sequence > 1; use "
+            "'ring' (or 'auto', which picks it)")
+    batch, heads = batch_and_head_axes(mesh, q.shape[0], q.shape[1])
+    qkv = P(batch, heads, None, None)
+    if pad_mask is None:
+        return shard_map(
+            lambda q_, k_, v_: flash_attention(q_, k_, v_, None, causal),
+            mesh=mesh, in_specs=(qkv,) * 3, out_specs=qkv,
+            check_vma=False)(q, k, v)
+    return shard_map(
+        lambda q_, k_, v_, m_: flash_attention(q_, k_, v_, m_, causal),
+        mesh=mesh, in_specs=(qkv,) * 3 + (P(batch, None),), out_specs=qkv,
+        check_vma=False)(q, k, v, pad_mask)
+
+
 def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           pad_mask: Optional[jnp.ndarray] = None,
                           causal: bool = False,
@@ -76,24 +121,19 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """Multi-head attention on [B, H, L, Dh] tensors.
 
     ``pad_mask`` is [B, L] (1 = real token); ``impl`` selects the kernel
-    (module docstring); "auto" uses the pallas flash kernel on TPU for long
-    sequences and XLA einsum otherwise.
+    (module docstring); "auto" resolves by :func:`resolve_attention_impl`.
     """
-    if impl == "auto":
-        from ..parallel.ring import current_mesh
-        mesh = current_mesh()
-        if mesh is not None and mesh.shape.get("sequence", 1) > 1:
-            impl = "ring"  # sequence-parallel mesh: attention must ring
-        else:
-            # Flash (fwd + blocked bwd) wins from ~1k context up: measured
-            # even with XLA at L=2048 and ~2x faster by L=8192 on v5e, with
-            # O(L) instead of O(L^2) HBM in BOTH directions. Below that the
-            # dense XLA path is faster and the [L, L] logits are small.
-            on_tpu = jax.default_backend() == "tpu"
-            impl = "pallas" if (on_tpu and q.shape[-2] >= 1024) else "xla"
+    from ..parallel.ring import current_mesh
+    mesh = current_mesh()
+    impl = resolve_attention_impl(impl, q.shape[-2], mesh)
     if impl == "xla":
         return _xla_attention(q, k, v, pad_mask, causal)
     if impl == "pallas":
+        # inside someone else's shard_map body (pipeline stages) the axes
+        # are already manual: the kernel is per-device there, as it is
+        if (mesh is not None and mesh.size > 1
+                and not jax.sharding.get_abstract_mesh().manual_axes):
+            return _flash_on_mesh(q, k, v, pad_mask, causal, mesh)
         from .flash_attention import flash_attention
         return flash_attention(q, k, v, pad_mask, causal)
     if impl == "ring":

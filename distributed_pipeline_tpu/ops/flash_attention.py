@@ -60,14 +60,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific bits are unavailable in some CPU-only wheels
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
+
+_VMEM = pltpu.VMEM
 
 __all__ = ["flash_attention", "flash_attention_lse"]
+
+# stable kernel names: traces and compiled-HLO text find them by these
+FWD_KERNEL_NAME = "flash_attention_fwd"
+BWD_KERNEL_NAME = "flash_attention_bwd"
 
 NEG_INF = -1e9
 LANES = 128  # TPU lane width: last-dim tiles and stat buffers align to this
@@ -364,29 +365,7 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _xla_forward(q, k, v, pad_mask, causal):
-    """Dense O(L^2) fallback with the kernels' exact masking semantics,
-    returning (out, lse [B*H, L] f32) — used only on wheels whose pallas
-    has no TPU grid support (pltpu import failed)."""
-    B, H, L, Dh = q.shape
-    s = jnp.einsum("bhld,bhmd->bhlm", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (Dh ** -0.5)
-    if pad_mask is not None:
-        s = s + (1.0 - pad_mask.astype(jnp.float32))[:, None, None, :] \
-            * NEG_INF
-    if causal:
-        tri = jnp.tril(jnp.ones((L, L), bool))
-        s = jnp.where(tri[None, None], s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m), 0.0)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bhlm,bhmd->bhld",
-                     (p / jnp.maximum(l, 1e-20)).astype(v.dtype), v)
-    lse = (m + jnp.log(jnp.maximum(l, 1e-20)))[..., 0]
-    return out.astype(q.dtype), lse.reshape(B * H, L)
-
-
-def _grid_call(kernel, steps, grid, in_specs, out_specs, out_shape,
+def _grid_call(name, kernel, steps, grid, in_specs, out_specs, out_shape,
                scratch_shapes, inputs):
     """pallas_call through a scalar-prefetch grid spec: the step table rides
     in SMEM ahead of the grid so index maps can route each step's blocks."""
@@ -398,7 +377,7 @@ def _grid_call(kernel, steps, grid, in_specs, out_specs, out_shape,
         scratch_shapes=scratch_shapes,
     )
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        kernel, grid_spec=grid_spec, out_shape=out_shape, name=name,
         interpret=_interpret())(steps, *inputs)
 
 
@@ -407,8 +386,6 @@ def _flash_forward(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    block_q: int, block_k: int):
     """Returns (out [B, H, L, Dh], lse [B*H, Lq, LANES] f32)."""
     B, H, L, Dh = q.shape
-    if pltpu is None:  # pragma: no cover — CPU wheels without pallas-TPU
-        return _xla_forward(q, k, v, pad_mask, causal)
     sm_scale = Dh ** -0.5  # scale by the REAL head dim; zero-padding Dh
     # leaves q·k unchanged
     block_q, block_k = _block_sizes(L, block_q, block_k)
@@ -451,7 +428,7 @@ def _flash_forward(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 pl.BlockSpec((1, block_q, LANES), _iq, memory_space=_VMEM))
     lse_shape = ((bh, nq, block_q) if compact else (bh, Lq, LANES))
     out, lse = _grid_call(
-        kernel, jnp.asarray(steps_np), grid, in_specs,
+        FWD_KERNEL_NAME, kernel, jnp.asarray(steps_np), grid, in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, D), _iq, memory_space=_VMEM),
             lse_spec,
@@ -486,13 +463,6 @@ def _flash_backward(q, k, v, pad_mask, o, lse, g, causal, block_q, block_k,
     softmax-jacobian term as ds = p*(dp - (delta - g_lse)) — the kernel
     runs unchanged on an adjusted delta."""
     B, H, L, Dh = q.shape
-    if pltpu is None:  # pragma: no cover — CPU wheels without pallas-TPU
-        (out_, lse_), vjp = jax.vjp(
-            lambda q_, k_, v_: _xla_forward(q_, k_, v_, pad_mask, causal),
-            q, k, v)
-        gl = (jnp.zeros_like(lse_) if g_lse is None
-              else g_lse[:, :lse_.shape[1]].astype(lse_.dtype))
-        return vjp((g, gl))
     sm_scale = Dh ** -0.5
     block_q, block_k = _block_sizes(L, block_q, block_k)
     qp, kp, vp, mask8, Lq, Lk, D = _prep(q, k, v, pad_mask, block_q, block_k)
@@ -583,7 +553,7 @@ def _flash_backward(q, k, v, pad_mask, o, lse, g, causal, block_q, block_k,
             scratch = [_VMEM((block_k, D), jnp.float32),
                        _VMEM((block_k, D), jnp.float32)]
         dq_part, dk_c, dv_c = _grid_call(
-            kernel, jnp.asarray(steps_np), (bh, steps_np.shape[0]), in_specs,
+            BWD_KERNEL_NAME, kernel, jnp.asarray(steps_np), (bh, steps_np.shape[0]), in_specs,
             out_specs=[dq_spec, k_spec, k_spec],
             out_shape=[
                 dq_shape,

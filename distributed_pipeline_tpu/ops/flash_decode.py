@@ -14,10 +14,11 @@ schedule (the compressed-step-table trick from ops/flash_attention.py).
 
 Step table (computed ON DEVICE inside the jitted decode step — positions
 and block tables are data, so the table costs no recompile and no host
-sync): a static worst-case ``[B * pages_per_slot, 7]`` int32 array of
-``(slot, page_id, first, last, needs_mask, page_base, pos)`` rows. Live
-rows cover exactly each slot's ``pos // page_size + 1`` live pages in
-slot-major order (a contiguous accumulation run per slot); dead rows are
+sync): a static worst-case ``[7, B * pages_per_slot]`` int32 array, one
+column of ``(slot, page_id, first, last, needs_mask, page_base, pos)`` per
+step (steps along the minor dimension: SMEM pads that one to 128 words).
+Live steps cover exactly each slot's ``pos // page_size + 1`` live pages in
+slot-major order (a contiguous accumulation run per slot); dead steps are
 packed at the tail and route to the trash page and a zero query row, so on
 TPU consecutive dead steps re-DMA nothing (identical index-map output) and
 the run's first/last flags make them self-contained no-ops. ``needs_mask``
@@ -42,10 +43,10 @@ this kernel later):
   ``(8, 128)`` f32 layout; pools that don't (small models) dispatch to the
   XLA path under ``impl="auto"`` — see :func:`resolve_decode_impl`;
 * int8 pools (serving/paged_kv.py ``write_*_kv_q8``) ride the SAME schedule:
-  each page's fp32 scale is bitcast to int32 and appended to its step row
-  (columns 7..8, K and V scales), so the scale arrives with the scalar
+  each page's fp32 scale is bitcast to int32 and appended to its step
+  (fields 7..8, K and V scales), so the scale arrives with the scalar
   prefetch and the kernel dequantizes the DMA'd page in VMEM
-  (``page.astype(f32) * scale``) before the dot — no second gather, no
+  (``page.astype(f32) * scale``) before the products — no second gather, no
   extra HBM traffic beyond the 8-byte-per-page scale pair. On real TPU
   int8 page blocks want ``(32, 128)`` tiles; small-model pools again fall
   back to the XLA arm, which dequantizes after ``gather_kv``.
@@ -56,6 +57,15 @@ mode off-TPU — CPU tests exercise the real kernel logic); ``"xla"`` forces
 the gather path. Numerics: the kernel's online softmax reassociates the
 sum, so outputs match the XLA path to float tolerance, not bitwise — the
 serving contract is greedy-token identity (tests/test_kernels.py).
+
+Chip status (PR 22): the kernel, its int8 form and the span form COMPILE
+for a described v5e (tests/test_chip_compile.py) after three repairs that
+interpret mode could not ask for — the two head-batched products moved
+from ``dot_general`` (no free lhs dimension: refused by Mosaic) to
+multiply-and-reduce on the VPU, the int8 scale's bitcast works on a
+splatted vector, and the step table turned field-major. No shipped preset
+selects it (``Dh = 64`` resolves to the XLA arm). Speed against that arm:
+not measured.
 
 HBM accounting: :func:`decode_hbm_bytes` reproduces the schedule's DMA
 traffic exactly (blocks x steps, consecutive-identical reuse deducted) —
@@ -74,17 +84,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific bits are unavailable in some CPU-only wheels
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
+
+_VMEM = pltpu.VMEM
 
 __all__ = ["flash_decode", "paged_decode_attention", "paged_span_attention",
            "resolve_decode_impl", "decode_hbm_bytes", "xla_paged_decode",
            "xla_paged_span_decode"]
 
+KERNEL_NAME = "flash_decode"  # stable: traces and HLO text find it
 NEG_INF = -1e9
 LANES = 128
 TRASH_PAGE = 0  # mirrors serving/paged_kv.py (leaf module, no import cycle)
@@ -103,7 +111,7 @@ def resolve_decode_impl(impl: str, page_shape=None) -> str:
         return impl
     if impl != "auto":
         raise ValueError(f"decode impl must be auto|pallas|xla, got {impl!r}")
-    if pltpu is None or jax.default_backend() != "tpu":
+    if jax.default_backend() != "tpu":
         return "xla"
     if page_shape is not None:
         _, _, h, dh = page_shape
@@ -115,11 +123,14 @@ def resolve_decode_impl(impl: str, page_shape=None) -> str:
 def _build_steps(block_table: jnp.ndarray, positions: jnp.ndarray,
                  page_size: int, n_slots: int, scales_k=None,
                  scales_v=None) -> jnp.ndarray:
-    """Traced ``[B * n_pages, 7]`` step table (module docstring): live rows
-    packed first, slot-major; dead rows route to (slot=B, trash page,
-    pos=-1) so they mask to zero and re-DMA nothing on TPU. With int8
-    scales the table widens to 9 columns: each row carries its page's K and
-    V scales as bitcast int32, gathered through the block table."""
+    """Traced ``[7, B * n_pages]`` step table (module docstring), one
+    COLUMN per step — SMEM pads the minor dimension to 128 words, so the
+    steps have to lie along it (step-major, a 4-link span over 8 slots of
+    64 pages asked for the whole 1 MiB of SMEM and was refused): live
+    steps packed first, slot-major; dead steps route to (slot=B, trash
+    page, pos=-1) so they mask to zero and re-DMA nothing on TPU. With
+    int8 scales the table grows to 9 fields: each step carries its page's
+    K and V scales as bitcast int32, gathered through the block table."""
     B, n = block_table.shape
     pos = positions.astype(jnp.int32)
     n_live = jnp.minimum(pos // page_size + 1, n)              # [B]
@@ -149,60 +160,66 @@ def _build_steps(block_table: jnp.ndarray, positions: jnp.ndarray,
             bits = jax.lax.bitcast_convert_type(
                 sc.astype(jnp.float32), jnp.int32)[block_table]   # [B, n]
             cols.append(pack(bits, 0))  # dead rows: scale 0 -> dequant to 0
-    return jnp.stack(cols, axis=1)
+    return jnp.stack(cols, axis=0)
 
 
 def _decode_kernel(steps_ref, q_ref, k_ref, v_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, scale: float, quant: bool):
     t = pl.program_id(0)
 
-    @pl.when(steps_ref[t, 2] == 1)
+    @pl.when(steps_ref[2, t] == 1)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0]                    # [H, Dh]
-    k = k_ref[0]                    # [page_size, H, Dh]
-    v = v_ref[0]
+    q = q_ref[0].astype(jnp.float32)        # [H, Dh]
+    k = k_ref[0].astype(jnp.float32)        # [page_size, H, Dh]
+    v = v_ref[0].astype(jnp.float32)
     if quant:  # int8 page + per-page scale riding the step table (bitcast)
-        sk = jax.lax.bitcast_convert_type(steps_ref[t, 7], jnp.float32)
-        sv = jax.lax.bitcast_convert_type(steps_ref[t, 8], jnp.float32)
-        k = k.astype(jnp.float32) * sk
-        v = v.astype(jnp.float32) * sv
-    # s[h, t] = q[h, :] . k[t, h, :]: head-batched single-query scores
-    s = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) * scale      # [H, page_size]
+        # (bitcast wants a vector on the chip: splat the SMEM word first)
+        def scale_of(col):
+            word = jnp.full((8, LANES), steps_ref[col, t], jnp.int32)
+            return jax.lax.bitcast_convert_type(word, jnp.float32)[:1, :1]
+
+        k = k * scale_of(7)
+        v = v * scale_of(8)
+    # s[t, h] = q[h, :] . k[t, h, :] — one query row per head has no free
+    # lhs dimension, and Mosaic takes no dot_general without one ("failed
+    # to parse TPU_DotDimensionNumbersAttr parameter
+    # 'lhs_non_contracting_dims'"). So both products are VPU work in the
+    # page's own [page_size, H, Dh] layout: multiply, then reduce over the
+    # lanes (scores) or over the page rows (output). A decode step reads
+    # every K/V byte once for one multiply-add each — it is bound by that
+    # read, not by the arithmetic the MXU would have saved.
+    s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale  # [ps, H, 1]
 
     def _fold(apply_mask):
         sl = s
         if apply_mask:
-            tglob = steps_ref[t, 5] + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            sl = jnp.where(tglob <= steps_ref[t, 6], sl, NEG_INF)
+            tglob = steps_ref[5, t] + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            sl = jnp.where(tglob <= steps_ref[6, t], sl, NEG_INF)
         m_prev = m_ref[:, :1]                            # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sl, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(sl, axis=0))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sl - m_new)
+        p = jnp.exp(sl - m_new[None])                    # [ps, H, 1]
         if apply_mask:  # exact zeros for masked entries (fully-dead rows
             # would otherwise softmax over the raw trash scores)
             p = jnp.where(sl > NEG_INF / 2, p, 0.0)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = alpha * acc_ref[:] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=0)
+        acc_ref[:] = alpha * acc_ref[:] + jnp.sum(p * v, axis=0)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    @pl.when(steps_ref[t, 4] == 0)
+    @pl.when(steps_ref[4, t] == 0)
     def _interior():  # fully-live page: skip the iota/compare mask
         _fold(False)
 
-    @pl.when(steps_ref[t, 4] == 1)
+    @pl.when(steps_ref[4, t] == 1)
     def _boundary():
         _fold(True)
 
-    @pl.when(steps_ref[t, 3] == 1)
+    @pl.when(steps_ref[3, t] == 1)
     def _finalize():
         # Dead runs have l == 0 exactly; emit zeros, not NaNs.
         l = l_ref[:, :1]
@@ -218,9 +235,6 @@ def flash_decode(q: jnp.ndarray, pages_k: jnp.ndarray, pages_v: jnp.ndarray,
     through its block table; everything later is skipped at schedule level.
     ``scales_k``/``scales_v`` ([P] fp32) flag an int8 pool: the kernel
     dequantizes each DMA'd page with its scale from the step table."""
-    if pltpu is None:  # pragma: no cover — CPU wheels without pallas-TPU
-        return xla_paged_decode(q, pages_k, pages_v, block_table, positions,
-                                scales_k, scales_v)
     B, H, Dh = q.shape
     _, page_size, _, _ = pages_k.shape
     quant = scales_k is not None
@@ -228,19 +242,19 @@ def flash_decode(q: jnp.ndarray, pages_k: jnp.ndarray, pages_v: jnp.ndarray,
                          scales_k, scales_v)
     # Row B is the dead-step sink: zero query in, garbage-free zeros out.
     qp = jnp.concatenate([q, jnp.zeros((1, H, Dh), q.dtype)], axis=0)
-    n_steps = steps.shape[0]
+    n_steps = steps.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_steps,),
         in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda t, s: (s[t, 0], 0, 0),
+            pl.BlockSpec((1, H, Dh), lambda t, s: (s[0, t], 0, 0),
                          memory_space=_VMEM),
             pl.BlockSpec((1, page_size, H, Dh),
-                         lambda t, s: (s[t, 1], 0, 0, 0), memory_space=_VMEM),
+                         lambda t, s: (s[1, t], 0, 0, 0), memory_space=_VMEM),
             pl.BlockSpec((1, page_size, H, Dh),
-                         lambda t, s: (s[t, 1], 0, 0, 0), memory_space=_VMEM),
+                         lambda t, s: (s[1, t], 0, 0, 0), memory_space=_VMEM),
         ],
-        out_specs=pl.BlockSpec((1, H, Dh), lambda t, s: (s[t, 0], 0, 0),
+        out_specs=pl.BlockSpec((1, H, Dh), lambda t, s: (s[0, t], 0, 0),
                                memory_space=_VMEM),
         scratch_shapes=[
             _VMEM((H, Dh), jnp.float32),      # acc
@@ -251,6 +265,7 @@ def flash_decode(q: jnp.ndarray, pages_k: jnp.ndarray, pages_v: jnp.ndarray,
         functools.partial(_decode_kernel, scale=Dh ** -0.5, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B + 1, H, Dh), q.dtype),
+        name=KERNEL_NAME,
         interpret=_interpret())(steps, qp, pages_k, pages_v)
     return out[:B]
 

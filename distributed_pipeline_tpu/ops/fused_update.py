@@ -23,10 +23,11 @@ increment identically), so checkpoints, ZeRO-1 shardings and restore are
 oblivious to which path wrote them.
 
 ZeRO-1 composition: the caller (trainer) runs this inside the jitted train
-step with mu/nu/EMA constrained to the zshard layout (parallel/partition
-``zero1_shardings``) and out_shardings pinned — the update is elementwise,
-so GSPMD partitions each leaf's kernel over the data axis and every shard
-touches only its own slice; no layout changes here.
+step with mu/nu/EMA in the zshard layout (parallel/partition
+``zero1_shardings``) and out_shardings pinned. GSPMD cannot partition a
+Mosaic kernel, so on a mesh of more than one device each leaf's kernel is
+wrapped in ``shard_map`` on that layout (:func:`_leaf_update_on_mesh`) and
+every shard touches only its own slice.
 
 Off-TPU the kernel runs in Pallas interpreter mode (real kernel logic on
 CPU, tier-1 testable). HBM accounting for the bench leg:
@@ -46,12 +47,9 @@ import jax.numpy as jnp
 import optax
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific bits are unavailable in some CPU-only wheels
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
+
+_VMEM = pltpu.VMEM
 
 __all__ = ["fused_adamw_ema", "update_hbm_bytes", "resolve_fused_update"]
 
@@ -63,10 +61,10 @@ def resolve_fused_update(val: Any) -> bool:
     """Resolve the tri-state ``--fused_update`` flag to a concrete bool.
 
     ``"auto"`` (the default since ISSUE 20) means "fused on TPU, staged
-    optax elsewhere": on TPU the one-pass kernel is the measured win
-    (bench leg gpt2-train-fused-update), while off-TPU interpreter mode
-    is pure overhead. Bools and the usual true/false spellings still
-    parse so existing argv and call sites keep working.
+    optax elsewhere": off-TPU interpreter mode is pure overhead; on TPU
+    the one-pass kernel against the optax chain is not measured. Bools
+    and the usual true/false spellings still parse so existing argv and
+    call sites keep working.
     """
     if isinstance(val, bool):
         return val
@@ -79,6 +77,7 @@ def resolve_fused_update(val: Any) -> bool:
         return False
     raise ValueError(f"fused_update must be auto/true/false, got {val!r}")
 
+KERNEL_NAME = "fused_adamw_ema"  # stable: traces and HLO text find it
 LANES = 128
 _BLOCK_ROWS = 256  # rows per grid step: 256x128 f32 = 128 KiB per operand
 
@@ -117,25 +116,10 @@ def _update_kernel(steps_ref, scal_ref, p_ref, g_ref, mu_ref, nu_ref,
         e_out[i][...] = e_in[i][...] * r + pn * (1.0 - r)
 
 
-def _xla_leaf_update(p, g, mu, nu, emas, scalars, b1, b2, eps, wd, rates):
-    """Same math as the kernel, flat jax ops — the fallback for wheels
-    without pallas-TPU grid support (pltpu import failed)."""
-    step_size, bc1, bc2 = scalars[0], scalars[1], scalars[2]
-    mu2 = (1 - b1) * g + b1 * mu
-    nu2 = (1 - b2) * (g * g) + b2 * nu
-    u = (mu2 / bc1) / (jnp.sqrt(nu2 / bc2) + eps)
-    u = step_size * (u + wd * p)
-    pn = (p + u).astype(p.dtype)
-    return pn, mu2, nu2, [e * r + pn * (1.0 - r) for e, r in zip(emas, rates)]
-
-
 def _leaf_update(p, g, mu, nu, emas: List[jnp.ndarray], scalars,
                  b1: float, b2: float, eps: float, wd: float,
                  rates: Tuple[float, ...]):
     """Run one leaf through the kernel: flatten -> [rows, LANES] blocks."""
-    if pltpu is None:  # pragma: no cover — CPU wheels without pallas-TPU
-        return _xla_leaf_update(p, g, mu, nu, emas, scalars,
-                                b1, b2, eps, wd, rates)
     shape, dt = p.shape, p.dtype
     n = p.size
     rows = -(-n // LANES)
@@ -162,7 +146,8 @@ def _leaf_update(p, g, mu, nu, emas: List[jnp.ndarray], scalars,
                           rates=rates),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((rows_p, LANES), dt)] * n_out,
-        interpret=_interpret())(jnp.zeros((1, 1), jnp.int32), svec, *ins)
+        name=KERNEL_NAME, interpret=_interpret())(
+            jnp.zeros((1, 1), jnp.int32), svec, *ins)
 
     def back(x):
         return x.reshape(-1)[:n].reshape(shape)
@@ -171,10 +156,35 @@ def _leaf_update(p, g, mu, nu, emas: List[jnp.ndarray], scalars,
         [back(o) for o in outs[3:]]
 
 
+def _leaf_update_on_mesh(mesh, spec, p, g, mu, nu, emas, scalars,
+                         b1, b2, eps, wd, rates):
+    """:func:`_leaf_update` on a mesh of more than one device. Mosaic
+    kernels cannot be partitioned by GSPMD ("wrap the call in a
+    shard_map"), so this is that shard_map: the kernel is elementwise, so
+    every operand and result of the leaf takes the leaf's own ``spec`` and
+    each device updates its shard; the scalars are replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..utils.jax_compat import shard_map
+
+    n_r = len(emas)
+
+    def body(scal, p_, g_, mu_, nu_, *es):
+        a, m, v, eo = _leaf_update(p_, g_, mu_, nu_, list(es), scal,
+                                   b1, b2, eps, wd, rates)
+        return (a, m, v, *eo)
+
+    outs = shard_map(body, mesh=mesh, in_specs=(P(),) + (spec,) * (4 + n_r),
+                     out_specs=(spec,) * (3 + n_r),
+                     check_vma=False)(scalars, p, g, mu, nu, *emas)
+    return outs[0], outs[1], outs[2], list(outs[3:])
+
+
 def fused_adamw_ema(params: Any, grads: Any, opt_state: Any,
                     ema: Dict[str, Any], *, lr_fn, b1: float = 0.9,
                     b2: float = 0.999, eps: float = 1e-8,
-                    weight_decay: float = 0.0) -> Tuple[Any, Any, Dict]:
+                    weight_decay: float = 0.0, mesh=None,
+                    specs: Any = None) -> Tuple[Any, Any, Dict]:
     """Drop-in replacement for the trainer's staged update:
     ``opt.update -> apply_updates -> update_ema per rate`` in one kernel
     pass per leaf.
@@ -185,7 +195,20 @@ def fused_adamw_ema(params: Any, grads: Any, opt_state: Any,
     identically-incremented counts. ``lr_fn`` maps the (pre-increment) step
     count to the learning rate — the trainer passes ``_lr_at`` or a
     constant, matching what it handed optax. ``ema`` maps rate strings to
-    params-shaped trees."""
+    params-shaped trees.
+
+    On a ``mesh`` of more than one device pass ``specs``, a params-shaped
+    tree of PartitionSpecs — the layout of the weight-update state (the
+    param layout, or the ZeRO-1 layout when that is finer): each leaf's
+    kernel then runs under ``shard_map`` on that spec, params and grads
+    are sliced down to it on the way in, and the caller's sharding
+    constraint gathers the new params back (the ZeRO-1 pattern)."""
+    on_mesh = mesh is not None and mesh.size > 1
+    if on_mesh and specs is None:
+        raise ValueError(
+            "fused_adamw_ema on a mesh of more than one device needs the "
+            "leaves' PartitionSpecs (specs=): a Mosaic kernel cannot be "
+            "partitioned automatically")
     adam = opt_state[0]
     count_inc = optax.safe_int32_increment(adam.count)
     # The same expressions optax evaluates per step (bias_correction /
@@ -203,15 +226,17 @@ def fused_adamw_ema(params: Any, grads: Any, opt_state: Any,
     leaves_mu = jax.tree_util.tree_leaves(adam.mu)
     leaves_nu = jax.tree_util.tree_leaves(adam.nu)
     leaves_e = [jax.tree_util.tree_leaves(ema[r]) for r in rate_keys]
+    leaves_s = tdef.flatten_up_to(specs) if on_mesh else None
     pn: List[jnp.ndarray] = []
     mun: List[jnp.ndarray] = []
     nun: List[jnp.ndarray] = []
     en: List[List[jnp.ndarray]] = [[] for _ in rate_keys]
     for i in range(len(leaves_p)):
-        a, m, v, es = _leaf_update(
-            leaves_p[i], leaves_g[i], leaves_mu[i], leaves_nu[i],
-            [leaves_e[j][i] for j in range(len(rate_keys))],
-            scalars, b1, b2, eps, weight_decay, rates)
+        leaf = (leaves_p[i], leaves_g[i], leaves_mu[i], leaves_nu[i],
+                [leaves_e[j][i] for j in range(len(rate_keys))],
+                scalars, b1, b2, eps, weight_decay, rates)
+        a, m, v, es = (_leaf_update_on_mesh(mesh, leaves_s[i], *leaf)
+                       if on_mesh else _leaf_update(*leaf))
         pn.append(a)
         mun.append(m)
         nun.append(v)

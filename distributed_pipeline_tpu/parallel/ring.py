@@ -52,6 +52,22 @@ def current_mesh():
     return None if m.empty else m
 
 
+def batch_and_head_axes(mesh, B: int, H: int):
+    """Mesh axes a [B, H, ...] attention operand can split over inside a
+    ``shard_map``: batch over the data-like axes and heads over ``tensor``,
+    each only where the axis size divides (a B=1 init trace must still work
+    on a dp>1 mesh — axes that don't divide fall back to replication).
+    Returns PartitionSpec entries ``(batch, heads)``."""
+    batch_axes, rem = [], B
+    for a in ("data", "fsdp", "expert"):  # mirror mesh.batch_spec
+        if mesh.shape.get(a, 1) > 1 and rem % mesh.shape[a] == 0:
+            batch_axes.append(a)
+            rem //= mesh.shape[a]
+    tp = mesh.shape.get("tensor", 1)
+    return (tuple(batch_axes) or None,
+            "tensor" if tp > 1 and H % tp == 0 else None)
+
+
 def _dense_block_attn(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       kmask: Optional[jnp.ndarray], causal: bool,
                       q_off: jnp.ndarray, k_off: jnp.ndarray,
@@ -179,17 +195,7 @@ def ring_attention_sharded(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if L % sp:
         raise ValueError(f"sequence length {L} not divisible by the "
                          f"sequence mesh axis ({sp})")
-    # Shard batch/heads only over axes whose size divides them (a B=1 init
-    # trace must still work on a dp>1 mesh — axes that don't divide fall
-    # back to replication).
-    batch_axes, rem = [], B
-    for a in ("data", "fsdp", "expert"):  # mirror mesh.batch_spec
-        if mesh.shape.get(a, 1) > 1 and rem % mesh.shape[a] == 0:
-            batch_axes.append(a)
-            rem //= mesh.shape[a]
-    batch = tuple(batch_axes) or None
-    heads = ("tensor" if mesh.shape["tensor"] > 1 and H % mesh.shape["tensor"] == 0
-             else None)
+    batch, heads = batch_and_head_axes(mesh, B, H)
     qkv_spec = P(batch, heads, "sequence", None)
     mask_spec = P(batch, "sequence")
 

@@ -153,9 +153,11 @@ def _resolve_chaos_plan(settings: ServeSettings):
 def _serve_single(settings: ServeSettings) -> dict:
     import numpy as np
 
+    from ..ops.flash_decode import resolve_decode_impl
     from ..parallel import make_mesh
     from ..serving import DecodeServer
     from ..utils import logger
+    from ..utils.perf import device_summary
     from .sample import load_run
 
     if settings.trace:
@@ -276,7 +278,17 @@ def _serve_single(settings: ServeSettings) -> dict:
         "page_size": settings.page_size,
         "traffic": settings.traffic,
         "compile_time_s": round(server.compile_time_s, 3),
+        "compile_s": {k: round(v, 3) for k, v in
+                      server.engine.compile_times.items()},
         "wall_s": round(wall_s, 2),
+        # what ran where: the device as jax reports it, and the decode
+        # attention arm the engine's pool geometry resolved to
+        "device": device_summary(),
+        "decode_impl": resolve_decode_impl(
+            settings.decode_impl,
+            (server.mgr.num_pages, settings.page_size,
+             wl.model.num_heads,
+             wl.model.hidden_size // wl.model.num_heads)),
     }
     if settings.spec_tokens > 0:
         # every fetched token is target-verified, so the accepted rate IS
@@ -1166,14 +1178,20 @@ def main(ns: argparse.Namespace) -> dict:
     # survive whatever cwd the replica subprocess starts in — normalize
     # once here so every downstream consumer sees an absolute path
     settings.checkpoint_path = os.path.abspath(settings.checkpoint_path)
+    if settings.replicas > 0 and not settings.fleet_worker_dir:
+        return _fleet_main(settings)  # jax-free parent: compiles nothing
+    # every main below compiles: one cache for all of them (and for
+    # training), so a server start after the first does not compile cold
+    from ..utils.perf import enable_persistent_compilation_cache
+    cache_dir = enable_persistent_compilation_cache()
+    print(f"# serve: persistent compilation cache: {cache_dir}",
+          file=sys.stderr, flush=True)
     if settings.fleet_worker_dir:
         if settings.disagg_role == "prefill":
             return _disagg_prefill_main(settings)
         if settings.disagg_role == "decode":
             return _disagg_decode_main(settings)
         return _fleet_worker_main(settings)
-    if settings.replicas > 0:
-        return _fleet_main(settings)
     return _serve_single(settings)
 
 

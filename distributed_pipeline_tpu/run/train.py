@@ -272,7 +272,6 @@ def _mpmd_main(args: TrainSettings) -> dict:
         ckpt_path, config,
         max_restarts=args.mpmd_max_restarts,
         hang_timeout_s=args.mpmd_hang_timeout_s,
-        worker_platform=os.environ.get("JAX_PLATFORMS", "cpu") or "cpu",
         trace_armed=True if args.trace else None)
     try:
         result = driver.run(args.learning_steps)
@@ -333,12 +332,13 @@ def main(namespace: argparse.Namespace) -> None:
                      comm=logger.distributed_mean_comm())
     seed_all(args.seed)
 
-    # Persistent compilation cache BEFORE anything compiles: a restarted or
-    # resumed run (same run dir) then pays a cache lookup instead of the
-    # full XLA compile — compile_time_s in the logs shows the difference.
+    # Persistent compilation cache BEFORE anything compiles: a restarted,
+    # resumed or simply repeated run then pays a cache lookup instead of
+    # the full XLA compile — compile_time_s in the logs shows the
+    # difference.
     from ..utils.perf import enable_persistent_compilation_cache
     cache_dir = enable_persistent_compilation_cache(
-        args.compilation_cache_dir, run_dir=ckpt_path)
+        args.compilation_cache_dir)
     if cache_dir:
         logger.info(f"persistent compilation cache: {cache_dir}")
 
@@ -398,6 +398,8 @@ def main(namespace: argparse.Namespace) -> None:
                or bool(os.environ.get(FORCE_DEVICES_ENV)))
     mesh = build_mesh(args, elastic=elastic)
     logger.info(local_mesh_info(mesh))
+    from ..utils.perf import device_summary
+    logger.info(f"devices: {json.dumps(device_summary())}")
 
     if rank == 0:  # args snapshot for reproducibility (train.py:82-87)
         with open(os.path.join(ckpt_path, "training_args.json"), "w") as f:
@@ -554,6 +556,9 @@ def main(namespace: argparse.Namespace) -> None:
     n_m = loop.n_params / 1e6
     logger.info(f"the parameter count is {loop.n_params} ({n_m:.1f}M)")
     loop.run_loop()
+    # the exit flush's metrics (the last dispatch_lag steps' losses) and
+    # the goodput summary were logged after the loop's last dump
+    logger.dumpkvs()
 
 
 if __name__ == "__main__":

@@ -145,6 +145,7 @@ class DecodeEngine:
         self._guard = transfer_guard
         self.params = params
         self.compile_time_s = 0.0
+        self.compile_times: dict = {}  # per program: serve_prefill, ...
         self._on_compile = on_compile
 
         s = decode_slots
@@ -342,6 +343,14 @@ class DecodeEngine:
         return out
 
     def _put(self, x: np.ndarray) -> jax.Array:
+        # COPY first: callers hand over host mirrors they keep mutating in
+        # place (the scheduler's block tables and active mask: release ->
+        # TRASH_PAGE) while earlier dispatches are still in flight, and
+        # the CPU backend's device_put may alias a suitably aligned numpy
+        # buffer instead of copying it. In-flight steps then read the
+        # mutated table: the last tokens of a request went stale,
+        # depending on page geometry, dispatch lag and timing.
+        x = np.array(x)
         if self.mesh is not None:
             return jax.device_put(x, replicated(self.mesh))
         return jax.device_put(x)
@@ -362,6 +371,7 @@ class DecodeEngine:
 
     def _note_compile(self, name: str, seconds: float) -> None:
         self.compile_time_s += seconds
+        self.compile_times[name] = self.compile_times.get(name, 0.0) + seconds
         if self._on_compile is not None:
             self._on_compile(name, seconds)
 

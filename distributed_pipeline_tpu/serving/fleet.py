@@ -395,7 +395,7 @@ class ServingFleet:
                  restart_backoff_s: float = 0.25,
                  restart_backoff_max_s: float = 5.0,
                  monitor_interval: float = 0.05,
-                 replica_platform: str = "cpu",
+                 replica_platform: Optional[str] = None,
                  transport: str = "file",
                  launch_fn: Optional[Callable[..., int]] = None) -> None:
         if n_replicas < 1:
@@ -407,12 +407,13 @@ class ServingFleet:
         self.worker_modname = worker_modname
         self.worker_argv = list(worker_argv)
         self.devices_per_proc = devices_per_proc
-        # Replica backend pin: "cpu" (the dev/test-ring default — forced
-        # fake devices, remote plugin disabled), a real platform name, or
-        # "" to inherit the environment (how TPU replicas run: the old
-        # unconditional launcher cpu pin made them impossible —
-        # run/serve.py resolves --replica_platform auto to the parent's
-        # platform before constructing the fleet).
+        # Replica backend pin: None = the parent's JAX_PLATFORMS if set,
+        # else no pin (parallel/launcher.py::inherited_platform); "cpu"
+        # also forces virtual devices. Replicas are processes, and a
+        # chip belongs to one process: several need an explicit cpu.
+        from ..parallel.launcher import require_workers_fit_host
+        require_workers_fit_host(n_replicas, replica_platform,
+                                 f"{n_replicas} fleet replicas")
         self.replica_platform = replica_platform
         self.hang_timeout_s = hang_timeout_s
         self.hang_startup_timeout_s = hang_startup_timeout_s

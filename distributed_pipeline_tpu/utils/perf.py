@@ -23,6 +23,7 @@ __all__ = ["device_peak_flops", "transformer_train_flops_per_token",
            "transformer_decode_flops_per_token", "active_param_count",
            "StepTimer", "mfu", "enable_persistent_compilation_cache",
            "timed_lower_compile", "AOTStep", "RecompileMonitor",
+           "device_summary", "tpu_kernel_census",
            "SanitizeReport", "SANITIZE_REPORT_NAME",
            "StallBreakdown", "EventStats", "GoodputTracker",
            "tree_bytes", "tree_bytes_per_replica", "peak_live_bytes"]
@@ -46,9 +47,10 @@ def device_peak_flops(device: Optional[jax.Device] = None) -> float:
     for key, flops in _PEAK_FLOPS:
         if key in kind:
             return flops
-    if d.platform == "tpu":  # unknown TPU generation: assume v4-class
-        return 275e12
-    return 1e11
+    # a device that is not in the table is an error, not a default: an
+    # MFU against another chip's peak is a wrong number that looks right
+    raise ValueError(f"no peak FLOP/s known for device kind {kind!r}: "
+                     f"add it to utils/perf.py::_PEAK_FLOPS with its source")
 
 
 def transformer_train_flops_per_token(n_params: int, n_layers: int,
@@ -148,61 +150,72 @@ def peak_live_bytes() -> int:
     return total
 
 
-def enable_persistent_compilation_cache(flag: str = "auto",
-                                        run_dir: str = "") -> str:
+# The one place a compile cache lives when nobody says otherwise: a fixed,
+# git-ignored directory in the checkout. The path is part of the cache key,
+# so a directory that moves (a run dir, a temp name, a pid, the clock)
+# never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".compile_cache")
+
+
+def enable_persistent_compilation_cache(flag: str = "auto") -> str:
     """Turn on JAX's on-disk compilation cache and return the directory
-    (\"\" = disabled).
+    (\"\" = disabled). The ONE owner of where the cache lives — train,
+    serve, bench and the launcher's workers all resolve it here:
 
-    Compile time is itself a hot path: a cold bench run pays a full XLA
-    compile per leg, and a restarted/resumed elastic run pays the whole
-    model compile again before its first step. Pointing
-    ``jax_compilation_cache_dir`` at a stable directory makes every one of
-    those a cache hit (arxiv 2204.06514 treats compile/dispatch setup as a
-    first-class throughput concern at scale; so do we).
+    * ``"off"`` / ``"none"`` / ``"0"`` — disabled in this process;
+    * ``JAX_COMPILATION_CACHE_DIR`` set — that directory, whatever the
+      flag says: a cache placed from outside is used and no other is set;
+    * ``"auto"`` / ``""`` — :data:`DEFAULT_COMPILE_CACHE_DIR`, the same
+      path for every run, server start and worker of this checkout;
+    * anything else — an explicit directory.
 
-    ``flag`` semantics (the ``--compilation_cache_dir`` contract):
-
-    * ``"off"`` / ``"none"`` / ``"0"`` — disabled;
-    * ``"auto"`` / ``""`` — ``<run_dir>/compile_cache`` (restarts and
-      resumes of the same run share it); disabled if no run dir is known;
-    * anything else — an explicit directory, shareable across runs.
-
-    The min-compile-time/entry-size gates are zeroed so the cache works for
-    small CPU graphs too (tests, dev rings). The resolved dir is exported as
-    ``JAX_COMPILATION_CACHE_DIR`` so spawned worker processes (the
-    launcher's dev ring) inherit the same cache.
+    The environment is never written: spawned workers inherit the
+    variable if the caller set it, and resolve the same fixed path if
+    not. The min-compile-time/entry-size gates are zeroed so the cache
+    works for small CPU graphs too (tests, dev rings).
 
     JAX initializes its cache object at most once per process and then
     ignores config-dir changes, so both re-pointing at a new dir and
-    ``"off"`` must go through ``compilation_cache.reset_cache()`` — without
-    it a second enable() (or a disable) in the same process is silently a
-    no-op against the first dir.
+    ``"off"`` go through ``compilation_cache.reset_cache()``.
     """
-
-    def _reset_initialized_cache() -> None:
-        try:
-            from jax._src import compilation_cache as _cc
-            if getattr(_cc, "_cache_initialized", False):
-                _cc.reset_cache()
-        except Exception:
-            pass  # private API drift: worst case is the once-only behavior
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
     if str(flag).lower() in ("off", "none", "0"):
-        _reset_initialized_cache()
+        cc.reset_cache()
         jax.config.update("jax_compilation_cache_dir", None)
-        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
         return ""
-    cache_dir = flag if flag and flag != "auto" else (
-        os.path.join(run_dir, "compile_cache") if run_dir else "")
-    if not cache_dir:
-        return ""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or (
+        flag if flag and flag != "auto" else DEFAULT_COMPILE_CACHE_DIR)
     os.makedirs(cache_dir, exist_ok=True)
-    _reset_initialized_cache()
+    cc.reset_cache()
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     return cache_dir
+
+
+def device_summary() -> Dict[str, Any]:
+    """The device a result ran on, as JAX reports it — every record that
+    carries a device metric names this beside it."""
+    d = jax.devices()
+    return {"platform": d[0].platform, "kind": d[0].device_kind,
+            "count": len(d)}
+
+
+def tpu_kernel_census(compiled: Any, names: Tuple[str, ...]) -> Dict[str, int]:
+    """How many Mosaic kernels (``tpu_custom_call``) of each stable kernel
+    name the compiled program holds — proof that a Pallas arm is IN the
+    program, not merely selected. Interpret-mode kernels (CPU) lower to
+    plain HLO and count 0."""
+    counts = {n: 0 for n in names}
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            for n in names:
+                if n in line:
+                    counts[n] += 1
+    return counts
 
 
 def timed_lower_compile(jitted: Any, *args: Any) -> Tuple[Any, float]:
@@ -281,16 +294,22 @@ class RecompileMonitor(logging.Handler):
     that tend to recompile; this monitor observes the ground truth. It
     turns on ``jax_log_compiles`` and attaches itself as a logging
     handler on the ``jax`` logger: every backend compile emits exactly
-    one ``"Compiling <name> ..."`` record (verified against this image's
-    jax 0.4.37 dispatch AND the AOT lower()/compile() path; persistent-
-    cache *hits* don't emit, so a warm restart legitimately counts 0).
+    one ``"Compiling <name> ..."`` record (jax 0.9.0: dispatch AND the AOT
+    lower()/compile() path; the record is written before the persistent
+    cache is consulted, so a cache *hit* counts like a compile and the
+    gauge does not depend on how warm the cache is).
     A steady-state training loop should stop counting after its step
     functions are built — growth after that is a silent retrace burning
     the accelerator.
 
     Use as a context manager or install()/uninstall(). ``count`` is the
     total since install; ``last`` keeps the most recent compile's name
-    line for diagnostics."""
+    line for diagnostics. Compiles inside a :meth:`not_counting` block go
+    to ``uncounted`` instead: the gauge is for retraces of the program's
+    own step functions, and a library the program calls between steps may
+    build small programs of its own (orbax slices each sharded array with
+    a jitted ``slice`` the first time it saves it — 22 of them in a
+    3-step run on two devices once read as "steady recompiles")."""
 
     _MARKER = "Compiling "
     _MAX_SITES = 16
@@ -298,10 +317,22 @@ class RecompileMonitor(logging.Handler):
     def __init__(self, capture_sites: bool = False) -> None:
         super().__init__(level=logging.NOTSET)
         self.count = 0
+        self.uncounted = 0
+        self._paused = 0
         self.last: str = ""
         self.sites: List[Dict[str, Any]] = []
         self._capture_sites = capture_sites
         self._prev_flag: Optional[bool] = None
+
+    @contextlib.contextmanager
+    def not_counting(self):
+        """Book compiles inside the block to ``uncounted`` (class
+        docstring)."""
+        self._paused += 1
+        try:
+            yield
+        finally:
+            self._paused -= 1
 
     def emit(self, record: logging.LogRecord) -> None:
         try:
@@ -309,6 +340,9 @@ class RecompileMonitor(logging.Handler):
         except Exception:  # pragma: no cover - malformed record
             return
         if msg.startswith(self._MARKER):
+            if self._paused:
+                self.uncounted += 1
+                return
             self.count += 1
             self.last = msg.split("\n", 1)[0][:200]
             if self._capture_sites and len(self.sites) < self._MAX_SITES:

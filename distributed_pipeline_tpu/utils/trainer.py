@@ -62,8 +62,9 @@ from . import checkpoint as ckpt_lib
 from . import logger
 from .perf import AOTStep, GoodputTracker, RecompileMonitor, \
     SanitizeReport, StallBreakdown, \
-    StepTimer, device_peak_flops, mfu, peak_live_bytes, tree_bytes, \
-    tree_bytes_per_replica, transformer_train_flops_per_token
+    StepTimer, device_peak_flops, device_summary, mfu, peak_live_bytes, \
+    tpu_kernel_census, tree_bytes, tree_bytes_per_replica, \
+    transformer_train_flops_per_token
 
 __all__ = ["TrainLoop", "TrainState", "update_ema"]
 
@@ -449,7 +450,12 @@ class TrainLoop:
             lr = lr * jnp.minimum(1.0, (step + 1) / self.warmup_steps)
         return lr
 
-    def _build_state(self, resume_checkpoint: str) -> None:
+    def _plan_state(self) -> Tuple[Any, Any]:
+        """Shapes and layouts of the train state, no device work: builds
+        the optimizer and the param / weight-update / optimizer-state
+        shardings, and returns the abstract (params, opt_state). Kept
+        apart from the allocation so that the step can be lowered from
+        shapes alone — for a chip that is described and not attached."""
         wl = self.workload
         init_rng = jax.random.fold_in(self._base_rng, 0)
         abstract = jax.eval_shape(wl.init_params, init_rng)
@@ -496,6 +502,13 @@ class TrainLoop:
             self.opt, lambda _, s: s, abstract_opt, zshard,
             transform_non_params=lambda _: rep)
         self._oshard = oshard
+        return abstract_unboxed, abstract_opt
+
+    def _build_state(self, resume_checkpoint: str) -> None:
+        wl = self.workload
+        init_rng = jax.random.fold_in(self._base_rng, 0)
+        self._plan_state()
+        pshard, zshard, oshard = self._pshard, self._zshard, self._oshard
 
         with self.mesh:
             params = jax.jit(
@@ -598,6 +611,8 @@ class TrainLoop:
         pshard = self._pshard
         base_rng = self._base_rng
         lr_at = self._lr_at
+        # the weight-update layout the fused kernel shard_maps over
+        zspecs = jax.tree_util.tree_map(lambda s: s.spec, self._zshard)
 
         def micro_scan(params: Any, batch: Dict[str, jnp.ndarray],
                        rng: jax.Array, with_grad: bool):
@@ -668,7 +683,8 @@ class TrainLoop:
                          else lambda _c: jnp.asarray(self.lr, jnp.float32))
                 params, opt_state, ema = fused_adamw_ema(
                     state.params, grads, state.opt_state, state.ema,
-                    lr_fn=lr_fn, weight_decay=self.weight_decay)
+                    lr_fn=lr_fn, weight_decay=self.weight_decay,
+                    mesh=self.mesh, specs=zspecs)
                 params = jax.lax.with_sharding_constraint(params, pshard)
             else:
                 updates, opt_state = opt.update(grads, state.opt_state,
@@ -837,8 +853,21 @@ class TrainLoop:
             self.stalls.add("h2d_wait_s", time.perf_counter() - t0)
             n_items = self.get_batch_length(batch)
         t0 = time.perf_counter()
-        with self.mesh, self._sanitize_guard():
-            self.state, metrics = self._train_step(self.state, prepared)
+        try:
+            with self.mesh, self._sanitize_guard():
+                self.state, metrics = self._train_step(self.state, prepared)
+        except Exception as e:
+            # --debug_nans: dispatch jit turns a NaN into FloatingPointError
+            # (after an op-by-op re-run that names the op), but an
+            # AOT-compiled call lets jax's internal error type through —
+            # not a FloatingPointError at all. The documented contract is
+            # one, so raise one, with the step. (Matched by name: the
+            # class lives in jax._src.)
+            if type(e).__name__ != "InternalFloatingPointError":
+                raise
+            raise FloatingPointError(
+                f"non-finite value in train step {self.step + 1} "
+                f"(--debug_nans): {e}") from e
         dispatched = time.perf_counter()
         self.stalls.add("dispatch_s", dispatched - t0)
         if first:
@@ -1002,6 +1031,31 @@ class TrainLoop:
         except OSError as e:  # beacon is telemetry: never fail a step
             logger.warn(f"progress beacon write failed: {e}")
 
+    def program_evidence(self) -> Dict[str, Any]:
+        """What actually ran: the device, the attention and update arms
+        this loop resolved, and — from the compiled train step's own text
+        — how many Mosaic kernels of each arm the program holds. An arm
+        that was selected but is absent from the program shows here as 0
+        (chip_smoke.py fails on it). Needs a compiled step; the
+        ``as_text()`` walk costs seconds on a real model, so it is only
+        taken in sanitize mode, once, at loop exit."""
+        from ..ops import flash_attention as fa, fused_update as fu
+        from ..ops.attention import resolve_attention_impl
+
+        out = {
+            "device": device_summary(),
+            "attention_impl": resolve_attention_impl(
+                getattr(self.workload.model, "attention_impl", "auto"),
+                self.workload.seq_len, self.mesh),
+            "fused_update": bool(self.fused_update),
+        }
+        compiled = self._train_step.compiled
+        if compiled is not None:
+            out["tpu_custom_calls"] = tpu_kernel_census(
+                compiled, (fa.FWD_KERNEL_NAME, fa.BWD_KERNEL_NAME,
+                           fu.KERNEL_NAME))
+        return out
+
     def _write_goodput_record(self) -> None:
         """Rank 0, at loop exit: the attempt's final goodput record
         (``goodput_attempt{A:03d}.json`` next to the checkpoints). The
@@ -1017,6 +1071,8 @@ class TrainLoop:
             "compile_time_s": self.compile_time_s or 0.0,
             **{k: round(v, 6) for k, v in self.goodput_summary().items()},
         }
+        if self.sanitize:
+            payload["program"] = self.program_evidence()
         try:
             import json as _json
             path = os.path.join(self.checkpoint_dir,
@@ -1275,12 +1331,19 @@ class TrainLoop:
         # metric ring so the logs at a save reflect every step saved.
         self.flush_metrics()
         # Sanitize mode keeps the transfer guard up through the save
-        # scheduling: Orbax's device->host fetch is explicit (and proven
-        # guard-clean by test), so anything that trips here is an
-        # accidental implicit transfer sneaking into the save path.
+        # scheduling, except for the one transfer a save IS: Orbax fetches
+        # with ``copy_to_host_async``, which the guard counts as implicit
+        # and refuses on a real device ("Disallowed device-to-host
+        # transfer" — on the CPU backend there is no such transfer, so no
+        # CPU test could see it). Host->device and device->device stay
+        # disallowed, so an accidental transfer sneaking into the save
+        # path still trips. The checkpoint library's own one-off slicing
+        # programs are not step retraces (RecompileMonitor.not_counting).
         t_save0 = time.perf_counter()
         t_save0_wall = time.time()
-        with self._sanitize_guard():
+        with self._sanitize_guard(), \
+                jax.transfer_guard_device_to_host("allow"), \
+                self._recompiles.not_counting():
             self._saver.save(
                 self.checkpoint_dir, self.step, self.state.params,
                 ema={r: self.state.ema[r] for r in self.ema_rates},

@@ -48,7 +48,7 @@ def bench_run(tmp_path_factory):
         "BENCH_BUDGET_S": "1",
         "BENCH_ARTIFACT": str(tmp / "legs.jsonl"),
         "BENCH_HISTORY": str(history),
-        "BENCH_CACHE_DIR": str(tmp / "cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp / "cache"),
         # glob: the headline + its satellite twins — enough legs to
         # observe ordering and skipping without a multi-minute test
         # (BENCH_ONLY without a wildcard is an EXACT match now)
@@ -234,7 +234,7 @@ def serve_bench_run(tmp_path_factory):
         "JAX_PLATFORMS": "cpu",
         "BENCH_BUDGET_S": "240",
         "BENCH_ARTIFACT": str(tmp / "legs.jsonl"),
-        "BENCH_CACHE_DIR": str(tmp / "cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp / "cache"),
         "BENCH_ONLY": "*serve-decode*",
         "BENCH_HISTORY": "",
     })
@@ -307,7 +307,7 @@ def fleet_bench_run(tmp_path_factory):
         "BENCH_BUDGET_S": "240",
         "BENCH_LEG_BUDGET_S": "240",
         "BENCH_ARTIFACT": str(tmp / "legs.jsonl"),
-        "BENCH_CACHE_DIR": str(tmp / "cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp / "cache"),
         "BENCH_ONLY": "gpt2-serve-fleet-chaos",
         "BENCH_HISTORY": "",
     })
@@ -358,7 +358,7 @@ def autoscale_bench_run(tmp_path_factory):
         "BENCH_BUDGET_S": "600",
         "BENCH_LEG_BUDGET_S": "600",
         "BENCH_ARTIFACT": str(tmp / "legs.jsonl"),
-        "BENCH_CACHE_DIR": str(tmp / "cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp / "cache"),
         "BENCH_ONLY": "gpt2-serve-autoscale",
         "BENCH_HISTORY": str(tmp / "history.jsonl"),
     })
@@ -432,7 +432,7 @@ def tune_bench_run(tmp_path_factory):
         "BENCH_BUDGET_S": "240",
         "BENCH_LEG_BUDGET_S": "240",
         "BENCH_ARTIFACT": str(tmp / "legs.jsonl"),
-        "BENCH_CACHE_DIR": str(tmp / "cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp / "cache"),
         "BENCH_ONLY": "diffuseq-base-seq128-tune",
         "BENCH_HISTORY": "",
     })
@@ -478,7 +478,7 @@ def trace_bench_run(tmp_path_factory):
         "JAX_PLATFORMS": "cpu",
         "BENCH_BUDGET_S": "240",
         "BENCH_ARTIFACT": str(tmp / "legs.jsonl"),
-        "BENCH_CACHE_DIR": str(tmp / "cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp / "cache"),
         "BENCH_ONLY": "diffuseq-base-seq128-trace",
         "BENCH_HISTORY": "",
     })
@@ -528,31 +528,64 @@ def test_compilation_cache_flag_roundtrips_through_settings():
 
 
 def test_enable_persistent_cache_resolution(tmp_path, monkeypatch):
+    """The one rule: the variable if set (and nothing else written or
+    exported), else one fixed path in the checkout — the same whatever
+    the run directory — and 'off'."""
+    from distributed_pipeline_tpu.utils import perf
+
+    fixed = str(tmp_path / "fixed")
+    monkeypatch.setattr(perf, "DEFAULT_COMPILE_CACHE_DIR", fixed)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    assert enable_persistent_compilation_cache("off") == ""
-    assert enable_persistent_compilation_cache("auto", run_dir="") == ""
     try:
-        d = enable_persistent_compilation_cache("auto",
-                                                run_dir=str(tmp_path))
-        assert d == os.path.join(str(tmp_path), "compile_cache")
-        assert os.path.isdir(d)
-        # exported so spawned workers inherit the same cache
-        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == d
+        assert enable_persistent_compilation_cache("off") == ""
+        # unset: the fixed path, identical for two different run dirs
+        # (run dirs no longer enter into it at all)
+        a = TrainSettings.from_argv(["--checkpoint_path", "/tmp/run_a"])
+        b = TrainSettings.from_argv(["--checkpoint_path", "/tmp/run_b"])
+        got = [enable_persistent_compilation_cache(s.compilation_cache_dir)
+               for s in (a, b)]
+        assert got == [fixed, fixed] and os.path.isdir(fixed)
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert "JAX_COMPILATION_CACHE_DIR" not in os.environ  # no export
+        assert perf.DEFAULT_COMPILE_CACHE_DIR == fixed
+        # the real default sits in the checkout, under a fixed name
+        assert os.path.basename(os.path.dirname(os.path.dirname(
+            perf.__file__))) == "distributed_pipeline_tpu"
+        # set: that directory, whatever the flag says, and no other made
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        os.rmdir(fixed)
+        for flag in ("auto", str(tmp_path / "flagged")):
+            assert enable_persistent_compilation_cache(flag) == outside
+        assert jax.config.jax_compilation_cache_dir == outside
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == outside
+        assert sorted(os.listdir(tmp_path)) == ["outside"]
+        # 'off' wins over the variable, and leaves it alone
+        assert enable_persistent_compilation_cache("off") == ""
+        assert jax.config.jax_compilation_cache_dir is None
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == outside
     finally:
         # "off" resets jax's once-only cache object too — leaving it
         # initialized would pin this tmp dir for the whole test process
         enable_persistent_compilation_cache("off")
 
 
-def test_cache_dir_reaches_worker_env(tmp_path):
-    env = _worker_env(1, 2, "127.0.0.1:9999", 2, run_timestamp="20260803",
-                      cache_dir=str(tmp_path))
+def test_cache_dir_reaches_worker_env(tmp_path, monkeypatch):
+    """No hand-down: a worker inherits the variable when the caller set
+    it, and is given none when not (it then resolves the fixed path)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    env = _worker_env(1, 2, "127.0.0.1:9999", 2, run_timestamp="20260803")
     assert env["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
     assert env["JAX_PROCESS_INDEX"] == "1"
     assert env["DPT_RUN_TIMESTAMP"] == "20260803"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    env = _worker_env(1, 2, "127.0.0.1:9999", 2)
+    assert "JAX_COMPILATION_CACHE_DIR" not in env
 
 
 def test_launcher_forwards_cache_env_to_ring(monkeypatch, tmp_path):
+    """The launcher neither takes nor passes a cache directory any more:
+    the ring gets no such argument and the environment is left as found."""
     from distributed_pipeline_tpu.parallel import launcher
 
     from tests._fake_ring import make_fake_ring
@@ -561,7 +594,8 @@ def test_launcher_forwards_cache_env_to_ring(monkeypatch, tmp_path):
     monkeypatch.setattr(launcher, "_run_worker_ring", fake)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert launcher.run_argv_as_distributed("mod", [], nprocs=2) == 0
-    assert fake.calls[0]["cache_dir"] == str(tmp_path)
+    assert "cache_dir" not in fake.calls[0]
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
 
 
 # ------------------------------------------------- AOT compile-time metrics
@@ -678,7 +712,7 @@ def decode_kernel_bench_run(tmp_path_factory):
         "BENCH_BUDGET_S": "600",
         "BENCH_LEG_BUDGET_S": "600",
         "BENCH_ARTIFACT": str(tmp / "legs.jsonl"),
-        "BENCH_CACHE_DIR": str(tmp / "cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp / "cache"),
         "BENCH_ONLY": "gpt2-serve-decode-kernel",
         "BENCH_HISTORY": str(tmp / "history.jsonl"),
     })
@@ -728,7 +762,7 @@ def fusedupd_bench_run(tmp_path_factory):
         "BENCH_BUDGET_S": "600",
         "BENCH_LEG_BUDGET_S": "600",
         "BENCH_ARTIFACT": str(tmp / "legs.jsonl"),
-        "BENCH_CACHE_DIR": str(tmp / "cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp / "cache"),
         "BENCH_ONLY": "diffuseq-base-seq128-fusedupd",
         "BENCH_HISTORY": str(tmp / "history.jsonl"),
     })

@@ -1,0 +1,205 @@
+"""Kernels of the main path, compiled for a chip that is described and not
+attached (``v5e:2x2``), at GPT-2-base widths — what interpret mode cannot
+show: slices off the tiling, too much fast memory, dot forms Mosaic does not
+take, and kernels GSPMD cannot partition. A compile that passes is a
+compile, not a chip run.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may hold the TPU library, and under pytest-xdist every
+worker imports every test file), and every case lives in this one file so
+that a single worker owns the library. The tests steer ``_interpret``
+themselves; the program grows no option for it. The persistent compile
+cache is off around these compiles: a chip entry cannot be read back
+without a chip and would only warn.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from distributed_pipeline_tpu.ops import attention as attention_ops
+from distributed_pipeline_tpu.ops import flash_attention as fa
+from distributed_pipeline_tpu.ops import flash_decode as fd
+from distributed_pipeline_tpu.ops import fused_update as fu
+from distributed_pipeline_tpu.parallel.mesh import AXES
+
+RATES = (0.5, 0.9, 0.99)  # the default --ema_rate
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """data=2, fsdp=2 over the described 2x2 — chip_smoke.py --chips 4's."""
+    return Mesh(np.array(topo.devices).reshape((2, 2, 1, 1, 1, 1)), AXES)
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Take the code's TPU branches although jax here sees the CPU: real
+    Mosaic lowering instead of interpret mode, 'auto' arms as on a TPU."""
+    for mod in (fa, fd, fu):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def assert_kernel(compiled, name):
+    lines = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert any(name in ln for ln in lines), (name, len(lines))
+
+
+# ------------------------------------------------------------ fused update
+
+@pytest.mark.parametrize("shape", [(768, 3072), (768,), (50257, 768)],
+                         ids=["mlp", "bias", "wte"])
+def test_fused_update_leaf_compiles(one_chip, as_on_tpu, shape):
+    def f(p, g, mu, nu, e0, e1, e2, scalars):
+        return fu._leaf_update(p, g, mu, nu, [e0, e1, e2], scalars,
+                               0.9, 0.999, 1e-8, 0.0, RATES)
+
+    x = sds(shape, jnp.float32, one_chip)
+    c = jax.jit(f).lower(*([x] * 7),
+                         sds((3,), jnp.float32, one_chip)).compile()
+    assert_kernel(c, fu.KERNEL_NAME)
+
+
+def test_fused_update_sharded_leaf_compiles_on_mesh(mesh4, as_on_tpu):
+    """As the trainer calls it on a mesh: the leaf fsdp-sharded, the kernel
+    under shard_map. Without the wrapper Mosaic refuses ("cannot be
+    automatically partitioned") — the fault that kept every multi-chip
+    run with default flags from compiling."""
+    spec = P("fsdp", None)
+    sh = NamedSharding(mesh4, spec)
+    x = sds((768, 3072), jnp.float32, sh)
+    scal = sds((3,), jnp.float32, NamedSharding(mesh4, P()))
+
+    def wrapped(p, g, mu, nu, e0, e1, e2, scalars):
+        return fu._leaf_update_on_mesh(mesh4, spec, p, g, mu, nu,
+                                       [e0, e1, e2], scalars,
+                                       0.9, 0.999, 1e-8, 0.0, RATES)
+
+    def bare(p, g, mu, nu, e0, e1, e2, scalars):
+        return fu._leaf_update(p, g, mu, nu, [e0, e1, e2], scalars,
+                               0.9, 0.999, 1e-8, 0.0, RATES)
+
+    c = jax.jit(wrapped, out_shardings=sh).lower(*([x] * 7), scal).compile()
+    assert_kernel(c, fu.KERNEL_NAME)
+    # per device: half the leaf (fsdp=2), for each of 7 operands
+    assert c.memory_analysis().argument_size_in_bytes \
+        < 0.6 * 7 * 768 * 3072 * 4
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(bare, out_shardings=sh).lower(*([x] * 7), scal).compile()
+
+
+# --------------------------------------------------------- flash attention
+
+def _flash_case(form, B):
+    q = (B, 12, 1024, 64)
+    if form == "causal":
+        return q, None, True
+    return q, (B, 1024), False  # DiffuSeq: bidirectional + pad mask
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("form", ["causal", "pad_mask"])
+def test_flash_attention_compiles(one_chip, as_on_tpu, form, grad):
+    qshape, mshape, causal = _flash_case(form, 8)
+    q = sds(qshape, jnp.bfloat16, one_chip)
+    args = [q, q, q]
+    if mshape:
+        args.append(sds(mshape, jnp.int32, one_chip))
+
+    def fwd(q_, k_, v_, m_=None):
+        return fa.flash_attention(q_, k_, v_, m_, causal)
+
+    def loss(q_, k_, v_, m_=None):
+        return fwd(q_, k_, v_, m_).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    c = jax.jit(fn).lower(*args).compile()
+    assert_kernel(c, fa.FWD_KERNEL_NAME)
+    if grad:
+        assert_kernel(c, fa.BWD_KERNEL_NAME)
+
+
+def test_flash_attention_batch_split_compiles_on_mesh(mesh4, as_on_tpu):
+    """The dispatcher's 'auto' at seq 1024 on the 2x2 mesh: the flash
+    kernel under shard_map, batch split four ways, sequence whole."""
+    sh = NamedSharding(mesh4, P(("data", "fsdp"), None, None, None))
+    q = sds((8, 12, 1024, 64), jnp.bfloat16, sh)
+
+    def auto(q_, k_, v_):
+        return attention_ops.dot_product_attention(q_, k_, v_, None, True)
+
+    with mesh4:
+        c = jax.jit(auto, out_shardings=sh).lower(q, q, q).compile()
+        assert_kernel(c, fa.FWD_KERNEL_NAME)
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            jax.jit(lambda q_, k_, v_: fa.flash_attention(
+                q_, k_, v_, None, True),
+                out_shardings=sh).lower(q, q, q).compile()
+
+
+# ------------------------------------------------------------ flash decode
+
+def _decode_args(one_chip, kv_dtype, L=0):
+    """A shape 'auto' accepts: H % 8 == 0, Dh == 128. 8 slots, 16-token
+    pages, 1024 tokens a slot."""
+    B, H, Dh, ps, n = 8, 16, 128, 16, 64
+    qshape = (B, H, L, Dh) if L else (B, H, Dh)
+    pool = sds((1 + B * n, ps, H, Dh), kv_dtype, one_chip)
+    args = [sds(qshape, jnp.bfloat16, one_chip), pool, pool,
+            sds((B, n), jnp.int32, one_chip),
+            sds((B, L) if L else (B,), jnp.int32, one_chip)]
+    if kv_dtype == jnp.int8:
+        sc = sds((1 + B * n,), jnp.float32, one_chip)
+        args += [sc, sc]
+    return args
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("span", [0, 4], ids=["decode", "span4"])
+def test_flash_decode_compiles(one_chip, as_on_tpu, kv, span):
+    kv_dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
+    args = _decode_args(one_chip, kv_dtype, span)
+    assert fd.resolve_decode_impl("auto", args[1].shape) == "pallas"
+    seam = fd.paged_span_attention if span else fd.paged_decode_attention
+
+    def f(q, pk, pv, bt, pos, sk=None, sv=None):
+        return seam(q, pk, pv, bt, pos, impl="auto", scales_k=sk,
+                    scales_v=sv)
+
+    c = jax.jit(f).lower(*args).compile()
+    assert_kernel(c, fd.KERNEL_NAME)

@@ -162,9 +162,18 @@ def test_padding_meter_arithmetic():
 def test_device_bandwidths_match_known_kinds():
     assert ledger_lib.device_bandwidths("TPU v5 lite")["hbm_bytes_per_s"] \
         == 8.1e11
-    assert ledger_lib.device_bandwidths("TPU v9x")["hbm_bytes_per_s"] \
-        == 1.2e12  # unknown TPU: v4-class
     assert ledger_lib.device_bandwidths("cpu")["ici_bytes_per_s"] == 1e10
+    # an unknown kind is an error, never another chip's numbers
+    with pytest.raises(ValueError, match="(?i)tpu v9x"):
+        ledger_lib.device_bandwidths("TPU v9x")
+    import types
+
+    from distributed_pipeline_tpu.utils import perf
+    fake = types.SimpleNamespace(device_kind="TPU v9x", platform="tpu")
+    with pytest.raises(ValueError, match="(?i)tpu v9x"):
+        perf.device_peak_flops(fake)
+    assert perf.device_peak_flops(types.SimpleNamespace(
+        device_kind="TPU v5 lite", platform="tpu")) == 197e12
 
 
 # ------------------------------------------- trainer ledger + goodput tie
