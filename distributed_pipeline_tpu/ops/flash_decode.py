@@ -63,9 +63,11 @@ for a described v5e (tests/test_chip_compile.py) after three repairs that
 interpret mode could not ask for — the two head-batched products moved
 from ``dot_general`` (no free lhs dimension: refused by Mosaic) to
 multiply-and-reduce on the VPU, the int8 scale's bitcast works on a
-splatted vector, and the step table turned field-major. No shipped preset
-selects it (``Dh = 64`` resolves to the XLA arm). Speed against that arm:
-not measured.
+splatted vector, and the step table turned field-major. It has RUN on a
+chip once, for correctness (``H16 / Dh128``, bf16 and int8, decode and
+4-link span: within 0.008 of the XLA arm on the same inputs — builder's
+run, PR 22). No shipped preset selects it (``Dh = 64`` resolves to the XLA
+arm). Speed against that arm: not measured.
 
 HBM accounting: :func:`decode_hbm_bytes` reproduces the schedule's DMA
 traffic exactly (blocks x steps, consecutive-identical reuse deducted) —
