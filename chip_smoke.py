@@ -3,6 +3,7 @@
 
     python chip_smoke.py            # one chip: train -> save -> serve
     python chip_smoke.py --chips 4  # four chips: 1-device vs data=2,fsdp=2
+    python chip_smoke.py --only decode|launcher  # one side check, one chip
 
 Default run (what the driver runs, one TPU chip), through the CLIs a user
 types, each phase a child process so each has the chip to itself and this
@@ -33,6 +34,14 @@ process never initialises a JAX backend:
 GPT-2-base steps on a one-device mesh and on a ``data=2, fsdp=2`` mesh at
 the same global batch and seed, and nothing else. Required: per-step losses
 agree within the stated tolerance; state really split over four devices.
+
+``--only decode`` runs the flash-decode kernel — which no shipped preset
+selects (``Dh = 64`` -> ``xla``) and the default run therefore never meets —
+against the XLA arm on the same inputs at a shape ``auto`` accepts.
+``--only launcher`` runs ``run.train --distributed --nprocs 1`` (the
+supervised, restartable run) twice into one directory and requires that
+the worker trained on the TPU and that the second run resumed. Each runs
+that phase and nothing else; the driver gives neither.
 
 The last line of stdout is one JSON object:
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
@@ -90,6 +99,21 @@ class Sizes:
     # a layout bug moves the loss by whole units.
     loss_tol: float
     child_timeout_s: float
+    # --only decode: (slots, heads, head_dim, page_size, pages a slot, span
+    # links), and the largest |pallas - xla| allowed as a share of the
+    # largest output: six bf16 rounding steps (2**-8 each). The builder's
+    # chip run (PR 22) read 2.3 to 2.4 steps for bf16 pools and 1.0 to 1.8
+    # for int8; one wrong page among a slot's live ones moves it by tens.
+    decode_geom: Tuple[int, int, int, int, int, int] = (8, 16, 128, 16, 64, 4)
+    decode_tol: float = 6 * 2.0 ** -8
+    # --only launcher: a thin model (the ring is what is shown, not the
+    # model) and the steps of each of the two runs
+    ring_argv: Tuple[str, ...] = (
+        "--model_family", "gpt2", "--vocab_size", "512", "--seq_len", "128",
+        "--hidden_size", "128", "--num_layers", "2", "--num_heads", "2",
+        "--dtype", "bfloat16", "--dataset", "synthetic-lm",
+        "--batch_size", "8", "--microbatch", "8")
+    ring_steps: int = 3
 
 
 REAL = Sizes(
@@ -592,6 +616,135 @@ def phase_mesh(sizes: Sizes, seed: int, n_chips: int = 4) -> Dict[str, Any]:
     return finish("mesh", res)
 
 
+def _decode_child(spec_path: str) -> None:
+    """flash-decode (``impl="pallas"``) against the XLA arm on one set of
+    random pools: bf16 and int8, single token and span."""
+    with open(spec_path) as f:
+        spec = json.load(f)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_pipeline_tpu.ops import flash_decode as fd
+    from distributed_pipeline_tpu.utils.perf import (
+        device_summary, enable_persistent_compilation_cache)
+
+    enable_persistent_compilation_cache()
+    B, H, Dh, ps, n, L = spec["geom"]
+    rng = np.random.default_rng(spec["seed"])
+    n_pool = 1 + B * n
+    table = jnp.asarray(1 + rng.permutation(B * n).reshape(B, n), jnp.int32)
+    depth = rng.integers(L, n * ps, (B,))           # live tokens a slot
+    out: Dict[str, Any] = {
+        "device": device_summary(), "cases": {},
+        "auto_resolves_to": fd.resolve_decode_impl(
+            "auto", (n_pool, ps, H, Dh))}
+    for kv in ("bf16", "int8"):
+        if kv == "int8":
+            pools = [jnp.asarray(rng.integers(-127, 128, (n_pool, ps, H, Dh)),
+                                 jnp.int8) for _ in range(2)]
+            scales = [jnp.asarray(rng.uniform(0.5, 1.5, (n_pool,)) / 127.0,
+                                  jnp.float32) for _ in range(2)]
+        else:
+            pools = [jnp.asarray(rng.standard_normal((n_pool, ps, H, Dh)),
+                                 jnp.bfloat16) for _ in range(2)]
+            scales = [None, None]
+        for span in (0, L):
+            if span:
+                q = rng.standard_normal((B, H, span, Dh))
+                pos = depth[:, None] - span + np.arange(span)[None, :]
+                seam = fd.paged_span_attention
+            else:
+                q = rng.standard_normal((B, H, Dh))
+                pos = depth - 1
+                seam = fd.paged_decode_attention
+            args = (jnp.asarray(q, jnp.bfloat16), pools[0], pools[1], table,
+                    jnp.asarray(pos, jnp.int32))
+            got = {impl: np.asarray(jax.jit(
+                lambda *a, impl=impl: seam(
+                    *a, impl=impl, scales_k=scales[0], scales_v=scales[1])
+                )(*args).astype(jnp.float32)) for impl in ("pallas", "xla")}
+            out["cases"][f"{kv}_{'span' if span else 'decode'}"] = {
+                "finite": bool(np.isfinite(got["pallas"]).all()),
+                "max_abs_diff_vs_xla": float(
+                    np.abs(got["pallas"] - got["xla"]).max()),
+                "max_abs_xla": float(np.abs(got["xla"]).max())}
+    with open(spec["result"], "w") as f:
+        json.dump(out, f)
+
+
+def phase_decode(sizes: Sizes, seed: int) -> Dict[str, Any]:
+    rc, got = run_py_child("_decode_child",
+                           {"geom": list(sizes.decode_geom), "seed": seed},
+                           "decode", sizes.child_timeout_s)
+    res: Dict[str, Any] = {"failures": []}
+    if rc != 0 or got is None:
+        res["failures"].append(
+            f"flash-decode check exited {rc}:\n"
+            + tail(os.path.join(OUT_DIR, "decode.log")))
+        return finish("decode", res)
+    res.update(got)
+    say(f"decode: device {got['device']}; geometry (slots, heads, head_dim, "
+        f"page, pages, span) {sizes.decode_geom}; 'auto' resolves to "
+        f"{got['auto_resolves_to']}; tolerance {sizes.decode_tol} of the "
+        f"largest output")
+    res["failures"] += platform_failures(got["device"])
+    for name, c in got["cases"].items():
+        say(f"decode: {name}: finite {c['finite']}, largest |pallas - xla| "
+            f"{c['max_abs_diff_vs_xla']:.6g} at outputs up to "
+            f"{c['max_abs_xla']:.4g}")
+        if not (c["finite"] and c["max_abs_diff_vs_xla"]
+                <= sizes.decode_tol * c["max_abs_xla"]):
+            res["failures"].append(
+                f"{name}: pallas differs from xla by "
+                f"{c['max_abs_diff_vs_xla']} (finite: {c['finite']})")
+    return finish("decode", res)
+
+
+def phase_launcher(sizes: Sizes, seed: int) -> Dict[str, Any]:
+    """``run.train --distributed --nprocs 1`` twice into one run directory:
+    the supervised worker must find the TPU (before PR 22 the launcher
+    pinned it to the CPU and said nothing) and the second run must resume
+    where the first stopped."""
+    run_dir = os.path.join(OUT_DIR, "ring_run")
+    res: Dict[str, Any] = {"failures": [], "attempts": []}
+    for k in (1, 2):
+        cmd = [sys.executable, "-m", f"{PACKAGE}.run.train",
+               "--distributed", "--nprocs", "1",
+               "--log_dir", os.path.join(OUT_DIR, f"ring_logs{k}"),
+               *sizes.ring_argv, "--seed", str(seed),
+               "--learning_steps", str(k * sizes.ring_steps),
+               "--save_interval", str(sizes.ring_steps),
+               "--eval_interval", "1000000", "--log_interval", "1",
+               "--sanitize", "true", "--checkpoint_path", run_dir]
+        say(f"launcher: run {k}: " + " ".join(cmd[1:]))
+        log = os.path.join(OUT_DIR, f"launcher{k}.log")
+        rc, _ = run_child(cmd, log, sizes.child_timeout_s)
+        if rc != 0:
+            res["failures"].append(f"run {k} exited {rc}:\n" + tail(log))
+            return finish("launcher", res)
+    with open(os.path.join(run_dir, "attempts.jsonl")) as f:
+        res["attempts"] = att = [json.loads(x) for x in f if x.strip()]
+    with open(os.path.join(run_dir, "goodput_attempt000.json")) as f:
+        prog = json.load(f).get("program") or {}
+    res["device"] = prog.get("device")
+    spans = [(a.get("start_step"), a.get("end_step")) for a in att]
+    say(f"launcher: worker's device {res['device']}; attempts "
+        f"(start_step, end_step) {spans}; steady recompiles "
+        f"{[a.get('steady_recompile_count') for a in att]}; update arm "
+        f"{'fused' if prog.get('fused_update') else 'optax'}, kernels "
+        f"{prog.get('tpu_custom_calls')}")
+    res["failures"] += platform_failures(res["device"])
+    n = sizes.ring_steps
+    if spans != [(0, n), (n, 2 * n)]:
+        res["failures"].append(
+            f"expected steps {[(0, n), (n, 2 * n)]} (the second run "
+            f"resuming), got {spans}")
+    if any(a.get("steady_recompile_count") for a in att):
+        res["failures"].append("steady recompiles in a supervised attempt")
+    return finish("launcher", res)
+
+
 # --------------------------------------------------------------------- main
 
 def run_one_chip(sizes: Sizes, seed: int) -> Tuple[bool, Optional[Dict]]:
@@ -615,11 +768,24 @@ def run_four_chips(sizes: Sizes, seed: int) -> Tuple[bool, Optional[Dict]]:
     return mesh["ok"], mesh.get("device") or probe["device"]
 
 
+def run_only(which: str, sizes: Sizes, seed: int
+             ) -> Tuple[bool, Optional[Dict]]:
+    probe = phase_probe()
+    if not probe["ok"]:
+        return False, probe["device"]
+    phase = {"decode": phase_decode, "launcher": phase_launcher}[which]
+    return phase(sizes, seed)["ok"], probe["device"]
+
+
 def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="1 (default): train -> save -> serve on one chip; "
                          "4: the sharded-training comparison, nothing else")
+    ap.add_argument("--only", choices=("decode", "launcher"),
+                    help="one side check on one chip and nothing else: "
+                         "flash-decode against the XLA arm, or the "
+                         "supervised launcher finding the TPU and resuming")
     ap.add_argument("--seed", type=int, default=102,
                     help="weights, data and prompts are made from it")
     ns = ap.parse_args(argv)
@@ -630,8 +796,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         else:
             shutil.rmtree(OUT_DIR, ignore_errors=True)
             os.makedirs(OUT_DIR)
-            ok, device = (run_four_chips(REAL, ns.seed) if ns.chips == 4
-                          else run_one_chip(REAL, ns.seed))
+            if ns.only:
+                ok, device = run_only(ns.only, REAL, ns.seed)
+            elif ns.chips == 4:
+                ok, device = run_four_chips(REAL, ns.seed)
+            else:
+                ok, device = run_one_chip(REAL, ns.seed)
     finally:
         # the contract's last line, and nothing after it
         print(json.dumps({"ok": bool(ok), "device": device}), flush=True)

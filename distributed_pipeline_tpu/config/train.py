@@ -87,13 +87,13 @@ class GeneralSettings(S):
                               "the graftlint static pass (python -m "
                               "distributed_pipeline_tpu.analysis); cheap "
                               "enough for CI runs")
-    compilation_cache_dir: str = _(
-        "auto", "persistent XLA compilation-cache directory: 'auto' = "
-                "one fixed git-ignored directory in the checkout "
+    compilation_cache_dir: Literal["auto", "off"] = _(
+        "auto", "persistent XLA compilation cache: 'auto' = the directory "
+                "JAX_COMPILATION_CACHE_DIR names if it is set, else one "
+                "fixed git-ignored directory in the checkout "
                 "(.compile_cache — every run, restart and server start "
-                "shares it), 'off' disables, else an explicit dir; "
-                "JAX_COMPILATION_CACHE_DIR, when set, is used instead of "
-                "either and nothing else is set")
+                "shares it); 'off' disables. To place it elsewhere set "
+                "the variable")
     prefetch_depth: int = _(
         2, "device-side input prefetch depth: keep N batches already "
            "device_put onto the mesh (with the compiled step's sharding) "

@@ -27,7 +27,10 @@ step with mu/nu/EMA in the zshard layout (parallel/partition
 ``zero1_shardings``) and out_shardings pinned. GSPMD cannot partition a
 Mosaic kernel, so on a mesh of more than one device each leaf's kernel is
 wrapped in ``shard_map`` on that layout (:func:`_leaf_update_on_mesh`) and
-every shard touches only its own slice.
+every shard touches only its own slice. Where that layout is finer than the
+param's own (ZeRO-1 on ``data > 1``) the kernel hands back the update and
+``p + u`` follows the gather, as in the optax chain — which keeps the two
+arms' params bit-equal there too.
 
 Off-TPU the kernel runs in Pallas interpreter mode (real kernel logic on
 CPU, tier-1 testable). HBM accounting for the bench leg:
@@ -88,9 +91,11 @@ def _interpret() -> bool:
 
 def _update_kernel(steps_ref, scal_ref, p_ref, g_ref, mu_ref, nu_ref,
                    *rest, b1: float, b2: float, eps: float, wd: float,
-                   rates: Tuple[float, ...]):
+                   rates: Tuple[float, ...], emit_update: bool = False):
     """optax.adamw's elementwise tail + every EMA lerp, one block pass.
-    ``rest`` is (ema_in..., p_out, mu_out, nu_out, ema_out...)."""
+    ``rest`` is (ema_in..., p_out, mu_out, nu_out, ema_out...). With
+    ``emit_update`` the first output is the update ``u`` and not ``p + u``
+    (:func:`_leaf_update_on_mesh`: the caller adds it after the gather)."""
     del steps_ref  # prefetch slot unused: no routing, blocks stream in order
     n_r = len(rates)
     e_in = rest[:n_r]
@@ -109,7 +114,7 @@ def _update_kernel(steps_ref, scal_ref, p_ref, g_ref, mu_ref, nu_ref,
     u = u + wd * p
     u = step_size * u
     pn = p + u
-    p_out[...] = pn.astype(p_out.dtype)
+    p_out[...] = (u if emit_update else pn).astype(p_out.dtype)
     mu_out[...] = mu
     nu_out[...] = nu
     for i, r in enumerate(rates):
@@ -118,7 +123,7 @@ def _update_kernel(steps_ref, scal_ref, p_ref, g_ref, mu_ref, nu_ref,
 
 def _leaf_update(p, g, mu, nu, emas: List[jnp.ndarray], scalars,
                  b1: float, b2: float, eps: float, wd: float,
-                 rates: Tuple[float, ...]):
+                 rates: Tuple[float, ...], emit_update: bool = False):
     """Run one leaf through the kernel: flatten -> [rows, LANES] blocks."""
     shape, dt = p.shape, p.dtype
     n = p.size
@@ -143,7 +148,7 @@ def _leaf_update(p, g, mu, nu, emas: List[jnp.ndarray], scalars,
         scratch_shapes=[])
     outs = pl.pallas_call(
         functools.partial(_update_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
-                          rates=rates),
+                          rates=rates, emit_update=emit_update),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((rows_p, LANES), dt)] * n_out,
         name=KERNEL_NAME, interpret=_interpret())(
@@ -156,35 +161,58 @@ def _leaf_update(p, g, mu, nu, emas: List[jnp.ndarray], scalars,
         [back(o) for o in outs[3:]]
 
 
-def _leaf_update_on_mesh(mesh, spec, p, g, mu, nu, emas, scalars,
+def _leaf_update_on_mesh(mesh, spec, pspec, p, g, mu, nu, emas, scalars,
                          b1, b2, eps, wd, rates):
     """:func:`_leaf_update` on a mesh of more than one device. Mosaic
     kernels cannot be partitioned by GSPMD ("wrap the call in a
     shard_map"), so this is that shard_map: the kernel is elementwise, so
-    every operand and result of the leaf takes the leaf's own ``spec`` and
-    each device updates its shard; the scalars are replicated."""
+    every operand and result of the leaf takes the weight-update layout
+    ``spec`` and each device updates its shard; the scalars are replicated.
+
+    Where that layout is finer than the param's own ``pspec`` (ZeRO-1) the
+    kernel hands back the update ``u`` and ``p + u`` is taken outside, on
+    the param layout — where the optax chain takes it: the gather lies
+    between ``u`` and the add there, so the add rounds on its own, while
+    an add inside the kernel may contract with ``step_size * u`` into one
+    rounding (XLA:CPU does) and the two param trees part by an ulp. The
+    EMA copies still lerp toward the kernel's own ``p + u``, so under
+    ZeRO-1 they may sit an ulp from the optax arm's on such a backend."""
     from jax.sharding import PartitionSpec as P
 
     from ..utils.jax_compat import shard_map
 
     n_r = len(emas)
+    gathered = _norm_spec(spec) != _norm_spec(pspec)
 
     def body(scal, p_, g_, mu_, nu_, *es):
         a, m, v, eo = _leaf_update(p_, g_, mu_, nu_, list(es), scal,
-                                   b1, b2, eps, wd, rates)
+                                   b1, b2, eps, wd, rates,
+                                   emit_update=gathered)
         return (a, m, v, *eo)
 
     outs = shard_map(body, mesh=mesh, in_specs=(P(),) + (spec,) * (4 + n_r),
                      out_specs=(spec,) * (3 + n_r),
                      check_vma=False)(scalars, p, g, mu, nu, *emas)
-    return outs[0], outs[1], outs[2], list(outs[3:])
+    new_p = p + outs[0] if gathered else outs[0]
+    return new_p, outs[1], outs[2], list(outs[3:])
+
+
+def _norm_spec(spec) -> Tuple:
+    """A PartitionSpec as a comparable tuple: ``"x"`` and ``("x",)`` are
+    one axis set, trailing ``None``s say nothing."""
+    dims = [() if d is None else (d,) if isinstance(d, str) else tuple(d)
+            for d in spec]
+    while dims and not dims[-1]:
+        dims.pop()
+    return tuple(dims)
 
 
 def fused_adamw_ema(params: Any, grads: Any, opt_state: Any,
                     ema: Dict[str, Any], *, lr_fn, b1: float = 0.9,
                     b2: float = 0.999, eps: float = 1e-8,
                     weight_decay: float = 0.0, mesh=None,
-                    specs: Any = None) -> Tuple[Any, Any, Dict]:
+                    specs: Any = None, param_specs: Any = None
+                    ) -> Tuple[Any, Any, Dict]:
     """Drop-in replacement for the trainer's staged update:
     ``opt.update -> apply_updates -> update_ema per rate`` in one kernel
     pass per leaf.
@@ -198,11 +226,12 @@ def fused_adamw_ema(params: Any, grads: Any, opt_state: Any,
     params-shaped trees.
 
     On a ``mesh`` of more than one device pass ``specs``, a params-shaped
-    tree of PartitionSpecs — the layout of the weight-update state (the
-    param layout, or the ZeRO-1 layout when that is finer): each leaf's
-    kernel then runs under ``shard_map`` on that spec, params and grads
-    are sliced down to it on the way in, and the caller's sharding
-    constraint gathers the new params back (the ZeRO-1 pattern)."""
+    tree of PartitionSpecs — the layout of the weight-update state — and,
+    when that is the finer ZeRO-1 layout, ``param_specs``, the params' own
+    (default: the same). Each leaf's kernel then runs under ``shard_map``
+    on its ``specs`` entry, params and grads are sliced down to it on the
+    way in, and where the two layouts differ the update is gathered and
+    added on the param layout (:func:`_leaf_update_on_mesh`)."""
     on_mesh = mesh is not None and mesh.size > 1
     if on_mesh and specs is None:
         raise ValueError(
@@ -227,6 +256,8 @@ def fused_adamw_ema(params: Any, grads: Any, opt_state: Any,
     leaves_nu = jax.tree_util.tree_leaves(adam.nu)
     leaves_e = [jax.tree_util.tree_leaves(ema[r]) for r in rate_keys]
     leaves_s = tdef.flatten_up_to(specs) if on_mesh else None
+    leaves_ps = (leaves_s if param_specs is None or not on_mesh
+                 else tdef.flatten_up_to(param_specs))
     pn: List[jnp.ndarray] = []
     mun: List[jnp.ndarray] = []
     nun: List[jnp.ndarray] = []
@@ -235,7 +266,8 @@ def fused_adamw_ema(params: Any, grads: Any, opt_state: Any,
         leaf = (leaves_p[i], leaves_g[i], leaves_mu[i], leaves_nu[i],
                 [leaves_e[j][i] for j in range(len(rate_keys))],
                 scalars, b1, b2, eps, weight_decay, rates)
-        a, m, v, es = (_leaf_update_on_mesh(mesh, leaves_s[i], *leaf)
+        a, m, v, es = (_leaf_update_on_mesh(mesh, leaves_s[i], leaves_ps[i],
+                                            *leaf)
                        if on_mesh else _leaf_update(*leaf))
         pn.append(a)
         mun.append(m)

@@ -40,6 +40,8 @@ __all__ = [
     "parse_and_autorun",
     "get_main_modname",
     "parse_capacity_schedule",
+    "WorkersDoNotFitHost",
+    "require_workers_fit_host",
     "FORCE_NPROCS_ENV",
     "FORCE_DEVICES_ENV",
 ]
@@ -193,18 +195,27 @@ def inherited_platform() -> str:
     return os.environ.get("JAX_PLATFORMS", "")
 
 
+class WorkersDoNotFitHost(ValueError):
+    """More accelerator worker processes than one host can hold
+    (:func:`require_workers_fit_host`). The CLIs turn it into their exit
+    message; library callers get an ordinary exception."""
+
+
 def require_workers_fit_host(n_workers: int, platform: Optional[str],
                              what: str) -> None:
     """Refuse to put more than one accelerator worker on this host. A
     chip belongs to one process at a time, one process drives every chip
     of its host, and nothing here splits a host's chips between
-    processes — so a second worker would fail on the device lock or, as
-    this launcher once arranged, train on the CPU and say nothing. Only
-    an explicit ``cpu`` platform (virtual devices) may hold several."""
+    processes (ROADMAP R8) — so a second worker would fail on the device
+    lock or, as this launcher once arranged, train on the CPU and say
+    nothing. Only an explicit ``cpu`` platform (virtual devices) may hold
+    several. Every place that adds a worker process asks here: a ring at
+    launch (for every worker count its elastic schedule names), the
+    serving fleet at construction and at each scale-up, the MPMD driver."""
     if platform is None:
         platform = inherited_platform()
     if n_workers > 1 and platform != "cpu":
-        raise SystemExit(
+        raise WorkersDoNotFitHost(
             f"{what}: {n_workers} worker processes on one host need an "
             f"explicit JAX_PLATFORMS=cpu (virtual devices); on platform "
             f"{platform or '<unpinned>'!r} a chip belongs to one process "
@@ -688,8 +699,6 @@ def run_argv_as_distributed(modname: str, script_argv: Sequence[str],
     Reference equivalent: in-process ``torch.distributed.run.run``
     (dist_run.py:13-54). Returns the final attempt's max worker exit code.
     """
-    require_workers_fit_host(nprocs, worker_platform,
-                             f"--nprocs {nprocs}")
     cmd_base = [sys.executable, "-m", modname, *script_argv]
     # Pin the run timestamp ONCE for all attempts: run/train.py derives its
     # auto-generated run dir from DPT_RUN_TIMESTAMP when set, so a respawned
@@ -710,6 +719,10 @@ def run_argv_as_distributed(modname: str, script_argv: Sequence[str],
         os.environ.get(FORCE_NPROCS_ENV, ""))
     devices_sched = parse_capacity_schedule(
         os.environ.get(FORCE_DEVICES_ENV, ""))
+    # every worker count any attempt may run at, checked before the first
+    # spawn (same reason): attempt 3 must not be the one to find out
+    most = max(nprocs_sched or [nprocs])
+    require_workers_fit_host(most, worker_platform, f"--nprocs {most}")
     fd, run_dir_file = tempfile.mkstemp(prefix="dpt_run_dir_")
     os.close(fd)
     label = f"[launcher{' ' + tag if tag else ''}]"
@@ -850,18 +863,21 @@ def parse_and_autorun(
         if modname is None:
             raise RuntimeError(
                 "--nprocs relaunch requires running as a module (python -m ...)")
-        code = run_argv_as_distributed(
-            modname, script_argv, dist_ns.nprocs,
-            dist_ns.devices_per_proc,
-            max_restarts=dist_ns.max_restarts,
-            monitor_interval=dist_ns.monitor_interval,
-            log_dir=dist_ns.log_dir,
-            log_tee=dist_ns.log_tee,
-            restart_window_s=dist_ns.restart_window_s,
-            restart_backoff_s=dist_ns.restart_backoff_s,
-            restart_backoff_max_s=dist_ns.restart_backoff_max_s,
-            hang_timeout_s=dist_ns.hang_timeout_s,
-            hang_startup_timeout_s=dist_ns.hang_startup_timeout_s)
+        try:
+            code = run_argv_as_distributed(
+                modname, script_argv, dist_ns.nprocs,
+                dist_ns.devices_per_proc,
+                max_restarts=dist_ns.max_restarts,
+                monitor_interval=dist_ns.monitor_interval,
+                log_dir=dist_ns.log_dir,
+                log_tee=dist_ns.log_tee,
+                restart_window_s=dist_ns.restart_window_s,
+                restart_backoff_s=dist_ns.restart_backoff_s,
+                restart_backoff_max_s=dist_ns.restart_backoff_max_s,
+                hang_timeout_s=dist_ns.hang_timeout_s,
+                hang_startup_timeout_s=dist_ns.hang_startup_timeout_s)
+        except WorkersDoNotFitHost as e:
+            raise SystemExit(str(e)) from None
         sys.exit(code)
 
     if dist_ns.distributed:

@@ -978,6 +978,20 @@ def _fleet_main(settings: ServeSettings) -> dict:
     platform = settings.replica_platform
     if platform == "auto":
         platform = os.environ.get("JAX_PLATFORMS", "")
+    # Every worker process this fleet may come to hold — replicas up to
+    # the autoscaler's ceiling, plus the disagg decode ring — must fit the
+    # host; the library refuses with an exception, the CLI with a message.
+    from ..parallel.launcher import WorkersDoNotFitHost, \
+        require_workers_fit_host
+    most = (max(settings.replicas,
+                max(settings.autoscale_max, settings.autoscale_min)
+                if settings.autoscale else 0)
+            + (1 if settings.disagg > 0 else 0))
+    try:
+        require_workers_fit_host(most, platform,
+                                 f"a fleet of up to {most} workers")
+    except WorkersDoNotFitHost as e:
+        raise SystemExit(str(e)) from None
     # build the workload BEFORE spawning anything: a knob fleet mode
     # cannot honor must abort with zero worker processes to clean up
     gen, reqs = fleet_workload(settings, vocab, max_prompt_len)

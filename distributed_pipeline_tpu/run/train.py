@@ -268,11 +268,15 @@ def _mpmd_main(args: TrainSettings) -> dict:
         "weight_decay": args.weight_decay,
         "link_capacity": args.mpmd_link_capacity,
     }
-    driver = PipelineDriver(
-        ckpt_path, config,
-        max_restarts=args.mpmd_max_restarts,
-        hang_timeout_s=args.mpmd_hang_timeout_s,
-        trace_armed=True if args.trace else None)
+    from ..parallel.launcher import WorkersDoNotFitHost
+    try:
+        driver = PipelineDriver(
+            ckpt_path, config,
+            max_restarts=args.mpmd_max_restarts,
+            hang_timeout_s=args.mpmd_hang_timeout_s,
+            trace_armed=True if args.trace else None)
+    except WorkersDoNotFitHost as e:  # stages are processes: ROADMAP R8
+        raise SystemExit(str(e)) from None
     try:
         result = driver.run(args.learning_steps)
     finally:

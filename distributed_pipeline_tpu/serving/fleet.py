@@ -472,6 +472,10 @@ class ServingFleet:
         autoscaler gets warm capacity for free. rids are never re-used
         (a scaled-down slot keeps its dir for the goodput fold), so a
         fresh replica can never inherit a dead attempt's ctrl state."""
+        from ..parallel.launcher import require_workers_fit_host
+        live = sum(self.alive(r) for r in range(self.n_replicas))
+        require_workers_fit_host(live + 1, self.replica_platform,
+                                 f"a fleet replica beside {live} live")
         rid = self.n_replicas
         p = ReplicaPaths(self.fleet_dir, rid).ensure()
         self.paths.append(p)

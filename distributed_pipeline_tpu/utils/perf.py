@@ -151,9 +151,9 @@ def peak_live_bytes() -> int:
 
 
 # The one place a compile cache lives when nobody says otherwise: a fixed,
-# git-ignored directory in the checkout. The path is part of the cache key,
-# so a directory that moves (a run dir, a temp name, a pid, the clock)
-# never hits.
+# git-ignored directory in the checkout. A directory that moves with the
+# run (a run dir, a temp name, a pid, the clock) holds nothing the next run
+# can find.
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".compile_cache")
@@ -164,17 +164,17 @@ def enable_persistent_compilation_cache(flag: str = "auto") -> str:
     (\"\" = disabled). The ONE owner of where the cache lives — train,
     serve, bench and the launcher's workers all resolve it here:
 
-    * ``"off"`` / ``"none"`` / ``"0"`` — disabled in this process;
-    * ``JAX_COMPILATION_CACHE_DIR`` set — that directory, whatever the
-      flag says: a cache placed from outside is used and no other is set;
-    * ``"auto"`` / ``""`` — :data:`DEFAULT_COMPILE_CACHE_DIR`, the same
-      path for every run, server start and worker of this checkout;
-    * anything else — an explicit directory.
+    * ``"off"`` — disabled in this process;
+    * ``"auto"`` — ``JAX_COMPILATION_CACHE_DIR`` if it is set (a cache
+      placed from outside is used and no other is set), else
+      :data:`DEFAULT_COMPILE_CACHE_DIR`, the same path for every run,
+      server start and worker of this checkout.
 
-    The environment is never written: spawned workers inherit the
-    variable if the caller set it, and resolve the same fixed path if
-    not. The min-compile-time/entry-size gates are zeroed so the cache
-    works for small CPU graphs too (tests, dev rings).
+    There is no third value: a directory of one's own is what the
+    variable is for. The environment is never written: spawned workers
+    inherit the variable if the caller set it, and resolve the same fixed
+    path if not. The min-compile-time/entry-size gates are zeroed so the
+    cache works for small CPU graphs too (tests, dev rings).
 
     JAX initializes its cache object at most once per process and then
     ignores config-dir changes, so both re-pointing at a new dir and
@@ -182,12 +182,16 @@ def enable_persistent_compilation_cache(flag: str = "auto") -> str:
     """
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    if str(flag).lower() in ("off", "none", "0"):
+    if flag == "off":
         cc.reset_cache()
         jax.config.update("jax_compilation_cache_dir", None)
         return ""
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or (
-        flag if flag and flag != "auto" else DEFAULT_COMPILE_CACHE_DIR)
+    if flag != "auto":
+        raise ValueError(
+            f"compilation cache is 'auto' or 'off', got {flag!r}: to place "
+            f"it elsewhere set JAX_COMPILATION_CACHE_DIR")
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 or DEFAULT_COMPILE_CACHE_DIR)
     os.makedirs(cache_dir, exist_ok=True)
     cc.reset_cache()
     jax.config.update("jax_compilation_cache_dir", cache_dir)
@@ -304,8 +308,8 @@ class RecompileMonitor(logging.Handler):
 
     Use as a context manager or install()/uninstall(). ``count`` is the
     total since install; ``last`` keeps the most recent compile's name
-    line for diagnostics. Compiles inside a :meth:`not_counting` block go
-    to ``uncounted`` instead: the gauge is for retraces of the program's
+    line for diagnostics. Compiles inside a :meth:`not_counting` block are
+    left out: the gauge is for retraces of the program's
     own step functions, and a library the program calls between steps may
     build small programs of its own (orbax slices each sharded array with
     a jitted ``slice`` the first time it saves it — 22 of them in a
@@ -317,7 +321,6 @@ class RecompileMonitor(logging.Handler):
     def __init__(self, capture_sites: bool = False) -> None:
         super().__init__(level=logging.NOTSET)
         self.count = 0
-        self.uncounted = 0
         self._paused = 0
         self.last: str = ""
         self.sites: List[Dict[str, Any]] = []
@@ -326,7 +329,7 @@ class RecompileMonitor(logging.Handler):
 
     @contextlib.contextmanager
     def not_counting(self):
-        """Book compiles inside the block to ``uncounted`` (class
+        """Leave compiles inside the block out of ``count`` (class
         docstring)."""
         self._paused += 1
         try:
@@ -341,7 +344,6 @@ class RecompileMonitor(logging.Handler):
             return
         if msg.startswith(self._MARKER):
             if self._paused:
-                self.uncounted += 1
                 return
             self.count += 1
             self.last = msg.split("\n", 1)[0][:200]

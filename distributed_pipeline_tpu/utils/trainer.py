@@ -611,8 +611,10 @@ class TrainLoop:
         pshard = self._pshard
         base_rng = self._base_rng
         lr_at = self._lr_at
-        # the weight-update layout the fused kernel shard_maps over
+        # the layouts the fused kernel shard_maps over: the weight-update
+        # state's, and the params' own (they differ under ZeRO-1)
         zspecs = jax.tree_util.tree_map(lambda s: s.spec, self._zshard)
+        pspecs = jax.tree_util.tree_map(lambda s: s.spec, pshard)
 
         def micro_scan(params: Any, batch: Dict[str, jnp.ndarray],
                        rng: jax.Array, with_grad: bool):
@@ -684,7 +686,7 @@ class TrainLoop:
                 params, opt_state, ema = fused_adamw_ema(
                     state.params, grads, state.opt_state, state.ema,
                     lr_fn=lr_fn, weight_decay=self.weight_decay,
-                    mesh=self.mesh, specs=zspecs)
+                    mesh=self.mesh, specs=zspecs, param_specs=pspecs)
                 params = jax.lax.with_sharding_constraint(params, pshard)
             else:
                 updates, opt_state = opt.update(grads, state.opt_state,

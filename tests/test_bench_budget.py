@@ -519,12 +519,17 @@ def test_trace_ab_leg_emits_paired_delta_row(trace_bench_run):
 # ------------------------------------------------ compilation-cache wiring
 
 def test_compilation_cache_flag_roundtrips_through_settings():
-    s = TrainSettings.from_argv(["--compilation_cache_dir", "/tmp/cc"])
-    assert s.compilation_cache_dir == "/tmp/cc"
+    s = TrainSettings.from_argv(["--compilation_cache_dir", "off"])
+    assert s.compilation_cache_dir == "off"
     assert TrainSettings().compilation_cache_dir == "auto"
     # and through the JSON path (the --config_json workflow)
     s2 = TrainSettings.model_validate(json.loads(s.to_json()))
-    assert s2.compilation_cache_dir == "/tmp/cc"
+    assert s2.compilation_cache_dir == "off"
+    # a directory of one's own is JAX_COMPILATION_CACHE_DIR's to name
+    with pytest.raises(SystemExit):
+        TrainSettings.from_argv(["--compilation_cache_dir", "/tmp/cc"])
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        enable_persistent_compilation_cache("/tmp/cc")
 
 
 def test_enable_persistent_cache_resolution(tmp_path, monkeypatch):
@@ -551,12 +556,11 @@ def test_enable_persistent_cache_resolution(tmp_path, monkeypatch):
         # the real default sits in the checkout, under a fixed name
         assert os.path.basename(os.path.dirname(os.path.dirname(
             perf.__file__))) == "distributed_pipeline_tpu"
-        # set: that directory, whatever the flag says, and no other made
+        # set: that directory, and no other made
         outside = str(tmp_path / "outside")
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
         os.rmdir(fixed)
-        for flag in ("auto", str(tmp_path / "flagged")):
-            assert enable_persistent_compilation_cache(flag) == outside
+        assert enable_persistent_compilation_cache("auto") == outside
         assert jax.config.jax_compilation_cache_dir == outside
         assert os.environ["JAX_COMPILATION_CACHE_DIR"] == outside
         assert sorted(os.listdir(tmp_path)) == ["outside"]
@@ -623,9 +627,9 @@ def test_aot_compile_metrics_and_cache_hit_path(tmp_path, monkeypatch):
     for. The resume leg doubles as a regression test for donating
     orbax-restored buffers into a cache-deserialized executable (jaxlib
     0.4.37 CPU heap corruption; trainer copies restored trees)."""
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     try:
-        enable_persistent_compilation_cache(str(tmp_path / "cache"))
+        enable_persistent_compilation_cache()
 
         cold = _tiny_loop(tmp_path, "run")
         assert cold.compile_time_s is None  # nothing compiled at build time

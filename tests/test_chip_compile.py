@@ -95,18 +95,25 @@ def test_fused_update_leaf_compiles(one_chip, as_on_tpu, shape):
     assert_kernel(c, fu.KERNEL_NAME)
 
 
-def test_fused_update_sharded_leaf_compiles_on_mesh(mesh4, as_on_tpu):
+@pytest.mark.parametrize("layout", ["fsdp", "zero1"])
+def test_fused_update_sharded_leaf_compiles_on_mesh(mesh4, as_on_tpu, layout):
     """As the trainer calls it on a mesh: the leaf fsdp-sharded, the kernel
-    under shard_map. Without the wrapper Mosaic refuses ("cannot be
-    automatically partitioned") — the fault that kept every multi-chip
-    run with default flags from compiling."""
-    spec = P("fsdp", None)
-    sh = NamedSharding(mesh4, spec)
-    x = sds((768, 3072), jnp.float32, sh)
-    scal = sds((3,), jnp.float32, NamedSharding(mesh4, P()))
+    under shard_map — on the param layout, and on the finer ZeRO-1 layout
+    (the kernel then emits the update and the add follows the gather).
+    Without the wrapper Mosaic refuses ("cannot be automatically
+    partitioned") — the fault that kept every multi-chip run with default
+    flags from compiling."""
+    pspec = P("fsdp", None)
+    spec = P("fsdp", "data") if layout == "zero1" else pspec
+    psh, sh = NamedSharding(mesh4, pspec), NamedSharding(mesh4, spec)
+    shape = (768, 3072)
+    args = ([sds(shape, jnp.float32, psh)] * 2      # p, g
+            + [sds(shape, jnp.float32, sh)] * 5     # mu, nu, 3 EMA copies
+            + [sds((3,), jnp.float32, NamedSharding(mesh4, P()))])
+    outs = (psh, sh, sh, [sh] * 3)
 
     def wrapped(p, g, mu, nu, e0, e1, e2, scalars):
-        return fu._leaf_update_on_mesh(mesh4, spec, p, g, mu, nu,
+        return fu._leaf_update_on_mesh(mesh4, spec, pspec, p, g, mu, nu,
                                        [e0, e1, e2], scalars,
                                        0.9, 0.999, 1e-8, 0.0, RATES)
 
@@ -114,13 +121,15 @@ def test_fused_update_sharded_leaf_compiles_on_mesh(mesh4, as_on_tpu):
         return fu._leaf_update(p, g, mu, nu, [e0, e1, e2], scalars,
                                0.9, 0.999, 1e-8, 0.0, RATES)
 
-    c = jax.jit(wrapped, out_shardings=sh).lower(*([x] * 7), scal).compile()
+    c = jax.jit(wrapped, out_shardings=outs).lower(*args).compile()
     assert_kernel(c, fu.KERNEL_NAME)
-    # per device: half the leaf (fsdp=2), for each of 7 operands
-    assert c.memory_analysis().argument_size_in_bytes \
-        < 0.6 * 7 * 768 * 3072 * 4
+    # per device: p and g halved (fsdp=2), the other five operands halved
+    # or, under ZeRO-1, quartered
+    per_leaf = 768 * 3072 * 4
+    want = (2 / 2 + 5 / (4 if layout == "zero1" else 2)) * per_leaf
+    assert c.memory_analysis().argument_size_in_bytes < 1.05 * want
     with pytest.raises(NotImplementedError, match="shard_map"):
-        jax.jit(bare, out_shardings=sh).lower(*([x] * 7), scal).compile()
+        jax.jit(bare, out_shardings=outs).lower(*args).compile()
 
 
 # --------------------------------------------------------- flash attention
@@ -170,6 +179,58 @@ def test_flash_attention_batch_split_compiles_on_mesh(mesh4, as_on_tpu):
             jax.jit(lambda q_, k_, v_: fa.flash_attention(
                 q_, k_, v_, None, True),
                 out_shardings=sh).lower(q, q, q).compile()
+
+
+# ---------------------------------------------------------- the whole step
+
+@pytest.mark.parametrize("zero1", [False, True], ids=["fsdp", "zero1"])
+def test_sharded_train_step_compiles_on_mesh(mesh4, as_on_tpu, zero1):
+    """The trainer's own step, default arms, lowered from shapes for the
+    described 2x2 mesh (``TrainLoop._plan_state`` gives the layouts with no
+    device work): a thin GPT-2 at seq 1024, so 'auto' is flash attention
+    and the fused update, under the trainer's own shardings, scan and
+    constraints. On the seed this raised "Mosaic kernels cannot be
+    automatically partitioned" — with default flags, on any mesh."""
+    from distributed_pipeline_tpu.models import create_model_from_config
+    from distributed_pipeline_tpu.ops.fused_update import \
+        resolve_fused_update
+    from distributed_pipeline_tpu.parallel.sharding import replicated
+    from distributed_pipeline_tpu.utils.trainer import TrainLoop, TrainState
+
+    wl = create_model_from_config(
+        model_family="gpt2", vocab_size=1000, seq_len=1024, hidden_size=128,
+        num_layers=2, num_heads=2, dtype="bfloat16")
+    lp = TrainLoop.__new__(TrainLoop)  # no state is allocated anywhere
+    lp.workload, lp.mesh, lp.ema_rates = wl, mesh4, ("0.9", "0.99")
+    lp.lr, lp.learning_steps, lp.warmup_steps, lp.weight_decay = \
+        1e-3, 10, 0, 0.0
+    lp.gradient_clipping, lp.partition_rules = -1.0, None
+    lp.shard_optimizer = zero1
+    lp.fused_update = resolve_fused_update("auto")
+    lp._base_rng = jax.random.PRNGKey(0)
+    lp.microbatch, lp.n_micro = 4, 2
+    lp._note_compile = lambda *a: None
+    assert lp.fused_update
+    abs_params, abs_opt = lp._plan_state()
+    lp._build_step_fns()
+
+    def shaped(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, sh: sds(a.shape, a.dtype, sh), tree, shardings)
+
+    state = TrainState(
+        step=sds((), jnp.int32, replicated(mesh4)),
+        params=shaped(abs_params, lp._pshard),
+        opt_state=shaped(abs_opt, lp._oshard),
+        ema={r: shaped(abs_params, lp._zshard) for r in lp.ema_rates})
+    bs = lp._batch_sharding
+    batch = {k: sds((lp.n_micro, lp.microbatch) + v.shape[1:], v.dtype,
+                    bs[k] if isinstance(bs, dict) else bs)
+             for k, v in wl.example_batch(1).items()}
+    with mesh4:
+        c = lp._train_step._jitted.lower(state, batch).compile()
+    for name in (fa.FWD_KERNEL_NAME, fa.BWD_KERNEL_NAME, fu.KERNEL_NAME):
+        assert_kernel(c, name)
 
 
 # ------------------------------------------------------------ flash decode

@@ -25,7 +25,14 @@ TINY = chip_smoke.Sizes(
     batch=16, microbatch=8, steps=4, lr=1e-2,
     requests=((8, 6, 2), (5, 4, 2)),
     decode_slots=4, page_size=4, max_prompt_len=16,
-    mesh_steps=2, loss_tol=1e-3, child_timeout_s=300.0)
+    mesh_steps=2, loss_tol=1e-3, child_timeout_s=300.0,
+    decode_geom=(2, 2, 8, 4, 3, 2), decode_tol=6 * 2.0 ** -8,
+    ring_argv=("--model_family", "gpt2", "--hidden_size", "32",
+               "--num_layers", "2", "--num_heads", "2", "--vocab_size", "64",
+               "--seq_len", "16", "--dtype", "float32",
+               "--dataset", "synthetic-lm", "--batch_size", "8",
+               "--microbatch", "8"),
+    ring_steps=2)
 
 
 @pytest.fixture
@@ -90,6 +97,39 @@ def test_a_failed_phase_fails_the_script(smoke, capsys, monkeypatch):
     assert smoke.main([]) == 0
     last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert last == {"ok": True, "device": tpu}
+
+
+@pytest.mark.parametrize("which", ["decode", "launcher"])
+def test_side_checks_run_clean_on_cpu(smoke, capsys, monkeypatch, which):
+    """--only decode / --only launcher at a tiny size: the check itself
+    holds (the interpreted kernel agrees with the XLA arm; the supervised
+    worker trains, and the second run resumes) and only the platform
+    fails it."""
+    res = {"decode": smoke.phase_decode,
+           "launcher": smoke.phase_launcher}[which](TINY, seed=7)
+    assert res["failures"] == NOT_TPU, res["failures"]
+    if which == "decode":
+        assert set(res["cases"]) == {"bf16_decode", "bf16_span",
+                                     "int8_decode", "int8_span"}
+        assert res["auto_resolves_to"] == "xla"  # Dh = 8, and no TPU
+    else:
+        assert [(a["start_step"], a["end_step"])
+                for a in res["attempts"]] == [(0, 2), (2, 4)]
+    # through the command line it is that phase and nothing else
+    tpu = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    ran = []
+    monkeypatch.setattr(smoke, "phase_probe", lambda: {
+        "ok": True, "failures": [], "device": tpu})
+    for name in ("train", "serve", "reference", "mesh", "decode",
+                 "launcher"):
+        monkeypatch.setattr(
+            smoke, f"phase_{name}",
+            lambda *a, _n=name, **k: ran.append(_n) or {
+                "ok": True, "failures": [], "device": tpu})
+    assert smoke.main(["--only", which]) == 0
+    assert ran == [which]
+    assert json.loads(capsys.readouterr().out.strip().splitlines()[-1]) \
+        == {"ok": True, "device": tpu}
 
 
 def test_mesh_phase_on_virtual_devices(smoke, monkeypatch):

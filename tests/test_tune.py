@@ -509,13 +509,28 @@ def test_launcher_threads_worker_platform(monkeypatch):
     launcher.run_argv_as_distributed("mod", [], nprocs=1)
     assert fake2.calls[0]["platform"] is None  # no default pins cpu
     # more workers than one host's chips can go to: a message, never a
-    # silent fall to the CPU
-    with pytest.raises(SystemExit, match="JAX_PLATFORMS=cpu"):
+    # silent fall to the CPU — an exception from the library ...
+    with pytest.raises(ValueError, match="JAX_PLATFORMS=cpu"):
         launcher.run_argv_as_distributed("mod", [], nprocs=2,
                                          worker_platform="tpu")
+    # ... for every count the elastic schedule names, before any spawn
+    fake3 = make_fake_ring()
+    monkeypatch.setattr(launcher, "_run_worker_ring", fake3)
+    monkeypatch.setenv(launcher.FORCE_NPROCS_ENV, "1,2")
+    with pytest.raises(launcher.WorkersDoNotFitHost, match="--nprocs 2"):
+        launcher.run_argv_as_distributed("mod", [], nprocs=1,
+                                         worker_platform="tpu")
+    assert fake3.calls == []
+    monkeypatch.delenv(launcher.FORCE_NPROCS_ENV)
     monkeypatch.delenv("JAX_PLATFORMS")
-    with pytest.raises(SystemExit, match="unpinned"):
+    with pytest.raises(ValueError, match="unpinned"):
         launcher.run_argv_as_distributed("mod", [], nprocs=2)
+    # ... and the exit message from the CLI
+    monkeypatch.setattr(launcher, "get_main_modname", lambda: "mod")
+    with pytest.raises(SystemExit, match="unpinned"):
+        launcher.parse_and_autorun(
+            launcher.create_distributed_parser(),
+            ["--distributed", "--nprocs", "2"])
 
 
 def test_fleet_threads_replica_platform(tmp_path):
@@ -533,11 +548,32 @@ def test_fleet_threads_replica_platform(tmp_path):
     fleet.stop(join_timeout_s=5.0)
     assert [c["worker_platform"] for c in calls] == ["tpu"]
     # replicas are processes and a chip belongs to one: two need cpu
-    with pytest.raises(SystemExit, match="2 fleet replicas"):
+    with pytest.raises(ValueError, match="2 fleet replicas"):
         ServingFleet(str(tmp_path / "two"), 2, "mod", [],
                      replica_platform="tpu", launch_fn=fake_launch)
     ServingFleet(str(tmp_path / "cpu"), 2, "mod", [],
                  launch_fn=fake_launch)  # inherits the tests' cpu
+    # the autoscaler's way in is held to the same rule: no second replica
+    # beside a live one, a replacement for a dead one is fine
+    import threading
+    gate = threading.Event()
+
+    def held_launch(mod, argv, **kw):
+        gate.wait(10.0)
+        return 0
+
+    grow = ServingFleet(str(tmp_path / "grow"), 1, "mod", [],
+                        replica_platform="tpu", launch_fn=held_launch)
+    grow.start()
+    try:
+        with pytest.raises(ValueError, match="beside 1 live"):
+            grow.add_replica()
+        assert grow.n_replicas == 1
+    finally:
+        gate.set()
+    grow._threads[0].join(10.0)
+    assert grow.add_replica() == 1
+    grow.stop(join_timeout_s=5.0)
 
 
 def test_serve_settings_replica_platform_default_auto():
