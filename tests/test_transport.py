@@ -286,6 +286,12 @@ def test_socket_fleet_affinity_routes_to_warm_replica(tmp_path):
         seed = router.submit(shared, 4)
         _drive(router, fleet, timeout_s=30.0)
         warm = seed.replica
+        # ... once the heartbeat that carries it has come round: under a
+        # loaded box the followers could be placed before it did
+        deadline = time.time() + 10.0
+        while (not router.clients[warm].prefix_index()
+               and time.time() < deadline):
+            time.sleep(0.02)
         for i in range(6):
             p = np.concatenate([shared[:4],
                                 np.asarray([10 + i] * 4, np.int32)])
