@@ -36,6 +36,8 @@ from typing import Any, Callable, Dict, Iterator, Optional
 
 import numpy as np
 
+from ..obs import trace as trace_lib
+
 __all__ = ["DeviceBatch", "prefetch_to_device"]
 
 
@@ -63,6 +65,7 @@ def prefetch_to_device(
     depth: int = 2,
     length_of: Optional[Callable[[Dict[str, np.ndarray]], int]] = None,
     stats: Optional[Any] = None,
+    tracer: Any = trace_lib.NULL,
 ) -> Iterator[DeviceBatch]:
     """Yield :class:`DeviceBatch` with up to ``depth`` batches already
     placed on device ahead of the consumer.
@@ -75,7 +78,10 @@ def prefetch_to_device(
     flight. ``length_of`` extracts the example count from the host batch
     (the trainer's ``get_batch_length`` hook). ``stats`` (a
     ``perf.StallBreakdown``) receives ``data_wait_s`` (blocked on the
-    host iterator) and ``h2d_wait_s`` (blocked in ``put``) attributions.
+    host iterator) and ``h2d_wait_s`` (blocked in ``put``) attributions;
+    ``tracer`` gets a ``data.host_wait`` and a ``data.h2d`` span between
+    the same clock readings (the generator runs on its consumer's thread,
+    so they nest under the span that pulled the batch).
 
     A finite upstream iterator drains cleanly: remaining buffered batches
     are yielded, then the wrapper stops. ``depth`` is validated eagerly
@@ -95,13 +101,15 @@ def prefetch_to_device(
             while not exhausted and len(buf) < depth:
                 t0 = time.perf_counter()
                 try:
-                    host = next(iterator)
+                    with tracer.span("data.host_wait", "data"):
+                        host = next(iterator)
                 except StopIteration:
                     exhausted = True
                     break
                 t1 = time.perf_counter()
-                n = length_of(host)
-                arrays = put(host)
+                with tracer.span("data.h2d", "data"):
+                    n = length_of(host)
+                    arrays = put(host)
                 t2 = time.perf_counter()
                 if stats is not None:
                     stats.add("data_wait_s", t1 - t0)

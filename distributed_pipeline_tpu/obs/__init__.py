@@ -9,7 +9,10 @@ backend import, the same discipline as :mod:`..chaos`):
   (never wall-clock-defaulted) span/trace IDs, appended to per-process
   ``trace_rank{k}.jsonl`` shards in the run dir. A zero-cost no-op path
   (:data:`~.trace.NULL`) makes tracing-off free: no span objects, no
-  writes, no branches beyond one attribute check.
+  writes, no branches beyond one attribute check. Unarmed, the spans
+  inside the train step and the serving tick FOLLOW the profiler
+  (:data:`~.trace.FOLLOW`): during a ``jax.profiler`` session each is a
+  ``TraceAnnotation`` in the xplane and an event in an in-memory ring.
 * :mod:`.export` — folds a run (or fleet) dir's trace shards + beacons +
   ``attempts.jsonl`` + the router ``journal.jsonl`` (+ the cost ledger
   as counter tracks) into ONE Chrome-trace-event / Perfetto-loadable

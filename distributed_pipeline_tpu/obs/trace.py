@@ -23,24 +23,39 @@ Event model (one JSON object per line, compact keys)::
   ``instant`` are pass statements; hot paths guard the (tiny) argument
   construction behind ``tracer.enabled``, so a disabled trace allocates
   no span objects and takes no clock readings.
+* Spans FOLLOW the profiler. While a ``jax.profiler`` session is on
+  (:func:`profiler_on`), every span also enters a
+  ``jax.profiler.TraceAnnotation`` for its extent — it sits in the
+  xplane's ``/host:CPU`` plane beside the device's ops, on the
+  profiler's own clock — and every event (live span or after-the-fact
+  booking) is appended on exit to one process-global bounded ring
+  (:func:`recorded`, :func:`dump`); nothing is written on the hot path.
+  An unarmed process gets :data:`FOLLOW` from :func:`tracer_for`: its
+  ``enabled`` IS ``TraceAnnotation.is_enabled()``, so with no session a
+  boundary costs that one call and gets the shared no-op span. An armed
+  :class:`Tracer` keeps its shard and gains both sinks during a session.
 
 Import-light (stdlib + the chaos JSONL reader only): the launcher,
-router, and status CLI trace without a jax import.
+router, and status CLI trace without a jax import — ``jax.profiler`` is
+touched only if ``jax`` is ALREADY in ``sys.modules``.
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
+import sys
 import threading
 import time
-from typing import Any, Dict, Optional, Union
+from typing import Any, Deque, Dict, List, Optional, Union
 
 from ..chaos.goodput import read_journal as read_trace  # one-owner reader
 
-__all__ = ["TRACE_ENV", "NULL", "NullTracer", "Stopwatch", "Tracer",
-           "enabled_by_env", "read_trace", "request_trace_id",
-           "trace_path", "tracer_for"]
+__all__ = ["TRACE_ENV", "FOLLOW", "NULL", "NullTracer", "Stopwatch", "Tracer",
+           "clear_recorded", "dump", "enabled_by_env", "profiler_on",
+           "read_trace", "recorded", "request_trace_id", "trace_path",
+           "tracer_for", "wall_at"]
 
 # Arming env var: rides the launcher's worker environment (dict(os.environ)
 # at spawn), so exporting it on the supervisor traces every worker of
@@ -79,6 +94,56 @@ def microbatch_trace_id(step: int, mb: int) -> str:
     return f"s{int(step):06d}.mb{int(mb):04d}"
 
 
+# ------------------------------------------------- the profiler's two sinks
+
+# Every event booked while a jax.profiler session is on, newest last.
+# Process-global and bounded: a benchmark's readers (and TrainLoop's
+# profile window) read it after the run, in the program's own process.
+_RING: Deque[Dict[str, Any]] = collections.deque(maxlen=65536)
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation, once jax is there
+
+
+def profiler_on() -> bool:
+    """Whether a ``jax.profiler`` session is recording right now. Never
+    imports jax: a process that has not imported it has no session."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        jax = sys.modules.get("jax")
+        ann = getattr(getattr(jax, "profiler", None), "TraceAnnotation",
+                      None)
+        if ann is None:
+            return False
+        _ANNOTATION = ann
+    return ann.is_enabled()
+
+
+def recorded() -> List[Dict[str, Any]]:
+    """The ring's events (a copy), oldest first."""
+    return list(_RING)
+
+
+def clear_recorded() -> None:
+    _RING.clear()
+
+
+def dump(path: str) -> int:
+    """Write the ring as the JSONL that :func:`read_trace` and
+    ``obs/export.py`` read; returns the number of events written."""
+    events = recorded()
+    with open(path, "w") as f:
+        for event in events:
+            f.write(json.dumps(event, separators=(",", ":")) + "\n")
+    return len(events)
+
+
+def wall_at(mono_t: float) -> float:
+    """Epoch seconds of a ``time.perf_counter()`` reading — the anchor
+    for booking (:meth:`Tracer.complete`) an interval that was measured
+    on the monotonic clock."""
+    return mono_t + (time.time() - time.perf_counter())
+
+
 class Stopwatch:
     """Monotonic interval timer — the sanctioned way to book wall time
     into a metric OUTSIDE utils/perf.py and obs/ (graftlint GL009 flags
@@ -103,28 +168,41 @@ class Stopwatch:
 
 
 class _Span:
-    """Live span context manager (only ever built by an ENABLED tracer)."""
+    """Live span context manager (only ever built by an ENABLED tracer).
+    Opened during a profiler session it holds a ``TraceAnnotation`` round
+    its own extent (entered first, left last, so the two agree to the
+    cost of two clock readings). ``args`` may be set before exit — the
+    ring and the shard then carry what only the end of the span knows;
+    the annotation carries what was known at the start."""
 
     __slots__ = ("_tracer", "name", "cat", "trace_id", "args", "_t0",
-                 "_watch", "sid")
+                 "_watch", "sid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 trace_id: Optional[str], args: Optional[dict]) -> None:
+                 trace_id: Optional[str], args: Optional[dict],
+                 session: bool = False) -> None:
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.trace_id = trace_id
         self.args = args
         self.sid = ""
+        self._ann = (_ANNOTATION(name, **(args or {})) if session
+                     else None)
 
     def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.time()
         self._watch = Stopwatch()
         self.sid = self._tracer._push()
         return self
 
     def __exit__(self, *exc: Any) -> None:
-        self._tracer._pop(self)
+        dur = self._watch.peek_s()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer._pop(self, dur)
 
 
 class _NullSpan:
@@ -133,6 +211,9 @@ class _NullSpan:
 
     __slots__ = ()
     sid = ""
+    # a call site may hand a span what only its end knows (``sp.args =
+    # ...``); the no-op span takes it and keeps nothing
+    args = property(lambda self: None, lambda self, value: None)
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -175,6 +256,8 @@ NULL = NullTracer()
 
 class Tracer:
     """Writes one process's trace shard; thread-safe, lazily opened.
+    ``path=None`` is the unarmed tracer (:data:`FOLLOW`): no shard, and
+    enabled only while a ``jax.profiler`` session is on.
 
     ``proc`` labels the process ("rank0", "launcher", ...) and prefixes
     every span id — IDs are ``{proc}:{counter}``, explicit and
@@ -184,15 +267,17 @@ class Tracer:
     :meth:`complete` bookings, which is how the goodput-aligned
     instrumentation reuses already-measured seconds)."""
 
-    enabled = True
-
-    def __init__(self, path: str, proc: str) -> None:
+    def __init__(self, path: Optional[str], proc: str) -> None:
         self.path = path
         self.proc = proc
         self._n = 0
         self._f: Any = None
         self._stack: list = []
         self._lock = threading.Lock()
+
+    @property
+    def enabled(self) -> bool:
+        return self.path is not None or profiler_on()
 
     # ------------------------------------------------------------- identity
 
@@ -210,11 +295,15 @@ class Tracer:
 
     def span(self, name: str, cat: str = "misc",
              trace_id: Optional[str] = None,
-             args: Optional[dict] = None) -> _Span:
+             args: Optional[dict] = None) -> Union[_Span, _NullSpan]:
         """Context manager measuring a live span (wall-clock anchor +
         monotonic duration, so a clock step mid-span cannot produce a
-        negative or inflated ``dur``)."""
-        return _Span(self, name, cat, trace_id, args)
+        negative or inflated ``dur``). Unarmed and outside a profiler
+        session: the shared no-op span."""
+        session = profiler_on()
+        if self.path is None and not session:
+            return _NULL_SPAN
+        return _Span(self, name, cat, trace_id, args, session)
 
     def _push(self) -> str:
         with self._lock:
@@ -222,15 +311,16 @@ class Tracer:
             self._stack.append(sid)
         return sid
 
-    def _pop(self, span: _Span) -> None:
+    def _pop(self, span: _Span, dur_s: float) -> None:
         with self._lock:
             if span.sid in self._stack:
                 self._stack.remove(span.sid)
             parent = self._parent()
         self._emit({"ph": "X", "name": span.name, "cat": span.cat,
-                    "t": span._t0, "dur": span._watch.peek_s(),
+                    "t": span._t0, "dur": dur_s,
                     "sid": span.sid, "parent": parent,
-                    "trace": span.trace_id, "args": span.args})
+                    "trace": span.trace_id, "args": span.args},
+                   session=span._ann is not None)
 
     def complete(self, name: str, cat: str, t0: float, dur_s: float,
                  trace_id: Optional[str] = None,
@@ -239,6 +329,8 @@ class Tracer:
         ``dur_s`` the caller's own measured seconds — pass the exact
         value handed to the goodput/stall tracker so the trace and the
         ledger can never disagree."""
+        if not self.enabled:
+            return ""  # unarmed, no session: as free as NULL's
         with self._lock:
             sid = self._next_id()
             parent = self._parent()
@@ -252,6 +344,8 @@ class Tracer:
                 t: Optional[float] = None,
                 trace_id: Optional[str] = None,
                 args: Optional[dict] = None) -> str:
+        if not self.enabled:
+            return ""
         with self._lock:
             sid = self._next_id()
             parent = self._parent()
@@ -263,9 +357,17 @@ class Tracer:
 
     # ---------------------------------------------------------------- sink
 
-    def _emit(self, event: Dict[str, Any]) -> None:
-        line = json.dumps({k: v for k, v in event.items() if v is not None},
-                          separators=(",", ":"))
+    def _emit(self, event: Dict[str, Any],
+              session: Optional[bool] = None) -> None:
+        """One event to its sinks: the ring while a profiler session is
+        on (``session``: what the span saw when it opened; None asks
+        now), the shard when armed."""
+        event = {k: v for k, v in event.items() if v is not None}
+        if profiler_on() if session is None else session:
+            _RING.append(event)
+        if self.path is None:
+            return
+        line = json.dumps(event, separators=(",", ":"))
         try:
             with self._lock:
                 if self._f is None:
@@ -285,14 +387,21 @@ class Tracer:
                 self._f = None
 
 
+# The unarmed tracer, one a process (one id counter, so ids in the ring
+# stay unique whoever books): what tracer_for hands out when tracing is
+# not armed, and what DecodeServer uses when it is given none.
+FOLLOW = Tracer(None, "host")
+
+
 def tracer_for(run_dir: str, who: Union[int, str],
                armed: Optional[bool] = None,
-               proc: Optional[str] = None) -> Union[Tracer, NullTracer]:
+               proc: Optional[str] = None) -> Tracer:
     """The one constructor call sites use: a live :class:`Tracer` when
     tracing is armed (``armed``; None defers to :func:`enabled_by_env`,
     False forces off regardless of the env) and a local run dir exists
-    to write into, else :data:`NULL` — so every caller gets the
-    zero-cost off path by default.
+    to write into, else :data:`FOLLOW` — off, at the cost of one
+    ``is_enabled()`` a boundary, until a ``jax.profiler`` session starts
+    (and as free as :data:`NULL` in a process that never imports jax).
 
     ``proc`` overrides the process label (default ``rank{who}``/the
     label) WITHOUT changing the shard filename — a fleet's replica
@@ -307,7 +416,7 @@ def tracer_for(run_dir: str, who: Union[int, str],
     if armed is None:
         armed = enabled_by_env()
     if not armed or not run_dir or "://" in run_dir:
-        return NULL
+        return FOLLOW
     if proc is None:
         proc = f"rank{who}" if isinstance(who, int) else str(who)
     path = trace_path(run_dir, who)

@@ -373,7 +373,10 @@ def _fleet_worker_main(settings: ServeSettings) -> dict:
         kv_quant=settings.kv_quant,
         spec_tokens=settings.spec_tokens,
         spec_draft=settings.spec_draft,
-        draft_layers=settings.draft_layers)
+        draft_layers=settings.draft_layers,
+        # the scheduler's own serve.* spans and each request's life, in
+        # this replica's shard under the router's trace id
+        tracer=proto.tracer)
 
     def _restore_params(target: str):
         # the abstract target's shardings place the tree during restore;
@@ -381,21 +384,6 @@ def _fleet_worker_main(settings: ServeSettings) -> dict:
         # guard raises here, the swap acks not-ok, and the canary aborts
         return _quantize_for_serving(
             settings, ckpt_lib.restore_checkpoint(target, abstract))
-
-    def _engine_step() -> None:
-        """One scheduler step, span-attributed by phase: the prefill-vs-
-        decode split is read off the server's own counters, so the
-        engine track shows exactly what the scheduler decided."""
-        if not proto.tracer.enabled:
-            server.step()
-            return
-        p0 = server.prefill_steps
-        t0_wall = time.time()
-        server.step()
-        proto.tracer.complete(
-            "prefill" if server.prefill_steps > p0 else "decode_span",
-            "engine", t0_wall, time.time() - t0_wall,
-            args={"in_flight": len(in_flight)})
 
     # Warmup BEFORE announcing ready: the prefill/decode AOT compiles run
     # here, so the first routed request's TTFT is service time, not
@@ -508,7 +496,7 @@ def _fleet_worker_main(settings: ServeSettings) -> dict:
         nonlocal tick
         with proto.tracker.timed("drain_s"):
             while server.busy:
-                _engine_step()
+                server.step()
                 tick += 1
                 proto.write_beacon(tick)
         _report_done()
@@ -539,7 +527,8 @@ def _fleet_worker_main(settings: ServeSettings) -> dict:
                 try:
                     req = server.submit(
                         np.asarray(payload["prompt"], np.int32),
-                        int(payload["max_new_tokens"]))
+                        int(payload["max_new_tokens"]),
+                        trace_id=payload.get("trace"))
                 except ValueError as e:
                     proto.write_result({"id": int(payload["id"]),
                                         "tokens": [], "ttft_s": None,
@@ -553,7 +542,7 @@ def _fleet_worker_main(settings: ServeSettings) -> dict:
                 admitted += 1
                 moved = True
             if server.busy:
-                _engine_step()
+                server.step()
                 moved = True
             _report_done()
             tick += 1
@@ -567,7 +556,7 @@ def _fleet_worker_main(settings: ServeSettings) -> dict:
     # graceful stop: drain whatever is still in flight before exiting 0
     with proto.tracker.timed("drain_s"):
         while server.busy:
-            _engine_step()
+            server.step()
             tick += 1
             proto.write_beacon(tick)
     _report_done()

@@ -40,6 +40,7 @@ from typing import Any, Deque, List, Optional
 import jax
 import numpy as np
 
+from ..obs import trace as trace_lib
 from ..utils.perf import EventStats, RecompileMonitor, SanitizeReport, \
     device_peak_flops
 from ..utils.perf import transformer_decode_flops_per_token \
@@ -62,10 +63,13 @@ class Request:
     # (min(max_new_tokens, max_len - prompt_len), fixed at submit — the
     # single cap admission, release, and fetch-truncation all share)
     eos_id: Optional[int] = None
-    submit_t: float = 0.0
+    submit_t: float = 0.0           # perf_counter readings, all three:
+    admit_t: Optional[float] = None   # slot and pages reserved
+    finish_t: Optional[float] = None  # `finished` turned true
     tokens: List[int] = dataclasses.field(default_factory=list)
     ttft_s: Optional[float] = None  # submit -> first token FETCHED
     finished: bool = False          # output collection complete
+    trace_id: Optional[str] = None  # the router's, when it minted one
 
     @property
     def prompt_len(self) -> int:
@@ -117,7 +121,11 @@ class DecodeServer:
                  prefix_cache: bool = False,
                  decode_impl: str = "auto", kv_quant: str = "fp",
                  spec_tokens: int = 0, spec_draft: str = "ngram",
-                 draft_layers: int = 2) -> None:
+                 draft_layers: int = 2, tracer: Any = None) -> None:
+        # Spans of the tick and of each request's life (obs/trace.py).
+        # Given none, the server follows the profiler: off — one
+        # is_enabled() a boundary — until a jax.profiler session is on.
+        self.tracer = tracer if tracer is not None else trace_lib.FOLLOW
         max_len = max_len or workload.seq_len
         max_prompt_len = max_prompt_len or max(2, max_len // 2)
         pages_per_slot = -(-max_len // page_size)
@@ -395,7 +403,8 @@ class DecodeServer:
         self.engine.set_rng(key)
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int,
-               eos_id: Optional[int] = None) -> Request:
+               eos_id: Optional[int] = None,
+               trace_id: Optional[str] = None) -> Request:
         prompt = np.ascontiguousarray(prompt, np.int32).ravel()
         if not 1 <= prompt.shape[0] <= self.engine.max_prompt_len:
             raise ValueError(
@@ -419,7 +428,7 @@ class DecodeServer:
         req = Request(id=self._req_counter, prompt=prompt,
                       max_new_tokens=max_new_tokens, g_max=g_max,
                       eos_id=self.default_eos_id if eos_id is None else eos_id,
-                      submit_t=time.perf_counter())
+                      submit_t=time.perf_counter(), trace_id=trace_id)
         self.queue.append(req)
         return req
 
@@ -495,15 +504,47 @@ class DecodeServer:
         now = time.perf_counter()
         req.tokens.append(int(first_token))
         self.tokens_fetched += 1
-        req.ttft_s = max(0.0, now - req.submit_t)
-        self.ttft.add(req.ttft_s)
-        if req.eos_id is not None and int(first_token) == req.eos_id:
-            req.finished = True
-        elif len(req.tokens) >= req.g_max:
-            req.finished = True
+        req.admit_t = max(now, req.submit_t)  # submit_t may be the caller's
+        self._book_admitted(req)
+        self._book_first_token(req, req.admit_t)
+        if (req.eos_id is not None and int(first_token) == req.eos_id) \
+                or len(req.tokens) >= req.g_max:
+            self._finish(req, req.admit_t)
         if req.finished or req.g_max <= 1:
             self._release(slot)
         return req
+
+    # --------------------------------------------- a request's life, stamped
+    # where it happens: the three fields are set always (they are counters
+    # of ttft_s's kind), the three request.* spans are booked from the same
+    # numbers under the request's trace id, so span and field cannot
+    # disagree and request.queue + request.first_token IS ttft_s.
+
+    def _request_span(self, name: str, req: Request, t0: float, t1: float,
+                      **args: Any) -> None:
+        self.tracer.complete(
+            name, "request", trace_lib.wall_at(t0), t1 - t0,
+            trace_id=req.trace_id or trace_lib.request_trace_id(req.id),
+            args={"id": req.id, **args})
+
+    def _book_admitted(self, req: Request) -> None:
+        if self.tracer.enabled:
+            self._request_span("request.queue", req, req.submit_t,
+                               req.admit_t, prompt_len=req.prompt_len)
+
+    def _book_first_token(self, req: Request, now: float) -> None:
+        req.ttft_s = now - req.submit_t
+        self.ttft.add(req.ttft_s)
+        if self.tracer.enabled:
+            self._request_span("request.first_token", req, req.admit_t, now)
+
+    def _finish(self, req: Request, now: float) -> None:
+        req.finished = True
+        req.finish_t = now
+        if self.tracer.enabled and req.ttft_s is not None:
+            self._request_span("request.decode", req,
+                               req.submit_t + req.ttft_s, now,
+                               n_tokens=len(req.tokens))
 
     def _release(self, slot: int) -> None:
         st = self.slots[slot]
@@ -562,6 +603,17 @@ class DecodeServer:
         preempts pages or slots from in-flight requests."""
         if not self.queue:
             return False  # hot path: nothing to admit, skip the slot scan
+        tr = self.tracer
+        with tr.span("serve.admit", "serve") as sp:
+            batch = self._admit_batch()
+            if tr.enabled:
+                # known only now: in the ring and the shard, not in the
+                # xplane (an annotation takes its arguments when it opens)
+                sp.args = {"n": len(batch), "prompt_tokens": sum(
+                    req.prompt_len for _, req in batch)}
+            return bool(batch)
+
+    def _admit_batch(self) -> List[tuple]:
         free = [s for s in range(len(self.slots)) if self.slots[s] is None]
         batch: List[tuple] = []
         while (self.queue and free
@@ -572,6 +624,8 @@ class DecodeServer:
                 break  # pool exhausted: wait for completions to free pages
             slot = free.pop(0)
             self.queue.popleft()
+            req.admit_t = time.perf_counter()
+            self._book_admitted(req)
             self.block_tables[slot, :] = TRASH_PAGE
             self.block_tables[slot, :len(pages)] = pages
             self.active[slot] = 1
@@ -580,7 +634,7 @@ class DecodeServer:
             self._dirty = True
             batch.append((slot, req))
         if not batch:
-            return False
+            return batch
         bp, lp = self.engine.prefill_batch, self.engine.max_prompt_len
         ids = np.zeros((bp, lp), np.int32)
         lens = np.zeros((bp,), np.int32)
@@ -591,7 +645,8 @@ class DecodeServer:
             lens[i] = req.prompt_len
             smap[i] = slot
             stables[i] = self.block_tables[slot]
-        toks = self.engine.prefill(ids, lens, smap, stables)
+        with self.tracer.span("serve.prefill_dispatch", "serve"):
+            toks = self.engine.prefill(ids, lens, smap, stables)
         if self._draft_engine is not None:
             # mirror the admission into the draft pool (its own static
             # tables); the draft's first-token pick is irrelevant — every
@@ -611,7 +666,7 @@ class DecodeServer:
             st = self.slots[slot]
             if st is not None and st.generated >= st.req.g_max:
                 self._release(slot)
-        return True
+        return batch
 
     def step(self) -> bool:
         """One scheduler tick: sweep EOS completions -> admit -> dispatch
@@ -620,20 +675,29 @@ class DecodeServer:
         tick runs inside the evidence watcher: the engine's own transfer
         guard still raises on an implicit transfer, but the trip's site
         lands in the report on the way out."""
+        tr = self.tracer
         with (self.sanitize_report.watch() if self.sanitize
-              else contextlib.nullcontext()):
+              else contextlib.nullcontext()), \
+            tr.span("serve.step", "serve", args={
+                "queued": len(self.queue),
+                "active": int(self.active.sum())} if tr.enabled else None):
             return self._step_inner()
 
-    def _step_inner(self) -> bool:
-        # EOS sweep: requests finished by content (observed at fetch, one
-        # step late) release their slot before new work is admitted. Only
-        # when a fetch actually flagged one — count-based completions
-        # release inline at dispatch time.
-        if self._needs_sweep:
+    def _sweep(self) -> None:
+        """EOS sweep: requests finished by content (observed at fetch, one
+        step late) release their slot before new work is admitted. Only
+        when a fetch actually flagged one — count-based completions
+        release inline at dispatch time."""
+        if not self._needs_sweep:
+            return
+        with self.tracer.span("serve.sweep", "serve"):
             for slot, st in enumerate(self.slots):
                 if st is not None and st.req.finished:
                     self._release(slot)
             self._needs_sweep = False
+
+    def _step_inner(self) -> bool:
+        self._sweep()
         # admit until the queue, the free slots, or the page pool runs out
         # (several prefill batches per tick when a burst arrives): decode
         # windows then run at full occupancy instead of ramping one
@@ -648,26 +712,24 @@ class DecodeServer:
             # sweep any EOS the fetch flagged before dispatching
             if self._ring:
                 self._fetch(0)
-            if self._needs_sweep:
-                for slot, st in enumerate(self.slots):
-                    if st is not None and st.req.finished:
-                        self._release(slot)
-                self._needs_sweep = False
+            self._sweep()
             if self.active.any():
-                self._spec_round()
+                with self.tracer.span("serve.spec_round", "serve"):
+                    self._spec_round()
                 dispatched = True
             if self.sanitize and self._recompiles_at_first_token is None \
                     and self.tokens_fetched > 0:
                 self._recompiles_at_first_token = self._recompiles.count
             return dispatched
         if self.active.any():
-            if self._dirty:
-                self.engine.set_block_tables(self.block_tables)
-                self.engine.set_active(self.active)
-                self._dirty = False
-            snap = [(s, st.req) for s, st in enumerate(self.slots)
-                    if st is not None and self.active[s]]
-            toks = self.engine.decode()
+            with self.tracer.span("serve.decode_dispatch", "serve"):
+                if self._dirty:
+                    self.engine.set_block_tables(self.block_tables)
+                    self.engine.set_active(self.active)
+                    self._dirty = False
+                snap = [(s, st.req) for s, st in enumerate(self.slots)
+                        if st is not None and self.active[s]]
+                toks = self.engine.decode()
             span = self.engine.decode_span
             self.decode_steps += 1
             # occupancy accounting: active vs compiled slot-steps this
@@ -730,15 +792,18 @@ class DecodeServer:
             # target will verify
             self._draft_engine.set_decode_state(cur_tok, cur_pos)
             handles = [self._draft_engine.decode() for _ in range(K)]
-            for j, h in enumerate(handles):
-                draft[j] = np.asarray(jax.device_get(h))
+            with self.tracer.span("serve.fetch_wait", "serve"):
+                for j, h in enumerate(handles):
+                    draft[j] = np.asarray(jax.device_get(h))
         else:
             for s, st in snap:
                 hist = np.concatenate(
                     [st.req.prompt, np.asarray(st.req.tokens, np.int32)])
                 draft[:, s] = ngram_propose(hist, K)
-        seq = np.asarray(jax.device_get(
-            self.engine.verify(draft, cur_tok, cur_pos)))
+        verified = self.engine.verify(draft, cur_tok, cur_pos)
+        with self.tracer.span("serve.fetch_wait", "serve"):
+            seq = np.asarray(jax.device_get(verified))
+        now = time.perf_counter()
         self.decode_steps += 1
         self.spec_rounds += 1
         self.slot_steps_active += len(snap) * (K + 1)
@@ -754,11 +819,10 @@ class DecodeServer:
                 req.tokens.append(tok)
                 self.tokens_fetched += 1
                 kept += 1
-                if req.eos_id is not None and tok == req.eos_id:
-                    req.finished = True     # EOS inside an accepted
-                elif len(req.tokens) >= req.g_max:
-                    req.finished = True     # prefix wins over the draft
-                if req.finished:
+                if (req.eos_id is not None and tok == req.eos_id) \
+                        or len(req.tokens) >= req.g_max:
+                    # EOS inside an accepted prefix wins over the draft
+                    self._finish(req, now)
                     break
                 if j < K and int(draft[j, s]) == tok:
                     matched += 1
@@ -776,28 +840,36 @@ class DecodeServer:
         fetched token vector to its snapshot's requests. The device_get here
         is the only host<->device sync in the loop — and it blocks on step
         N-lag while step N executes (the PR 5 overlap)."""
-        while len(self._ring) > lag:
-            toks_dev, snap = self._ring.popleft()
-            arr = np.asarray(jax.device_get(toks_dev))
-            rows = arr if arr.ndim == 2 else arr[None]  # [span|1, S]
-            now = time.perf_counter()
-            for slot, req in snap:
-                if req.finished:
-                    continue
-                for row in rows:
-                    tok = int(row[slot])
-                    req.tokens.append(tok)
-                    self.tokens_fetched += 1
-                    if req.ttft_s is None:
-                        req.ttft_s = now - req.submit_t
-                        self.ttft.add(req.ttft_s)
-                    if req.eos_id is not None and tok == req.eos_id:
-                        req.finished = True
-                        self._needs_sweep = True  # slot may still be held
-                    elif len(req.tokens) >= req.g_max:
-                        req.finished = True  # overshoot rows are discarded
+        if len(self._ring) <= lag:
+            return
+        tr = self.tracer
+        fetched0 = self.tokens_fetched
+        with tr.span("serve.fetch", "serve") as sp:
+            while len(self._ring) > lag:
+                toks_dev, snap = self._ring.popleft()
+                with tr.span("serve.fetch_wait", "serve"):
+                    # the host WAITING for the device, and nothing else
+                    arr = np.asarray(jax.device_get(toks_dev))
+                rows = arr if arr.ndim == 2 else arr[None]  # [span|1, S]
+                now = time.perf_counter()
+                for slot, req in snap:
                     if req.finished:
-                        break
+                        continue
+                    for row in rows:
+                        tok = int(row[slot])
+                        req.tokens.append(tok)
+                        self.tokens_fetched += 1
+                        if req.ttft_s is None:
+                            self._book_first_token(req, now)
+                        if req.eos_id is not None and tok == req.eos_id:
+                            self._finish(req, now)
+                            self._needs_sweep = True  # slot may still be held
+                        elif len(req.tokens) >= req.g_max:
+                            self._finish(req, now)  # overshoot rows discarded
+                        if req.finished:
+                            break
+            if tr.enabled:
+                sp.args = {"n_tokens": self.tokens_fetched - fetched0}
 
     def drain(self) -> None:
         """Run until every submitted request has completed and every token

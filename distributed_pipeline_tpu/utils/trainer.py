@@ -330,10 +330,13 @@ class TrainLoop:
                                              jax.process_index())
         # Span tracing (obs/): armed by the trace flag or DPT_TRACE (the
         # env rides the launcher's worker environment to every attempt of
-        # every ring, like DPT_PREFETCH_DEPTH). Off -> the NULL tracer:
-        # one attribute check per hook, no span objects, no writes. Spans
-        # are booked from the SAME measured seconds handed to the goodput
-        # tracker, so the trace and the ledger can never disagree.
+        # every ring, like DPT_PREFETCH_DEPTH). Not armed -> the tracer
+        # that follows the profiler (trace_lib.FOLLOW): one is_enabled()
+        # per hook, no span objects, no writes, until a jax.profiler
+        # session is on — then the spans below sit in the xplane beside
+        # the device's ops and in the in-memory ring. After-the-fact
+        # spans are booked from the SAME measured seconds handed to the
+        # goodput tracker, so the trace and the ledger can never disagree.
         self.tracer = trace_lib.tracer_for(
             self.checkpoint_dir, jax.process_index(), armed=self._trace)
         # global batch = per-host batch x hosts (reference trainer.py:89)
@@ -385,14 +388,14 @@ class TrainLoop:
                          (time.perf_counter() - self._construct_t0)
                          - self.goodput.get("restore_s"))
         self._g_prev_t = time.perf_counter()
-        self._g_prev_wall = time.time()
         self._g_prev_stall = self._stall_sum()
         self._g_prev_compile = self.goodput.get("compile_s")
 
     def _wrap_prefetch(self, data: Iterator) -> Iterator[DeviceBatch]:
         return prefetch_to_device(
             data, put=self._prepare, depth=self.prefetch_depth,
-            length_of=self.get_batch_length, stats=self.stalls)
+            length_of=self.get_batch_length, stats=self.stalls,
+            tracer=self.tracer)
 
     def set_data(self, data: Iterator, *, eval_data: Optional[Iterator] = None,
                  eval_batches_consumed: Optional[int] = None,
@@ -634,13 +637,14 @@ class TrainLoop:
                 return d["loss"], d
 
             def one(mb, i):
-                r = jax.random.fold_in(rng, i)
-                if with_grad:
-                    (_, d), g = jax.value_and_grad(
-                        loss_fn, has_aux=True)(params, mb, r)
-                    return g, d
-                _, d = loss_fn(params, mb, r)
-                return (), d
+                with jax.named_scope("microbatch"):
+                    r = jax.random.fold_in(rng, i)
+                    if with_grad:
+                        (_, d), g = jax.value_and_grad(
+                            loss_fn, has_aux=True)(params, mb, r)
+                        return g, d
+                    _, d = loss_fn(params, mb, r)
+                    return (), d
 
             def body(carry, xs):
                 mb, i = xs
@@ -673,28 +677,38 @@ class TrainLoop:
             grads, metrics = micro_scan(state.params, batch, rng,
                                         with_grad=True)
             gnorm = optax.global_norm(grads)
-            if clip > 0:  # reference grad_clip, trainer.py:246-255
-                scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-            if self.fused_update:
-                # single-pass kernel (ops/fused_update.py): same opt_state
-                # structure, bit-identical losses — the optax chain below
-                # is the reference twin
-                lr_fn = (self._lr_at
-                         if self.learning_steps > 0 or self.warmup_steps > 0
-                         else lambda _c: jnp.asarray(self.lr, jnp.float32))
-                params, opt_state, ema = fused_adamw_ema(
-                    state.params, grads, state.opt_state, state.ema,
-                    lr_fn=lr_fn, weight_decay=self.weight_decay,
-                    mesh=self.mesh, specs=zspecs, param_specs=pspecs)
-                params = jax.lax.with_sharding_constraint(params, pshard)
-            else:
-                updates, opt_state = opt.update(grads, state.opt_state,
-                                                state.params)
-                params = optax.apply_updates(state.params, updates)
-                params = jax.lax.with_sharding_constraint(params, pshard)
-                ema = {r: update_ema(state.ema[r], params, rate_of[r])
-                       for r in rates}
+            # named scopes are op metadata only (xprof's op profile can
+            # split the step by them); the compiled program is the same
+            with jax.named_scope("optimizer"):
+                if clip > 0:  # reference grad_clip, trainer.py:246-255
+                    scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    grads = jax.tree_util.tree_map(lambda g: g * scale,
+                                                   grads)
+                if self.fused_update:
+                    # single-pass kernel (ops/fused_update.py): same
+                    # opt_state structure, bit-identical losses — the optax
+                    # chain below is the reference twin
+                    lr_fn = (self._lr_at
+                             if self.learning_steps > 0
+                             or self.warmup_steps > 0
+                             else lambda _c: jnp.asarray(self.lr,
+                                                         jnp.float32))
+                    with jax.named_scope("fused_adamw_ema"):
+                        params, opt_state, ema = fused_adamw_ema(
+                            state.params, grads, state.opt_state,
+                            state.ema, lr_fn=lr_fn,
+                            weight_decay=self.weight_decay, mesh=self.mesh,
+                            specs=zspecs, param_specs=pspecs)
+                    params = jax.lax.with_sharding_constraint(params,
+                                                              pshard)
+                else:
+                    updates, opt_state = opt.update(grads, state.opt_state,
+                                                    state.params)
+                    params = optax.apply_updates(state.params, updates)
+                    params = jax.lax.with_sharding_constraint(params,
+                                                              pshard)
+                    ema = {r: update_ema(state.ema[r], params, rate_of[r])
+                           for r in rates}
             metrics = dict(metrics)
             metrics["grad_norm"] = gnorm          # device scalar — no sync
             metrics["lr"] = lr_at(state.step)
@@ -815,19 +829,22 @@ class TrainLoop:
         the ``data_wait_s`` stall gauge. With device prefetch on, the
         wrapper attributes its own waits internally (this call returns a
         buffered :class:`DeviceBatch` without double counting)."""
-        if self.chaos is not None:
-            # An injected iterator stall is exactly the failure the
-            # data_wait gauge measures — attribute it there so the stall
-            # lands in the goodput breakdown as input-pipeline time.
-            stalled = self.chaos.on_data(self)
-            if stalled:
-                self.stalls.add("data_wait_s", stalled)
-        if self.prefetch_depth > 0:
-            return next(self.data)
-        t0 = time.perf_counter()
-        batch = next(self.data)
-        self.stalls.add("data_wait_s", time.perf_counter() - t0)
-        return batch
+        with self.tracer.span("train.next_batch", "train"):
+            if self.chaos is not None:
+                # An injected iterator stall is exactly the failure the
+                # data_wait gauge measures — attribute it there so the
+                # stall lands in the goodput breakdown as input-pipeline
+                # time.
+                stalled = self.chaos.on_data(self)
+                if stalled:
+                    self.stalls.add("data_wait_s", stalled)
+            if self.prefetch_depth > 0:
+                return next(self.data)
+            t0 = time.perf_counter()
+            with self.tracer.span("data.host_wait", "data"):
+                batch = next(self.data)
+            self.stalls.add("data_wait_s", time.perf_counter() - t0)
+            return batch
 
     def run_step(self, batch: Union[Dict[str, np.ndarray], DeviceBatch]
                  ) -> Dict[str, Any]:
@@ -840,6 +857,14 @@ class TrainLoop:
         device scalars, but logging them is deferred: step N-k's metrics
         are fetched/logged while step N runs, so the host never blocks on
         the step it just enqueued (flush_metrics drains the tail)."""
+        tr = self.tracer
+        with tr.span("train.run_step", "train",
+                     args={"step": self.step + 1} if tr.enabled else None):
+            return self._run_step(batch)
+
+    def _run_step(self, batch: Union[Dict[str, np.ndarray], DeviceBatch]
+                  ) -> Dict[str, Any]:
+        tr = self.tracer
         if self.chaos is not None:
             # Kill/corrupt faults scheduled for the step about to run —
             # self.step is the count of COMPLETED steps, so a fault at
@@ -851,12 +876,14 @@ class TrainLoop:
             n_items = batch.n_items
         else:
             t0 = time.perf_counter()
-            prepared = self._prepare(batch)
+            with tr.span("data.h2d", "data"):
+                prepared = self._prepare(batch)
             self.stalls.add("h2d_wait_s", time.perf_counter() - t0)
             n_items = self.get_batch_length(batch)
         t0 = time.perf_counter()
         try:
-            with self.mesh, self._sanitize_guard():
+            with tr.span("train.dispatch", "train"), self.mesh, \
+                    self._sanitize_guard():
                 self.state, metrics = self._train_step(self.state, prepared)
         except Exception as e:
             # --debug_nans: dispatch jit turns a NaN into FloatingPointError
@@ -906,27 +933,29 @@ class TrainLoop:
                       + (self._stall_sum() - self._g_prev_stall))
             self.goodput.add(
                 "recompute_s", max(0.0, (now - self._g_prev_t) - booked))
-        if self.tracer.enabled:
+        if tr.enabled:
             # the step span IS the goodput step-slice (previous run_step
             # completion -> this one): same boundary, same seconds, so
             # summing trace step spans reproduces the ledger's step time
-            self.tracer.complete(
-                "step", "train", self._g_prev_wall, now - self._g_prev_t,
+            tr.complete(
+                "step", "train", trace_lib.wall_at(self._g_prev_t),
+                now - self._g_prev_t,
                 args={"step": self.step,
                       "recompute": self.step <= self.recompute_until_step})
-            self._g_prev_wall = time.time()
         self._g_prev_t = now
         self._g_prev_stall = self._stall_sum()
         self._g_prev_compile = self.goodput.get("compile_s")
         if self.progress_file:
-            self._write_beacon()
+            with tr.span("train.log", "train"):
+                self._write_beacon()
         if self.dispatch_lag > 0:
             self._inflight.append((self.step, dispatched, metrics))
             while len(self._inflight) > self.dispatch_lag:
                 self._emit_lagged()
         else:
             logger.logkvs_mean(metrics)
-        self.log_step()
+        with tr.span("train.log", "train"):
+            self.log_step()
         return metrics
 
     def _emit_lagged(self) -> None:
@@ -937,7 +966,10 @@ class TrainLoop:
         bound). The values logged are exactly the step's device scalars,
         just late."""
         step_idx, dispatched, metrics = self._inflight.popleft()
-        jax.block_until_ready(metrics["loss"])
+        tr = self.tracer
+        with tr.span("train.metrics_wait", "train",
+                     args={"step": step_idx} if tr.enabled else None):
+            jax.block_until_ready(metrics["loss"])
         self.stalls.add("device_step_s", time.perf_counter() - dispatched)
         logger.logkvs_mean(metrics)
 
@@ -1232,8 +1264,19 @@ class TrainLoop:
                         f"-> {self.profile_dir}")
         elif loop_step == stop and self._profiling:
             jax.block_until_ready(self.state.params)
-            jax.profiler.stop_trace()
-            self._profiling = False
+            self._stop_profile()
+
+    def _stop_profile(self) -> None:
+        """Close the profiler window and leave the program's own spans of
+        it (the ring of obs/trace.py: on the xplane's clock, nested, with
+        their arguments) beside the trace as ``host_spans.jsonl``."""
+        jax.profiler.stop_trace()
+        self._profiling = False
+        try:
+            trace_lib.dump(os.path.join(self.profile_dir,
+                                        "host_spans.jsonl"))
+        except OSError as e:  # telemetry: never fail the run
+            logger.warn(f"host_spans.jsonl write failed: {e}")
 
     def run_loop(self) -> None:
         """Interval-driven outer loop (reference run_loop trainer.py:175-196):
@@ -1279,8 +1322,7 @@ class TrainLoop:
                     self.save(wait=False)  # write overlaps training
         finally:
             if self._profiling:  # run ended (or raised) inside the window:
-                jax.profiler.stop_trace()  # flush the trace either way
-                self._profiling = False
+                self._stop_profile()  # flush the trace either way
             try:
                 # final flush: the last dispatch_lag steps' metrics are
                 # still in flight — without this they would never reach

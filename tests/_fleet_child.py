@@ -149,17 +149,15 @@ def step_decode() -> bool:
     """One 'decode step': every in-flight request gains one token; the
     shared sleep stands in for device time (continuous batching: the
     step costs one interval regardless of occupancy). Traced like the
-    real worker's engine track (DPT_TRACE): one decode_span per step."""
+    real worker's scheduler (DPT_TRACE): one serve.step span per tick."""
     global completed, tokens_out
     if not in_flight:
         return False
-    t0_wall = time.time() if proto.tracer.enabled else 0.0
-    time.sleep(ns.token_interval_s)
+    tr = proto.tracer
+    with tr.span("serve.step", "serve", args={
+            "queued": 0, "active": len(in_flight)} if tr.enabled else None):
+        time.sleep(ns.token_interval_s)
     now = time.time()
-    if proto.tracer.enabled:
-        proto.tracer.complete("decode_span", "engine", t0_wall,
-                              now - t0_wall,
-                              args={"in_flight": len(in_flight)})
     for rk in list(in_flight):
         payload, toks = in_flight[rk]
         toks.append(token_fn(payload["prompt"], len(toks)))

@@ -90,10 +90,36 @@ def test_trace_reader_skips_torn_tail(tmp_path):
                    for e in ct["traceEvents"])
 
 
+def _tiny_gpt2():
+    from distributed_pipeline_tpu.models import create_model_from_config
+
+    return create_model_from_config(
+        model_family="gpt2", vocab_size=64, seq_len=16, hidden_size=32,
+        num_layers=2, num_heads=2, dtype="float32")
+
+
+def _tiny_loop(wl, ckpt_dir, **kw):
+    from distributed_pipeline_tpu.data import load_data_from_args
+    from distributed_pipeline_tpu.parallel import make_mesh
+    from distributed_pipeline_tpu.utils.trainer import TrainLoop
+
+    data = load_data_from_args("train", batch_size=8,
+                               dataset="synthetic-lm", seq_len=16,
+                               vocab_size=64, seed=0)
+    return TrainLoop(model=wl, data=data, batch_size=8, lr=1e-3,
+                     learning_steps=100, log_interval=10 ** 9,
+                     save_interval=10 ** 9, mesh=make_mesh(dp=8),
+                     checkpoint_dir=ckpt_dir, seed=5, **kw)
+
+
 def test_tracing_off_path_is_free(tmp_path, monkeypatch):
     """The off path allocates NO span objects and writes nothing: span()
-    returns one shared singleton, and any _Span construction or shard
-    write during a disabled TrainLoop step is a test failure."""
+    returns one shared singleton, and any _Span construction, shard
+    write, TraceAnnotation or ring append during a TrainLoop step or a
+    DecodeServer tick with no profiler session is a test failure. What
+    the off path does cost is counted: one ``is_enabled()`` a boundary."""
+    import jax
+
     assert trace_lib.NULL.span("a") is trace_lib.NULL.span("b")
     assert trace_lib.NULL.complete("x", "c", 0.0, 1.0) == ""
     assert not trace_lib.NULL.enabled
@@ -101,32 +127,339 @@ def test_tracing_off_path_is_free(tmp_path, monkeypatch):
     def bomb(*a, **k):
         raise AssertionError("tracing-off path built a span / wrote")
 
+    asked = []
+
+    class Annotation:
+        """jax.profiler.TraceAnnotation's place: asked, never built."""
+
+        __init__ = bomb
+
+        @staticmethod
+        def is_enabled():
+            asked.append(1)
+            return False
+
+    class Ring(list):
+        append = bomb
+
     monkeypatch.delenv(trace_lib.TRACE_ENV, raising=False)
     monkeypatch.setattr(trace_lib._Span, "__init__", bomb)
     monkeypatch.setattr(trace_lib.Tracer, "_emit", bomb)
+    monkeypatch.setattr(trace_lib, "_ANNOTATION", Annotation)
+    monkeypatch.setattr(trace_lib, "_RING", Ring())
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
 
-    from distributed_pipeline_tpu.data import load_data_from_args
-    from distributed_pipeline_tpu.models import create_model_from_config
     from distributed_pipeline_tpu.parallel import make_mesh
+    from distributed_pipeline_tpu.serving import DecodeServer
     from distributed_pipeline_tpu.utils import logger
-    from distributed_pipeline_tpu.utils.trainer import TrainLoop
 
-    wl = create_model_from_config(
-        model_family="gpt2", vocab_size=64, seq_len=16, hidden_size=32,
-        num_layers=2, num_heads=2, dtype="float32")
-    data = load_data_from_args("train", batch_size=8,
-                               dataset="synthetic-lm", seq_len=16,
-                               vocab_size=64, seed=0)
-    loop = TrainLoop(model=wl, data=data, batch_size=8, lr=1e-3,
-                     learning_steps=100, log_interval=10 ** 9,
-                     save_interval=10 ** 9, mesh=make_mesh(dp=8),
-                     checkpoint_dir=str(tmp_path), seed=5)
-    assert loop.tracer is trace_lib.NULL
+    wl = _tiny_gpt2()
+    loop = _tiny_loop(wl, str(tmp_path), prefetch_depth=2, dispatch_lag=1)
+    # not armed: the tracer that follows the profiler, off with no session
+    assert loop.tracer is trace_lib.FOLLOW
+    assert not loop.tracer.enabled
+    assert loop.tracer.span("a") is trace_lib._NULL_SPAN
+    server = DecodeServer(wl, wl.init_params(jax.random.PRNGKey(3)),
+                          decode_slots=2, page_size=4, max_prompt_len=8,
+                          max_len=16, mesh=make_mesh(dp=8))
+    assert server.tracer is trace_lib.FOLLOW
     with logger.scoped_configure(format_strs=[]):
-        loop.run_step(next(loop.data))
-        loop.run_step(next(loop.data))
+        loop.run_step(loop.next_batch())
+        loop.run_step(loop.next_batch())
+        del asked[:]
+        loop.run_step(loop.next_batch())
+        per_step = len(asked)
         loop.save()
+        req = server.submit(np.arange(1, 6, dtype=np.int32), 4)
+        server.step()
+        del asked[:]
+        server.step()
+        per_tick = len(asked)
+        server.drain()
+    # the whole off cost: a boolean a boundary (train: next_batch, two
+    # data spans, run_step and its args, dispatch, the step booking,
+    # metrics_wait and its args, log; serve: step and its args, dispatch,
+    # fetch, fetch_wait and their args checks)
+    assert 1 <= per_step <= 16, per_step
+    assert 1 <= per_tick <= 16, per_tick
+    assert req.finished and req.admit_t is not None \
+        and req.finish_t >= req.submit_t + req.ttft_s
     assert not os.path.exists(trace_lib.trace_path(str(tmp_path), 0))
+    assert len(trace_lib.recorded()) == 0
+
+
+# The spans of ISSUE 27's table. LIVE spans are opened where the work
+# happens, so they are in the profiler's host plane and in the ring; the
+# three request.* spans are booked after the fact from Request's own
+# stamps (an annotation cannot start in the past): ring and shard only.
+TRAIN_SPANS = ("train.next_batch", "data.host_wait", "data.h2d",
+               "train.run_step", "train.dispatch", "train.metrics_wait",
+               "train.log")
+SERVE_SPANS = ("serve.step", "serve.sweep", "serve.admit",
+               "serve.prefill_dispatch", "serve.decode_dispatch",
+               "serve.fetch", "serve.fetch_wait", "serve.spec_round")
+REQUEST_SPANS = ("request.queue", "request.first_token", "request.decode")
+PARENTS = {   # span -> the spans it may lie directly beneath
+    "data.host_wait": {"train.next_batch"},
+    "data.h2d": {"train.next_batch", "train.run_step"},
+    "train.dispatch": {"train.run_step"},
+    "train.metrics_wait": {"train.run_step", None},   # None: flush_metrics
+    "train.log": {"train.run_step"},
+    "serve.sweep": {"serve.step"}, "serve.admit": {"serve.step"},
+    "serve.prefill_dispatch": {"serve.admit"},
+    "serve.decode_dispatch": {"serve.step"},
+    "serve.fetch": {"serve.step", None},              # None: drain's last
+    "serve.fetch_wait": {"serve.fetch", "serve.spec_round"},
+    "serve.spec_round": {"serve.step"},
+}
+
+
+@pytest.fixture(scope="module")
+def profiled_session(tmp_path_factory):
+    """One CPU ``jax.profiler`` session round a tiny TrainLoop (both feed
+    arms) and a tiny DecodeServer (plain and speculative): the ring's
+    events, the host plane's events, and the requests served."""
+    import glob
+    import warnings
+
+    import jax
+
+    from distributed_pipeline_tpu.parallel import make_mesh
+    from distributed_pipeline_tpu.serving import DecodeServer
+    from distributed_pipeline_tpu.utils import logger
+
+    tmp = tmp_path_factory.mktemp("profiled")
+    wl = _tiny_gpt2()
+    params = wl.init_params(jax.random.PRNGKey(3))
+    mesh = make_mesh(dp=8)
+    kw = dict(decode_slots=2, page_size=4, max_prompt_len=8, max_len=16,
+              mesh=mesh)
+    prompt = np.arange(1, 6, dtype=np.int32)
+    trace_lib.clear_recorded()
+    with logger.scoped_configure(format_strs=[]):
+        fed = _tiny_loop(wl, str(tmp / "a"), prefetch_depth=2,
+                         dispatch_lag=1)
+        eager = _tiny_loop(wl, str(tmp / "b"))
+        server = DecodeServer(wl, params, **kw)
+        spec = DecodeServer(wl, params, spec_tokens=2, **kw)
+        # warm every program outside the session; a later token of the
+        # greedy answer becomes the EOS that makes the session sweep
+        for loop in (fed, eager):
+            loop.run_step(loop.next_batch())
+        warm = [srv.submit(prompt, 6) for srv in (server, spec)]
+        server.drain()
+        spec.drain()
+        assert warm[0].tokens == warm[1].tokens
+        answer = warm[0].tokens
+        eos_at = next(i for i, t in enumerate(answer) if t != answer[0])
+        assert trace_lib.recorded() == []      # no session: ring empty
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp / "xplane"), profiler_options=opts)
+        for loop in (fed, eager):
+            for _ in range(3):
+                loop.run_step(loop.next_batch())
+            loop.flush_metrics()
+        reqs = [server.submit(prompt, 6, eos_id=answer[eos_at]),
+                server.submit(prompt[:3], 5),
+                server.submit(prompt[:4], 4,
+                              trace_id=trace_lib.request_trace_id(4242))]
+        server.drain()
+        reqs.append(spec.submit(      # its own counter: ids would collide
+            prompt, 6, trace_id=trace_lib.request_trace_id(4243)))
+        spec.drain()
+        jax.profiler.stop_trace()
+        for loop in (fed, eager):
+            loop.run_step(loop.next_batch())   # after it: ring untouched
+    ring = trace_lib.recorded()
+    trace_lib.clear_recorded()
+    from jax.profiler import ProfileData
+    found = glob.glob(str(tmp / "xplane" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    plane = {}
+    for pl in ProfileData.from_file(found[0]).planes:
+        if pl.name.startswith("/host:CPU"):
+            for line in pl.lines:
+                for e in line.events:
+                    with warnings.catch_warnings():
+                        # "builtin type event_stats has no __module__"
+                        warnings.simplefilter("ignore", DeprecationWarning)
+                        stats = dict(e.stats)
+                    plane.setdefault(e.name, []).append(
+                        (e.start_ns * 1e-9, e.duration_ns * 1e-9, stats))
+    return {"ring": ring, "plane": plane, "reqs": reqs,
+            "eos_tokens": eos_at + 1}
+
+
+@pytest.mark.parametrize("name", TRAIN_SPANS + SERVE_SPANS)
+def test_profiler_session_puts_live_span_in_host_plane_and_ring(
+        profiled_session, name):
+    """Two sinks, one clock: the span is a TraceAnnotation in /host:CPU
+    and an event in the ring, as often in the one as in the other, each
+    pair's durations within 1 ms and ``t - start_ns`` one constant (the
+    session's start) for every span of the session."""
+    ring = [e for e in profiled_session["ring"] if e["name"] == name]
+    plane = sorted(profiled_session["plane"].get(name, []))
+    assert ring and len(ring) == len(plane), (name, len(ring), len(plane))
+    ring.sort(key=lambda e: e["t"])
+    for e, (start, dur, _stats) in zip(ring, plane):
+        assert abs(e["dur"] - dur) < 1e-3, (name, e["dur"], dur)
+    anchor = min(e["t"] for e in profiled_session["ring"]
+                 if e["name"] in TRAIN_SPANS + SERVE_SPANS)
+    first = min(s for n in TRAIN_SPANS + SERVE_SPANS
+                for s, _, _ in profiled_session["plane"][n])
+    for e, (start, _dur, _stats) in zip(ring, plane):
+        assert abs((e["t"] - start) - (anchor - first)) < 1e-3, name
+
+
+def test_span_arguments_reach_both_sinks(profiled_session):
+    """What a span knows when it opens is an annotation's stat and the
+    ring event's args; what only its end knows (admit, fetch) is in the
+    ring alone."""
+    ring, plane = profiled_session["ring"], profiled_session["plane"]
+    steps = [e["args"]["step"] for e in ring if e["name"] == "train.run_step"]
+    assert sorted(steps) == [2, 2, 3, 3, 4, 4]
+    assert sorted(int(st["step"]) for _, _, st in plane["train.run_step"]) \
+        == sorted(steps)
+    tick = next(e for e in ring if e["name"] == "serve.step")
+    assert tick["args"] == {"queued": 3, "active": 0}
+    assert {"queued", "active"} <= set(plane["serve.step"][0][2])
+    admits = [e["args"] for e in ring if e["name"] == "serve.admit"]
+    assert sum(a["n"] for a in admits) == 4
+    assert sum(a["prompt_tokens"] for a in admits) == 5 + 3 + 4 + 5
+    fetched = sum(e["args"]["n_tokens"] for e in ring
+                  if e["name"] == "serve.fetch")
+    # the plain server's tokens, and the one token the speculative server
+    # fetches from its prefill (its rounds fetch inside serve.spec_round)
+    assert fetched == 1 + sum(len(r.tokens)
+                              for r in profiled_session["reqs"][:3])
+
+
+def test_children_lie_inside_parents(profiled_session):
+    ring = profiled_session["ring"]
+    by_sid = {e["sid"]: e for e in ring}
+    assert len(by_sid) == len(ring)             # ids unique in the ring
+    seen = set()
+    for e in ring:
+        if e["name"] not in PARENTS:
+            continue
+        parent = by_sid.get(e.get("parent"))
+        pname = parent["name"] if parent else None
+        assert pname in PARENTS[e["name"]], (e["name"], pname)
+        seen.add((e["name"], pname))
+        if parent is not None:
+            # wall anchors are time.time() readings: microseconds of slack
+            assert e["t"] >= parent["t"] - 1e-4, (e, parent)
+            assert e["t"] + e["dur"] <= parent["t"] + parent["dur"] + 1e-4
+    # both feed arms ran: the prefetch generator under next_batch, the
+    # eager arm's transfer under run_step
+    assert ("data.h2d", "train.next_batch") in seen
+    assert ("data.h2d", "train.run_step") in seen
+    assert ("serve.fetch_wait", "serve.spec_round") in seen
+
+
+def test_request_spans_are_the_requests_own_stamps(profiled_session):
+    ring, reqs = profiled_session["ring"], profiled_session["reqs"]
+    assert not any(n in profiled_session["plane"] for n in REQUEST_SPANS)
+    spans = {}
+    for e in ring:
+        if e["name"] in REQUEST_SPANS:
+            assert e["cat"] == "request"
+            spans.setdefault(e["trace"], {})[e["name"]] = e
+    assert trace_lib.request_trace_id(4242) in spans   # the caller's id
+    assert len(spans) == len(reqs)
+    for r in reqs:
+        got = spans[r.trace_id or trace_lib.request_trace_id(r.id)]
+        assert set(got) == set(REQUEST_SPANS)
+        assert r.submit_t <= r.admit_t <= r.submit_t + r.ttft_s <= r.finish_t
+        q, f, d = (got[n] for n in REQUEST_SPANS)
+        assert q["args"] == {"id": r.id, "prompt_len": r.prompt_len}
+        assert d["args"] == {"id": r.id, "n_tokens": len(r.tokens)}
+        # span and field cannot disagree: to the microsecond
+        assert q["dur"] == pytest.approx(r.admit_t - r.submit_t, abs=1e-6)
+        assert q["dur"] + f["dur"] == pytest.approx(r.ttft_s, abs=1e-6)
+        assert d["dur"] == pytest.approx(
+            r.finish_t - r.submit_t - r.ttft_s, abs=1e-6)
+        assert f["t"] == pytest.approx(q["t"] + q["dur"], abs=1e-3)
+    # the EOS request stopped early and was swept
+    assert len(reqs[0].tokens) == profiled_session["eos_tokens"] < 6
+
+
+def test_armed_server_books_into_its_shard_under_the_routers_id(tmp_path):
+    """--trace/DPT_TRACE with no profiler session (a fleet replica): the
+    scheduler's spans and the request's life land in the shard, under the
+    trace id the router minted; the ring stays empty."""
+    import jax
+
+    from distributed_pipeline_tpu.parallel import make_mesh
+    from distributed_pipeline_tpu.serving import DecodeServer
+
+    trace_lib.clear_recorded()
+    wl = _tiny_gpt2()
+    tr = trace_lib.tracer_for(str(tmp_path), 0, armed=True, proc="r1.rank0")
+    server = DecodeServer(wl, wl.init_params(jax.random.PRNGKey(3)),
+                          decode_slots=2, page_size=4, max_prompt_len=8,
+                          max_len=16, mesh=make_mesh(dp=8), tracer=tr)
+    tid = trace_lib.request_trace_id(77)
+    req = server.submit(np.arange(1, 6, dtype=np.int32), 3, trace_id=tid)
+    server.drain()
+    tr.close()
+    events = trace_lib.read_trace(trace_lib.trace_path(str(tmp_path), 0))
+    names = {e["name"] for e in events}
+    assert {"serve.step", "serve.admit", "serve.prefill_dispatch",
+            "serve.decode_dispatch", "serve.fetch", "serve.fetch_wait"} \
+        <= names
+    life = {e["name"]: e for e in events if e.get("trace") == tid}
+    assert set(life) == set(REQUEST_SPANS)
+    assert life["request.queue"]["dur"] + life["request.first_token"]["dur"] \
+        == pytest.approx(req.ttft_s, abs=1e-6)
+    assert all(e["sid"].startswith("r1.rank0:") for e in events)
+    assert trace_lib.recorded() == []
+
+
+def test_profile_window_leaves_host_spans_beside_the_trace(tmp_path):
+    """TrainLoop's own profiler window (--profile_dir): on stopping it the
+    ring is dumped as host_spans.jsonl, readable by read_trace."""
+    from distributed_pipeline_tpu.utils import logger
+
+    trace_lib.clear_recorded()
+    loop = _tiny_loop(_tiny_gpt2(), str(tmp_path / "run"),
+                      profile_dir=str(tmp_path / "prof"),
+                      profile_steps="1:3", prefetch_depth=2, dispatch_lag=1)
+    loop.learning_steps = 4
+    with logger.scoped_configure(format_strs=[]):
+        loop.run_loop()
+    events = trace_lib.read_trace(str(tmp_path / "prof" /
+                                      "host_spans.jsonl"))
+    trace_lib.clear_recorded()
+    names = [e["name"] for e in events]
+    assert names.count("train.run_step") == 2      # loop steps 1 and 2
+    assert {"train.next_batch", "train.dispatch", "step"} <= set(names)
+
+
+def test_router_and_status_import_path_imports_no_jax():
+    """obs/trace.py follows the profiler only if jax is ALREADY imported:
+    the router, the fleet parent, the exporter and the status CLI stay
+    jax-free, and their unarmed tracer is off for good."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from distributed_pipeline_tpu.obs import trace, export\n"
+        "from distributed_pipeline_tpu.serving import router, fleet\n"
+        "from distributed_pipeline_tpu.run import status\n"
+        "tr = trace.tracer_for('', 'router')\n"
+        "assert tr is trace.FOLLOW and not tr.enabled\n"
+        "assert tr.span('x') is trace._NULL_SPAN\n"
+        "assert tr.complete('x', 'c', 0.0, 1.0) == ''\n"
+        "assert tr.instant('x') == '' and not trace.recorded()\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run([sys.executable, "-c", code], cwd=root,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
 
 
 def test_trainloop_traced_spans_match_goodput_boundaries(tmp_path,
@@ -404,6 +737,14 @@ def test_traced_fleet_kill_and_swap_export_one_timeline(tmp_path,
         assert {"drain", "swap"} <= names, (rid, names)
     assert any(e["name"] == "ready"
                and e["args"].get("params_step") == 2 for e in events)
+
+    # the scheduler's own tick spans stand where the worker's guessed
+    # `engine` track stood: a serve.step span on the `serve` track of a
+    # replica's pid, and nothing left under `engine`
+    ticks = [e for e in events if e["name"] == "serve.step"]
+    assert ticks and all(e["cat"] == "serve" and e["pid"] != router_pid
+                         for e in ticks)
+    assert not any(e["cat"] == "engine" for e in events)
 
     # span ids stay unique across the MERGED fleet timeline: the worker
     # labels are replica-qualified (r1.rank0) and attempt-qualified
