@@ -647,8 +647,8 @@ def main() -> None:
             else jax.numpy.dtype("float32")
         abstract = (
             jax.ShapeDtypeStruct((slots, h, dh), jdt),
-            jax.ShapeDtypeStruct((pool_pages, page_size, h, dh), jdt),
-            jax.ShapeDtypeStruct((pool_pages, page_size, h, dh), jdt),
+            jax.ShapeDtypeStruct((pool_pages, page_size, h * dh), jdt),
+            jax.ShapeDtypeStruct((pool_pages, page_size, h * dh), jdt),
             jax.ShapeDtypeStruct((slots, n_pages), jax.numpy.int32),
             jax.ShapeDtypeStruct((slots,), jax.numpy.int32),
         )

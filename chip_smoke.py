@@ -641,12 +641,12 @@ def _decode_child(spec_path: str) -> None:
             "auto", (n_pool, ps, H, Dh))}
     for kv in ("bf16", "int8"):
         if kv == "int8":
-            pools = [jnp.asarray(rng.integers(-127, 128, (n_pool, ps, H, Dh)),
+            pools = [jnp.asarray(rng.integers(-127, 128, (n_pool, ps, H * Dh)),
                                  jnp.int8) for _ in range(2)]
             scales = [jnp.asarray(rng.uniform(0.5, 1.5, (n_pool,)) / 127.0,
                                   jnp.float32) for _ in range(2)]
         else:
-            pools = [jnp.asarray(rng.standard_normal((n_pool, ps, H, Dh)),
+            pools = [jnp.asarray(rng.standard_normal((n_pool, ps, H * Dh)),
                                  jnp.bfloat16) for _ in range(2)]
             scales = [None, None]
         for span in (0, L):

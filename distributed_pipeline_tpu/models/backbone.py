@@ -55,7 +55,8 @@ class SelfAttention(nn.Module):
     ``paged_pages > 0`` (with ``decode=True``) switches the cache to the
     PAGED layout behind the serving layer (serving/paged_kv.py): K/V live in
     a shared pool of fixed-size pages (``pages_k``/``pages_v`` variables,
-    [paged_pages, page_size, H, Dh]) indirected through a per-slot
+    [paged_pages, page_size, H * Dh]: a token's heads in one lane-dense
+    row, serving/paged_kv.py says why) indirected through a per-slot
     ``block_table`` [B, pages_per_slot] argument, and ``cache_index`` is a
     PER-SLOT position vector [B] — each decode slot sits at its own depth,
     which is what continuous batching needs. Page 0 is the trash page:
@@ -126,10 +127,10 @@ class SelfAttention(nn.Module):
         quant = self.kv_quant == "int8"
         pool_dtype = jnp.int8 if quant else k.dtype
         pk = self.variable("cache", "pages_k", jnp.zeros,
-                           (self.paged_pages, self.page_size, H, Dh),
+                           (self.paged_pages, self.page_size, H * Dh),
                            pool_dtype)
         pv = self.variable("cache", "pages_v", jnp.zeros,
-                           (self.paged_pages, self.page_size, H, Dh),
+                           (self.paged_pages, self.page_size, H * Dh),
                            pool_dtype)
         sk = sv = None
         if quant:  # [P] per-page fp32 scale sidecars

@@ -7,10 +7,11 @@ token branch) is pure XLA today: ``gather_kv`` materializes a dense
 generated token that is ~3x the live K/V bytes (pool read + copy write +
 copy read), and it scales with the slot's page RESERVATION, not its live
 length. This kernel removes the copy: each grid step DMAs ONE live page
-``[page_size, H, Dh]`` directly from the pool through the slot's block
-table, folds it into online-softmax scratch in VMEM, and writes only the
-``[B, H, Dh]`` output. Dead pages and inactive slots never enter the
-schedule (the compressed-step-table trick from ops/flash_attention.py).
+``[page_size, H * Dh]`` directly from the pool through the slot's block
+table, splits its heads and folds it into online-softmax scratch in VMEM,
+and writes only the ``[B, H, Dh]`` output. Dead pages and inactive slots
+never enter the schedule (the compressed-step-table trick from
+ops/flash_attention.py).
 
 Step table (computed ON DEVICE inside the jitted decode step — positions
 and block tables are data, so the table costs no recompile and no host
@@ -28,8 +29,12 @@ is set only on a slot's LAST live page — the one place the within-page
 Page-layout contract (what TP layouts and int8 pages must keep to ride
 this kernel later):
 
-* pool is ``[num_pages, page_size, H, Dh]`` per layer, K and V separate;
-  page 0 is the trash page (serving/paged_kv.py) — the kernel never reads
+* pool is ``[num_pages, page_size, H * Dh]`` per layer, K and V separate:
+  a token's heads side by side in one lane-dense row (serving/paged_kv.py
+  says why: with ``Dh`` alone in the lanes the TPU stored the pool
+  page-minor and every program relaid it). Nothing reshapes the POOL;
+  the XLA arm splits the heads of the gathered view, this kernel of the
+  page block in VMEM. Page 0 is the trash page — the kernel never reads
   it through a live step, dead steps may;
 * a block-table row lists a slot's pages head-first; entries past the live
   prefix may be anything (trash, stale, shared) — the schedule never
@@ -39,9 +44,13 @@ this kernel later):
   (the caller writes via ``write_token_kv`` BEFORE attending);
 * page sharing (serving/paged_kv.py ``PrefixCache``) is invisible here:
   two slots listing the same page id just schedule two DMAs of it;
-* on real TPU the ``(H, Dh)`` trailing dims of a page block must tile the
-  ``(8, 128)`` f32 layout; pools that don't (small models) dispatch to the
-  XLA path under ``impl="auto"`` — see :func:`resolve_decode_impl`;
+* on real TPU the kernel's ``(H, Dh)`` view of a page block (a reshape of
+  the ``[page_size, H * Dh]`` block in VMEM) must tile the ``(8, 128)``
+  f32 layout: Mosaic takes the split at ``Dh % 128 == 0`` and refuses it
+  at ``Dh`` 64 ("unsupported shape cast"); models that don't tile
+  dispatch to the XLA path under ``impl="auto"`` — see
+  :func:`resolve_decode_impl`, which reads ``H`` and ``Dh`` from its
+  caller (the stored pool no longer shows them);
 * int8 pools (serving/paged_kv.py ``write_*_kv_q8``) ride the SAME schedule:
   each page's fp32 scale is bitcast to int32 and appended to its step
   (fields 7..8, K and V scales), so the scale arrives with the scalar
@@ -107,8 +116,10 @@ def _interpret() -> bool:
 def resolve_decode_impl(impl: str, page_shape=None) -> str:
     """``auto`` -> "pallas" on TPU when the page layout tiles, else "xla".
 
-    ``page_shape`` is the pool's ``[P, page_size, H, Dh]`` (optional: auto
-    on TPU without it assumes tileable). Forced values pass through."""
+    ``page_shape`` is the pool's geometry ``(P, page_size, H, Dh)`` as the
+    caller knows it — ``H`` and ``Dh`` come from the model or the query,
+    the stored pool being ``[P, page_size, H * Dh]`` (optional: auto on TPU
+    without it assumes tileable). Forced values pass through."""
     if impl in ("pallas", "xla"):
         return impl
     if impl != "auto":
@@ -176,8 +187,11 @@ def _decode_kernel(steps_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q = q_ref[0].astype(jnp.float32)        # [H, Dh]
-    k = k_ref[0].astype(jnp.float32)        # [page_size, H, Dh]
-    v = v_ref[0].astype(jnp.float32)
+    # the page block arrives lane-dense, [page_size, H * Dh]: split the
+    # heads here, in VMEM (the pool in HBM is never reshaped)
+    split = (k_ref.shape[1],) + q.shape     # [page_size, H, Dh]
+    k = k_ref[0].reshape(split).astype(jnp.float32)
+    v = v_ref[0].reshape(split).astype(jnp.float32)
     if quant:  # int8 page + per-page scale riding the step table (bitcast)
         # (bitcast wants a vector on the chip: splat the SMEM word first)
         def scale_of(col):
@@ -232,13 +246,13 @@ def flash_decode(q: jnp.ndarray, pages_k: jnp.ndarray, pages_v: jnp.ndarray,
                  block_table: jnp.ndarray, positions: jnp.ndarray,
                  scales_k=None, scales_v=None) -> jnp.ndarray:
     """Paged single-query attention: ``q`` [B, H, Dh], pool
-    ``[P, page_size, H, Dh]``, ``block_table`` [B, n_pages], ``positions``
+    ``[P, page_size, H * Dh]``, ``block_table`` [B, n_pages], ``positions``
     [B] -> [B, H, Dh]. Attends positions ``0..positions[b]`` of each slot
     through its block table; everything later is skipped at schedule level.
     ``scales_k``/``scales_v`` ([P] fp32) flag an int8 pool: the kernel
     dequantizes each DMA'd page with its scale from the step table."""
     B, H, Dh = q.shape
-    _, page_size, _, _ = pages_k.shape
+    page_size = pages_k.shape[1]
     quant = scales_k is not None
     steps = _build_steps(block_table, positions, page_size, B,
                          scales_k, scales_v)
@@ -251,10 +265,10 @@ def flash_decode(q: jnp.ndarray, pages_k: jnp.ndarray, pages_v: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((1, H, Dh), lambda t, s: (s[0, t], 0, 0),
                          memory_space=_VMEM),
-            pl.BlockSpec((1, page_size, H, Dh),
-                         lambda t, s: (s[1, t], 0, 0, 0), memory_space=_VMEM),
-            pl.BlockSpec((1, page_size, H, Dh),
-                         lambda t, s: (s[1, t], 0, 0, 0), memory_space=_VMEM),
+            pl.BlockSpec((1, page_size, H * Dh),
+                         lambda t, s: (s[1, t], 0, 0), memory_space=_VMEM),
+            pl.BlockSpec((1, page_size, H * Dh),
+                         lambda t, s: (s[1, t], 0, 0), memory_space=_VMEM),
         ],
         out_specs=pl.BlockSpec((1, H, Dh), lambda t, s: (s[0, t], 0, 0),
                                memory_space=_VMEM),
@@ -281,8 +295,9 @@ def xla_paged_decode(q: jnp.ndarray, pages_k: jnp.ndarray,
     (``scales_*`` given) are dequantized right after the gather."""
     from ..serving.paged_kv import dequant_gathered, gather_kv
     from .attention import dot_product_attention
-    ks = gather_kv(pages_k, block_table)        # [B, H, n*page_size, Dh]
-    vs = gather_kv(pages_v, block_table)
+    h = q.shape[1]
+    ks = gather_kv(pages_k, block_table, h)     # [B, H, n*page_size, Dh]
+    vs = gather_kv(pages_v, block_table, h)
     if scales_k is not None:
         ps = pages_k.shape[1]
         ks = dequant_gathered(ks, scales_k, block_table, ps, q.dtype)
@@ -302,7 +317,8 @@ def paged_decode_attention(q, pages_k, pages_v, block_table, positions,
     ``q`` [B, H, Dh]; returns [B, H, Dh]. The caller has already written
     the token's K/V into the pool (page-layout contract); for int8 pools it
     passes the [P] scale sidecars and both arms dequantize."""
-    if resolve_decode_impl(impl, pages_k.shape) == "pallas":
+    _, H, Dh = q.shape
+    if resolve_decode_impl(impl, pages_k.shape[:2] + (H, Dh)) == "pallas":
         return flash_decode(q, pages_k, pages_v, block_table, positions,
                             scales_k, scales_v)
     return xla_paged_decode(q, pages_k, pages_v, block_table, positions,
@@ -325,13 +341,13 @@ def xla_paged_span_decode(q: jnp.ndarray, pages_k: jnp.ndarray,
     a span link's output is bitwise the single-token output at the same
     position — the spec-decode identity contract rides on this."""
     from ..serving.paged_kv import dequant_gathered, gather_kv
-    ks = gather_kv(pages_k, block_table)        # [B, H, n*page_size, Dh]
-    vs = gather_kv(pages_v, block_table)
+    h, dh = q.shape[1], q.shape[-1]
+    ks = gather_kv(pages_k, block_table, h)     # [B, H, n*page_size, Dh]
+    vs = gather_kv(pages_v, block_table, h)
     if scales_k is not None:
         ps = pages_k.shape[1]
         ks = dequant_gathered(ks, scales_k, block_table, ps, q.dtype)
         vs = dequant_gathered(vs, scales_v, block_table, ps, q.dtype)
-    dh = q.shape[-1]
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, ks) * jnp.asarray(
         dh ** -0.5, q.dtype)
     live = (jnp.arange(ks.shape[2])[None, None, :]
@@ -352,7 +368,7 @@ def paged_span_attention(q, pages_k, pages_v, block_table, positions,
     pseudo-slots (each link repeats its slot's block-table row); the XLA
     arm gathers each slot once and masks per link."""
     B, H, L, Dh = q.shape
-    if resolve_decode_impl(impl, pages_k.shape) == "pallas":
+    if resolve_decode_impl(impl, pages_k.shape[:2] + (H, Dh)) == "pallas":
         qf = q.transpose(0, 2, 1, 3).reshape(B * L, H, Dh)
         bt = jnp.repeat(block_table, L, axis=0)
         o = flash_decode(qf, pages_k, pages_v, bt, positions.reshape(-1),

@@ -391,16 +391,17 @@ class DecodeEngine:
 
     def _pool_leaves(self) -> list:
         """(path-key, leaf) pairs for the paged K/V pool leaves of the
-        cache pytree. The pools are the only 4-D
-        ``[max_pages, page_size, H, Dh]`` leaves (backbone
+        cache pytree. The pools are the only 3-D
+        ``[max_pages, page_size, H * Dh]`` leaves (backbone
         ``_paged_attention`` creates exactly ``pages_k``/``pages_v`` per
-        layer), and ``jax.tree_util.keystr`` names each deterministically
+        layer; a payload row is one token's heads side by side), and
+        ``jax.tree_util.keystr`` names each deterministically
         — a decode engine built from the same model config on ANOTHER
         process derives the same keys, which is what makes the
         extract/ingest wire format stable across a StageLink."""
         flat, _ = jax.tree_util.tree_flatten_with_path(self.cache)
         return [(jax.tree_util.keystr(path), leaf) for path, leaf in flat
-                if (getattr(leaf, "ndim", 0) == 4
+                if (getattr(leaf, "ndim", 0) == 3
                     and leaf.shape[0] == self.max_pages
                     and leaf.shape[1] == self.page_size)
                 # int8 pools: the [P] per-page scale sidecars are page

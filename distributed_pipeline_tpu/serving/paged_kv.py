@@ -13,11 +13,21 @@ the pool size, not ``slots x max_len``.
 Device side (this module, pure jax — it is a leaf: no framework imports, so
 models/backbone.py can call into it without a cycle):
 
-* pages tensor per layer: ``[num_pages, page_size, H, Dh]`` for K and V;
-* :func:`write_prompt_kv` — scatter a prefill's [B, H, L, Dh] K/V rows into
-  the slots' pages (invalid/padded rows -> the trash page);
-* :func:`write_token_kv`  — scatter one decode step's [B, H, Dh] row at each
-  slot's own position;
+* pages tensor per layer: ``[num_pages, page_size, H * Dh]`` for K and V —
+  a token's heads lie side by side in ONE lane-dense row. The TPU tiles an
+  array's two minor dimensions to (8, 128) ((16, 128) for bf16): with
+  ``Dh`` = 64 alone in the lanes the compiler stored a ``[P, ps, H, Dh]``
+  pool page-index-minor and relaid every pool to row-major and back in
+  every program that scatters or gathers by page (three pool-sized copies
+  a pool a decode step on the v5e, PERF.md PR 28). ``H * Dh`` is the model
+  width: whole lane tiles, no padding, and the layout the page-indexed
+  scatter and gather want. The heads are split from the GATHERED view,
+  never from the pool;
+* :func:`write_prompt_kv` — scatter a prefill's [B, H, L, Dh] K/V as
+  ``[B * L, H * Dh]`` rows into the slots' pages (invalid/padded rows ->
+  the trash page);
+* :func:`write_token_kv`  — scatter one decode step's [B, H, Dh] as
+  ``[B, H * Dh]`` rows at each slot's own position;
 * :func:`gather_kv`       — gather a slot-major dense ``[B, H, Lmax, Dh]``
   view for attention (the pure-XLA stand-in for a fused flash-decode
   kernel, which slots in behind the same seam later — ROADMAP item 4).
@@ -53,18 +63,27 @@ TRASH_PAGE = 0  # reserved: masked/invalid writes land here, reads never do
 Q8_MAX = 127.0  # symmetric int8: value = q * scale, q in [-127, 127]
 
 
-def gather_kv(pages: jnp.ndarray, block_table: jnp.ndarray) -> jnp.ndarray:
+def _rows(kv: jnp.ndarray) -> jnp.ndarray:
+    """[B, H, L, Dh] -> the pool's row form [B * L, H * Dh]."""
+    b, h, l, dh = kv.shape
+    return kv.transpose(0, 2, 1, 3).reshape(b * l, h * dh)
+
+
+def gather_kv(pages: jnp.ndarray, block_table: jnp.ndarray,
+              num_heads: int) -> jnp.ndarray:
     """Dense per-slot view of the paged pool.
 
-    ``pages`` [P, page_size, H, Dh], ``block_table`` [B, n_pages] ->
-    [B, H, n_pages * page_size, Dh]. Entries beyond a slot's live length
-    are trash-page garbage; the caller masks them (backbone
-    ``_paged_attention``), and masked entries contribute exact zeros to the
-    softmax — at equal padded length the result is bit-identical to the
-    dense cache."""
-    g = pages[block_table]                        # [B, n, page_size, H, Dh]
-    b, n, ps, h, dh = g.shape
-    return g.reshape(b, n * ps, h, dh).transpose(0, 2, 1, 3)
+    ``pages`` [P, page_size, H * Dh], ``block_table`` [B, n_pages] ->
+    [B, H, n_pages * page_size, Dh] (the heads are split here, from the
+    gathered view; the pool itself is never reshaped). Entries beyond a
+    slot's live length are trash-page garbage; the caller masks them
+    (backbone ``_paged_attention``), and masked entries contribute exact
+    zeros to the softmax — at equal padded length the result is
+    bit-identical to the dense cache."""
+    g = pages[block_table]                        # [B, n, page_size, H*Dh]
+    b, n, ps, hd = g.shape
+    return g.reshape(b, n * ps, num_heads,
+                     hd // num_heads).transpose(0, 2, 1, 3)
 
 
 def write_prompt_kv(pages: jnp.ndarray, block_table: jnp.ndarray,
@@ -73,30 +92,31 @@ def write_prompt_kv(pages: jnp.ndarray, block_table: jnp.ndarray,
 
     ``kv`` [B, H, L, Dh] holds positions 0..L-1 of each slot's prompt;
     ``valid`` [B, L] (1 = real prompt token) routes padded tail positions
-    to the trash page instead. Returns the updated pages tensor."""
-    b, h, l, dh = kv.shape
+    to the trash page instead. Returns the updated pages tensor
+    ([P, page_size, H * Dh], as it came)."""
+    b, _, l, _ = kv.shape
     ps = pages.shape[1]
     pos = jnp.arange(l, dtype=jnp.int32)
     page_idx = jnp.minimum(pos // ps, block_table.shape[1] - 1)
     phys = block_table[:, page_idx]               # [B, L]
     phys = jnp.where(valid > 0, phys, TRASH_PAGE)
-    rows = kv.transpose(0, 2, 1, 3).reshape(b * l, h, dh)
     off = jnp.broadcast_to(pos % ps, (b, l)).reshape(-1)
-    return pages.at[phys.reshape(-1), off].set(rows)
+    return pages.at[phys.reshape(-1), off].set(_rows(kv))
 
 
 def write_token_kv(pages: jnp.ndarray, block_table: jnp.ndarray,
                    kv: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
     """Scatter one decode step's K (or V) row at each slot's own position.
 
-    ``kv`` [B, H, Dh]; ``positions`` [B] is the index being written. Slots
-    whose block-table row is all trash (inactive/freed) write to the trash
-    page; positions past the table width clamp into the row, whose value is
-    then trash for exactly those slots."""
+    ``kv`` [B, H, Dh] (written as [B, H * Dh] rows); ``positions`` [B] is
+    the index being written. Slots whose block-table row is all trash
+    (inactive/freed) write to the trash page; positions past the table
+    width clamp into the row, whose value is then trash for exactly those
+    slots."""
     ps = pages.shape[1]
     page_idx = jnp.minimum(positions // ps, block_table.shape[1] - 1)
     phys = jnp.take_along_axis(block_table, page_idx[:, None], axis=1)[:, 0]
-    return pages.at[phys, positions % ps].set(kv)
+    return pages.at[phys, positions % ps].set(kv.reshape(kv.shape[0], -1))
 
 
 def write_span_kv(pages: jnp.ndarray, block_table: jnp.ndarray,
@@ -114,13 +134,12 @@ def write_span_kv(pages: jnp.ndarray, block_table: jnp.ndarray,
     by the next legitimate feed before any query reads it, so a clamp
     collision's last-write-wins nondeterminism can never reach an
     accepted token."""
-    b, h, l, dh = kv.shape
+    l = kv.shape[2]
     ps = pages.shape[1]
     pos = start[:, None] + jnp.arange(l, dtype=jnp.int32)[None, :]  # [B, L]
     pos = jnp.minimum(pos, block_table.shape[1] * ps - 1)
     phys = jnp.take_along_axis(block_table, pos // ps, axis=1)      # [B, L]
-    rows = kv.transpose(0, 2, 1, 3).reshape(b * l, h, dh)
-    return pages.at[phys.reshape(-1), (pos % ps).reshape(-1)].set(rows)
+    return pages.at[phys.reshape(-1), (pos % ps).reshape(-1)].set(_rows(kv))
 
 
 def _q8(rows: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
@@ -138,23 +157,24 @@ def write_prompt_kv_q8(pages: jnp.ndarray, scales: jnp.ndarray,
     """int8 twin of :func:`write_prompt_kv`: quantize a prefill's K (or V)
     rows at page granularity and SET each touched page's scale.
 
-    ``pages`` is the int8 pool, ``scales`` the [P] fp32 sidecar. A touched
-    page's scale becomes ``absmax(its prompt rows) / 127`` — SET, not
-    max-accumulated against the leftover scale of whatever request used the
-    page before, so quantization is a pure function of prompt content and a
-    shared-prefix page is rewritten identically by every sharing prefill
+    ``pages`` is the int8 pool ([P, page_size, H * Dh]), ``scales`` the
+    [P] fp32 sidecar. A touched page's scale becomes
+    ``absmax(its prompt rows) / 127`` — SET, not max-accumulated against
+    the leftover scale of whatever request used the page before, so
+    quantization is a pure function of prompt content and a shared-prefix
+    page is rewritten identically by every sharing prefill
     (the PrefixCache soundness argument survives quantization: same tokens
     -> same rows -> same scale -> same int8 bits). Untouched pages (and the
     trash page, which every prefill scribbles on) keep their scales: the
     trash scale is garbage, but no read ever maps it."""
-    b, h, l, dh = kv.shape
+    b, _, l, _ = kv.shape
     ps = pages.shape[1]
     pos = jnp.arange(l, dtype=jnp.int32)
     page_idx = jnp.minimum(pos // ps, block_table.shape[1] - 1)
     phys = block_table[:, page_idx]               # [B, L]
     phys = jnp.where(valid > 0, phys, TRASH_PAGE).reshape(-1)
-    rows = kv.transpose(0, 2, 1, 3).reshape(b * l, h, dh)
-    row_amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=(1, 2))
+    rows = _rows(kv)
+    row_amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=1)
     fresh = jnp.zeros_like(scales).at[phys].max(row_amax / Q8_MAX)
     touched = jnp.zeros_like(scales, dtype=jnp.int32).at[phys].max(1)
     # trash writes must not perturb the (meaningless but live-indexed)
@@ -162,7 +182,7 @@ def write_prompt_kv_q8(pages: jnp.ndarray, scales: jnp.ndarray,
     touched = touched.at[TRASH_PAGE].set(0)
     new_scales = jnp.where(touched > 0, fresh, scales)
     off = jnp.broadcast_to(pos % ps, (b, l)).reshape(-1)
-    q = _q8(rows, new_scales[phys][:, None, None])
+    q = _q8(rows, new_scales[phys][:, None])
     return pages.at[phys, off].set(q), new_scales
 
 
@@ -183,15 +203,16 @@ def write_token_kv_q8(pages: jnp.ndarray, scales: jnp.ndarray,
     ps = pages.shape[1]
     page_idx = jnp.minimum(positions // ps, block_table.shape[1] - 1)
     phys = jnp.take_along_axis(block_table, page_idx[:, None], axis=1)[:, 0]
-    row_amax = jnp.max(jnp.abs(kv.astype(jnp.float32)), axis=(1, 2))  # [B]
+    rows = kv.reshape(kv.shape[0], -1)            # [B, H*Dh]
+    row_amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=1)  # [B]
     old = scales[phys]
     new = jnp.maximum(old, row_amax / Q8_MAX)
     ratio = jnp.where(new > 0, old / jnp.where(new > 0, new, 1.0), 0.0)
-    page = pages[phys].astype(jnp.float32)        # [B, ps, H, Dh]
-    page = jnp.clip(jnp.round(page * ratio[:, None, None, None]),
+    page = pages[phys].astype(jnp.float32)        # [B, ps, H*Dh]
+    page = jnp.clip(jnp.round(page * ratio[:, None, None]),
                     -Q8_MAX, Q8_MAX).astype(jnp.int8)
     page = page.at[jnp.arange(phys.shape[0]), positions % ps].set(
-        _q8(kv, new[:, None, None]))
+        _q8(rows, new[:, None]))
     # duplicate phys ids only ever happen on the trash page (inactive
     # slots) — last-write-wins there is fine, nothing reads it
     return pages.at[phys].set(page), scales.at[phys].set(new)
@@ -212,21 +233,21 @@ def write_span_kv_q8(pages: jnp.ndarray, scales: jnp.ndarray,
     O(pool) compute instead of O(touched pages). Verify dispatches are
     span-granular (one per K-token round), so the extra traffic
     amortizes; swap to a page-set scatter if TPU profiles object."""
-    b, h, l, dh = kv.shape
+    l = kv.shape[2]
     ps = pages.shape[1]
     pos = start[:, None] + jnp.arange(l, dtype=jnp.int32)[None, :]  # [B, L]
     pos = jnp.minimum(pos, block_table.shape[1] * ps - 1)
     phys = jnp.take_along_axis(block_table, pos // ps, axis=1).reshape(-1)
-    rows = kv.transpose(0, 2, 1, 3).reshape(b * l, h, dh)
-    row_amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=(1, 2))
+    rows = _rows(kv)
+    row_amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=1)
     new_scales = scales.at[phys].max(row_amax / Q8_MAX)
     ratio = jnp.where(new_scales > 0,
                       scales / jnp.where(new_scales > 0, new_scales, 1.0),
                       0.0)
     pages = jnp.clip(jnp.round(pages.astype(jnp.float32)
-                               * ratio[:, None, None, None]),
+                               * ratio[:, None, None]),
                      -Q8_MAX, Q8_MAX).astype(jnp.int8)
-    q = _q8(rows, new_scales[phys][:, None, None])
+    q = _q8(rows, new_scales[phys][:, None])
     return pages.at[phys, (pos % ps).reshape(-1)].set(q), new_scales
 
 

@@ -14,6 +14,7 @@ without a chip and would only warn.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -240,7 +241,7 @@ def _decode_args(one_chip, kv_dtype, L=0):
     pages, 1024 tokens a slot."""
     B, H, Dh, ps, n = 8, 16, 128, 16, 64
     qshape = (B, H, L, Dh) if L else (B, H, Dh)
-    pool = sds((1 + B * n, ps, H, Dh), kv_dtype, one_chip)
+    pool = sds((1 + B * n, ps, H * Dh), kv_dtype, one_chip)
     args = [sds(qshape, jnp.bfloat16, one_chip), pool, pool,
             sds((B, n), jnp.int32, one_chip),
             sds((B, L) if L else (B,), jnp.int32, one_chip)]
@@ -255,7 +256,9 @@ def _decode_args(one_chip, kv_dtype, L=0):
 def test_flash_decode_compiles(one_chip, as_on_tpu, kv, span):
     kv_dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
     args = _decode_args(one_chip, kv_dtype, span)
-    assert fd.resolve_decode_impl("auto", args[1].shape) == "pallas"
+    q = args[0].shape                       # H, Dh come from the query
+    assert fd.resolve_decode_impl(
+        "auto", args[1].shape[:2] + (q[1], q[-1])) == "pallas"
     seam = fd.paged_span_attention if span else fd.paged_decode_attention
 
     def f(q, pk, pv, bt, pos, sk=None, sv=None):
@@ -264,3 +267,77 @@ def test_flash_decode_compiles(one_chip, as_on_tpu, kv, span):
 
     c = jax.jit(f).lower(*args).compile()
     assert_kernel(c, fd.KERNEL_NAME)
+
+
+# --------------------------------------- the serving programs and the pool
+
+_HLO_OP = re.compile(r"= (\w+)\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(")
+
+
+def pool_sized_copies(text, n_elements):
+    """``copy`` ops of a compiled program whose result holds at least
+    ``n_elements``: the relayouts of a whole page pool, if any."""
+    found = []
+    for ln in text.splitlines():
+        m = _HLO_OP.search(ln)
+        if m and m.group(3) == "copy" and np.prod(
+                [int(d) for d in m.group(2).split(",") if d]) >= n_elements:
+            found.append(f"{m.group(1)}[{m.group(2)}]")
+    return found
+
+
+@pytest.mark.parametrize("heads", [12, 20])
+@pytest.mark.parametrize("kv_quant", ["fp", "int8"], ids=["bf16", "int8"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "span4"])
+def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
+                                                   program, kv_quant, heads):
+    """The engine's own program bodies at GPT-2 widths (``Dh`` 64: 'auto'
+    resolves to the XLA arm), two layers, 16 slots x 64 pages of 16. With
+    ``Dh`` alone in the lanes the chip's compiler stored each
+    ``[P, 16, H, 64]`` pool page-minor and copied it to row-major and back
+    in every program (62 % of the serve cell's device time, PERF.md PR 28);
+    stored ``[P, 16, H * 64]`` no program copies anything of a pool's
+    size."""
+    from flax import linen as nn
+
+    from distributed_pipeline_tpu.models import create_model_from_config
+    from distributed_pipeline_tpu.serving.engine import DecodeEngine
+
+    slots, ps, n, lp, bp, span = 16, 16, 64, 512, 8, 4
+    wl = create_model_from_config(
+        model_family="gpt2", vocab_size=1000, seq_len=n * ps,
+        hidden_size=64 * heads, num_layers=2, num_heads=heads,
+        dtype="bfloat16")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(nn.meta.unbox(
+        jax.eval_shape(wl.init_params, jax.random.PRNGKey(0))))
+    eng = DecodeEngine(wl, params, decode_slots=slots, page_size=ps,
+                       max_pages=1 + slots * n, max_prompt_len=lp,
+                       prefill_batch=bp, kv_quant=kv_quant,
+                       spec_tokens=span if program == "span4" else 0)
+    pools = [leaf for _, leaf in eng._pool_leaves() if leaf.ndim > 1]
+    assert len(pools) == 4 and {p.size for p in pools} == {
+        (1 + slots * n) * ps * heads * 64}
+    cache = on_chip(eng.cache)
+
+    def i32(*shape):
+        return sds(shape, jnp.int32, one_chip)
+
+    key = sds((2,), jnp.uint32, one_chip)
+    state = (i32(slots), i32(slots))            # tokens, positions
+    if program == "decode":
+        step, args = eng._decode_step, (
+            *state, i32(slots, n), i32(slots), key)
+    elif program == "prefill":
+        step, args = eng._prefill_step, (
+            i32(bp, lp), i32(bp), i32(bp), i32(bp, n), *state, key)
+    else:
+        step, args = eng._verify_step, (
+            i32(span, slots), *state, i32(slots, n), i32(slots), key)
+    text = step._jitted.lower(params, cache, *args).compile().as_text()
+    assert "tpu_custom_call" not in text        # the XLA arm, as shipped
+    assert pool_sized_copies(text, pools[0].size) == []

@@ -39,11 +39,12 @@ from distributed_pipeline_tpu.utils.trainer import TrainLoop
 
 def paged_case(rng, *, slots, n_pages, page_size, n_heads, head_dim,
                positions, table=None):
-    """Random pool + block tables; page 0 is the trash page and is filled
-    with large garbage so any accidental read of it shows up loudly."""
+    """Random pool ([P, page_size, H * Dh], the stored shape) + block
+    tables; page 0 is the trash page and is filled with large garbage so
+    any accidental read of it shows up loudly."""
     P = 1 + slots * n_pages
-    k = rng.standard_normal((P, page_size, n_heads, head_dim))
-    v = rng.standard_normal((P, page_size, n_heads, head_dim))
+    k = rng.standard_normal((P, page_size, n_heads * head_dim))
+    v = rng.standard_normal((P, page_size, n_heads * head_dim))
     k[TRASH_PAGE] = 37.0
     v[TRASH_PAGE] = -53.0
     if table is None:
@@ -59,7 +60,8 @@ def dense_reference(q, k_pool, v_pool, table, positions):
     q, k_pool, v_pool = map(np.asarray, (q, k_pool, v_pool))
     table, positions = np.asarray(table), np.asarray(positions)
     B, H, Dh = q.shape
-    ps = k_pool.shape[1]
+    k_pool = k_pool.reshape(k_pool.shape[:2] + (H, Dh))
+    v_pool = v_pool.reshape(v_pool.shape[:2] + (H, Dh))
     out = np.zeros_like(q)
     for b in range(B):
         n_live = positions[b] + 1
@@ -136,6 +138,67 @@ def test_flash_decode_under_jit_and_seam_dispatch():
     np.testing.assert_allclose(np.asarray(f(q, k, v, bt, pos)),
                                np.asarray(g(q, k, v, bt, pos)),
                                rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+@pytest.mark.parametrize("heads,head_dim", [(4, 8), (5, 64)],
+                         ids=["H4xDh8", "H5xDh64"])
+def test_paged_decode_through_the_merged_head_axis(heads, head_dim, kv):
+    """Head shapes the pool's merged ``H * Dh`` axis changes (five heads:
+    no power of two; ``Dh`` 64: GPT-2's, the width that was padded).
+    Rows written by the pool's own writers — a prompt, then one token a
+    slot — come back head for head on both arms: fp to float tolerance
+    of a straight softmax over the rows as they were handed in, int8
+    within the envelope quantized K/V leaves a softmax average
+    (test_spec_decode's 0.05)."""
+    from distributed_pipeline_tpu.serving.paged_kv import (
+        write_prompt_kv, write_prompt_kv_q8, write_token_kv,
+        write_token_kv_q8)
+    rng = np.random.default_rng(19)
+    B, ps, n = 3, 4, 3
+    P = 1 + B * n
+    lens = np.asarray([5, 9, 2])                  # live prompt tokens a slot
+    table = jnp.asarray(1 + rng.permutation(B * n).reshape(B, n), jnp.int32)
+    rows = {name: rng.standard_normal((B, heads, n * ps, head_dim))
+            for name in ("k", "v")}
+    tok = {name: rng.standard_normal((B, heads, head_dim))
+           for name in ("k", "v")}
+    valid = jnp.asarray(np.arange(n * ps)[None, :] < lens[:, None],
+                        jnp.int32)
+    pos = jnp.asarray(lens, jnp.int32)            # the token lands here
+    pools, scales = {}, {}
+    for name in ("k", "v"):
+        kv_rows = jnp.asarray(rows[name], jnp.float32)
+        kv_tok = jnp.asarray(tok[name], jnp.float32)
+        if kv == "int8":
+            pool = jnp.zeros((P, ps, heads * head_dim), jnp.int8)
+            sc = jnp.zeros((P,), jnp.float32)
+            pool, sc = write_prompt_kv_q8(pool, sc, table, kv_rows, valid)
+            pools[name], scales[name] = write_token_kv_q8(
+                pool, sc, table, kv_tok, pos)
+        else:
+            pool = jnp.full((P, ps, heads * head_dim), 37.0, jnp.float32)
+            pool = write_prompt_kv(pool, table, kv_rows, valid)
+            pools[name] = write_token_kv(pool, table, kv_tok, pos)
+            scales[name] = None
+        assert pools[name].shape == (P, ps, heads * head_dim)
+    q = jnp.asarray(rng.standard_normal((B, heads, head_dim)), jnp.float32)
+    ref = np.zeros((B, heads, head_dim))
+    for b, n_live in enumerate(lens):
+        ks = np.concatenate([rows["k"][b][:, :n_live],
+                             tok["k"][b][:, None]], 1)     # [H, live+1, Dh]
+        vs = np.concatenate([rows["v"][b][:, :n_live],
+                             tok["v"][b][:, None]], 1)
+        s = np.einsum("hd,hkd->hk", np.asarray(q)[b], ks) * head_dim ** -0.5
+        p = np.exp(s - s.max(-1, keepdims=True))
+        ref[b] = np.einsum("hk,hkd->hd", p / p.sum(-1, keepdims=True), vs)
+    tol = dict(atol=0.05, rtol=0) if kv == "int8" else dict(
+        rtol=2e-5, atol=2e-6)
+    for arm in (xla_paged_decode, flash_decode):
+        got = arm(q, pools["k"], pools["v"], table, pos,
+                  scales["k"], scales["v"])
+        np.testing.assert_allclose(np.asarray(got), ref, err_msg=arm.__name__,
+                                   **tol)
 
 
 def test_resolve_decode_impl_dispatch():

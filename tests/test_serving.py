@@ -54,7 +54,7 @@ def test_paged_write_gather_roundtrips_dense():
     rng = np.random.default_rng(1)
     B, H, L, Dh, ps = 3, 2, 8, 4, 2
     n_pages_per_slot = L // ps
-    pages = jnp.zeros((1 + B * n_pages_per_slot, ps, H, Dh), jnp.float32)
+    pages = jnp.zeros((1 + B * n_pages_per_slot, ps, H * Dh), jnp.float32)
     table = jnp.asarray(
         1 + np.arange(B * n_pages_per_slot).reshape(B, n_pages_per_slot),
         jnp.int32)
@@ -67,7 +67,7 @@ def test_paged_write_gather_roundtrips_dense():
     tok = jnp.asarray(rng.standard_normal((B, H, Dh)), jnp.float32)
     pos = jnp.asarray(lens, jnp.int32)  # append right after each prompt
     pages = write_token_kv(pages, table, tok, jnp.minimum(pos, L - 1))
-    dense = np.asarray(gather_kv(pages, table))  # [B, H, L, Dh]
+    dense = np.asarray(gather_kv(pages, table, H))  # [B, H, L, Dh]
     ref = np.asarray(kv).copy()
     for b, n in enumerate(lens):
         ref[b, :, n:] = 0.0                      # invalid prompt tail unwritten
@@ -77,7 +77,7 @@ def test_paged_write_gather_roundtrips_dense():
 
 def test_paged_invalid_writes_go_to_trash():
     B, H, L, Dh, ps = 2, 1, 4, 2, 2
-    pages = jnp.zeros((1 + B * 2, ps, H, Dh), jnp.float32)
+    pages = jnp.zeros((1 + B * 2, ps, H * Dh), jnp.float32)
     table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     kv = jnp.ones((B, H, L, Dh), jnp.float32)
     pages = write_prompt_kv(pages, table, kv, jnp.zeros((B, L), jnp.int32))
@@ -138,6 +138,100 @@ def test_paged_geometry_is_bit_identical(wl_and_params):
                          rng=jax.random.PRNGKey(7), page_size=SEQ)
     np.testing.assert_array_equal(s2, s1)
     assert not np.array_equal(s2, g2)  # temperature actually sampled
+
+
+@pytest.mark.parametrize("kv_quant", ["fp", "int8"])
+@pytest.mark.parametrize("heads,head_dim", [(4, 8), (5, 64)],
+                         ids=["H4xDh8", "H5xDh64"])
+def test_merged_head_axis_pool_serves_identically(heads, head_dim, kv_quant):
+    """The pool is stored ``[pages, page_size, H * Dh]``; at head shapes
+    the merged axis changes, (1) the engine accounts for exactly the bytes
+    the geometry says, (2) tokens served through the paged pool are the
+    dense-cache decode's, and (3) pages extracted from one engine and
+    ingested by another reproduce the donor's tokens, the payload being
+    rows of the stored shape.
+
+    What "the dense decode's" can mean on the CPU backend: in float32,
+    every token. In bfloat16 the dense loop and the served programs are
+    different XLA:CPU programs and a near-tie argmax can flip after a few
+    tokens (seen on the 4-D pool too: not the layout's), so a bf16 pool is
+    held to the dense decode on the first token and, token for token, to
+    the same pool with ONE page a slot — paging and the head merge then
+    change nothing. An int8 pool keeps the prefill's logits, so the first
+    token; its later ones carry the documented divergence, bounded at the
+    seam in test_kernels."""
+    from distributed_pipeline_tpu.mpmd.disagg import PrefillClient
+    from distributed_pipeline_tpu.serving.engine import DecodeEngine
+
+    def build(dtype):
+        w = tiny_workload(hidden_size=heads * head_dim, num_heads=heads,
+                          dtype=dtype)
+        return w, w.init_params(jax.random.PRNGKey(3))
+
+    wl, params = build("bfloat16")
+    quant = kv_quant == "int8"
+    ps, layers = 4, 2
+
+    def server(page_size=ps, **kw):
+        return DecodeServer(wl, params, seed=0, kv_quant=kv_quant,
+                            page_size=page_size, **kw)
+
+    # (1) bytes: K and V pools a layer, plus an int8 pool's [P] sidecars
+    served = dict(decode_slots=4, max_prompt_len=8, max_len=SEQ)
+    srv = server(**served)
+    pages = srv.mgr.num_pages
+    assert srv.engine.kv_pool_bytes() == 2 * layers * (
+        pages * ps * heads * head_dim * (1 if quant else 2)
+        + (pages * 4 if quant else 0))
+    pools = [leaf for _, leaf in srv.engine._pool_leaves() if leaf.ndim > 1]
+    assert {leaf.shape for leaf in pools} == {(pages, ps, heads * head_dim)}
+
+    # (2) paged against dense, at the same padded length
+    ids = prompt_ids(batch=3, seed=4)
+    plen = SEQ // 2
+    whole = dict(decode_slots=3, max_prompt_len=SEQ, max_len=SEQ,
+                 prefill_batch=3)
+    dense = np.asarray(gpt2_decode(wl, params, jnp.asarray(ids), plen,
+                                   use_cache=True))
+    paged = one_shot_decode(wl, params, ids, plen, server=server(**whole))
+    np.testing.assert_array_equal(paged[:, :plen + 1], dense[:, :plen + 1])
+    if not quant:
+        one_page = one_shot_decode(wl, params, ids, plen, server=server(
+            page_size=SEQ, **whole))
+        np.testing.assert_array_equal(paged, one_page)
+        wl32, params32 = build("float32")
+        np.testing.assert_array_equal(
+            one_shot_decode(wl32, params32, ids, plen, page_size=ps),
+            np.asarray(gpt2_decode(wl32, params32, jnp.asarray(ids), plen,
+                                   use_cache=True)))
+
+    # (3) donor engine -> wire -> a second server. A slot a request and
+    # fresh pools on both sides: an int8 decode write into a reserved page
+    # takes max(the page's leftover scale, its own), so int8 tokens follow
+    # a pool's history (PERF.md section 7), which this case keeps equal
+    rng = np.random.default_rng(5)
+    pairs = [(rng.integers(4, VOCAB, (1 + i % 6,)).astype(np.int32),
+              2 + i % 4) for i in range(4)]
+    reqs = [srv.submit(p, max_new_tokens=m) for p, m in pairs]
+    srv.drain()
+    donor = PrefillClient(wl, params, page_size=ps, max_prompt_len=8,
+                          max_len=SEQ)
+    e = donor.engine                       # the client's own is an fp engine
+    donor.engine = DecodeEngine(
+        wl, params, decode_slots=1, page_size=ps, max_pages=e.max_pages,
+        max_prompt_len=8, max_len=SEQ, prefill_batch=1, kv_quant=kv_quant)
+    recv = server(**served)
+    got = []
+    for prompt, budget in pairs:
+        out = donor.prefill(prompt)
+        assert {rows.shape[1:] for rows in out["kv"].values()
+                if rows.ndim > 1} == {(ps, heads * head_dim)}
+        got.append(recv.submit_prefilled(
+            prompt, budget, first_token=out["first_token"],
+            kv_pages=out["kv"]))
+    recv.drain()
+    assert [r.tokens for r in got] == [r.tokens for r in reqs]
+    assert recv.mgr.free_pages == recv.mgr.capacity
 
 
 def test_decode_span_is_equivalent(wl_and_params):

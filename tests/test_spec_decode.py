@@ -194,7 +194,7 @@ def test_write_span_kv_matches_sequential_token_writes():
     on."""
     rng = np.random.default_rng(2)
     B, H, L, Dh, ps = 3, 2, 4, 8, 4
-    pool = jnp.asarray(rng.standard_normal((1 + 3 * B, ps, H, Dh)),
+    pool = jnp.asarray(rng.standard_normal((1 + 3 * B, ps, H * Dh)),
                        jnp.float32)
     table = jnp.asarray(1 + np.arange(3 * B).reshape(B, 3), jnp.int32)
     kv = jnp.asarray(rng.standard_normal((B, H, L, Dh)), jnp.float32)
@@ -213,16 +213,17 @@ def test_write_span_kv_overshoot_clamps_not_wraps():
     re-enters at offset 0, corrupting a live row."""
     rng = np.random.default_rng(3)
     H, Dh, ps = 2, 4, 4
-    pool = jnp.asarray(rng.standard_normal((3, ps, H, Dh)), jnp.float32)
+    pool = jnp.asarray(rng.standard_normal((3, ps, H * Dh)), jnp.float32)
     table = jnp.asarray([[1, 2]], jnp.int32)       # addressable = 8
     kv = jnp.asarray(rng.standard_normal((1, H, 3, Dh)), jnp.float32)
     out = np.asarray(write_span_kv(pool, table, kv, jnp.asarray([7])))
     # positions 7, 8, 9 -> cells 7, 7, 7: last link wins the clamped cell
-    np.testing.assert_array_equal(out[2, 3], np.asarray(kv[0, :, 2]))
+    np.testing.assert_array_equal(out[2, 3],
+                                  np.asarray(kv[0, :, 2]).reshape(-1))
     # every other cell — notably page 2 offset 0, the wrap target — is
     # bitwise untouched
     ref = np.asarray(pool).copy()
-    ref[2, 3] = np.asarray(kv[0, :, 2])
+    ref[2, 3] = np.asarray(kv[0, :, 2]).reshape(-1)
     np.testing.assert_array_equal(out, ref)
 
 
@@ -233,7 +234,7 @@ def test_write_span_kv_q8_bounded_and_leaves_cold_pages_alone():
     rng = np.random.default_rng(4)
     B, H, L, Dh, ps = 2, 2, 3, 8, 4
     P = 1 + 2 * B
-    pool = jnp.zeros((P, ps, H, Dh), jnp.int8)
+    pool = jnp.zeros((P, ps, H * Dh), jnp.int8)
     scales = jnp.zeros((P,), jnp.float32)
     table = jnp.asarray(1 + np.arange(2 * B).reshape(B, 2), jnp.int32)
     warm = jnp.asarray(rng.standard_normal((B, H, ps, Dh)), jnp.float32)
@@ -249,7 +250,7 @@ def test_write_span_kv_q8_bounded_and_leaves_cold_pages_alone():
     assert np.all(np.asarray(s2) >= np.asarray(scales) - 1e-7)
     np.testing.assert_array_equal(np.asarray(out[jnp.asarray([1, 3])]),
                                   cold)
-    dense = dequant_gathered(gather_kv(out, table), s2, table, ps,
+    dense = dequant_gathered(gather_kv(out, table, H), s2, table, ps,
                              jnp.float32)
     d = np.asarray(dense)
     sc = np.asarray(s2)[np.asarray(table)]         # [B, n_pages]
@@ -287,13 +288,13 @@ def test_int8_prompt_roundtrip_error_within_page_scale():
     documented divergence floor everything downstream inherits."""
     rng = np.random.default_rng(5)
     B, H, Dh, ps = 2, 2, 8, 4
-    pool = jnp.zeros((1 + 2 * B, ps, H, Dh), jnp.int8)
+    pool = jnp.zeros((1 + 2 * B, ps, H * Dh), jnp.int8)
     scales = jnp.zeros((1 + 2 * B,), jnp.float32)
     table = jnp.asarray(1 + np.arange(2 * B).reshape(B, 2), jnp.int32)
     kv = jnp.asarray(rng.standard_normal((B, H, 2 * ps, Dh)), jnp.float32)
     valid = jnp.ones((B, 2 * ps), jnp.int32)
     pool, scales = write_prompt_kv_q8(pool, scales, table, kv, valid)
-    dense = np.asarray(dequant_gathered(gather_kv(pool, table), scales,
+    dense = np.asarray(dequant_gathered(gather_kv(pool, table, H), scales,
                                         table, ps, jnp.float32))
     sc = np.asarray(scales)[np.asarray(table)]
     for b in range(B):
@@ -311,8 +312,8 @@ def test_int8_span_attention_divergence_bounded():
     rng = np.random.default_rng(6)
     B, H, L, Dh, ps, n = 2, 2, 2, 8, 4, 3
     P = 1 + n * B
-    fp_pool = jnp.zeros((P, ps, H, Dh), jnp.float32)
-    q_pool = jnp.zeros((P, ps, H, Dh), jnp.int8)
+    fp_pool = jnp.zeros((P, ps, H * Dh), jnp.float32)
+    q_pool = jnp.zeros((P, ps, H * Dh), jnp.int8)
     scales = jnp.zeros((P,), jnp.float32)
     table = jnp.asarray(1 + np.arange(n * B).reshape(B, n), jnp.int32)
     kv = jnp.asarray(rng.standard_normal((B, H, n * ps, Dh)), jnp.float32)
