@@ -6,7 +6,11 @@ stub) with two concrete families behind the same factory call the reference
 entry point makes (``run/train.py:71`` passes ``**args.dict()``):
 
 * ``diffuseq`` — seq2seq embedding diffusion (base/large/xl presets);
-* ``gpt2``     — causal LM (base/medium/large/xl presets).
+* ``gpt2``     — causal LM (base/medium/large/xl presets);
+* ``deepseek_v32`` — DeepSeek-V3.2-Exp as one chip's share of an
+  expert-parallel deployment (models/deepseek_v32.py): served through
+  ``DecodeServer``'s chunked prefill; its ``arch`` flag carries the source's
+  ``config.json`` keys and the cut.
 
 The factory returns a :class:`Workload`: the flax module plus pure
 ``init_params`` / ``compute_losses`` functions — the reference's user-hook
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .backbone import TransformerBackbone
+from .deepseek_v32 import DeepseekV32Config, DeepseekV32Model
 from .diffuseq import DiffuSeqModel, diffuseq_losses
 from .diffusion import DiffusionSchedule, make_schedule
 from .gpt2 import GPT2Model, gpt2_losses
@@ -49,6 +54,8 @@ PRESETS: Dict[str, Dict[str, Tuple[int, int, int]]] = {
         "large": (1280, 36, 20),
         "xl": (1600, 48, 25),
     },
+    # the published model; a deployment's cut comes through `arch`
+    "deepseek_v32": {"base": (7168, 61, 128)},
 }
 DIFFUSEQ_EMB_DIM = 128  # DiffuSeq uses a low-dim embedding space
 
@@ -109,6 +116,12 @@ def _example_batch_fn(seq_len: int) -> Callable[[int], Dict[str, np.ndarray]]:
     return fn
 
 
+def _served_not_trained(params, batch, rng):
+    raise NotImplementedError(
+        "the deepseek_v32 share is served, not trained: it has no loss, no "
+        "partition rules and forward-only kernels (ROADMAP R3)")
+
+
 def create_model_from_config(*, model_family: str = "diffuseq",
                              model_size: str = "base",
                              vocab_size: int = 8192, seq_len: int = 128,
@@ -124,6 +137,7 @@ def create_model_from_config(*, model_family: str = "diffuseq",
                              scan_layers: bool = False,
                              pp_chunks: int = 4, pp_schedule: str = "1f1b",
                              pp_virtual: int = 2, scan_unroll: int = 0,
+                             arch: Optional[Dict[str, Any]] = None,
                              **_unused: Any) -> Workload:
     """Build a :class:`Workload` from (a superset of) ``TrainSettings`` fields
     — callable as ``create_model_from_config(**settings.dict())`` exactly like
@@ -146,6 +160,19 @@ def create_model_from_config(*, model_family: str = "diffuseq",
             f"num_layers {layers} must divide by moe_every {moe_every}")
     heads = num_heads or preset[2]
     jdtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
+
+    if model_family == "deepseek_v32":
+        # explicit size flags win over `arch` (a dict of the source's
+        # keys); the presets' zeros leave it alone
+        cfg = DeepseekV32Config.from_arch(
+            arch or {}, vocab_size=vocab_size, hidden_size=hidden_size,
+            n_layers=num_layers, num_attention_heads=num_heads)
+        model = DeepseekV32Model(cfg=cfg, seq_len=seq_len, dtype=jdtype)
+        return Workload(
+            model=model, family="deepseek_v32", seq_len=seq_len,
+            hidden_size=cfg.hidden_size, num_layers=cfg.n_layers,
+            compute_losses=_served_not_trained,
+            example_batch=_example_batch_fn(seq_len))
 
     # Declared sharding: the family's partition-rule table rides the
     # Workload (parallel/partition.py; function-level import keeps the

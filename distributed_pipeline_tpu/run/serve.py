@@ -284,7 +284,9 @@ def _serve_single(settings: ServeSettings) -> dict:
         # what ran where: the device as jax reports it, and the decode
         # attention arm the engine's pool geometry resolved to
         "device": device_summary(),
-        "decode_impl": resolve_decode_impl(
+        # (a model that brings its own decode step has the one arm)
+        "decode_impl": "xla" if server.engine.chunked
+        else resolve_decode_impl(
             settings.decode_impl,
             (server.mgr.num_pages, settings.page_size,
              wl.model.num_heads,

@@ -74,9 +74,15 @@ class DecodeEngine:
 
     Parameters
     ----------
-    workload, params : the model (named-blocks GPT-2 family) and its live
-        parameter tree (passed through untouched — whatever sharding they
-        carry is what the executables compile against).
+    workload, params : the model and its live parameter tree (passed
+        through untouched — whatever sharding they carry is what the
+        executables compile against). Either the named-blocks flax causal
+        LM, whose backbone holds the paged K/V branch, or a model that
+        brings its own paged-cache functions (``chunked_prefill``,
+        ``cache_shapes``, ``prefill_chunk``, ``decode_step``:
+        models/deepseek_v32.py); for the latter the prefill executable
+        takes ONE chunk of one prompt (``prefill_chunk`` tokens) and the
+        scheduler walks a prompt chunk by chunk, a chunk a tick.
     decode_slots : compiled decode batch size S. Decode ALWAYS runs at S
         (inactive slots write to the trash page and their outputs are
         ignored) — the executable never re-specializes to occupancy.
@@ -107,9 +113,20 @@ class DecodeEngine:
                  spec_tokens: int = 0,
                  on_compile: Optional[Callable[[str, float], None]] = None):
         model = workload.model
-        if workload.family != "gpt2":
-            raise ValueError(f"DecodeEngine serves the gpt2 (causal LM) "
-                             f"family, got {workload.family!r}")
+        # by what the model can do, not by its family's name: either it
+        # brings its own paged-cache functions and a chunked prefill
+        # (models/deepseek_v32.py), or it is the flax causal LM whose
+        # backbone holds the paged K/V branch
+        self.chunked = bool(getattr(model, "chunked_prefill", False))
+        if not self.chunked and not hasattr(model, "paged_pages"):
+            raise ValueError(
+                f"DecodeEngine needs a causal LM with a paged cache (a "
+                f"`paged_pages` field or `chunked_prefill`); the "
+                f"{workload.family!r} family's model has neither")
+        if self.chunked and (spec_tokens > 0 or kv_quant != "fp"):
+            raise NotImplementedError(
+                "speculative verify and the int8 pool are the flax "
+                "backbone's; a chunked-prefill model has neither yet")
         if getattr(model, "scan_layers", False):
             raise NotImplementedError(
                 "paged decode needs per-layer named blocks; scan_layers "
@@ -129,6 +146,14 @@ class DecodeEngine:
         self.max_len = max_len
         self.pages_per_slot = -(-max_len // page_size)
         self.prefill_batch = prefill_batch or min(decode_slots, 8)
+        # chunked prefill: one chunk of ONE prompt a dispatch; the length
+        # is the engine's own (a prompt of max_prompt_len in at most 16,
+        # 1024 at most: at 2048 the prompts' last chunks were 15 % padding
+        # and a token took as long, PERF.md PR 29)
+        self.prefill_chunk = min(max_prompt_len, max(page_size, min(
+            1024, -(-max_prompt_len // 16)))) if self.chunked else 0
+        # int32 counters a program returns behind its tokens (one fetch)
+        self.n_counters = len(getattr(model, "counters", ()))
         if decode_span < 1:
             raise ValueError(f"decode_span must be >= 1, got {decode_span}")
         self.decode_span = decode_span
@@ -150,14 +175,35 @@ class DecodeEngine:
 
         s = decode_slots
         bp = self.prefill_batch
-        # decode=True + paged_pages selects the paged attention branch;
-        # inference never drops MoE tokens (models/sampling.py rationale)
-        # decode_impl picks the decode-step attention kernel behind the
-        # ROADMAP-reserved seam (ops/flash_decode.py dispatch rules)
-        dm = model.clone(decode=True, moe_no_drop=True,
-                         paged_pages=max_pages, page_size=page_size,
-                         decode_impl=decode_impl, kv_quant=kv_quant)
         pick = _slot_picker(temperature, top_k, top_p)
+        if self.chunked:
+            dm = None
+
+            def slot_logits(p, cache, tokens, positions, block_table,
+                            active):
+                cache, logits, counted, _ = model.decode_step(
+                    p["params"], cache, tokens, positions, block_table,
+                    active)
+                return logits, cache, counted
+        else:
+            # decode=True + paged_pages selects the paged attention branch;
+            # inference never drops MoE tokens (models/sampling.py
+            # rationale); decode_impl picks the decode-step attention
+            # kernel behind the ROADMAP-reserved seam (ops/flash_decode.py
+            # dispatch rules)
+            dm = model.clone(decode=True, moe_no_drop=True,
+                             paged_pages=max_pages, page_size=page_size,
+                             decode_impl=decode_impl, kv_quant=kv_quant)
+
+            def slot_logits(p, cache, tokens, positions, block_table,
+                            active):
+                del active
+                logits, mvars = dm.apply({**p, "cache": cache},
+                                         tokens[:, None], None,
+                                         cache_index=positions,
+                                         block_table=block_table,
+                                         mutable=["cache"])
+                return logits, mvars["cache"], None
 
         def prefill_fn(p, cache, ids, prompt_lens, slot_map, slot_tables,
                        tokens, positions, key):
@@ -184,6 +230,27 @@ class DecodeEngine:
             positions = positions.at[safe].set(prompt_lens, mode="drop")
             return mvars["cache"], tokens, positions
 
+        def prefill_chunk_fn(p, cache, ids, meta, table_row, tokens,
+                             positions, key):
+            """One chunk of one prompt: ``ids`` [C] (zero-padded), ``meta``
+            = (first position, valid tokens, target slot, 1 on the
+            prompt's last chunk), ``table_row`` the slot's pages. Writes
+            the chunk's cache rows; on the last chunk picks the request's
+            first token and merges token/position into the decode state at
+            the slot (same fold as above). Returns the state and, for the
+            one fetch, the tokens with the chunk's counters behind them."""
+            start, n_valid, slot, is_last = meta[0], meta[1], meta[2], meta[3]
+            cache, logits, counted = model.prefill_chunk(
+                p["params"], cache, ids, start, n_valid, table_row)
+            prompt_len = start + n_valid
+            first = pick(logits[None], prompt_len[None], slot[None], key)[0]
+            safe = jnp.where(is_last > 0, slot, s)        # s = out of bounds
+            tokens = tokens.at[safe].set(first.astype(tokens.dtype),
+                                         mode="drop")
+            positions = positions.at[safe].set(prompt_len, mode="drop")
+            return (cache, tokens, positions,
+                    jnp.concatenate([tokens, counted]))
+
         def decode_fn(p, cache, tokens, positions, block_table, active, key):
             """``decode_span`` tokens for every slot: each inner step feeds
             each slot's current token at its own position, writes its K/V
@@ -191,30 +258,30 @@ class DecodeEngine:
             token (folded at the position it will occupy). Inactive slots
             write to trash and keep their state frozen. Returns the new
             state plus the picked tokens — [S] at span 1, [span, S] above
-            (the scheduler's fetch attributes rows in order)."""
+            (the scheduler's fetch attributes rows in order); a model that
+            counts appends its counters to each row."""
 
             slot_ids = jnp.arange(s, dtype=jnp.int32)
 
             def one(cache, tokens, positions):
-                logits, mvars = dm.apply({**p, "cache": cache},
-                                         tokens[:, None], None,
-                                         cache_index=positions,
-                                         block_table=block_table,
-                                         mutable=["cache"])
+                logits, cache, counted = slot_logits(
+                    p, cache, tokens, positions, block_table, active)
                 nxt_pos = positions + 1
-                nxt = pick(logits[:, 0], nxt_pos, slot_ids, key)
+                nxt = pick(logits if logits.ndim == 2 else logits[:, 0],
+                           nxt_pos, slot_ids, key)
                 tokens = jnp.where(active > 0, nxt.astype(tokens.dtype),
                                    tokens)
                 positions = jnp.where(active > 0, nxt_pos, positions)
-                return mvars["cache"], tokens, positions
+                out = tokens if counted is None else jnp.concatenate(
+                    [tokens, counted])
+                return cache, tokens, positions, out
 
             if decode_span == 1:
-                cache, tokens, positions = one(cache, tokens, positions)
-                return cache, tokens, positions, tokens
+                return one(cache, tokens, positions)
 
             def body(carry, _):
-                c, t, q = one(*carry)
-                return (c, t, q), t
+                c, t, q, out = one(*carry)
+                return (c, t, q), out
 
             (cache, tokens, positions), seq = jax.lax.scan(
                 body, (cache, tokens, positions), None, length=decode_span)
@@ -267,13 +334,16 @@ class DecodeEngine:
         # Cache structure WITHOUT compiling an init variant: eval_shape the
         # first-call (variable-creating) apply, then zero-fill. Every real
         # prefill/decode then shares one with-cache signature.
-        ids0 = jax.ShapeDtypeStruct((bp, max_prompt_len), jnp.int32)
-        pad0 = jax.ShapeDtypeStruct((bp, max_prompt_len), jnp.int32)
-        bt0 = jax.ShapeDtypeStruct((bp, self.pages_per_slot), jnp.int32)
-        cache_abs = jax.eval_shape(
-            lambda p, i, m, bt: dm.apply(p, i, m, block_table=bt,
-                                         mutable=["cache"])[1]["cache"],
-            params, ids0, pad0, bt0)
+        if self.chunked:
+            cache_abs = model.cache_shapes(max_pages, page_size)
+        else:
+            ids0 = jax.ShapeDtypeStruct((bp, max_prompt_len), jnp.int32)
+            pad0 = jax.ShapeDtypeStruct((bp, max_prompt_len), jnp.int32)
+            bt0 = jax.ShapeDtypeStruct((bp, self.pages_per_slot), jnp.int32)
+            cache_abs = jax.eval_shape(
+                lambda p, i, m, bt: dm.apply(p, i, m, block_table=bt,
+                                             mutable=["cache"])[1]["cache"],
+                params, ids0, pad0, bt0)
 
         okw_p: dict = {}
         okw_d: dict = {}
@@ -286,14 +356,16 @@ class DecodeEngine:
             # later (ROADMAP item 4).
             rep = replicated(mesh)
             cache_rep = jax.tree_util.tree_map(lambda _: rep, cache_abs)
-            okw_p["out_shardings"] = (cache_rep, rep, rep)
+            okw_p["out_shardings"] = (cache_rep, rep, rep) + (
+                (rep,) if self.chunked else ())
             okw_d["out_shardings"] = (cache_rep, rep, rep, rep)
         # pin_signature: every arg shape is fixed by construction (slots,
         # prefill batch, table width are compiled-in), so the per-call
         # signature walk over the params tree is pure overhead on the
         # one-dispatch-per-token hot path
         self._prefill_step = AOTStep(
-            jax.jit(prefill_fn, donate_argnums=(1,), **okw_p),
+            jax.jit(prefill_chunk_fn if self.chunked else prefill_fn,
+                    donate_argnums=(1,), **okw_p),
             "serve_prefill", on_compile=self._note_compile,
             pin_signature=True)
         self._decode_step = AOTStep(
@@ -490,6 +562,25 @@ class DecodeEngine:
                 self._put(np.ascontiguousarray(slot_tables, np.int32)),
                 self.tokens, self.positions, self._key)
         return self.tokens
+
+    def prefill_one_chunk(self, ids: np.ndarray, start: int, n_valid: int,
+                          slot: int, table_row: np.ndarray,
+                          is_last: bool) -> jax.Array:
+        """Run the chunked-prefill executable for ONE chunk of one prompt
+        (``ids`` [prefill_chunk], zero-padded past ``n_valid``; positions
+        ``start ..`` of the request bound for ``slot``). Returns the
+        fetchable handle: the post-merge tokens [S] with the chunk's
+        counters behind them."""
+        with self._ctx():
+            (self.cache, self.tokens, self.positions,
+             out) = self._prefill_step(
+                self.params, self.cache,
+                self._put(np.ascontiguousarray(ids, np.int32)),
+                self._put(np.array([start, n_valid, slot, int(is_last)],
+                                   np.int32)),
+                self._put(np.ascontiguousarray(table_row, np.int32)),
+                self.tokens, self.positions, self._key)
+        return out
 
     def decode(self) -> jax.Array:
         """Advance every slot by ``decode_span`` token(s) (dispatch only —

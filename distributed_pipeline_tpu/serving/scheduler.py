@@ -17,6 +17,12 @@ generation budget is known at admission), so the host never has to sync on
 content to schedule; an optional EOS id finishes a request early, observed
 one lagged step late by construction.
 
+A model with a CHUNKED prefill (``engine.chunked``; models/deepseek_v32.py)
+is admitted the same way (slot and worst-case pages at once) but its prompt
+is written a chunk a tick, oldest request first, beside that tick's decode
+step: a long prompt stalls the decoding slots for one chunk, not for itself.
+The slot joins the decode batch with the dispatch of its last chunk.
+
 Invariants the tests pin (tests/test_serving.py):
 
 * no slot or page leaks — after drain, every slot is free and the page
@@ -85,6 +91,7 @@ class _SlotState:
     pages: np.ndarray               # page ids reserved for this request
     generated: int = 1              # prefill produced token #1
     position: int = 0               # index of the token currently in state
+    # (chunked prefill: prompt rows written so far, until the slot decodes)
 
 
 class DecodeServer:
@@ -160,6 +167,10 @@ class DecodeServer:
                 top_k=top_k, top_p=top_p, rng=rng, seed=seed, mesh=mesh,
                 transfer_guard=sanitize, decode_impl=decode_impl,
                 kv_quant=kv_quant, spec_tokens=spec_tokens)
+            if self.engine.chunked and prefix_cache:
+                raise NotImplementedError(
+                    "the prefix cache skips whole prompt prefills; a "
+                    "chunked prefill would have to start mid-prompt")
             self._draft_engine: Optional[DecodeEngine] = None
             self._draft_fpt = 0.0
             if spec_tokens > 0 and spec_draft == "model":
@@ -231,6 +242,14 @@ class DecodeServer:
         self.spec_rounds = 0
         self.draft_proposed = 0
         self.draft_accepted = 0
+        # chunked prefill (a model that brings its own): slots whose
+        # prompt is still being written, oldest first, one chunk a tick
+        self._prefilling: Deque[int] = collections.deque()
+        # what the model's programs count (engine.n_counters int32 behind
+        # each fetched token vector), summed by the program that counted
+        names = tuple(getattr(workload.model, "counters", ()))
+        self.counted = {"prefill": dict.fromkeys(names, 0),
+                        "decode": dict.fromkeys(names, 0)}
 
     # ----------------------------------------------------------- gauges etc.
 
@@ -308,6 +327,8 @@ class DecodeServer:
         self.spec_rounds = 0
         self.draft_proposed = 0
         self.draft_accepted = 0
+        for group in self.counted.values():
+            group.update(dict.fromkeys(group, 0))
 
     @property
     def accept_rate(self) -> float:
@@ -626,6 +647,14 @@ class DecodeServer:
             self.queue.popleft()
             req.admit_t = time.perf_counter()
             self._book_admitted(req)
+            if self.engine.chunked:
+                # the slot is held but decodes nothing yet: its table row
+                # stays trash (a decode step writes every slot's row)
+                # until the last chunk of its prompt is dispatched
+                self.slots[slot] = _SlotState(req=req, pages=pages)
+                self._prefilling.append(slot)
+                batch.append((slot, req))
+                continue
             self.block_tables[slot, :] = TRASH_PAGE
             self.block_tables[slot, :len(pages)] = pages
             self.active[slot] = 1
@@ -633,7 +662,7 @@ class DecodeServer:
                                           position=req.prompt_len)
             self._dirty = True
             batch.append((slot, req))
-        if not batch:
+        if not batch or self.engine.chunked:
             return batch
         bp, lp = self.engine.prefill_batch, self.engine.max_prompt_len
         ids = np.zeros((bp, lp), np.int32)
@@ -660,13 +689,48 @@ class DecodeServer:
         # [prefill_batch, max_prompt_len] shape the executable ran at
         self.prompt_tokens_prefilled += int(lens.sum())
         self.prefill_token_slots += bp * lp
-        self._ring.append((toks, list(batch)))
+        self._ring.append((toks, list(batch), "prefill"))
         # a budget-1 request is already complete at dispatch level
         for slot, _ in batch:
             st = self.slots[slot]
             if st is not None and st.generated >= st.req.g_max:
                 self._release(slot)
         return batch
+
+    def _prefill_chunk(self) -> None:
+        """Dispatch the next chunk of the oldest prompt still being
+        written. Its last chunk picks the first token on the device and
+        the slot joins this tick's decode step."""
+        slot = self._prefilling[0]
+        st = self.slots[slot]
+        req, size = st.req, self.engine.prefill_chunk
+        start = st.position
+        n_valid = min(size, req.prompt_len - start)
+        is_last = start + n_valid >= req.prompt_len
+        ids = np.zeros((size,), np.int32)
+        ids[:n_valid] = req.prompt[start:start + n_valid]
+        table_row = np.zeros((self.engine.pages_per_slot,), np.int32)
+        table_row[:len(st.pages)] = st.pages
+        tr = self.tracer
+        with tr.span("serve.prefill_chunk", "serve", args={
+                "tokens": n_valid, "slot": slot} if tr.enabled else None):
+            out = self.engine.prefill_one_chunk(ids, start, n_valid, slot,
+                                                table_row, is_last)
+        self.prefill_steps += 1
+        self.prompt_tokens_prefilled += n_valid
+        self.prefill_token_slots += size
+        st.position = start + n_valid
+        self._ring.append((out, [(slot, req)] if is_last else [], "prefill"))
+        if not is_last:
+            return
+        self._prefilling.popleft()
+        if st.generated >= req.g_max:
+            self._release(slot)        # a budget of one: done at dispatch
+            return
+        self.block_tables[slot, :] = TRASH_PAGE
+        self.block_tables[slot, :len(st.pages)] = st.pages
+        self.active[slot] = 1
+        self._dirty = True
 
     def step(self) -> bool:
         """One scheduler tick: sweep EOS completions -> admit -> dispatch
@@ -705,6 +769,9 @@ class DecodeServer:
         dispatched = False
         while self._admit():
             dispatched = True
+        if self._prefilling:
+            self._prefill_chunk()
+            dispatched = True
         if self.spec_tokens > 0:
             # speculative path: synchronous rounds (the verify result IS
             # next round's input), so drain the prefill ring first — the
@@ -737,7 +804,7 @@ class DecodeServer:
             # the decode-side padding waste)
             self.slot_steps_active += int(self.active.sum()) * span
             self.slot_steps_total += len(self.slots) * span
-            self._ring.append((toks, snap))
+            self._ring.append((toks, snap, "decode"))
             for s, _ in snap:
                 st = self.slots[s]
                 # mirrors advance by the full span (the device does,
@@ -844,13 +911,21 @@ class DecodeServer:
             return
         tr = self.tracer
         fetched0 = self.tokens_fetched
+        n_counted = self.engine.n_counters
+        counted0 = ({k: dict(v) for k, v in self.counted.items()}
+                    if n_counted and tr.enabled else None)
         with tr.span("serve.fetch", "serve") as sp:
             while len(self._ring) > lag:
-                toks_dev, snap = self._ring.popleft()
+                toks_dev, snap, program = self._ring.popleft()
                 with tr.span("serve.fetch_wait", "serve"):
                     # the host WAITING for the device, and nothing else
                     arr = np.asarray(jax.device_get(toks_dev))
                 rows = arr if arr.ndim == 2 else arr[None]  # [span|1, S]
+                if n_counted:
+                    # the program's counters came with its tokens
+                    group = self.counted[program]
+                    for name, v in zip(group, rows[:, -n_counted:].sum(0)):
+                        group[name] += int(v)
                 now = time.perf_counter()
                 for slot, req in snap:
                     if req.finished:
@@ -870,6 +945,11 @@ class DecodeServer:
                             break
             if tr.enabled:
                 sp.args = {"n_tokens": self.tokens_fetched - fetched0}
+                if counted0 is not None:
+                    sp.args.update({
+                        program: {k: v - counted0[program][k]
+                                  for k, v in group.items()}
+                        for program, group in self.counted.items()})
 
     def drain(self) -> None:
         """Run until every submitted request has completed and every token

@@ -341,3 +341,37 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
     text = step._jitted.lower(params, cache, *args).compile().as_text()
     assert "tpu_custom_call" not in text        # the XLA arm, as shipped
     assert pool_sized_copies(text, pools[0].size) == []
+
+
+def test_mla_block_attend_compiles_at_published_widths(one_chip):
+    """The chunked MLA prefill's attention block (ops/mla_attention.py) at
+    DeepSeek-V3.2-Exp's widths (128 heads, 192-wide queries and keys, 128-
+    wide values), a chunk of 1024 queries against a block of 512 keys: the
+    192-long contraction, the [1, queries] statistics rows and the carry
+    updated in place are what interpret mode cannot refuse."""
+    from distributed_pipeline_tpu.ops import mla_attention as ma
+
+    h, dq, dv, c, k = 128, 192, 128, 1024, 512
+    carry = (sds((h, 1, c), jnp.float32, one_chip),
+             sds((h, 1, c), jnp.float32, one_chip),
+             sds((h, dv, c), jnp.float32, one_chip))
+    compiled = ma.block_attend.lower(
+        sds((h, dq, c), jnp.bfloat16, one_chip),
+        sds((h, k, dq), jnp.bfloat16, one_chip),
+        sds((h, dv, k), jnp.bfloat16, one_chip),
+        sds((k, c), jnp.float32, one_chip), carry,
+        scale=0.1352).compile()
+    assert_kernel(compiled, ma.KERNEL_NAME)
+
+
+def test_lightning_index_scores_compiles_at_published_widths(one_chip):
+    """The indexer's score block (64 heads of 128, 1024 queries against 512
+    keys): a head a grid step, the weighted sum resident in VMEM."""
+    from distributed_pipeline_tpu.ops import mla_attention as ma
+
+    j, di, c, k = 64, 128, 1024, 512
+    compiled = ma.index_scores.lower(
+        sds((j, di, c), jnp.bfloat16, one_chip),
+        sds((j, 1, c), jnp.float32, one_chip),
+        sds((k, di), jnp.bfloat16, one_chip)).compile()
+    assert_kernel(compiled, ma.INDEX_KERNEL_NAME)

@@ -767,3 +767,110 @@ def test_traced_fleet_kill_and_swap_export_one_timeline(tmp_path,
     assert snap["completed"] == 9 and snap["replayed"] >= 1
     assert snap["ttft_p95_s"] is not None
     assert {r["params_step"] for r in snap["replicas"]} == {2}
+
+
+# ---- the chunked-prefill family (ISSUE 29): one more live span, and the
+# programs' counters booked on the fetch that brought them
+
+def _tiny_deepseek():
+    from distributed_pipeline_tpu.models import create_model_from_config
+    arch = {"hidden_size": 32, "n_layers": 2, "n_dense_layers": 1,
+            "num_attention_heads": 2, "q_lora_rank": 16, "kv_lora_rank": 8,
+            "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 8,
+            "index_n_heads": 4, "index_head_dim": 16, "index_topk": 6,
+            "intermediate_size": 64, "moe_intermediate_size": 16,
+            "n_routed_experts": 8, "n_routed_experts_held": 4, "n_group": 2,
+            "topk_group": 1, "num_experts_per_tok": 2,
+            "rope_scaling": {"factor": 40,
+                             "original_max_position_embeddings": 16}}
+    return create_model_from_config(model_family="deepseek_v32",
+                                    vocab_size=64, seq_len=48,
+                                    dtype="float32", arch=arch)
+
+
+def test_chunked_prefill_span_and_program_counters(tmp_path):
+    """``serve.prefill_chunk`` (tokens, slot) lies inside ``serve.step`` in
+    the host plane and the ring; the five counters of the model's programs
+    come back with the tokens and are booked, by program, as arguments of
+    the ``serve.fetch`` that brought them: their sums over the ring are the
+    server's own ``counted``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from distributed_pipeline_tpu.models.deepseek_v32 import COUNTERS
+    from distributed_pipeline_tpu.serving import DecodeServer
+
+    wl = _tiny_deepseek()
+    server = DecodeServer(wl, wl.init_params(jax.random.PRNGKey(1)),
+                          decode_slots=2, page_size=4, max_prompt_len=24,
+                          max_len=48)
+    assert server.engine.prefill_chunk == 4      # the engine's own: a page
+    warm = server.submit(np.arange(1, 20, dtype=np.int32), 5)
+    server.drain()
+    assert warm.finished and trace_lib.recorded() == []
+    server.reset_stats()
+    trace_lib.clear_recorded()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    reqs = [server.submit(np.arange(1, 20, dtype=np.int32), 6),
+            server.submit(np.arange(3, 14, dtype=np.int32), 4)]
+    server.drain()
+    jax.profiler.stop_trace()
+    ring = trace_lib.recorded()
+    trace_lib.clear_recorded()
+    assert all(r.finished for r in reqs)
+    chunks = [e for e in ring if e["name"] == "serve.prefill_chunk"]
+    assert sorted(e["args"]["tokens"] for e in chunks) == [3, 3] + 6 * [4]
+    assert {e["args"]["slot"] for e in chunks} == {0, 1}
+    by_sid = {e["sid"]: e for e in ring}
+    assert all(by_sid[e["parent"]]["name"] == "serve.step" for e in chunks)
+    found = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [e.name for pl in ProfileData.from_file(found[0]).planes
+             if pl.name.startswith("/host:CPU")
+             for line in pl.lines for e in line.events]
+    assert names.count("serve.prefill_chunk") == len(chunks)
+    fetches = [e["args"] for e in ring if e["name"] == "serve.fetch"]
+    assert sum(a["n_tokens"] for a in fetches) == 10
+    for program in ("prefill", "decode"):
+        for name in COUNTERS:
+            booked = sum(a[program][name] for a in fetches)
+            assert booked == server.counted[program][name] > 0, name
+    layers = 2
+    assert server.counted["prefill"]["kv_rows_live"] == layers * (
+        sum(range(1, 20)) + sum(range(1, 12)))
+    assert server.counted["decode"]["kv_rows_attended"] == layers * 6 * (
+        5 + 3)
+
+
+def test_gpt2_tick_asks_the_profiler_as_often_as_before(monkeypatch):
+    """The counters and the chunk span cost a GPT-2 tick nothing: a decode
+    tick asks ``is_enabled()`` 6 times (serve.step and its args, the decode
+    dispatch, fetch and its args, fetch_wait), counted on PR 28's tree and
+    on this one."""
+    import jax
+
+    from distributed_pipeline_tpu.serving import DecodeServer
+
+    asked = []
+
+    class Annotation:
+        @staticmethod
+        def is_enabled():
+            asked.append(1)
+            return False
+
+    wl = _tiny_gpt2()
+    server = DecodeServer(wl, wl.init_params(jax.random.PRNGKey(3)),
+                          decode_slots=2, page_size=4, max_prompt_len=8,
+                          max_len=16)
+    server.submit(np.arange(1, 6, dtype=np.int32), 6)
+    server.step()
+    server.step()
+    monkeypatch.setattr(trace_lib, "_ANNOTATION", Annotation)
+    server.step()
+    monkeypatch.undo()
+    server.drain()
+    assert len(asked) == 6, len(asked)
