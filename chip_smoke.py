@@ -35,9 +35,10 @@ GPT-2-base steps on a one-device mesh and on a ``data=2, fsdp=2`` mesh at
 the same global batch and seed, and nothing else. Required: per-step losses
 agree within the stated tolerance; state really split over four devices.
 
-``--only decode`` runs the flash-decode kernel — which no shipped preset
-selects (``Dh = 64`` -> ``xla``) and the default run therefore never meets —
-against the XLA arm on the same inputs at a shape ``auto`` accepts.
+``--only decode`` runs the flash-decode kernel against the XLA arm on the
+same inputs, alone (the default run meets it inside the served model), at
+GPT-2-large's head shape and at ``H16 / Dh128``, and says what ``auto``
+resolves to at each and how long a call of each arm takes.
 ``--only launcher`` runs ``run.train --distributed --nprocs 1`` (the
 supervised, restartable run) twice into one directory and requires that
 the worker trained on the TPU and that the second run resumed. Each runs
@@ -65,6 +66,7 @@ import shutil
 import signal
 import subprocess
 import sys
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
@@ -99,12 +101,14 @@ class Sizes:
     # a layout bug moves the loss by whole units.
     loss_tol: float
     child_timeout_s: float
-    # --only decode: (slots, heads, head_dim, page_size, pages a slot, span
-    # links), and the largest |pallas - xla| allowed as a share of the
-    # largest output: six bf16 rounding steps (2**-8 each). The builder's
-    # chip run (PR 22) read 2.3 to 2.4 steps for bf16 pools and 1.0 to 1.8
-    # for int8; one wrong page among a slot's live ones moves it by tens.
-    decode_geom: Tuple[int, int, int, int, int, int] = (8, 16, 128, 16, 64, 4)
+    # --only decode: each geometry (slots, heads, head_dim, page_size, pages
+    # a slot, span links), and the largest |pallas - xla| allowed as a share
+    # of the largest output: six bf16 rounding steps (2**-8 each). The
+    # builder's chip run (PR 22) read 2.3 to 2.4 steps for bf16 pools and
+    # 1.0 to 1.8 for int8; one wrong page among a slot's live ones moves it
+    # by tens.
+    decode_geoms: Tuple[Tuple[int, int, int, int, int, int], ...] = (
+        (8, 16, 128, 16, 64, 4), (16, 20, 64, 16, 64, 4))
     decode_tol: float = 6 * 2.0 ** -8
     # --only launcher: a thin model (the ring is what is shown, not the
     # model) and the steps of each of the two runs
@@ -618,7 +622,7 @@ def phase_mesh(sizes: Sizes, seed: int, n_chips: int = 4) -> Dict[str, Any]:
 
 def _decode_child(spec_path: str) -> None:
     """flash-decode (``impl="pallas"``) against the XLA arm on one set of
-    random pools: bf16 and int8, single token and span."""
+    random pools a geometry: bf16 and int8, single token and span."""
     with open(spec_path) as f:
         spec = json.load(f)
     import jax
@@ -630,53 +634,74 @@ def _decode_child(spec_path: str) -> None:
         device_summary, enable_persistent_compilation_cache)
 
     enable_persistent_compilation_cache()
-    B, H, Dh, ps, n, L = spec["geom"]
     rng = np.random.default_rng(spec["seed"])
-    n_pool = 1 + B * n
-    table = jnp.asarray(1 + rng.permutation(B * n).reshape(B, n), jnp.int32)
-    depth = rng.integers(L, n * ps, (B,))           # live tokens a slot
-    out: Dict[str, Any] = {
-        "device": device_summary(), "cases": {},
-        "auto_resolves_to": fd.resolve_decode_impl(
-            "auto", (n_pool, ps, H, Dh))}
-    for kv in ("bf16", "int8"):
-        if kv == "int8":
-            pools = [jnp.asarray(rng.integers(-127, 128, (n_pool, ps, H * Dh)),
-                                 jnp.int8) for _ in range(2)]
-            scales = [jnp.asarray(rng.uniform(0.5, 1.5, (n_pool,)) / 127.0,
-                                  jnp.float32) for _ in range(2)]
-        else:
-            pools = [jnp.asarray(rng.standard_normal((n_pool, ps, H * Dh)),
-                                 jnp.bfloat16) for _ in range(2)]
-            scales = [None, None]
-        for span in (0, L):
-            if span:
-                q = rng.standard_normal((B, H, span, Dh))
-                pos = depth[:, None] - span + np.arange(span)[None, :]
-                seam = fd.paged_span_attention
+    out: Dict[str, Any] = {"device": device_summary(), "cases": {},
+                           "auto_resolves_to": {}}
+
+    def call_ms(fn, args, calls=10):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            got = fn(*args)
+        jax.block_until_ready(got)
+        return 1e3 * (time.perf_counter() - t0) / calls
+
+    for B, H, Dh, ps, n, L in spec["geoms"]:
+        shape = f"H{H}xDh{Dh}"
+        n_pool = 1 + B * n
+        table = jnp.asarray(1 + rng.permutation(B * n).reshape(B, n),
+                            jnp.int32)
+        depth = rng.integers(L, n * ps, (B,))       # live tokens a slot
+        for kv in ("bf16", "int8"):
+            if kv == "int8":
+                pools = [jnp.asarray(
+                    rng.integers(-127, 128, (n_pool, ps, H * Dh)), jnp.int8)
+                    for _ in range(2)]
+                scales = [jnp.asarray(
+                    rng.uniform(0.5, 1.5, (n_pool,)) / 127.0, jnp.float32)
+                    for _ in range(2)]
             else:
-                q = rng.standard_normal((B, H, Dh))
-                pos = depth - 1
-                seam = fd.paged_decode_attention
-            args = (jnp.asarray(q, jnp.bfloat16), pools[0], pools[1], table,
-                    jnp.asarray(pos, jnp.int32))
-            got = {impl: np.asarray(jax.jit(
-                lambda *a, impl=impl: seam(
-                    *a, impl=impl, scales_k=scales[0], scales_v=scales[1])
-                )(*args).astype(jnp.float32)) for impl in ("pallas", "xla")}
-            out["cases"][f"{kv}_{'span' if span else 'decode'}"] = {
-                "finite": bool(np.isfinite(got["pallas"]).all()),
-                "max_abs_diff_vs_xla": float(
-                    np.abs(got["pallas"] - got["xla"]).max()),
-                "max_abs_xla": float(np.abs(got["xla"]).max())}
+                pools = [jnp.asarray(
+                    rng.standard_normal((n_pool, ps, H * Dh)), jnp.bfloat16)
+                    for _ in range(2)]
+                scales = [None, None]
+            out["auto_resolves_to"][f"{shape}.{kv}"] = fd.resolve_decode_impl(
+                "auto", (n_pool, ps, H, Dh), pools[0].dtype)
+            for span in (0, L):
+                if span:
+                    q = rng.standard_normal((B, H, span, Dh))
+                    pos = depth[:, None] - span + np.arange(span)[None, :]
+                    seam = fd.paged_span_attention
+                else:
+                    q = rng.standard_normal((B, H, Dh))
+                    pos = depth - 1
+                    seam = fd.paged_decode_attention
+                args = (jnp.asarray(q, jnp.bfloat16), pools[0], pools[1],
+                        table, jnp.asarray(pos, jnp.int32))
+                arms = {impl: jax.jit(
+                    lambda *a, impl=impl: seam(
+                        *a, impl=impl, scales_k=scales[0],
+                        scales_v=scales[1])) for impl in ("pallas", "xla")}
+                got = {impl: np.asarray(fn(*args).astype(jnp.float32))
+                       for impl, fn in arms.items()}
+                out["cases"][
+                    f"{shape}.{kv}_{'span' if span else 'decode'}"] = {
+                    "finite": bool(np.isfinite(got["pallas"]).all()),
+                    "max_abs_diff_vs_xla": float(
+                        np.abs(got["pallas"] - got["xla"]).max()),
+                    "max_abs_xla": float(np.abs(got["xla"]).max()),
+                    "live_tokens": int(depth.sum()),
+                    "call_ms": {impl: call_ms(fn, args)
+                                for impl, fn in arms.items()}}
     with open(spec["result"], "w") as f:
         json.dump(out, f)
 
 
 def phase_decode(sizes: Sizes, seed: int) -> Dict[str, Any]:
-    rc, got = run_py_child("_decode_child",
-                           {"geom": list(sizes.decode_geom), "seed": seed},
-                           "decode", sizes.child_timeout_s)
+    rc, got = run_py_child(
+        "_decode_child",
+        {"geoms": [list(g) for g in sizes.decode_geoms], "seed": seed},
+        "decode", sizes.child_timeout_s)
     res: Dict[str, Any] = {"failures": []}
     if rc != 0 or got is None:
         res["failures"].append(
@@ -684,15 +709,18 @@ def phase_decode(sizes: Sizes, seed: int) -> Dict[str, Any]:
             + tail(os.path.join(OUT_DIR, "decode.log")))
         return finish("decode", res)
     res.update(got)
-    say(f"decode: device {got['device']}; geometry (slots, heads, head_dim, "
-        f"page, pages, span) {sizes.decode_geom}; 'auto' resolves to "
-        f"{got['auto_resolves_to']}; tolerance {sizes.decode_tol} of the "
-        f"largest output")
+    say(f"decode: device {got['device']}; geometries (slots, heads, "
+        f"head_dim, page, pages, span) {sizes.decode_geoms}; 'auto' "
+        f"resolves to {got['auto_resolves_to']}; tolerance "
+        f"{sizes.decode_tol} of the largest output")
     res["failures"] += platform_failures(got["device"])
     for name, c in got["cases"].items():
         say(f"decode: {name}: finite {c['finite']}, largest |pallas - xla| "
             f"{c['max_abs_diff_vs_xla']:.6g} at outputs up to "
-            f"{c['max_abs_xla']:.4g}")
+            f"{c['max_abs_xla']:.4g}; a call (host clock, "
+            f"{c['live_tokens']} live tokens): pallas "
+            f"{c['call_ms']['pallas']:.3f} ms, xla "
+            f"{c['call_ms']['xla']:.3f} ms")
         if not (c["finite"] and c["max_abs_diff_vs_xla"]
                 <= sizes.decode_tol * c["max_abs_xla"]):
             res["failures"].append(

@@ -87,7 +87,8 @@ class ServeSettings(S):
                 "'pallas' streams K/V pages straight from the paged pool "
                 "through a flash-decode kernel (no gathered copy); 'xla' "
                 "is the gather+dot reference; 'auto' picks pallas on TPU "
-                "and xla elsewhere")
+                "where a pool row is whole lane tiles (H*Dh % 128 == 0, "
+                "page_size % 16 == 0) and xla elsewhere")
     kv_quant: Literal["fp", "int8"] = _(
         "fp", "paged KV pool storage (ISSUE 20): 'int8' quantizes K/V at "
               "page granularity with [P] fp32 per-page scales — pool "

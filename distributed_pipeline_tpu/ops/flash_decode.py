@@ -1,41 +1,61 @@
 """Flash-decode: single-query attention straight out of the paged KV pool.
 
-The serving decode step (models/backbone.py ``_paged_attention``, single-
-token branch) is pure XLA today: ``gather_kv`` materializes a dense
-``[B, H, pages_per_slot * page_size, Dh]`` copy of every slot's pages in HBM
-— dead tail pages included — then masked softmax attention re-reads it. Per
-generated token that is ~3x the live K/V bytes (pool read + copy write +
-copy read), and it scales with the slot's page RESERVATION, not its live
-length. This kernel removes the copy: each grid step DMAs ONE live page
-``[page_size, H * Dh]`` directly from the pool through the slot's block
-table, splits its heads and folds it into online-softmax scratch in VMEM,
-and writes only the ``[B, H, Dh]`` output. Dead pages and inactive slots
-never enter the schedule (the compressed-step-table trick from
-ops/flash_attention.py).
+The XLA arm of the serving decode step (``xla_paged_decode`` ->
+serving/paged_kv.py ``gather_kv``) materializes a dense copy of every
+slot's page RESERVATION in HBM — dead tail pages included — splits its
+heads (at ``Dh`` 64, half a lane tile, a relayout that moves every byte)
+and runs masked softmax attention over all of it: its cost follows slots x
+reservation, not live tokens (PERF.md PR 28, PR 30). This kernel removes
+the copy and the split: each grid step DMAs a BLOCK of ``G`` consecutive
+live pages ``[page_size, H * Dh]`` directly from the pool through the
+slot's block table, folds it into online-softmax scratch in VMEM, and
+writes only a ``[1, H * Dh]`` output row a slot. Dead pages never enter
+the schedule.
+
+No head is split, of the pool or of the query. A slot's query row
+``[1, H * Dh]`` is spread once into a block-diagonal matrix
+``Qd [heads, H * Dh]`` (row ``h`` keeps head ``h``'s ``Dh`` lanes, zeros
+elsewhere), so ``Qd @ K^T`` over the block's rows AS THEY LIE IN THE POOL
+is the per-head scores ``[heads, rows]`` — heads on the sublanes,
+positions on the lanes, flash attention's own layout. The softmax runs
+there in float32; ``P @ V`` gives ``[heads, H * Dh]``, of which row ``h``
+is only wanted on head ``h``'s own lanes: that diagonal is taken once, at
+the slot's last block. Both products have free dimensions on both sides
+(MXU, operands in the pool's own type, float32 accumulation: what the XLA
+arm's einsums do), every array is two-dimensional with whole lane tiles,
+``H`` need not divide by 8 and ``Dh`` need not be 128. The price is
+``heads`` (``H`` padded to 16) times the arithmetic a per-head product
+would need, on an MXU a decode step otherwise leaves idle — and no row of
+the pool is converted, split or multiplied on the VPU (computing the
+scores as ``(k * q) @ S`` with a 0/1 head-sum matrix needs float32
+products to stay exact and took 3.7 x the kernel time: PERF.md PR 30).
 
 Step table (computed ON DEVICE inside the jitted decode step — positions
 and block tables are data, so the table costs no recompile and no host
-sync): a static worst-case ``[7, B * pages_per_slot]`` int32 array, one
-column of ``(slot, page_id, first, last, needs_mask, page_base, pos)`` per
-step (steps along the minor dimension: SMEM pads that one to 128 words).
-Live steps cover exactly each slot's ``pos // page_size + 1`` live pages in
-slot-major order (a contiguous accumulation run per slot); dead steps are
-packed at the tail and route to the trash page and a zero query row, so on
-TPU consecutive dead steps re-DMA nothing (identical index-map output) and
-the run's first/last flags make them self-contained no-ops. ``needs_mask``
-is set only on a slot's LAST live page — the one place the within-page
-``position <= pos`` compare is not vacuous (interior pages are fully live).
+sync): a static worst-case ``[5 + G, B * ceil(n / G)]`` int32 array, one
+column of ``(slot, first, last, base, pos)`` + ``G`` page ids per step
+(steps along the minor dimension: SMEM pads that one to 128 words). ``G``
+comes from ``page_size`` alone (a block of ``BLOCK_ROWS`` positions: 16
+pages of 16) and the pages a slot has. The columns cover each slot's
+``ceil((pos // page_size + 1) / G)`` live blocks in slot-major order (a
+contiguous accumulation run per slot, found by comparing the column's
+index with the running sum of the slots' block counts: no sort); their
+number is the kernel's GRID, a traced scalar, so no dead step runs (a
+static grid of ``B * ceil(n / G)`` steps spent half the kernel's time on
+its dead tail, 0.5 us a step). Entries of a slot's last block past its
+last live page name the trash page and are masked by ``position <= pos``
+(the mask is vacuous elsewhere and applied everywhere: one form). A slot
+with no live position (``pos`` < 0) still gets one block, all masked, and
+reads zeros.
 
-Page-layout contract (what TP layouts and int8 pages must keep to ride
-this kernel later):
+Page-layout contract (what TP layouts must keep to ride this kernel):
 
 * pool is ``[num_pages, page_size, H * Dh]`` per layer, K and V separate:
   a token's heads side by side in one lane-dense row (serving/paged_kv.py
   says why: with ``Dh`` alone in the lanes the TPU stored the pool
-  page-minor and every program relaid it). Nothing reshapes the POOL;
-  the XLA arm splits the heads of the gathered view, this kernel of the
-  page block in VMEM. Page 0 is the trash page — the kernel never reads
-  it through a live step, dead steps may;
+  page-minor and every program relaid it). Nothing reshapes the POOL; the
+  XLA arm splits the heads of its gathered view, this kernel splits
+  nothing. Page 0 is the trash page — read only under the position mask;
 * a block-table row lists a slot's pages head-first; entries past the live
   prefix may be anything (trash, stale, shared) — the schedule never
   visits them;
@@ -44,43 +64,41 @@ this kernel later):
   (the caller writes via ``write_token_kv`` BEFORE attending);
 * page sharing (serving/paged_kv.py ``PrefixCache``) is invisible here:
   two slots listing the same page id just schedule two DMAs of it;
-* on real TPU the kernel's ``(H, Dh)`` view of a page block (a reshape of
-  the ``[page_size, H * Dh]`` block in VMEM) must tile the ``(8, 128)``
-  f32 layout: Mosaic takes the split at ``Dh % 128 == 0`` and refuses it
-  at ``Dh`` 64 ("unsupported shape cast"); models that don't tile
-  dispatch to the XLA path under ``impl="auto"`` — see
-  :func:`resolve_decode_impl`, which reads ``H`` and ``Dh`` from its
-  caller (the stored pool no longer shows them);
-* int8 pools (serving/paged_kv.py ``write_*_kv_q8``) ride the SAME schedule:
-  each page's fp32 scale is bitcast to int32 and appended to its step
-  (fields 7..8, K and V scales), so the scale arrives with the scalar
-  prefetch and the kernel dequantizes the DMA'd page in VMEM
-  (``page.astype(f32) * scale``) before the products — no second gather, no
-  extra HBM traffic beyond the 8-byte-per-page scale pair. On real TPU
-  int8 page blocks want ``(32, 128)`` tiles; small-model pools again fall
-  back to the XLA arm, which dequantizes after ``gather_kv``.
+* on real TPU a row must be whole lane tiles, ``(H * Dh) % 128 == 0``, and
+  a page block whole sublane tiles of the pool's type (``page_size`` a
+  multiple of 16; of 8 for a float32 pool); other shapes dispatch to the
+  XLA path under ``impl="auto"`` — see :func:`resolve_decode_impl`, which
+  reads ``H``, ``Dh``, ``page_size`` and the type from its caller;
+* int8 pools (serving/paged_kv.py ``write_*_kv_q8``) ride the SAME
+  schedule: each page's fp32 scales are bitcast to int32 and appended to
+  its step's column (``2 G`` more rows), so they arrive with the scalar
+  prefetch; the page is cast to the query's type (exact) and its scale
+  multiplies the page's score columns (K) and weight columns (V) — no
+  second gather, no extra HBM traffic beyond 8 bytes a page. The chip's
+  compiler takes a 16-row int8 block (half its (32, 128) tile).
 
 Dispatch: ``impl="auto"`` -> this kernel on TPU (layout permitting), the
 XLA gather path elsewhere; ``"pallas"`` forces the kernel (interpreter
-mode off-TPU — CPU tests exercise the real kernel logic); ``"xla"`` forces
-the gather path. Numerics: the kernel's online softmax reassociates the
-sum, so outputs match the XLA path to float tolerance, not bitwise — the
-serving contract is greedy-token identity (tests/test_kernels.py).
+mode off-TPU — CPU tests exercise the real kernel logic, also on rows
+that are not whole lane tiles); ``"xla"`` forces the gather path.
+Numerics: the kernel's online softmax reassociates the sum and keeps its
+scores in float32 where the XLA arm rounds them to the activation type, so
+outputs match the XLA path to float tolerance, not bitwise — the serving
+contract is greedy-token identity (tests/test_kernels.py).
 
-Chip status (PR 22): the kernel, its int8 form and the span form COMPILE
-for a described v5e (tests/test_chip_compile.py) after three repairs that
-interpret mode could not ask for — the two head-batched products moved
-from ``dot_general`` (no free lhs dimension: refused by Mosaic) to
-multiply-and-reduce on the VPU, the int8 scale's bitcast works on a
-splatted vector, and the step table turned field-major. It has RUN on a
-chip once, for correctness (``H16 / Dh128``, bf16 and int8, decode and
-4-link span: within 0.008 of the XLA arm on the same inputs — builder's
-run, PR 22). No shipped preset selects it (``Dh = 64`` resolves to the XLA
-arm). Speed against that arm: not measured.
+Chip status (PR 30): 'auto' selects the kernel for GPT-2-large
+(``H20 / Dh64``), GPT-2-base (``H12 / Dh64``) and ``H16 / Dh128``, bf16 and
+int8 pools; it compiles for a described v5e at those shapes, decode and
+4-link span (tests/test_chip_compile.py), and RUNS on the chip inside the
+served model (``gpt2-large.serve.closed16``, PERF.md PR 30) and alone
+against the XLA arm (``python chip_smoke.py --only decode``: both
+geometries, bf16 and int8, decode and span, with each arm's time a call).
+The span form runs B * L pseudo-slots through the kernel and so reads a
+slot's pages L times (the XLA span arm gathers them once).
 
 HBM accounting: :func:`decode_hbm_bytes` reproduces the schedule's DMA
-traffic exactly (blocks x steps, consecutive-identical reuse deducted) —
-this is the kernel-arm number the ``gpt2-serve-decode-kernel`` bench leg
+traffic (distinct pages named, the step table, a q and an output row a
+slot) — the kernel-arm number the ``gpt2-serve-decode-kernel`` bench leg
 lands next to the XLA twin's cost-analysis bytes, because interpreter-mode
 emulation (scan + full-array updates) does not share the kernel's memory
 profile and cannot be cost-analyzed faithfully off-TPU.
@@ -107,19 +125,26 @@ KERNEL_NAME = "flash_decode"  # stable: traces and HLO text find it
 NEG_INF = -1e9
 LANES = 128
 TRASH_PAGE = 0  # mirrors serving/paged_kv.py (leaf module, no import cycle)
+BLOCK_ROWS = 256        # positions a grid step attends: G pages of page_size
+# step-table rows (one column a grid step); G page ids follow, then for an
+# int8 pool G K-scale words and G V-scale words
+_SLOT, _FIRST, _LAST, _BASE, _POS, _PAGE0 = range(6)
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def resolve_decode_impl(impl: str, page_shape=None) -> str:
+def resolve_decode_impl(impl: str, page_shape=None, kv_dtype=None) -> str:
     """``auto`` -> "pallas" on TPU when the page layout tiles, else "xla".
 
     ``page_shape`` is the pool's geometry ``(P, page_size, H, Dh)`` as the
     caller knows it — ``H`` and ``Dh`` come from the model or the query,
-    the stored pool being ``[P, page_size, H * Dh]`` (optional: auto on TPU
-    without it assumes tileable). Forced values pass through."""
+    the stored pool being ``[P, page_size, H * Dh]`` — and ``kv_dtype`` the
+    pool's type (optional, both: auto on TPU without them assumes a bf16
+    pool that tiles). The rule is on shapes and the type alone: a row of
+    whole lane tiles, and a page block ``(page_size, H * Dh)`` of whole
+    sublane tiles of the pool's type. Forced values pass through."""
     if impl in ("pallas", "xla"):
         return impl
     if impl != "auto":
@@ -127,119 +152,152 @@ def resolve_decode_impl(impl: str, page_shape=None) -> str:
     if jax.default_backend() != "tpu":
         return "xla"
     if page_shape is not None:
-        _, _, h, dh = page_shape
-        if h % 8 != 0 or dh % LANES != 0:  # pragma: no cover — TPU-only
-            return "xla"  # layout contract: (H, Dh) must tile (8, 128)
-    return "pallas"  # pragma: no cover — TPU-only
+        _, page_size, h, dh = page_shape
+        # float32 tiles hold 8 rows, bfloat16 16; an int8 tile holds 32,
+        # but the chip's compiler takes a 16-row int8 block (half a tile:
+        # tests/test_chip_compile.py) and the chip ran it (chip_smoke.py)
+        rows = 8 if jnp.dtype(kv_dtype or jnp.bfloat16).itemsize == 4 else 16
+        if (h * dh) % LANES != 0 or page_size % rows != 0:
+            return "xla"
+    return "pallas"
+
+
+def _pages_per_block(page_size: int, n_pages: int) -> int:
+    """``G``: pages a grid step attends — a block of about ``BLOCK_ROWS``
+    positions (16 pages of 16), never more pages than a slot has."""
+    return max(1, min(BLOCK_ROWS // page_size, n_pages))
+
+
+def _live_counts(positions, page_size: int, n_pages: int, g: int, xp):
+    """Live pages and live blocks a slot ([B] each; ``xp`` is jnp for the
+    traced table, numpy for the byte census). An empty slot still gets one
+    block, all masked, so that its output row is written (zeros)."""
+    n_live = xp.clip(positions // page_size + 1, 0, n_pages)
+    return n_live, xp.maximum(-(-n_live // g), 1)
 
 
 def _build_steps(block_table: jnp.ndarray, positions: jnp.ndarray,
-                 page_size: int, n_slots: int, scales_k=None,
-                 scales_v=None) -> jnp.ndarray:
-    """Traced ``[7, B * n_pages]`` step table (module docstring), one
-    COLUMN per step — SMEM pads the minor dimension to 128 words, so the
-    steps have to lie along it (step-major, a 4-link span over 8 slots of
-    64 pages asked for the whole 1 MiB of SMEM and was refused): live
-    steps packed first, slot-major; dead steps route to (slot=B, trash
-    page, pos=-1) so they mask to zero and re-DMA nothing on TPU. With
-    int8 scales the table grows to 9 fields: each step carries its page's
-    K and V scales as bitcast int32, gathered through the block table."""
+                 page_size: int, g: int, scales_k=None, scales_v=None):
+    """Traced step table ``[5 + G, B * ceil(n / G)]`` (module docstring)
+    and the number of its columns that are steps — the kernel's grid, a
+    traced scalar. One COLUMN per step — SMEM pads the minor dimension to
+    128 words, so the steps have to lie along it. Step ``t`` belongs to the
+    slot whose run of live blocks holds it (a compare against the running
+    sum of the slots' live-block counts: no sort); the columns past the
+    last run are never visited. With int8 scales the table grows by
+    ``2 G`` rows: each page's K and V scale as bitcast int32, gathered
+    through the block table."""
     B, n = block_table.shape
+    nb = -(-n // g)
     pos = positions.astype(jnp.int32)
-    n_live = jnp.minimum(pos // page_size + 1, n)              # [B]
-    j = jnp.arange(n, dtype=jnp.int32)
-    live = j[None, :] < n_live[:, None]                        # [B, n]
-    slot = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, n))
-    first = (j[None, :] == 0) & live
-    last = (j[None, :] == n_live[:, None] - 1) & live
-    base = jnp.broadcast_to((j * page_size)[None, :], (B, n))
-    posb = jnp.broadcast_to(pos[:, None], (B, n))
-    dead = (~live).reshape(-1).astype(jnp.int32)
-    order = jnp.argsort(dead, stable=True)  # stable: keeps slot-major order
-    dsel = dead[order]
-
-    def pack(x, fill):
-        return jnp.where(dsel == 1, fill,
-                         x.reshape(-1)[order]).astype(jnp.int32)
-
-    cols = [
-        pack(slot, n_slots), pack(block_table, TRASH_PAGE),
-        pack(first.astype(jnp.int32), 1), pack(last.astype(jnp.int32), 1),
-        # needs_mask == last: only a slot's final page is partially live
-        pack(last.astype(jnp.int32), 1),
-        pack(base, 0), pack(posb, -1)]
+    n_live, nb_live = _live_counts(pos, page_size, n, g, jnp)
+    ends = jnp.cumsum(nb_live)
+    t = jnp.arange(B * nb, dtype=jnp.int32)
+    slot = jnp.minimum(
+        jnp.sum(t[:, None] >= ends[None, :], axis=1), B - 1).astype(jnp.int32)
+    blk = t - (ends - nb_live)[slot]
+    j = blk[:, None] * g + jnp.arange(g, dtype=jnp.int32)[None, :]  # [T, G]
+    # entries of a slot's last block past its live prefix name the trash
+    # page (the table's own entries there may be anything) and are masked
+    # by position
+    pages = jnp.where(j < n_live[slot][:, None],
+                      block_table[slot[:, None], jnp.minimum(j, n - 1)],
+                      TRASH_PAGE).astype(jnp.int32)
+    rows = [slot, blk == 0, blk == nb_live[slot] - 1,
+            blk * (g * page_size), pos[slot]]
+    rows += list(pages.T)
     if scales_k is not None:
         for sc in (scales_k, scales_v):
             bits = jax.lax.bitcast_convert_type(
-                sc.astype(jnp.float32), jnp.int32)[block_table]   # [B, n]
-            cols.append(pack(bits, 0))  # dead rows: scale 0 -> dequant to 0
-    return jnp.stack(cols, axis=0)
+                sc.astype(jnp.float32), jnp.int32)[pages]         # [T, G]
+            rows += list(bits.T)
+    return jnp.stack([r.astype(jnp.int32) for r in rows], axis=0), ends[-1]
 
 
-def _decode_kernel(steps_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, scale: float, quant: bool):
+def _decode_kernel(steps_ref, q_ref, *refs, scale: float, g: int,
+                   head_dim: int, quant: bool):
+    k_refs, v_refs = refs[:g], refs[g:2 * g]
+    o_ref, qd_ref, acc_ref, m_ref, l_ref = refs[2 * g:]
     t = pl.program_id(0)
+    heads, width = qd_ref.shape            # heads: H padded to 16 rows
+    page_size = k_refs[0].shape[1]
+    n_rows = g * page_size
+    dtype = qd_ref.dtype                   # the products' operand type
+    exact = None if dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
 
-    @pl.when(steps_ref[2, t] == 1)
+    def own_lanes():
+        """[heads, H * Dh] mask: row h owns head h's Dh lanes of a row."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
+        lo = jax.lax.broadcasted_iota(
+            jnp.int32, (heads, width), 0) * head_dim
+        return (lane >= lo) & (lane < lo + head_dim)
+
+    @pl.when(steps_ref[_FIRST, t] == 1)
     def _init():
+        # No head is split, of the pool or of the query. The slot's query
+        # row becomes a block-diagonal [heads, H * Dh] matrix (row h keeps
+        # head h's lanes, zeros elsewhere), so that Qd @ K^T over the
+        # pool's own lane-dense rows IS the per-head scores.
+        qd_ref[:] = jnp.where(own_lanes(), q_ref[0].astype(jnp.float32),
+                              0.0).astype(dtype)
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32)        # [H, Dh]
-    # the page block arrives lane-dense, [page_size, H * Dh]: split the
-    # heads here, in VMEM (the pool in HBM is never reshaped)
-    split = (k_ref.shape[1],) + q.shape     # [page_size, H, Dh]
-    k = k_ref[0].reshape(split).astype(jnp.float32)
-    v = v_ref[0].reshape(split).astype(jnp.float32)
-    if quant:  # int8 page + per-page scale riding the step table (bitcast)
-        # (bitcast wants a vector on the chip: splat the SMEM word first)
-        def scale_of(col):
-            word = jnp.full((8, LANES), steps_ref[col, t], jnp.int32)
-            return jax.lax.bitcast_convert_type(word, jnp.float32)[:1, :1]
+    def block(page_refs):
+        """The step's G pages as one [G * page_size, H * Dh] block, rows in
+        position order, as they lie in the pool (int8: cast, exact)."""
+        pages = [ref[0].astype(dtype) for ref in page_refs]
+        return pages[0] if g == 1 else jnp.concatenate(pages, axis=0)
 
-        k = k * scale_of(7)
-        v = v * scale_of(8)
-    # s[t, h] = q[h, :] . k[t, h, :] — one query row per head has no free
-    # lhs dimension, and Mosaic takes no dot_general without one ("failed
-    # to parse TPU_DotDimensionNumbersAttr parameter
-    # 'lhs_non_contracting_dims'"). So both products are VPU work in the
-    # page's own [page_size, H, Dh] layout: multiply, then reduce over the
-    # lanes (scores) or over the page rows (output). A decode step reads
-    # every K/V byte once for one multiply-add each — it is bound by that
-    # read, not by the arithmetic the MXU would have saved.
-    s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale  # [ps, H, 1]
+    def page_scales(row):
+        """int8 pools: the G pages' scales from the step table, a lane a
+        block row ([1, G * page_size]). The words are selected as int32
+        and bitcast as one vector (the chip takes no scalar bitcast)."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (8, n_rows), 1)
+        words = jnp.zeros((8, n_rows), jnp.int32)
+        for i in range(g):
+            words = jnp.where(lane >= i * page_size, steps_ref[row + i, t],
+                              words)
+        return jax.lax.bitcast_convert_type(words, jnp.float32)[:1]
 
-    def _fold(apply_mask):
-        sl = s
-        if apply_mask:
-            tglob = steps_ref[5, t] + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            sl = jnp.where(tglob <= steps_ref[6, t], sl, NEG_INF)
-        m_prev = m_ref[:, :1]                            # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sl, axis=0))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sl - m_new[None])                    # [ps, H, 1]
-        if apply_mask:  # exact zeros for masked entries (fully-dead rows
-            # would otherwise softmax over the raw trash scores)
-            p = jnp.where(sl > NEG_INF / 2, p, 0.0)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=0)
-        acc_ref[:] = alpha * acc_ref[:] + jnp.sum(p * v, axis=0)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    # scores [heads, rows]: heads along the sublanes, the block's
+    # positions along the lanes — flash attention's own layout, with
+    # free dimensions on both sides of both products (MXU).
+    s = jax.lax.dot_general(
+        qd_ref[:], block(k_refs), (((1,), (1,)), ((), ())),
+        precision=exact, preferred_element_type=jnp.float32) * scale
+    if quant:
+        s = s * page_scales(_PAGE0 + g)
+    at = steps_ref[_BASE, t] + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 1)
+    live = at <= steps_ref[_POS, t]    # vacuous but on a slot's last
+    s = jnp.where(live, s, NEG_INF)    # block; one form, no branch
+    m_prev = m_ref[:]                  # [heads, 128], lane-replicated
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # exact zeros for masked positions (a block with no live position
+    # would otherwise softmax over the raw trash scores)
+    p = jnp.where(live, jnp.exp(s - m_new[:, :1]), 0.0)
+    l_new = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[:] = m_new
+    l_ref[:] = l_new
+    if quant:
+        p = p * page_scales(_PAGE0 + 2 * g)
+    # [heads, H * Dh]: row h is head h's weights over EVERY head's
+    # lanes; only its own Dh lanes are kept, at the slot's last block
+    acc = alpha[:, :1] * acc_ref[:] + jnp.dot(
+        p.astype(dtype), block(v_refs), precision=exact,
+        preferred_element_type=jnp.float32)
+    acc_ref[:] = acc
 
-    @pl.when(steps_ref[4, t] == 0)
-    def _interior():  # fully-live page: skip the iota/compare mask
-        _fold(False)
-
-    @pl.when(steps_ref[4, t] == 1)
-    def _boundary():
-        _fold(True)
-
-    @pl.when(steps_ref[3, t] == 1)
+    @pl.when(steps_ref[_LAST, t] == 1)
     def _finalize():
-        # Dead runs have l == 0 exactly; emit zeros, not NaNs.
-        l = l_ref[:, :1]
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+        # a slot with no live position has l == 0: zeros, not NaNs
+        own = jnp.where(own_lanes(),
+                        acc / jnp.maximum(l_new[:, :1], 1e-20), 0.0)
+        o_ref[0] = jnp.sum(own, axis=0, keepdims=True).astype(
+            o_ref.dtype)
 
 
 def flash_decode(q: jnp.ndarray, pages_k: jnp.ndarray, pages_v: jnp.ndarray,
@@ -250,40 +308,56 @@ def flash_decode(q: jnp.ndarray, pages_k: jnp.ndarray, pages_v: jnp.ndarray,
     [B] -> [B, H, Dh]. Attends positions ``0..positions[b]`` of each slot
     through its block table; everything later is skipped at schedule level.
     ``scales_k``/``scales_v`` ([P] fp32) flag an int8 pool: the kernel
-    dequantizes each DMA'd page with its scale from the step table."""
+    scales each page's scores and weights with its pair from the step
+    table."""
+    return _flash_decode(q, pages_k, pages_v, block_table, positions,
+                         scales_k, scales_v, interpret=_interpret())
+
+
+# jitted, so that a model's layers share ONE trace of the kernel and its
+# table (traced a layer, 36 layers of 33 block specs cost the serve cell
+# 12 s of set-up); where it runs is part of the cache's key
+@functools.partial(jax.jit, static_argnames="interpret")
+def _flash_decode(q, pages_k, pages_v, block_table, positions, scales_k,
+                  scales_v, *, interpret: bool):
     B, H, Dh = q.shape
-    page_size = pages_k.shape[1]
+    page_size, width = pages_k.shape[1:]
+    g = _pages_per_block(page_size, block_table.shape[1])
     quant = scales_k is not None
-    steps = _build_steps(block_table, positions, page_size, B,
-                         scales_k, scales_v)
-    # Row B is the dead-step sink: zero query in, garbage-free zeros out.
-    qp = jnp.concatenate([q, jnp.zeros((1, H, Dh), q.dtype)], axis=0)
-    n_steps = steps.shape[1]
+    dtype = q.dtype if quant else jnp.promote_types(q.dtype, pages_k.dtype)
+    steps, n_steps = _build_steps(block_table, positions, page_size, g,
+                                  scales_k, scales_v)
+
+    def page_spec(i):
+        # the SAME pool operand is named G times; spec i reads page id i
+        # of the step's column (the pipeline's own DMAs, nothing manual)
+        return pl.BlockSpec((1, page_size, width),
+                            lambda t, s: (s[_PAGE0 + i, t], 0, 0),
+                            memory_space=_VMEM)
+
+    row_spec = pl.BlockSpec((1, 1, width), lambda t, s: (s[_SLOT, t], 0, 0),
+                            memory_space=_VMEM)
+    heads = -(-H // 16) * 16        # whole sublane tiles of either type
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_steps,),
-        in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda t, s: (s[0, t], 0, 0),
-                         memory_space=_VMEM),
-            pl.BlockSpec((1, page_size, H * Dh),
-                         lambda t, s: (s[1, t], 0, 0), memory_space=_VMEM),
-            pl.BlockSpec((1, page_size, H * Dh),
-                         lambda t, s: (s[1, t], 0, 0), memory_space=_VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, H, Dh), lambda t, s: (s[0, t], 0, 0),
-                               memory_space=_VMEM),
+        grid=(n_steps,),   # traced: the live blocks, no dead step runs
+        in_specs=[row_spec] + [page_spec(i) for i in range(g)] * 2,
+        out_specs=row_spec,
         scratch_shapes=[
-            _VMEM((H, Dh), jnp.float32),      # acc
-            _VMEM((H, LANES), jnp.float32),   # running max (lane-replicated)
-            _VMEM((H, LANES), jnp.float32),   # running normalizer
+            _VMEM((heads, width), dtype),         # block-diagonal query
+            _VMEM((heads, width), jnp.float32),   # acc
+            _VMEM((heads, LANES), jnp.float32),   # running max
+            _VMEM((heads, LANES), jnp.float32),   # running normaliser
         ])
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=Dh ** -0.5, quant=quant),
+        functools.partial(_decode_kernel, scale=Dh ** -0.5, g=g,
+                          head_dim=Dh, quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B + 1, H, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, width), q.dtype),
         name=KERNEL_NAME,
-        interpret=_interpret())(steps, qp, pages_k, pages_v)
-    return out[:B]
+        interpret=interpret)(
+            steps, q.reshape(B, 1, width), *[pages_k] * g, *[pages_v] * g)
+    return out.reshape(B, H, Dh)
 
 
 def xla_paged_decode(q: jnp.ndarray, pages_k: jnp.ndarray,
@@ -318,7 +392,8 @@ def paged_decode_attention(q, pages_k, pages_v, block_table, positions,
     the token's K/V into the pool (page-layout contract); for int8 pools it
     passes the [P] scale sidecars and both arms dequantize."""
     _, H, Dh = q.shape
-    if resolve_decode_impl(impl, pages_k.shape[:2] + (H, Dh)) == "pallas":
+    if resolve_decode_impl(impl, pages_k.shape[:2] + (H, Dh),
+                           pages_k.dtype) == "pallas":
         return flash_decode(q, pages_k, pages_v, block_table, positions,
                             scales_k, scales_v)
     return xla_paged_decode(q, pages_k, pages_v, block_table, positions,
@@ -368,7 +443,8 @@ def paged_span_attention(q, pages_k, pages_v, block_table, positions,
     pseudo-slots (each link repeats its slot's block-table row); the XLA
     arm gathers each slot once and masks per link."""
     B, H, L, Dh = q.shape
-    if resolve_decode_impl(impl, pages_k.shape[:2] + (H, Dh)) == "pallas":
+    if resolve_decode_impl(impl, pages_k.shape[:2] + (H, Dh),
+                           pages_k.dtype) == "pallas":
         qf = q.transpose(0, 2, 1, 3).reshape(B * L, H, Dh)
         bt = jnp.repeat(block_table, L, axis=0)
         o = flash_decode(qf, pages_k, pages_v, bt, positions.reshape(-1),
@@ -384,33 +460,32 @@ def decode_hbm_bytes(block_table: np.ndarray, positions: np.ndarray,
                      quantized: bool = False) -> int:
     """Exact HBM bytes one kernel invocation DMAs, from its own schedule.
 
-    Counts each DISTINCT live page's K and V blocks once across the whole
+    Counts each DISTINCT page's K and V blocks once across the whole
     schedule — the schedule visits pages slot-major, so a page shared by
     many slots (PrefixCache) or revisited consecutively is fetched once;
-    dedup is by page-id set, which also zero-rates the packed dead tail.
-    (The pre-r22 census deduped only consecutive-identical visits, which
-    under-credited the kernel on shared-prefix workloads where the same
-    prefix pages appear in every slot's run.) Adds one q read and one
-    output write per slot and the SMEM step table. ``kv_dtype_bytes``
-    prices the pool separately from q/out (int8 pools: 1 vs 4);
-    ``quantized`` widens the table to 9 columns — the per-page scale pair
-    rides it, so it costs table bytes, not extra page traffic."""
+    dedup is by page-id set. The trash page is one more page of that set:
+    the schedule names it for the entries of a slot's last block past its
+    live prefix, so it is counted once when any slot has such entries (the
+    table's columns past the last live block are not steps: the grid ends
+    there). Adds one q read and one output write per slot and the SMEM
+    step table, ``[5 + G, B * ceil(n / G)]`` words with ``G`` the kernel's
+    own (:func:`_pages_per_block`). ``kv_dtype_bytes`` prices the pool
+    separately from q/out (int8 pools: 1 vs 4); ``quantized`` adds the
+    table's ``2 G`` scale rows — the per-page scale pair rides it, so it
+    costs table bytes, not extra page traffic."""
     bt = np.asarray(block_table)
     pos = np.asarray(positions)
     B, n = bt.shape
     if kv_dtype_bytes is None:
         kv_dtype_bytes = 1 if quantized else dtype_bytes
-    page_bytes = page_size * n_heads * head_dim * kv_dtype_bytes
-    qo_bytes = n_heads * head_dim * dtype_bytes
-    n_live = np.minimum(pos // page_size + 1, n)
-    total = 0
-    seen: set = set()
-    for b in range(B):
-        for j in range(int(n_live[b])):
-            page = int(bt[b, j])
-            if page not in seen:
-                total += 2 * page_bytes            # K and V blocks
-                seen.add(page)
-        total += 2 * qo_bytes                      # q read + out write
-    total += (B * n) * (9 if quantized else 7) * 4  # step table (SMEM)
+    width = n_heads * head_dim
+    g = _pages_per_block(page_size, n)
+    nb = -(-n // g)
+    n_live, nb_live = _live_counts(pos, page_size, n, g, np)
+    seen = {int(p) for b in range(B) for p in bt[b, :int(n_live[b])]}
+    if (nb_live * g > n_live).any():
+        seen.add(TRASH_PAGE)
+    total = len(seen) * 2 * page_size * width * kv_dtype_bytes  # K and V
+    total += B * 2 * width * dtype_bytes           # q read + out write
+    total += (B * nb) * (5 + (3 if quantized else 1) * g) * 4  # step table
     return int(total)

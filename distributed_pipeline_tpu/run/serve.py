@@ -290,7 +290,8 @@ def _serve_single(settings: ServeSettings) -> dict:
             settings.decode_impl,
             (server.mgr.num_pages, settings.page_size,
              wl.model.num_heads,
-             wl.model.hidden_size // wl.model.num_heads)),
+             wl.model.hidden_size // wl.model.num_heads),
+            "int8" if settings.kv_quant == "int8" else wl.model.dtype),
     }
     if settings.spec_tokens > 0:
         # every fetched token is target-verified, so the accepted rate IS

@@ -21,16 +21,19 @@ models/backbone.py can call into it without a cycle):
   every program that scatters or gathers by page (three pool-sized copies
   a pool a decode step on the v5e, PERF.md PR 28). ``H * Dh`` is the model
   width: whole lane tiles, no padding, and the layout the page-indexed
-  scatter and gather want. The heads are split from the GATHERED view,
-  never from the pool;
+  scatter and gather want. The XLA decode arm splits the heads of its
+  GATHERED view, never of the pool; the flash-decode kernel
+  (ops/flash_decode.py), which 'auto' takes on the TPU for rows of whole
+  lane tiles, reads the rows as they lie and splits nothing;
 * :func:`write_prompt_kv` — scatter a prefill's [B, H, L, Dh] K/V as
   ``[B * L, H * Dh]`` rows into the slots' pages (invalid/padded rows ->
   the trash page);
 * :func:`write_token_kv`  — scatter one decode step's [B, H, Dh] as
   ``[B, H * Dh]`` rows at each slot's own position;
 * :func:`gather_kv`       — gather a slot-major dense ``[B, H, Lmax, Dh]``
-  view for attention (the pure-XLA stand-in for a fused flash-decode
-  kernel, which slots in behind the same seam later — ROADMAP item 4).
+  view for attention: the pure-XLA arm of the decode seam (CPU, shapes the
+  kernel's rule refuses, the tests' twin); its cost follows slots x
+  reservation, the flash-decode kernel's follows live tokens.
 
 Everything is gather/scatter/``where`` — no host control flow — so the ops
 trace into the AOT-compiled prefill/decode executables and run on CPU for
@@ -74,8 +77,9 @@ def gather_kv(pages: jnp.ndarray, block_table: jnp.ndarray,
     """Dense per-slot view of the paged pool.
 
     ``pages`` [P, page_size, H * Dh], ``block_table`` [B, n_pages] ->
-    [B, H, n_pages * page_size, Dh] (the heads are split here, from the
-    gathered view; the pool itself is never reshaped). Entries beyond a
+    [B, H, n_pages * page_size, Dh] (the XLA arm's view: the heads are
+    split here, from the gathered copy; the pool itself is never
+    reshaped). Entries beyond a
     slot's live length are trash-page garbage; the caller masks them
     (backbone ``_paged_attention``), and masked entries contribute exact
     zeros to the softmax — at equal padded length the result is

@@ -236,10 +236,11 @@ def test_sharded_train_step_compiles_on_mesh(mesh4, as_on_tpu, zero1):
 
 # ------------------------------------------------------------ flash decode
 
-def _decode_args(one_chip, kv_dtype, L=0):
-    """A shape 'auto' accepts: H % 8 == 0, Dh == 128. 8 slots, 16-token
-    pages, 1024 tokens a slot."""
-    B, H, Dh, ps, n = 8, 16, 128, 16, 64
+def _decode_args(one_chip, kv_dtype, geom, L=0):
+    """``geom`` = (slots, heads, head_dim): 16-token pages, 1024 tokens a
+    slot."""
+    B, H, Dh = geom
+    ps, n = 16, 64
     qshape = (B, H, L, Dh) if L else (B, H, Dh)
     pool = sds((1 + B * n, ps, H * Dh), kv_dtype, one_chip)
     args = [sds(qshape, jnp.bfloat16, one_chip), pool, pool,
@@ -251,14 +252,25 @@ def _decode_args(one_chip, kv_dtype, L=0):
     return args
 
 
+def kernel_calls(text, name):
+    return [ln for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln and name in ln]
+
+
+@pytest.mark.parametrize("geom", [(8, 16, 128), (16, 20, 64), (16, 12, 64)],
+                         ids=["H16xDh128", "H20xDh64", "H12xDh64"])
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("span", [0, 4], ids=["decode", "span4"])
-def test_flash_decode_compiles(one_chip, as_on_tpu, kv, span):
+def test_flash_decode_compiles(one_chip, as_on_tpu, kv, span, geom):
+    """The head-free kernel at the shape the old form ran on the chip and
+    at GPT-2-large's and GPT-2-base's (``Dh`` 64, the cell's 16 slots x 64
+    pages of 16): 'auto' picks it by the shape rule, and the compiler takes
+    it as ONE custom call."""
     kv_dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
-    args = _decode_args(one_chip, kv_dtype, span)
+    args = _decode_args(one_chip, kv_dtype, geom, span)
     q = args[0].shape                       # H, Dh come from the query
     assert fd.resolve_decode_impl(
-        "auto", args[1].shape[:2] + (q[1], q[-1])) == "pallas"
+        "auto", args[1].shape[:2] + (q[1], q[-1]), kv_dtype) == "pallas"
     seam = fd.paged_span_attention if span else fd.paged_decode_attention
 
     def f(q, pk, pv, bt, pos, sk=None, sv=None):
@@ -266,7 +278,7 @@ def test_flash_decode_compiles(one_chip, as_on_tpu, kv, span):
                     scales_v=sv)
 
     c = jax.jit(f).lower(*args).compile()
-    assert_kernel(c, fd.KERNEL_NAME)
+    assert len(kernel_calls(c.as_text(), fd.KERNEL_NAME)) == 1
 
 
 # --------------------------------------- the serving programs and the pool
@@ -274,15 +286,17 @@ def test_flash_decode_compiles(one_chip, as_on_tpu, kv, span):
 _HLO_OP = re.compile(r"= (\w+)\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(")
 
 
-def pool_sized_copies(text, n_elements):
-    """``copy`` ops of a compiled program whose result holds at least
-    ``n_elements``: the relayouts of a whole page pool, if any."""
+def results_of_size(text, n_elements, op=None, exact=False):
+    """Results of a compiled program's ops (all, or those named ``op``)
+    that hold at least — or exactly — ``n_elements``."""
     found = []
     for ln in text.splitlines():
         m = _HLO_OP.search(ln)
-        if m and m.group(3) == "copy" and np.prod(
-                [int(d) for d in m.group(2).split(",") if d]) >= n_elements:
-            found.append(f"{m.group(1)}[{m.group(2)}]")
+        if not m or (op and m.group(3) != op):
+            continue
+        size = np.prod([int(d) for d in m.group(2).split(",") if d])
+        if size == n_elements or (not exact and size > n_elements):
+            found.append(f"{m.group(1)}[{m.group(2)}] {m.group(3)}")
     return found
 
 
@@ -291,22 +305,27 @@ def pool_sized_copies(text, n_elements):
 @pytest.mark.parametrize("program", ["decode", "prefill", "span4"])
 def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
                                                    program, kv_quant, heads):
-    """The engine's own program bodies at GPT-2 widths (``Dh`` 64: 'auto'
-    resolves to the XLA arm), two layers, 16 slots x 64 pages of 16. With
-    ``Dh`` alone in the lanes the chip's compiler stored each
-    ``[P, 16, H, 64]`` pool page-minor and copied it to row-major and back
-    in every program (62 % of the serve cell's device time, PERF.md PR 28);
-    stored ``[P, 16, H * 64]`` no program copies anything of a pool's
-    size."""
+    """The engine's own program bodies at GPT-2 widths (``Dh`` 64), two
+    layers, 16 slots x 64 pages of 16. With ``Dh`` alone in the lanes the
+    chip's compiler stored each ``[P, 16, H, 64]`` pool page-minor and
+    copied it to row-major and back in every program (62 % of the serve
+    cell's device time, PERF.md PR 28); stored ``[P, 16, H * 64]`` no
+    program copies anything of a pool's size. Since PR 30 'auto' resolves
+    the decode step and the verify span to the flash-decode kernel at these
+    shapes: one call a layer, and the gathered view of every slot's
+    reservation (``[slots, pages * page_size, H * Dh]``, then its head
+    split: 30 % of the cell's device time, PERF.md PR 30) is written
+    nowhere. The prefill attends the prompt's own K/V in XLA (prompts
+    under 1024) and holds no kernel."""
     from flax import linen as nn
 
     from distributed_pipeline_tpu.models import create_model_from_config
     from distributed_pipeline_tpu.serving.engine import DecodeEngine
 
-    slots, ps, n, lp, bp, span = 16, 16, 64, 512, 8, 4
+    slots, ps, n, lp, bp, span, layers = 16, 16, 64, 512, 8, 4, 2
     wl = create_model_from_config(
         model_family="gpt2", vocab_size=1000, seq_len=n * ps,
-        hidden_size=64 * heads, num_layers=2, num_heads=heads,
+        hidden_size=64 * heads, num_layers=layers, num_heads=heads,
         dtype="bfloat16")
 
     def on_chip(tree):
@@ -339,8 +358,15 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
         step, args = eng._verify_step, (
             i32(span, slots), *state, i32(slots, n), i32(slots), key)
     text = step._jitted.lower(params, cache, *args).compile().as_text()
-    assert "tpu_custom_call" not in text        # the XLA arm, as shipped
-    assert pool_sized_copies(text, pools[0].size) == []
+    assert results_of_size(text, pools[0].size, op="copy") == []
+    if program == "prefill":
+        assert "tpu_custom_call" not in text
+        return
+    assert fd.resolve_decode_impl(
+        "auto", (1 + slots * n, ps, heads, 64), pools[0].dtype) == "pallas"
+    assert len(kernel_calls(text, fd.KERNEL_NAME)) == layers
+    assert results_of_size(text, slots * n * ps * heads * 64,
+                           exact=True) == []
 
 
 def test_mla_block_attend_compiles_at_published_widths(one_chip):

@@ -26,7 +26,8 @@ TINY = chip_smoke.Sizes(
     requests=((8, 6, 2), (5, 4, 2)),
     decode_slots=4, page_size=4, max_prompt_len=16,
     mesh_steps=2, loss_tol=1e-3, child_timeout_s=300.0,
-    decode_geom=(2, 2, 8, 4, 3, 2), decode_tol=6 * 2.0 ** -8,
+    decode_geoms=((2, 2, 8, 4, 3, 2), (2, 2, 64, 16, 10, 2)),
+    decode_tol=6 * 2.0 ** -8,
     ring_argv=("--model_family", "gpt2", "--hidden_size", "32",
                "--num_layers", "2", "--num_heads", "2", "--vocab_size", "64",
                "--seq_len", "16", "--dtype", "float32",
@@ -109,9 +110,10 @@ def test_side_checks_run_clean_on_cpu(smoke, capsys, monkeypatch, which):
            "launcher": smoke.phase_launcher}[which](TINY, seed=7)
     assert res["failures"] == NOT_TPU, res["failures"]
     if which == "decode":
-        assert set(res["cases"]) == {"bf16_decode", "bf16_span",
-                                     "int8_decode", "int8_span"}
-        assert res["auto_resolves_to"] == "xla"  # Dh = 8, and no TPU
+        assert set(res["cases"]) == {
+            f"{shape}.{kv}_{form}" for shape in ("H2xDh8", "H2xDh64")
+            for kv in ("bf16", "int8") for form in ("decode", "span")}
+        assert set(res["auto_resolves_to"].values()) == {"xla"}  # no TPU
     else:
         assert [(a["start_step"], a["end_step"])
                 for a in res["attempts"]] == [(0, 2), (2, 4)]

@@ -127,6 +127,85 @@ def test_flash_decode_prefix_cache_shared_pages():
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-6)
 
 
+# Blocks of G pages a grid step. 16-row pages -> G = 16 (256 positions a
+# block); 40 pages a slot is not a multiple of G: blocks of 16, 16 and 8.
+BLOCK_CASES = {
+    "one_live_position": [0],
+    "ends_in_a_blocks_first_page": [5, 261, 520],
+    "on_a_blocks_edge": [255, 511],
+    "one_past_a_blocks_edge": [256, 512],
+    "full_reservation_partial_last_block": [639, 600],
+    "mixed_depths": [0, 15, 16, 255, 256, 400, 639],
+}
+
+
+@pytest.mark.parametrize("positions", list(BLOCK_CASES.values()),
+                         ids=list(BLOCK_CASES))
+def test_flash_decode_blocks_of_pages(positions):
+    from distributed_pipeline_tpu.ops.flash_decode import _pages_per_block
+    ps, n, H, Dh = 16, 40, 2, 8
+    assert _pages_per_block(ps, n) == 16 and n % 16 != 0
+    rng = np.random.default_rng(23)
+    q, k, v, bt, pos = paged_case(
+        rng, slots=len(positions), n_pages=n, page_size=ps, n_heads=H,
+        head_dim=Dh, positions=positions)
+    got = np.asarray(flash_decode(q, k, v, bt, pos))
+    np.testing.assert_allclose(got, np.asarray(
+        xla_paged_decode(q, k, v, bt, pos)), rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(got, dense_reference(q, k, v, bt, pos),
+                               rtol=2e-5, atol=2e-6)
+    # table entries past the live prefix may be anything: the schedule
+    # names the trash page in their place, also INSIDE a live block
+    btp = np.asarray(bt).copy()
+    for b, p in enumerate(positions):
+        btp[b, p // ps + 1:] = 1 + (7 * b) % (len(positions) * n)
+    moved = np.asarray(flash_decode(q, k, v, jnp.asarray(btp), pos))
+    np.testing.assert_array_equal(moved, got)
+
+
+def test_flash_decode_inactive_slot_and_shared_prefix_in_blocks():
+    """A released slot (table all trash, a stale position) beside two
+    slots that share their first twenty pages (more than one block): the
+    inactive row attends the trash page like the XLA arm, the others are
+    untouched by it."""
+    ps, n, H, Dh = 16, 40, 2, 8
+    rng = np.random.default_rng(29)
+    table = 1 + np.arange(3 * n).reshape(3, n)
+    table[1, :20] = table[0, :20]              # shared prefix pages
+    table[2, :] = TRASH_PAGE                   # inactive
+    q, k, v, bt, pos = paged_case(
+        rng, slots=3, n_pages=n, page_size=ps, n_heads=H, head_dim=Dh,
+        positions=[340, 500, 40], table=table)
+    got = np.asarray(flash_decode(q, k, v, bt, pos))
+    ref = np.asarray(xla_paged_decode(q, k, v, bt, pos))
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got[:2], dense_reference(
+        q, k, v, bt, pos)[:2], rtol=2e-5, atol=2e-6)
+    # a slot with NO live position (position -1) reads zeros, not NaNs
+    none = np.asarray(flash_decode(q, k, v, bt, jnp.asarray([340, -1, 40])))
+    np.testing.assert_array_equal(none[1], 0.0)
+    np.testing.assert_allclose(none[0], got[0], rtol=1e-6)
+
+
+def test_flash_decode_int8_scales_a_page_of_a_block():
+    """Each page of a block carries its own K and V scale (a factor of 30
+    apart here) through the step table."""
+    ps, n, H, Dh, B = 16, 40, 2, 8, 3
+    rng = np.random.default_rng(31)
+    P = 1 + B * n
+    pools = [jnp.asarray(rng.integers(-127, 128, (P, ps, H * Dh)), jnp.int8)
+             for _ in range(2)]
+    scales = [jnp.asarray(rng.uniform(0.1, 3.0, (P,)) / 127.0, jnp.float32)
+              for _ in range(2)]
+    bt = jnp.asarray(1 + rng.permutation(B * n).reshape(B, n), jnp.int32)
+    q = jnp.asarray(rng.standard_normal((B, H, Dh)), jnp.float32)
+    pos = jnp.asarray([3, 260, 639], jnp.int32)
+    got = flash_decode(q, pools[0], pools[1], bt, pos, *scales)
+    ref = xla_paged_decode(q, pools[0], pools[1], bt, pos, *scales)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+
+
 def test_flash_decode_under_jit_and_seam_dispatch():
     """The seam is called from inside the engine's jitted decode step:
     tracing must work and forced impls must agree through it."""
@@ -201,35 +280,54 @@ def test_paged_decode_through_the_merged_head_axis(heads, head_dim, kv):
                                    **tol)
 
 
-def test_resolve_decode_impl_dispatch():
+def test_resolve_decode_impl_dispatch(monkeypatch):
     assert resolve_decode_impl("pallas") == "pallas"   # forced passes through
     assert resolve_decode_impl("xla") == "xla"
     if jax.default_backend() != "tpu":
         assert resolve_decode_impl("auto") == "xla"    # no TPU -> gather path
     with pytest.raises(ValueError, match="auto|pallas|xla"):
         resolve_decode_impl("cuda")
+    # on a TPU the rule reads shapes and the pool's type, no model's name:
+    # a row of whole lane tiles, a page block of whole tiles of the type
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for (ps, h, dh), kv, want in [
+            ((16, 20, 64), jnp.bfloat16, "pallas"),    # GPT-2-large
+            ((16, 12, 64), jnp.bfloat16, "pallas"),    # GPT-2-base
+            ((16, 16, 128), jnp.bfloat16, "pallas"),
+            ((16, 20, 64), jnp.int8, "pallas"),
+            ((8, 20, 64), jnp.float32, "pallas"),
+            ((8, 20, 64), jnp.bfloat16, "xla"),        # half a bf16 tile
+            ((16, 5, 64), jnp.bfloat16, "xla"),        # row 320: 2.5 tiles
+            ((16, 4, 8), jnp.bfloat16, "xla")]:
+        assert resolve_decode_impl("auto", (9, ps, h, dh), kv) == want, (
+            ps, h, dh, kv)
+    assert resolve_decode_impl("auto") == "pallas"
 
 
 def test_decode_hbm_bytes_counts_live_pages_only():
-    """The byte model is the schedule: live pages x (K+V), consecutive
-    duplicates free, q/out per slot, step table — and it must scale with
+    """The byte model is the schedule: distinct pages x (K+V) — the trash
+    page among them once, named for a last block's entries past the live
+    prefix — q/out per slot, the step table; and it must scale with
     POSITION, not the page reservation."""
     ps, H, Dh = 4, 2, 8
     bt = np.asarray([[1, 2, 3], [4, 5, 6]])
     page = ps * H * Dh * 4
     qo = H * Dh * 4
-    tab = 2 * 3 * 7 * 4
+
+    def tab(slots, n, quantized=False):  # G = n at these sizes: one block
+        return slots * (5 + (3 if quantized else 1) * n) * 4
+
     got = decode_hbm_bytes(bt, np.asarray([0, 5]), ps, H, Dh)
-    # slot 0: 1 live page; slot 1: 2 live pages -> 3 distinct page visits
-    assert got == 3 * 2 * page + 2 * 2 * qo + tab
+    # slot 0: 1 live page; slot 1: 2 live pages; + the trash page
+    assert got == (3 + 1) * 2 * page + 2 * 2 * qo + tab(2, 3)
     # growing the reservation (dead tail) must not move the number
     bt_wide = np.concatenate([bt, np.full((2, 5), TRASH_PAGE)], 1)
     wide = decode_hbm_bytes(bt_wide, np.asarray([0, 5]), ps, H, Dh)
-    assert wide == got + 2 * 5 * 7 * 4             # only the table grows
-    # consecutive identical pages (packed dead runs on TPU) are deducted
+    assert wide == got - tab(2, 3) + tab(2, 8)     # only the table grows
+    # a repeated page is fetched once; a full slot names no trash page
     shared = decode_hbm_bytes(np.asarray([[1, 1]]), np.asarray([7]),
                               ps, H, Dh)
-    assert shared == 1 * 2 * page + 2 * qo + 2 * 7 * 4
+    assert shared == 1 * 2 * page + 2 * qo + tab(1, 2)
 
 
 def test_decode_hbm_bytes_dedups_shared_pages_across_slots():
@@ -243,12 +341,29 @@ def test_decode_hbm_bytes_dedups_shared_pages_across_slots():
     qo = H * Dh * 4
     bt = np.asarray([[1, 2], [1, 3]])
     got = decode_hbm_bytes(bt, np.asarray([7, 7]), ps, H, Dh)
-    assert got == 3 * 2 * page + 2 * 2 * qo + 2 * 2 * 7 * 4
-    # int8 pool: pages priced at 1 byte/elt, q/out stay fp, table widens
-    # to 9 columns for the per-page scale pair
+    assert got == 3 * 2 * page + 2 * 2 * qo + 2 * (5 + 2) * 4
+    # int8 pool: pages priced at 1 byte/elt, q/out stay fp, the table
+    # gains 2 G scale rows for the per-page scale pairs
     q8 = decode_hbm_bytes(bt, np.asarray([7, 7]), ps, H, Dh,
                           quantized=True)
-    assert q8 == 3 * 2 * (ps * H * Dh) + 2 * 2 * qo + 2 * 2 * 9 * 4
+    assert q8 == 3 * 2 * (ps * H * Dh) + 2 * 2 * qo + 2 * (5 + 3 * 2) * 4
+
+
+def test_decode_hbm_bytes_follows_blocks_of_pages():
+    """At 16-row pages a block is 16 pages: 40 pages a slot make 3 block
+    columns a slot, each 5 + 16 words; a slot 260 positions deep has 17
+    live pages in 2 live blocks, the second padded with the trash page (its
+    third column is no step); a slot at the end of its reservation names
+    all 40 of its pages and, 40 being no multiple of 16, the trash page
+    for the rest of its third block."""
+    ps, H, Dh, n = 16, 2, 8, 40
+    bt = 1 + np.arange(2 * n).reshape(2, n)
+    page = ps * H * Dh * 4
+    got = decode_hbm_bytes(bt, np.asarray([259, 639]), ps, H, Dh)
+    assert got == (17 + 40 + 1) * 2 * page + 2 * 2 * H * Dh * 4 + (
+        2 * 3 * (5 + 16) * 4)
+    full = decode_hbm_bytes(bt, np.asarray([639, 639]), ps, H, Dh)
+    assert full == (80 + 1) * 2 * page + 2 * 2 * H * Dh * 4 + 2 * 3 * 21 * 4
 
 
 # ------------------------------------------- DecodeServer token identity
