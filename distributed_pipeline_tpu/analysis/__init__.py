@@ -2,7 +2,7 @@
 
 Generic linters cannot see the bug classes that actually burn TPU runs
 here — the ones past rounds fixed by hand (CHANGES.md r6): PRNG key
-reuse (artifacts/moe_gap.py), a hidden step-2 recompile from unpinned
+reuse, a hidden step-2 recompile from unpinned
 ``out_shardings``, donating Orbax-restored buffers into a
 cache-deserialized executable. This subpackage is the correctness-
 tooling layer production JAX stacks carry for exactly these hazards:

@@ -35,7 +35,7 @@ GL010 unattributed-flops   a FLOPs/MFU figure computed from raw numeric
                            figure in the repo shares one numerator with
                            the roofline cost ledger; derive through
                            transformer_train_flops_per_token /
-                           active_param_count / roofline_attribution
+                           roofline_attribution
 GL011 cross-module-key-reuse  the same PRNG key flowing into two
                            (transitively proven) key-consuming callees,
                            consumed after a split across a call
@@ -47,7 +47,7 @@ GL012 stray-pallas-call    pl.pallas_call outside ops/ — kernels live
                            behind the ops/ dispatch seams (auto/forced
                            impl knobs, interpret fallback, layout
                            contracts); a call site elsewhere bypasses
-                           dispatch, fallback AND the bench accounting
+                           dispatch, fallback AND the byte accounting
 
 Interprocedural halves (callgraph.py, ISSUE 15): GL002, GL003, GL005
 and GL007 each carry a ``check_graph`` in addition to their per-module
@@ -137,8 +137,7 @@ _is_key_param = callgraph.is_key_param
 class KeyReuse(Rule):
     """GL001: the same PRNG key consumed by two samplers, consumed after
     ``jax.random.split``, or consumed inside a loop without per-iteration
-    rebinding — all three produce silently correlated randomness (the
-    artifacts/moe_gap.py class of bug fixed by hand in r6)."""
+    rebinding — all three produce silently correlated randomness."""
 
     code = "GL001-key-reuse"
     description = ("PRNG key reused: each key must reach exactly one "
@@ -1081,12 +1080,11 @@ class UnattributedFlops(Rule):
     result binds to a flops/mfu/fpt-named variable, keyword, or dict
     key — outside the two sanctioned owners. Scattered ``6*N + 12*l*h*s``
     re-derivations are how the repo's MFU numbers drift apart: each
-    inline copy silently disagrees with the cost ledger's (the bench's
-    MoE active-params adjustment lived exactly this way until it was
-    dogfooded into ``perf.active_param_count``). A pure call into the
-    owners (``transformer_train_flops_per_token(...)``, ``mfu(...)``,
-    ``roofline_attribution(...)``) — or any expression without literal
-    arithmetic — stays legal, so the rule gates without noise."""
+    inline copy silently disagrees with the cost ledger's. A pure call
+    into the owners (``transformer_train_flops_per_token(...)``,
+    ``mfu(...)``, ``roofline_attribution(...)``) — or any expression
+    without literal arithmetic — stays legal, so the rule gates without
+    noise."""
 
     code = "GL010-unattributed-flops"
     description = ("FLOPs/MFU figure computed from raw numeric constants "
@@ -1125,7 +1123,7 @@ class UnattributedFlops(Rule):
                 f"{name!r} computed from raw numeric constants — FLOPs/"
                 f"MFU arithmetic belongs to utils/perf.py (analytic "
                 f"numerators: transformer_train_flops_per_token, "
-                f"active_param_count, mfu) or obs/ledger.py (roofline "
+                f"mfu) or obs/ledger.py (roofline "
                 f"attribution), so every figure shares one numerator "
                 f"with the cost ledger")
 
@@ -1188,15 +1186,14 @@ class StrayPallasCall(Rule):
     """GL012: ``pl.pallas_call`` outside ``ops/`` — kernels live behind
     the ops/ dispatch seams (``resolve_decode_impl`` auto/forced knobs,
     ``interpret=`` CPU fallback, the (8, 128) layout contracts and the
-    schedule-derived HBM byte accounting the bench legs report). A call
-    site anywhere else gets none of that: it hard-fails off-TPU, dodges
-    the impl knob the configs thread through the stack, and its bytes
-    never reach the ledger, so the kernel's roofline win is invisible
-    to regress.py."""
+    schedule-derived HBM byte accounting). A call site anywhere else
+    gets none of that: it hard-fails off-TPU, dodges the impl knob the
+    configs thread through the stack, and its bytes are counted by no
+    roofline."""
 
     code = "GL012-stray-pallas-call"
     description = ("pl.pallas_call outside ops/ bypasses the dispatch "
-                   "seam, interpret fallback and bench byte accounting")
+                   "seam, interpret fallback and byte accounting")
 
     def check(self, module: Module) -> Iterator[Finding]:
         path = module.path.replace("\\", "/")

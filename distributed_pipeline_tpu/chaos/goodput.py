@@ -19,7 +19,7 @@ Three artifact kinds live in the run dir, written by different parties:
     wall ≈ useful + startup + restore + compile + save + data_stall
            + recompute + hang + lost + downtime
 
-with ``goodput = useful / wall`` — the bench's acceptance metric.
+with ``goodput = useful / wall``.
 ``hang`` is LAUNCHER-attributed (the attempt record's ``hang_s``): the
 window between an attempt's last observed progress and the hang
 watchdog killing it — time a silently wedged worker burned while still

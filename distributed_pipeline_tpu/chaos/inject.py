@@ -95,7 +95,7 @@ class ChaosInjector:
     """Fires plan faults from the trainer's hook points.
 
     ``run_dir`` anchors the once-per-run markers; when the trainer passes
-    no checkpoint dir (bench loops), markers degrade to in-process memory
+    no checkpoint dir, markers degrade to in-process memory
     — enough for single-attempt use, while multi-attempt kill/restart
     scenarios always have a run dir by construction (that is where the
     checkpoint being resumed lives)."""

@@ -73,7 +73,7 @@ class TuneSettings(S):
     final_window_steps: int = _(4, "steps per ABBA window in the final")
     screen_only: bool = _(False, "stop after the screen rung (no halving "
                                  "or finals): the cheap mode --auto_tune "
-                                 "and the bench leg run")
+                                 "runs")
     child_timeout_s: float = _(150.0, "hard cap per measurement child; a "
                                       "wedged candidate folds to a pruned "
                                       "row at this deadline")

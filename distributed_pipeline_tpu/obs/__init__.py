@@ -24,10 +24,6 @@ backend import, the same discipline as :mod:`..chaos`):
   (``mfu + gap_host + gap_comms + gap_memory_bound + gap_residual == 1``
   exactly), snapshotted to ``<run_dir>/perf_ledger.json`` behind
   ``--cost_ledger`` and rendered by ``run/perf_report.py``.
-* :mod:`.regress` — the bench-history regression sentinel: newest
-  recorded bench run vs a trailing baseline window, per-leg verdicts on
-  tokens/s / MFU / peak bytes / steady recompiles, nonzero exit on a
-  past-band regression (CI-gateable).
 * ``run/status.py`` — the live, read-only fleet status CLI built on the
   same readers.
 

@@ -282,7 +282,7 @@ def roofline_attribution(*, tokens_per_s: float, flops_per_token: float,
 
 
 def attribution_columns(row: Dict[str, Any]) -> Dict[str, Any]:
-    """The bench-row subset of a ledger program row: ``mfu`` (unrounded —
+    """The summary subset of a ledger program row: ``mfu`` (unrounded —
     the gap-sum identity must hold to 1e-6, which survives no 4-decimal
     rounding), the four gap terms, the collective payload, and the
     padding waste."""

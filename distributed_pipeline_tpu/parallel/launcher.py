@@ -273,7 +273,7 @@ def _worker_env(i: int, nprocs: int, coord: str, devices_per_proc: int,
 # "2,1" means attempt 0 gets 2, every later attempt gets 1. On a real
 # fleet, surviving capacity comes from the scheduler/instance metadata;
 # on this box's single-host dev rings the env IS the capacity probe, so
-# shrink/grow restarts are reproducible in tests and bench legs.
+# shrink/grow restarts are reproducible in tests.
 FORCE_NPROCS_ENV = "DPT_FORCE_NPROCS"
 FORCE_DEVICES_ENV = "DPT_FORCE_DEVICES_PER_PROC"
 

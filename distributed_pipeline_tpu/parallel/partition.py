@@ -19,7 +19,7 @@ axis. Weight-update state is only ever consumed elementwise inside the
 train step, so XLA's SPMD partitioner gathers it on use (all-gather of the
 updates, not of the 2x-Adam + EMA state), and per-replica weight-update
 memory drops by the data-parallel factor — the refactor that unlocks
-larger-model bench legs (utils/trainer.py wires it behind
+larger models (utils/trainer.py wires it behind
 ``--shard_optimizer``).
 
 Three invariants the tests pin (tests/test_partition.py):

@@ -266,7 +266,7 @@ def _serve_single(settings: ServeSettings) -> dict:
         "decode_tokens": server.tokens_fetched,
         # replicated decode state: every chip runs the same step, so the
         # service rate IS the per-chip rate (dividing by device_count
-        # would understate it — same reasoning as bench.measure_decode)
+        # would understate it)
         "decode_tokens_per_s_per_chip": round(
             server.tokens_fetched / max(wall_s, 1e-9), 1),
         "time_to_first_token_s": round(ttft["mean"], 4),
@@ -964,7 +964,7 @@ def _fleet_main(settings: ServeSettings) -> dict:
 
     # Replica backend: 'auto' = the parent's own platform selection
     # (JAX_PLATFORMS in this jax-free parent's env — "cpu" under every
-    # test/dev/bench ring, unset on a real TPU host so replicas get the
+    # test/dev ring, unset on a real TPU host so replicas get the
     # chips). The old launcher behavior pinned cpu UNCONDITIONALLY,
     # which made TPU fleet replicas impossible (r13 NOTE).
     platform = settings.replica_platform
@@ -1118,8 +1118,8 @@ def _fleet_main(settings: ServeSettings) -> dict:
 
     # fleet-wide decode roofline (ISSUE 18 satellite): average the
     # replicas' serve_decode attribution rows (each worker's --cost_ledger
-    # snapshot in its replica dir) so the fleet summary — and the bench
-    # rows built from it — carry mfu_gap_memory_bound next to goodput
+    # snapshot in its replica dir) so the fleet summary carries
+    # mfu_gap_memory_bound next to goodput
     decode_roofline = None
     if settings.cost_ledger:
         from ..obs import ledger as ledger_lib

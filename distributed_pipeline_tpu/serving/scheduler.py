@@ -315,7 +315,7 @@ class DecodeServer:
         return self.ttft.summary()["mean"]
 
     def reset_stats(self) -> None:
-        """Zero the serving gauges (bench: warmup vs timed window)."""
+        """Zero the serving gauges (a warmup, then the timed window)."""
         self.ttft = EventStats()
         self.decode_steps = 0
         self.prefill_steps = 0

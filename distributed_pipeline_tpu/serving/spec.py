@@ -11,7 +11,7 @@ here; two arms ship:
   current suffix in ``prompt + generated``. Exact-match repetition —
   retrieval prompts, code, template-y text, and greedy loops — verifies at
   high accept rates; fresh text just verifies 1 token/round like the
-  non-speculative path. This is the CPU-friendly draft: the bench leg's
+  non-speculative path. This is the CPU-friendly draft: its
   speedup is pure dispatch amortization, no second model.
 * ``model`` — a truncated-layer draft: the FIRST ``draft_layers`` blocks of
   the target plus its embeddings/ln_f/tied head, run as a second (much

@@ -20,7 +20,7 @@ module replaces the knob with seeded, deterministic arrival processes:
 DETERMINISM is the load-bearing property: the same ``(process, rate,
 seed, ...)`` tuple produces the IDENTICAL schedule and prompts in any
 process on any host (``numpy.random.default_rng`` / PCG64 is a fixed
-algorithm), so a bench leg's arrival pattern is reproducible and a
+algorithm), so a run's arrival pattern is reproducible and a
 replayed run queues exactly the same way. Tests pin this cross-process.
 
 Import-light (numpy only, no jax): the fleet supervisor process drives

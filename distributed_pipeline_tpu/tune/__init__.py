@@ -1,7 +1,7 @@
 """Profile-guided sharding auto-tuner (ISSUE 13, ROADMAP item 4).
 
-Layout became DATA in r11 (``--partition_rules`` regex tables), the bench
-harness made deltas measurable on a noisy box (paired-interleaved ABBA),
+Layout became DATA in r11 (``--partition_rules`` regex tables), paired-
+interleaved ABBA windows made deltas measurable on a noisy box,
 and the footprint gauges made memory a number. This package composes them
 into a CONTROL LOOP: enumerate candidate rule tables x mesh-axis splits
 for a model/shape (:mod:`.candidates`), statically reject anything that
@@ -15,8 +15,8 @@ for resume (:mod:`.search`), and emit the winner as a
 playbook, arxiv 2204.06514).
 
 Lazy exports (PEP 562): the fleet/launcher style — importing the package
-costs nothing until a symbol is touched, so import-light callers (bench
-parent, tests reading journals) never pay the jax import hiding behind
+costs nothing until a symbol is touched, so import-light callers
+(tests reading journals) never pay the jax import hiding behind
 :mod:`.candidates`.
 """
 

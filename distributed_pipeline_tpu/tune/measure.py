@@ -2,8 +2,7 @@
 the JSON result contract, and OOM/timeout error-row folding.
 
 Every number the tuner ranks on comes from a CHILD process, for three
-reasons the ZeRO-1 A/B leg already proved out (run/zero1_ab.py, now a
-thin client of this module):
+reasons:
 
 * the mesh under test may need a DIFFERENT device count than the parent
   (``--xla_force_host_platform_device_count`` is consumed at backend
@@ -17,7 +16,7 @@ thin client of this module):
   resumed/repeated trials pay a lookup instead of a compile).
 
 The child prints ONE machine-readable JSON row on stdout (the parent
-parses the last non-empty line — the bench contract); everything else
+parses the last non-empty line); everything else
 goes to stderr. Two modes:
 
 * single arm (``--spec``): the successive-halving screen — warmup then a
@@ -191,7 +190,7 @@ def timed_window(loop, steps: int) -> float:
 
 def arm_row(loop, n_steps: int, total_s: float) -> Dict[str, Any]:
     """One arm's result fields: rate + the footprint gauges the tuner
-    ranks and reports on (the bench train-row columns)."""
+    ranks and reports on."""
     import jax
 
     fp = loop.footprint()

@@ -20,9 +20,9 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 
 __all__ = ["device_peak_flops", "transformer_train_flops_per_token",
-           "transformer_decode_flops_per_token", "active_param_count",
+           "transformer_decode_flops_per_token",
            "StepTimer", "mfu", "enable_persistent_compilation_cache",
-           "timed_lower_compile", "AOTStep", "RecompileMonitor",
+           "AOTStep", "RecompileMonitor",
            "device_summary", "tpu_kernel_census",
            "SanitizeReport", "SANITIZE_REPORT_NAME",
            "StallBreakdown", "EventStats", "GoodputTracker",
@@ -74,37 +74,6 @@ def mfu(tokens_per_sec: float, flops_per_token: float,
         n_devices: Optional[int] = None) -> float:
     n = n_devices if n_devices is not None else jax.device_count()
     return tokens_per_sec * flops_per_token / (device_peak_flops() * n)
-
-
-def active_param_count(params: Any, n_params: int, *, moe_experts: int = 0,
-                       moe_top_k: int = 2) -> int:
-    """Params ACTIVE per token: a top-k routed MoE block only runs top_k
-    of its ``moe_experts`` expert MLPs, so counting every expert's
-    weights would overstate the model FLOPs. Inactive mass is derived
-    from the actual expert weight shapes (leading dim == moe_experts
-    under a "moe" module — or dim 1 under a scan-group stack) so it
-    tracks models/moe.py by construction. Dense models (or top_k >=
-    experts) return ``n_params`` unchanged. One owner for the FLOPs-side
-    param accounting (graftlint GL010): MFU numerators derive from THIS
-    count, here or in obs/ledger.py."""
-    if moe_experts <= moe_top_k:
-        return n_params
-    import numpy as np
-    from jax.tree_util import tree_flatten_with_path
-
-    leaves, _ = tree_flatten_with_path(params)
-    # expert dim position differs by layout: named blocks stack experts
-    # on dim 0 ([experts, ...]); MoEScanBlocks prepends a scan-group dim
-    # ([groups, experts, ...]) — accept either.
-    expert_params = sum(
-        int(np.prod(leaf.shape))
-        for path, leaf in leaves
-        if any("moe" in str(getattr(k, "key", k)) for k in path)
-        and leaf.ndim >= 2
-        and (leaf.shape[0] == moe_experts
-             or (leaf.ndim >= 3 and leaf.shape[1] == moe_experts)))
-    return n_params - round(expert_params
-                            * (moe_experts - moe_top_k) / moe_experts)
 
 
 def tree_bytes(tree: Any) -> int:
@@ -222,28 +191,18 @@ def tpu_kernel_census(compiled: Any, names: Tuple[str, ...]) -> Dict[str, int]:
     return counts
 
 
-def timed_lower_compile(jitted: Any, *args: Any) -> Tuple[Any, float]:
-    """Explicit AOT ``lower()``/``compile()`` of a jitted callable against
-    concrete example args. Returns ``(compiled_executable, seconds)``.
-
-    Dispatch-time compilation hides the (often dominant) compile cost inside
-    the first call, where no one can measure it; lowering ahead of time puts
-    a number on it — ``compile_time_s`` — and a persistent-cache hit shows
-    up as that number collapsing."""
-    t0 = time.perf_counter()
-    compiled = jitted.lower(*args).compile()
-    return compiled, time.perf_counter() - t0
-
-
 class AOTStep:
     """Lazily AOT-compiled wrapper around a jitted step function.
 
     First call (or any call whose arg shapes/dtypes changed) runs an
-    explicit ``lower()/compile()`` through :func:`timed_lower_compile` and
-    reports the duration to ``on_compile(name, seconds)``; subsequent calls
-    dispatch straight to the compiled executable. Shape changes fall back to
-    a fresh compile rather than erroring, so callers keep jit's flexibility
-    while gaining the timing split.
+    explicit, timed ``lower()/compile()`` and reports the duration to
+    ``on_compile(name, seconds)``; subsequent calls dispatch straight to
+    the compiled executable. Dispatch-time compilation hides the (often
+    dominant) compile cost inside the first call, where no one can measure
+    it; lowering ahead of time puts a number on it — ``compile_time_s`` —
+    and a persistent-cache hit shows up as that number collapsing. Shape
+    changes fall back to a fresh compile rather than erroring, so callers
+    keep jit's flexibility while gaining the timing split.
 
     ``pin_signature=True`` skips the per-call signature walk once compiled:
     for a large pytree argument (a params tree) the tree_map costs real
@@ -282,7 +241,9 @@ class AOTStep:
             return self._compiled(*args)
         sig = self._signature(args)
         if self._compiled is None or sig != self._sig:
-            self._compiled, dt = timed_lower_compile(self._jitted, *args)
+            t0 = time.perf_counter()
+            self._compiled = self._jitted.lower(*args).compile()
+            dt = time.perf_counter() - t0
             self._sig = sig
             self.compile_time_s += dt
             if self._on_compile is not None:

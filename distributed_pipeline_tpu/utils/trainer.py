@@ -308,7 +308,7 @@ class TrainLoop:
         except BaseException:
             # construction can die mid-build (param init / AOT compile is
             # where an HBM OOM fires) and callers that retry with a smaller
-            # batch (bench.py) never get a handle to stop_sanitizer() —
+            # batch never get a handle to stop_sanitizer() —
             # detach the process-global hooks here so a failed attempt
             # doesn't leak the 'jax' logging handler or leave
             # jax_log_compiles stuck on.
@@ -724,8 +724,8 @@ class TrainLoop:
         # Explicit AOT lower()/compile() instead of dispatch-time jit: the
         # first run_step/forward_only triggers a TIMED compile, surfaced as
         # the compile_time_s / time_to_first_step_s metrics (perf.AOTStep).
-        # With the persistent compilation cache enabled (run/train.py,
-        # bench.py) a warm restart's compile_time_s collapses to the cache
+        # With the persistent compilation cache enabled (run/train.py)
+        # a warm restart's compile_time_s collapses to the cache
         # lookup, and the split makes that visible instead of folding it
         # into the first step's wall time.
         #
@@ -779,7 +779,7 @@ class TrainLoop:
         """Detach the sanitizer's process-global hooks (the 'jax' logging
         handler and the jax_log_compiles flag) and return the final
         recompile count. Idempotent; a no-op when sanitize was off. Call
-        when the loop is done in a process that keeps running (bench legs,
+        when the loop is done in a process that keeps running (the
         tests) — nothing re-arms it. Also the moment the evidence sidecar
         is finalized: steady-state recompiles become violations, and the
         report (possibly empty — that's the 'ran clean' evidence) lands
@@ -909,7 +909,7 @@ class TrainLoop:
                          round(self.time_to_first_step_s, 3))
             # Steady-state recompile baseline: compiles after this point
             # are silent retraces — the gauge that must stay frozen on a
-            # warm-cache resume (the chaos bench acceptance).
+            # warm-cache resume.
             self._recompiles_at_first_step = self._recompiles.count
             # Ledger rate anchor: tokens/s and per-step stall means
             # measured from here on cover only steady steps (the first
@@ -1121,7 +1121,7 @@ class TrainLoop:
         the per-replica (one device's addressable shard) bytes — the
         number ZeRO-1 exists to shrink — and the backend's peak live
         allocation (0 where the backend doesn't report memory stats, e.g.
-        CPU). Logged every log window and carried on bench train rows."""
+        CPU). Logged every log window."""
         s = self.state
         return {
             "params_bytes": tree_bytes(s.params),
@@ -1231,7 +1231,7 @@ class TrainLoop:
             logger.logkv(gauge, round(mean_s, 6))
         # Cumulative goodput ratio (useful-step share of the attempt's
         # wall so far) rides the same cadence: a run bleeding time to
-        # restarts/stalls shows it here long before the bench does.
+        # restarts/stalls shows it here, window by window.
         logger.logkv("goodput", round(self.goodput_summary()["goodput"], 4))
         # Memory footprint: params/opt-state bytes (per-replica is the
         # ZeRO-1 acceptance gauge) + backend peak live bytes.

@@ -1,0 +1,201 @@
+"""The benchmark under tier-1's guard.
+
+``benchmark/`` measures the program from outside (``BENCHMARK.json``,
+``benchmark/run.py``) and imports nothing of it, so nothing in ``tests/``
+ran it: its own tests, two of its three cells' rehearsals, and the names by
+which its readers find the program's work in a trace. A renamed jitted
+function or ``pallas_call`` moves no end-to-end metric and passes every other
+test, and leaves a per-layer metric ``null`` from then on. Three guards:
+
+* the benchmark's own tests, one child ``pytest`` a file, as a user runs
+  them (``benchmark/tests/conftest.py`` sets its own four CPU devices);
+* every cell of ``BENCHMARK.json`` rehearsed on the CPU through ``run.py``;
+* each kernel and program name a reader asks the trace for, against the
+  name the package gives: the benchmark's side is read off the benchmark
+  (its constants, or what a reader asks a recording trace for), the
+  package's side off its constants and off executables it compiled here.
+"""
+
+import glob
+import importlib
+import json
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+OWN_TESTS = sorted(
+    os.path.relpath(p, ROOT)
+    for p in glob.glob(os.path.join(BENCH, "tests", "test_*.py")))
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+
+
+def child_env():
+    """A user's shell on the CPU: tier-1's eight virtual devices are not
+    handed down, so the benchmark's conftest sets the four it documents."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)
+    return env
+
+
+# ------------------------------------------------- the benchmark's own tests
+
+@pytest.mark.parametrize("path", OWN_TESTS, ids=os.path.basename)
+def test_benchmark_own_tests_pass(path):
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", path, "-q", "-p", "no:cacheprovider"],
+        capture_output=True, text=True, timeout=600, cwd=ROOT,
+        env=child_env())
+    assert out.returncode == 0, (out.stdout + out.stderr)[-2000:]
+
+
+# ------------------------------------------------------- every cell, walked
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_rehearses_on_the_cpu(cell):
+    """``run.py --rehearse`` walks the cell's driver end to end at the
+    files' tiny sizes: build, warm, window, drain, reference."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
+         "--seed", "2147483777", "--seconds", "2", "--rehearse"],
+        capture_output=True, text=True, timeout=600, cwd=ROOT,
+        env=child_env())
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] and line["rehearsal"] and line["failed"] == 0
+    assert line["metrics"] == {}      # a CPU run reports no device metric
+    assert line["checks"]
+    if "served_logit_gap" in line["checks"]:
+        assert line["checks"]["served_logit_gap"]["tokens"] > 0
+
+
+# ---------------------------------------- the names the readers look for
+
+@pytest.fixture(scope="module")
+def bench_run():
+    """``benchmark/run.py`` as a module, with ``benchmark/`` importable for
+    the readers it loads by path."""
+    sys.path.insert(0, BENCH)
+    try:
+        import run
+        yield run
+    finally:
+        sys.path.remove(BENCH)
+
+
+def constant(name):
+    """The name a reader's module keeps as a constant."""
+    return lambda reader: [reader.__globals__[name]]
+
+
+def asked(reader):
+    """What a reader with its literals inside asks the trace for, off a
+    trace that records the questions and holds nothing."""
+    names = []
+
+    class Trace:
+        @staticmethod
+        def op_seconds(name):
+            names.append(name)
+            return 0.0, 0
+        module_seconds = op_seconds
+    assert reader({
+        "trace": Trace, "peaks": {"flops_bf16": 1.0, "hbm_bytes_s": 1.0},
+        "traffic": {"global_batch": 2, "seq_len": 16},
+        "counters": {"traced_steps": 2, "chips": 1, "dims": {
+            "heads": 2, "head_dim": 16, "layers": 2, "width": 32}}}) is None
+    return names
+
+
+def kernel_case(metric, benchmark_side, module, attr):
+    return pytest.param(
+        metric, benchmark_side, module, attr,
+        id=re.sub(r"_(hbm_roofline|roofline|time_share)$", "", metric))
+
+
+@pytest.mark.parametrize("metric, benchmark_side, module, attr", [
+    kernel_case("flash_attention_fwd_roofline", asked,
+                "flash_attention", "FWD_KERNEL_NAME"),
+    kernel_case("flash_attention_bwd_roofline", asked,
+                "flash_attention", "BWD_KERNEL_NAME"),
+    kernel_case("fused_adamw_ema_time_share", asked,
+                "fused_update", "KERNEL_NAME"),
+    kernel_case("flash_decode_hbm_roofline", constant("KERNEL"),
+                "flash_decode", "KERNEL_NAME"),
+    kernel_case("mla_block_attend_roofline", constant("ATTEND_KERNEL"),
+                "mla_attention", "KERNEL_NAME"),
+    kernel_case("lightning_index_scores_roofline", constant("INDEX_KERNEL"),
+                "mla_attention", "INDEX_KERNEL_NAME"),
+])
+def test_kernel_names_the_readers_look_for(bench_run, metric, benchmark_side,
+                                           module, attr):
+    """``tests/test_chip_compile.py`` holds the compiled HLO to the
+    package's constant; this holds the constant to what the reader asks."""
+    reader, _ = bench_run.load_reader(metric)
+    ops = importlib.import_module(f"distributed_pipeline_tpu.ops.{module}")
+    assert getattr(ops, attr) in benchmark_side(reader)
+
+
+def compiled_name(step):
+    """The XLA module's name of an ``AOTStep`` that has run: what the
+    profiler names the program's device events by."""
+    return re.match(r"HloModule (\w+)", step.compiled.as_text()).group(1)
+
+
+@pytest.fixture(scope="module")
+def train_programs(tmp_path_factory):
+    from tests.test_trainer import make_loop
+    loop = make_loop(tmp_path_factory.mktemp("guard_loop"))
+    loop.run_step(next(loop.data))
+    return {"train": compiled_name(loop._train_step)}
+
+
+def served_programs(wl, params):
+    from distributed_pipeline_tpu.serving import DecodeServer
+    server = DecodeServer(wl, params, decode_slots=2, page_size=4,
+                          max_prompt_len=8, max_len=16)
+    server.submit(np.arange(4, 9, dtype=np.int32), max_new_tokens=3)
+    server.drain()
+    return {k: compiled_name(v)
+            for k, v in server.engine.executables().items()}
+
+
+@pytest.fixture(scope="module")
+def gpt2_programs():
+    from tests.test_serving import tiny_workload
+    wl = tiny_workload()
+    return served_programs(wl, wl.init_params(jax.random.PRNGKey(3)))
+
+
+@pytest.fixture(scope="module")
+def chunked_programs():
+    from tests.test_deepseek_v32 import TINY, build
+    wl, _, tree = build(TINY)
+    return served_programs(wl, tree)
+
+
+@pytest.mark.parametrize("metric, benchmark_side, programs, phase", [
+    pytest.param("fused_adamw_ema_time_share", asked, "train_programs",
+                 "train", id="jit_train_step"),
+    pytest.param("decode_hbm_roofline", constant("DECODE_PROGRAM"),
+                 "gpt2_programs", "decode", id="jit_decode_fn"),
+    pytest.param("decode_hbm_roofline.sparse_latent",
+                 constant("DECODE_PROGRAM"), "chunked_programs", "decode",
+                 id="jit_decode_fn.sparse_latent"),
+    pytest.param("prefill_mfu.serve", constant("PREFILL_PROGRAM"),
+                 "chunked_programs", "prefill", id="jit_prefill_chunk_fn"),
+])
+def test_program_names_the_readers_look_for(request, bench_run, metric,
+                                            benchmark_side, programs, phase):
+    """Each program a reader sums the device time of, compiled here at the
+    tiny widths of the trainer's and the servers' own tests. (No reader
+    asks for the one-shot prefill of the GPT-2 family, so none is held.)"""
+    reader, _ = bench_run.load_reader(metric)
+    assert request.getfixturevalue(programs)[phase] in benchmark_side(reader)

@@ -12,6 +12,9 @@ from distributed_pipeline_tpu.config import (
     TrainSettings,
     item,
 )
+from distributed_pipeline_tpu.utils.perf import (
+    enable_persistent_compilation_cache,
+)
 
 
 class Inner(S):
@@ -152,3 +155,17 @@ def test_abbreviated_flags_rejected():
         parser.parse_args(["--log_int", "50"])
     ns = parser.parse_args(["--log_interval", "50"])  # exact name still works
     assert ns.log_interval == 50
+
+
+def test_compilation_cache_flag_roundtrips_through_settings():
+    s = TrainSettings.from_argv(["--compilation_cache_dir", "off"])
+    assert s.compilation_cache_dir == "off"
+    assert TrainSettings().compilation_cache_dir == "auto"
+    # and through the JSON path (the --config_json workflow)
+    s2 = TrainSettings.model_validate(json.loads(s.to_json()))
+    assert s2.compilation_cache_dir == "off"
+    # a directory of one's own is JAX_COMPILATION_CACHE_DIR's to name
+    with pytest.raises(SystemExit):
+        TrainSettings.from_argv(["--compilation_cache_dir", "/tmp/cc"])
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        enable_persistent_compilation_cache("/tmp/cc")

@@ -257,7 +257,7 @@ def test_skip_batches_nonloop_exhausts():
 
 
 @pytest.mark.slow  # heaviest tier: three TrainLoop builds (VERDICT r5 weak
-# #3); the fast resume+warm-cache path is covered by test_bench_budget's
+# #3); the fast resume+warm-cache path is covered by test_trainer's
 # test_aot_compile_metrics_and_cache_hit_path every run
 def test_bit_exact_resume(tmp_path):
     """The gold assertion for elastic recovery: interrupt at step 3, resume,
@@ -309,3 +309,32 @@ def test_bit_exact_resume(tmp_path):
     for x, y in zip(jax.tree_util.tree_leaves(a.state.ema["0.9"]),
                     jax.tree_util.tree_leaves(b2.state.ema["0.9"])):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_get_batch_length_hook_feeds_samples(tmp_path):
+    """The reference's get_batch_length user hook: overriding it changes the
+    cumulative ``samples`` gauge without touching the loop."""
+    from distributed_pipeline_tpu.models import create_model_from_config
+    from distributed_pipeline_tpu.parallel import make_mesh
+    from distributed_pipeline_tpu.utils import logger
+    from distributed_pipeline_tpu.utils.trainer import TrainLoop
+
+    class HalfCounted(TrainLoop):
+        def get_batch_length(self, batch):
+            return super().get_batch_length(batch) // 2
+
+    wl = create_model_from_config(
+        model_family="gpt2", vocab_size=64, seq_len=16, hidden_size=32,
+        num_layers=2, num_heads=2, dtype="float32")
+    data = load_data_from_args("train", batch_size=8, dataset="synthetic-lm",
+                               seq_len=16, vocab_size=64, seed=0)
+    loop = HalfCounted(model=wl, data=data, batch_size=8, lr=1e-3,
+                       learning_steps=100, log_interval=10 ** 9,
+                       save_interval=10 ** 9, mesh=make_mesh(dp=8),
+                       checkpoint_dir=str(tmp_path), seed=5)
+    with logger.scoped_configure(format_strs=[]):
+        loop.run_step(next(loop.data))
+        loop.run_step(next(loop.data))
+        kvs = logger.getkvs()
+    assert kvs["samples"] == 2 * (8 // 2)  # hook value, not step*batch
+    assert loop.get_batch_length(next(loop.data)) == 4

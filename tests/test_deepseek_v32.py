@@ -7,7 +7,6 @@ copies of the reference, and the family on the serving entry points."""
 
 import json
 import os
-import subprocess
 import sys
 
 import jax
@@ -385,7 +384,7 @@ def test_yarn_constants_of_the_source():
     assert abs(ref.softmax_scale(published) - cfg.softmax_scale) < 1e-9
 
 
-# --------------------------------------- the copies, the files, the cell
+# ------------------------------------------------ the copies, the files
 
 def test_the_two_reference_copies_give_the_same_logits(tiny):
     """benchmark/harness/ keeps its own copy (the benchmark imports nothing
@@ -423,22 +422,6 @@ def test_configuration_file_states_its_cut():
     assert cfg["published"]["num_hidden_layers"] == 61
     for key in ("assumed", "deployment"):
         assert cfg[key]
-
-
-def test_new_cell_rehearses_on_the_cpu():
-    """``run.py --rehearse`` walks the cell's driver end to end at the
-    files' tiny sizes: build, warm, window, drain, reference."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", "deepseek-v3.2-exp-ep16.serve.closed-long16",
-         "--seed", "2147483777", "--seconds", "2", "--rehearse"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] and line["rehearsal"] and line["failed"] == 0
-    assert line["metrics"] == {}
-    assert line["checks"]["served_logit_gap"]["tokens"] > 0
 
 
 def test_family_through_run_serve(tmp_path):

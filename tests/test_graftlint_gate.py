@@ -18,8 +18,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BASELINE = os.path.join(ROOT, "graftlint_baseline.json")
 GATED_PATHS = [
     os.path.join(ROOT, "distributed_pipeline_tpu"),
-    os.path.join(ROOT, "artifacts"),
-    os.path.join(ROOT, "bench.py"),
     os.path.join(ROOT, "__graft_entry__.py"),
     # the steady-state-throughput tests drive the trainer's outer loop
     # directly — exactly where GL007 (host-sync-in-loop) hazards breed

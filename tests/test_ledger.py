@@ -1,4 +1,4 @@
-"""Cost ledger + roofline attribution + regression sentinel (ISSUE 14).
+"""Cost ledger + roofline attribution (ISSUE 14).
 
 Pins the evidence layer the perf front reads from: cost_analysis/
 memory_analysis extraction off CPU-compiled programs, the HLO collective
@@ -6,8 +6,7 @@ tally against a hand-counted forced-host dp=2 program, the exact
 mfu-plus-gaps-equals-one identity, the ledger-vs-goodput seconds
 identity (the ledger reuses the trainer's OWN stall sums — same object,
 exact equality), padding-waste arithmetic on both the train and serve
-sides, perf_report CLI end-to-end, the obs/regress verdicts over
-synthetic (torn-tail-bearing) histories, graftlint GL010, and the
+sides, perf_report CLI end-to-end, graftlint GL010, and the
 status/export ledger surfaces.
 """
 
@@ -20,7 +19,6 @@ import numpy as np
 import pytest
 
 from distributed_pipeline_tpu.obs import ledger as ledger_lib
-from distributed_pipeline_tpu.obs import regress as regress_lib
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -333,101 +331,6 @@ def test_serving_ledger_rows_and_padding_hand_count():
     assert pre["padding_waste_frac"] == pytest.approx(1 - 15 / 32)
     # decode occupancy waste: dispatches with one empty slot accrue it
     assert 0 <= dec["padding_waste_frac"] < 1
-
-
-# ----------------------------------------------------- regression sentinel
-
-def _hist_rows(run_id, tps, mfu=0.5, peak=100, rec=0,
-               name="diffuseq-base-seq128"):
-    return {"name": name, "tokens_per_sec_per_chip": tps, "mfu": mfu,
-            "peak_live_bytes": peak, "recompile_count": rec,
-            "run_id": run_id, "t": 1.0}
-
-
-def _write_history(path, rows, torn_tail=False):
-    with open(path, "w") as f:
-        for r in rows:
-            f.write(json.dumps(r) + "\n")
-        if torn_tail:
-            f.write('{"name": "torn half li')
-
-
-def test_regress_verdicts_flat_improved_regressed(tmp_path):
-    hist = str(tmp_path / "h.jsonl")
-    rows = [_hist_rows("r1", 1000), _hist_rows("r2", 1010),
-            # newest: tokens/s inside the band, mfu up 10%, serve leg
-            # regressed on recompiles
-            _hist_rows("r3", 1005, mfu=0.55)]
-    rows.insert(1, _hist_rows("r1", 500, rec=0,
-                              name="gpt2-serve-decode-b8"))
-    rows.insert(3, _hist_rows("r2", 505, rec=0,
-                              name="gpt2-serve-decode-b8"))
-    rows.append(_hist_rows("r3", 502, rec=2,
-                           name="gpt2-serve-decode-b8"))
-    _write_history(hist, rows, torn_tail=True)  # torn tail tolerated
-    from distributed_pipeline_tpu.chaos.goodput import read_journal
-    runs = regress_lib.group_runs(read_journal(hist))
-    assert [rid for rid, _ in runs] == ["r1", "r2", "r3"]
-    s = regress_lib.compare_runs(runs, band_pct=3.0, baseline_runs=3)
-    train = s["legs"]["diffuseq-base-seq128"]
-    assert train["metrics"]["tokens_per_s"]["verdict"] == "flat"
-    assert train["metrics"]["mfu"]["verdict"] == "improved"
-    assert train["verdict"] == "improved"
-    serve = s["legs"]["gpt2-serve-decode-b8"]
-    # steady recompiles are a 0-contract: ANY increase regresses
-    assert serve["metrics"]["recompile_count"]["verdict"] == "regressed"
-    assert serve["verdict"] == "regressed"
-    assert s["verdict"] == "regressed" and s["regressed"] == 1
-
-
-def test_regress_flags_a_leg_that_stopped_producing_data(tmp_path):
-    hist = str(tmp_path / "h.jsonl")
-    _write_history(hist, [
-        _hist_rows("r1", 1000), _hist_rows("r2", 1000),
-        {"name": "diffuseq-base-seq128", "error": "LegTimeout: boom",
-         "run_id": "r3", "t": 1.0}])
-    from distributed_pipeline_tpu.chaos.goodput import read_journal
-    s = regress_lib.compare_runs(regress_lib.group_runs(
-        read_journal(hist)))
-    leg = s["legs"]["diffuseq-base-seq128"]
-    assert leg["verdict"] == "regressed" and "errored" in leg["reason"]
-
-
-def test_regress_budget_skip_is_not_a_regression(tmp_path):
-    """A {"skipped": "budget"} marker in the newest run is the bench's
-    documented normal mode under BENCH_BUDGET_S — no comparison, never
-    a red gate (only an ERROR row regresses against baseline data)."""
-    hist = str(tmp_path / "h.jsonl")
-    _write_history(hist, [
-        _hist_rows("r1", 1000), _hist_rows("r2", 1000),
-        {"name": "diffuseq-base-seq128", "skipped": "budget",
-         "run_id": "r3", "t": 1.0}])
-    s, rc = regress_lib.main(["--history", hist, "--json"])
-    assert rc == 0 and s["verdict"] != "regressed"
-    assert "diffuseq-base-seq128" not in s["legs"]
-
-
-def test_regress_insufficient_history_is_honest(tmp_path):
-    hist = str(tmp_path / "h.jsonl")
-    _write_history(hist, [_hist_rows("r1", 1000)])
-    s, rc = regress_lib.main(["--history", hist, "--json"])
-    assert rc == 0 and s["verdict"] == "insufficient-history"
-
-
-def test_regress_main_exit_codes(tmp_path, capsys):
-    hist = str(tmp_path / "h.jsonl")
-    _write_history(hist, [_hist_rows("r1", 1000), _hist_rows("r2", 1000),
-                          _hist_rows("r3", 800)])
-    s, rc = regress_lib.main(["--history", hist])
-    assert rc == 1 and s["verdict"] == "regressed"
-    out = capsys.readouterr()
-    assert json.loads(out.out)["verdict"] == "regressed"  # machine line
-    assert "regressed" in out.err                         # human table
-    _write_history(hist, [_hist_rows("r1", 1000), _hist_rows("r2", 1000),
-                          _hist_rows("r3", 1001)])
-    _, rc = regress_lib.main(["--history", hist, "--json"])
-    assert rc == 0
-    capsys.readouterr()
 
 
 # ----------------------------------------------------------------- GL010

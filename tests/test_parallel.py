@@ -1,5 +1,6 @@
 """Distributed substrate tests on the fake 8-device CPU mesh (SURVEY.md §4)."""
 
+import os
 import subprocess
 import sys
 
@@ -14,6 +15,7 @@ from distributed_pipeline_tpu.parallel import (
     make_mesh,
     resolve_axis_sizes,
 )
+from distributed_pipeline_tpu.parallel.launcher import _worker_env
 
 
 def test_fake_devices_present():
@@ -327,3 +329,31 @@ def test_launcher_log_tee(tmp_path, capfd):
     assert "[worker 0]" in out and "[worker 1]" in out
     for i in range(2):
         assert "tee-marker-xyz" in (tmp_path / f"worker_{i}.log").read_text()
+
+
+def test_cache_dir_reaches_worker_env(tmp_path, monkeypatch):
+    """No hand-down: a worker inherits the variable when the caller set
+    it, and is given none when not (it then resolves the fixed path)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    env = _worker_env(1, 2, "127.0.0.1:9999", 2, run_timestamp="20260803")
+    assert env["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
+    assert env["JAX_PROCESS_INDEX"] == "1"
+    assert env["DPT_RUN_TIMESTAMP"] == "20260803"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    env = _worker_env(1, 2, "127.0.0.1:9999", 2)
+    assert "JAX_COMPILATION_CACHE_DIR" not in env
+
+
+def test_launcher_forwards_cache_env_to_ring(monkeypatch, tmp_path):
+    """The launcher neither takes nor passes a cache directory any more:
+    the ring gets no such argument and the environment is left as found."""
+    from distributed_pipeline_tpu.parallel import launcher
+
+    from tests._fake_ring import make_fake_ring
+
+    fake = make_fake_ring()
+    monkeypatch.setattr(launcher, "_run_worker_ring", fake)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert launcher.run_argv_as_distributed("mod", [], nprocs=2) == 0
+    assert "cache_dir" not in fake.calls[0]
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_pipeline_tpu.config.train import TrainSettings
 from distributed_pipeline_tpu.data import load_data_from_args
 from distributed_pipeline_tpu.models import create_model_from_config
 from distributed_pipeline_tpu.parallel import make_mesh
@@ -23,6 +24,10 @@ from distributed_pipeline_tpu.parallel.sharding import (
 )
 from distributed_pipeline_tpu.utils import checkpoint as ckpt
 from distributed_pipeline_tpu.utils import logger
+from distributed_pipeline_tpu.utils.perf import (
+    AOTStep,
+    enable_persistent_compilation_cache,
+)
 from distributed_pipeline_tpu.utils.trainer import TrainLoop, update_ema
 
 
@@ -522,6 +527,100 @@ def test_zero_intervals_disable_periodic_actions(tmp_path):
     assert loop.step == 3
     saved = sorted(d for d in os.listdir(tmp_path) if d.startswith("model_"))
     assert saved == ["model_000003"]  # exit save only, no periodic saves
+
+
+# ------------------------------- compilation cache + AOT compile metrics
+# (ahead of the sanitizer tests: after them this process traces a step about
+# twice as slowly, and the cache-hit test below compares two compile times)
+
+def test_enable_persistent_cache_resolution(tmp_path, monkeypatch):
+    """The one rule: the variable if set (and nothing else written or
+    exported), else one fixed path in the checkout — the same whatever
+    the run directory — and 'off'."""
+    from distributed_pipeline_tpu.utils import perf
+
+    fixed = str(tmp_path / "fixed")
+    monkeypatch.setattr(perf, "DEFAULT_COMPILE_CACHE_DIR", fixed)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        assert enable_persistent_compilation_cache("off") == ""
+        # unset: the fixed path, identical for two different run dirs
+        # (run dirs no longer enter into it at all)
+        a = TrainSettings.from_argv(["--checkpoint_path", "/tmp/run_a"])
+        b = TrainSettings.from_argv(["--checkpoint_path", "/tmp/run_b"])
+        got = [enable_persistent_compilation_cache(s.compilation_cache_dir)
+               for s in (a, b)]
+        assert got == [fixed, fixed] and os.path.isdir(fixed)
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert "JAX_COMPILATION_CACHE_DIR" not in os.environ  # no export
+        assert perf.DEFAULT_COMPILE_CACHE_DIR == fixed
+        # the real default sits in the checkout, under a fixed name
+        assert os.path.basename(os.path.dirname(os.path.dirname(
+            perf.__file__))) == "distributed_pipeline_tpu"
+        # set: that directory, and no other made
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        os.rmdir(fixed)
+        assert enable_persistent_compilation_cache("auto") == outside
+        assert jax.config.jax_compilation_cache_dir == outside
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == outside
+        assert sorted(os.listdir(tmp_path)) == ["outside"]
+        # 'off' wins over the variable, and leaves it alone
+        assert enable_persistent_compilation_cache("off") == ""
+        assert jax.config.jax_compilation_cache_dir is None
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == outside
+    finally:
+        # "off" resets jax's once-only cache object too — leaving it
+        # initialized would pin this tmp dir for the whole test process
+        enable_persistent_compilation_cache("off")
+
+
+def test_aot_compile_metrics_and_cache_hit_path(tmp_path, monkeypatch):
+    """compile_time_s/time_to_first_step_s are populated by the first step,
+    and a RESUMED TrainLoop under a warm persistent cache compiles
+    measurably faster — the exact elastic-restart path the cache exists
+    for. The resume leg doubles as a regression test for donating
+    orbax-restored buffers into a cache-deserialized executable (jaxlib
+    0.4.37 CPU heap corruption; trainer copies restored trees)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    try:
+        enable_persistent_compilation_cache()
+
+        cold = make_loop(tmp_path / "run")
+        assert cold.compile_time_s is None  # nothing compiled at build time
+        cold.run_step(next(cold.data))
+        assert cold.compile_time_s > 0
+        assert cold.time_to_first_step_s >= cold.compile_time_s
+        assert os.listdir(str(tmp_path / "cache")), \
+            "persistent cache wrote nothing"
+        cold.save()
+
+        warm = make_loop(tmp_path / "run")  # same dir: auto-resumes
+        assert warm.step == 1
+        warm.run_step(next(warm.data))
+        warm.run_step(next(warm.data))  # steady state past the restore
+        # The XLA compile is the dominant share of the cold number; a cache
+        # hit replaces it with a disk read. 0.7 leaves headroom for the
+        # (uncached) trace+lower share while still failing if the cache
+        # silently stopped hitting.
+        assert warm.compile_time_s < cold.compile_time_s * 0.7, (
+            warm.compile_time_s, cold.compile_time_s)
+    finally:
+        enable_persistent_compilation_cache("off")
+
+
+def test_aot_step_recompiles_on_shape_change():
+    calls = []
+    step = AOTStep(jax.jit(lambda x: x * 2), "mul",
+                   on_compile=lambda n, s: calls.append((n, s)))
+    a = step(jnp.ones((4,)))
+    b = step(jnp.ones((4,)))          # same shape: no recompile
+    assert len(calls) == 1
+    c = step(jnp.ones((8,)))          # shape change: falls back to recompile
+    assert len(calls) == 2
+    assert float(a.sum()) == 8 and float(b.sum()) == 8
+    assert float(c.sum()) == 16
+    assert step.compile_time_s == pytest.approx(sum(s for _, s in calls))
 
 
 # ---------------------------------------------------------- sanitizer mode
