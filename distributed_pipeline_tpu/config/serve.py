@@ -40,7 +40,9 @@ class ServeSettings(S):
                         "(0 = the model's seq_len)")
     max_new_tokens: int = _(64, "generation budget per request")
     prefill_batch: int = _(0, "prompts prefilled per admission dispatch "
-                              "(0 = min(decode_slots, 8))")
+                              "(0 = the engine's token budget: 512 // "
+                              "max_prompt_len rows, at least 1, at most "
+                              "min(decode_slots, 8))")
     decode_span: int = _(4, "tokens generated per decode dispatch (a "
                             "lax.scan inside the executable): amortizes "
                             "host dispatch over span tokens; admission "

@@ -43,6 +43,16 @@ from ..utils.perf import AOTStep
 
 __all__ = ["DecodeEngine"]
 
+# Positions one prefill dispatch of the flax-backbone family computes when the
+# caller names no ``prefill_batch``: the rows are this budget over
+# ``max_prompt_len`` (at least one, at most min(decode_slots, 8)). A row of
+# 512 positions is already compute-bound for every GPT-2 preset, so further
+# rows buy no rate and a dummy row costs a whole row's time. Read on the v5e,
+# GPT-2-large, a closed loop of 16 clients that brings 1.1 requests an
+# admission (PERF.md section 6, PR 32): [1, 512] 11.0 ms a dispatch and
+# 2,163 tokens/s, [2, 512] 22.0 ms and 1,877, [8, 512] 66.4 ms and 1,166.
+PREFILL_TOKENS = 512
+
 
 def _slot_picker(temperature: float, top_k: int, top_p: float):
     """Per-slot token picker ``(logits [*, V], positions [*], slots [*],
@@ -92,6 +102,10 @@ class DecodeEngine:
         table width; <= the model's trained seq_len for position bounds).
     prefill_batch : compiled prefill batch size (queued prompts batch
         opportunistically up to it; short admissions pad with dummy rows).
+        0 = the engine's own token budget: ``PREFILL_TOKENS //
+        max_prompt_len`` rows, at least one and at most
+        ``min(decode_slots, 8)`` — ONE row at a ``max_prompt_len`` of 512,
+        eight at 64. A burst is admitted by several dispatches in one tick.
     decode_span : tokens generated per decode DISPATCH (a lax.scan of
         decode steps inside the executable, token chain on device). Host
         dispatch cost amortizes over span tokens — the lever when steps
@@ -145,7 +159,8 @@ class DecodeEngine:
         self.max_prompt_len = max_prompt_len
         self.max_len = max_len
         self.pages_per_slot = -(-max_len // page_size)
-        self.prefill_batch = prefill_batch or min(decode_slots, 8)
+        self.prefill_batch = prefill_batch or min(
+            decode_slots, 8, max(1, PREFILL_TOKENS // max_prompt_len))
         # chunked prefill: one chunk of ONE prompt a dispatch; the length
         # is the engine's own (a prompt of max_prompt_len in at most 16,
         # 1024 at most: at 2048 the prompts' last chunks were 15 % padding

@@ -348,7 +348,9 @@ class DecodeServer:
         term — while the PREFILL row carries the extraction plus the
         prompt-padding waste (prefill runs at the compiled
         [prefill_batch, max_prompt_len] shape regardless of actual
-        prompt lengths). ``n_devices`` defaults to 1: decode state is
+        prompt lengths; the rows are the engine's token budget unless
+        the caller named them, so a lone admission pads one row of 512
+        and not eight). ``n_devices`` defaults to 1: decode state is
         replicated, so the service rate IS the per-chip rate (the
         measure_decode rationale)."""
         from ..obs import ledger as ledger_lib

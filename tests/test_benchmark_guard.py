@@ -191,11 +191,12 @@ def chunked_programs():
                  id="jit_decode_fn.sparse_latent"),
     pytest.param("prefill_mfu.serve", constant("PREFILL_PROGRAM"),
                  "chunked_programs", "prefill", id="jit_prefill_chunk_fn"),
+    pytest.param("prefill_mfu.serve.dense", constant("PREFILL_PROGRAM"),
+                 "gpt2_programs", "prefill", id="jit_prefill_fn"),
 ])
 def test_program_names_the_readers_look_for(request, bench_run, metric,
                                             benchmark_side, programs, phase):
     """Each program a reader sums the device time of, compiled here at the
-    tiny widths of the trainer's and the servers' own tests. (No reader
-    asks for the one-shot prefill of the GPT-2 family, so none is held.)"""
+    tiny widths of the trainer's and the servers' own tests."""
     reader, _ = bench_run.load_reader(metric)
     assert request.getfixturevalue(programs)[phase] in benchmark_side(reader)

@@ -302,7 +302,8 @@ def results_of_size(text, n_elements, op=None, exact=False):
 
 @pytest.mark.parametrize("heads", [12, 20])
 @pytest.mark.parametrize("kv_quant", ["fp", "int8"], ids=["bf16", "int8"])
-@pytest.mark.parametrize("program", ["decode", "prefill", "span4"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "prefill1",
+                                     "span4"])
 def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
                                                    program, kv_quant, heads):
     """The engine's own program bodies at GPT-2 widths (``Dh`` 64), two
@@ -316,7 +317,10 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
     reservation (``[slots, pages * page_size, H * Dh]``, then its head
     split: 30 % of the cell's device time, PERF.md PR 30) is written
     nowhere. The prefill attends the prompt's own K/V in XLA (prompts
-    under 1024) and holds no kernel."""
+    under 1024) and holds no kernel; it is held at eight rows (what a
+    caller may still ask for) and, as ``prefill1``, at the ONE row the
+    engine's token budget resolves for ``max_prompt_len`` 512 (PR 32: the
+    shape the serve cell runs)."""
     from flax import linen as nn
 
     from distributed_pipeline_tpu.models import create_model_from_config
@@ -336,8 +340,12 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
         jax.eval_shape(wl.init_params, jax.random.PRNGKey(0))))
     eng = DecodeEngine(wl, params, decode_slots=slots, page_size=ps,
                        max_pages=1 + slots * n, max_prompt_len=lp,
-                       prefill_batch=bp, kv_quant=kv_quant,
+                       prefill_batch=0 if program == "prefill1" else bp,
+                       kv_quant=kv_quant,
                        spec_tokens=span if program == "span4" else 0)
+    if program == "prefill1":
+        bp = eng.prefill_batch
+        assert bp == 1
     pools = [leaf for _, leaf in eng._pool_leaves() if leaf.ndim > 1]
     assert len(pools) == 4 and {p.size for p in pools} == {
         (1 + slots * n) * ps * heads * 64}
@@ -351,7 +359,7 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
     if program == "decode":
         step, args = eng._decode_step, (
             *state, i32(slots, n), i32(slots), key)
-    elif program == "prefill":
+    elif program.startswith("prefill"):
         step, args = eng._prefill_step, (
             i32(bp, lp), i32(bp), i32(bp), i32(bp, n), *state, key)
     else:
@@ -359,7 +367,7 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
             i32(span, slots), *state, i32(slots, n), i32(slots), key)
     text = step._jitted.lower(params, cache, *args).compile().as_text()
     assert results_of_size(text, pools[0].size, op="copy") == []
-    if program == "prefill":
+    if program.startswith("prefill"):
         assert "tpu_custom_call" not in text
         return
     assert fd.resolve_decode_impl(

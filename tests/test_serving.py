@@ -551,6 +551,125 @@ def test_server_smoke_sanitize_compiles_exactly_once(wl_and_params):
         srv.stop_sanitizer()
 
 
+# ------------------------------------- the prefill dispatch's token budget
+
+LONG = 1024    # positions of the workload the budget's cases are built on
+
+
+@pytest.fixture(scope="module")
+def long_wl_and_params():
+    """The tiny model with GPT-2's 1,024 positions, so that an engine can
+    be built at the shapes a deployment has (its programs compile only at
+    the first dispatch: building one costs an eval_shape and the pool)."""
+    wl = tiny_workload(seq_len=LONG)
+    return wl, wl.init_params(jax.random.PRNGKey(3))
+
+
+@pytest.mark.parametrize("max_prompt_len,slots,asked,rows", [
+    (512, 16, 0, 1),     # the serve cell, and GPT-2's default max_len // 2
+    (256, 16, 0, 2),
+    (64, 16, 0, 8),      # short prompts keep the rows they had
+    (64, 4, 0, 4),       # never more rows than slots
+    (1024, 16, 0, 1),    # a row over the budget: still one
+    (512, 16, 3, 3),     # an explicit prefill_batch is honoured
+    (8, 2, 0, 2),        # the tiny shapes of these tests
+    (128, 16, 0, 4),
+    (512, 1, 0, 1),
+])
+def test_prefill_rows_follow_the_token_budget(long_wl_and_params,
+                                              max_prompt_len, slots, asked,
+                                              rows):
+    """``prefill_batch=0`` sizes the one compiled prefill shape by
+    ``PREFILL_TOKENS`` positions a dispatch, not by eight rows: the rows
+    are the budget over ``max_prompt_len``, at least one, at most
+    ``min(decode_slots, 8)``; a caller's own number stands."""
+    from distributed_pipeline_tpu.serving import engine as engine_lib
+
+    assert engine_lib.PREFILL_TOKENS == 512
+    wl, params = long_wl_and_params
+    eng = engine_lib.DecodeEngine(
+        wl, params, decode_slots=slots, page_size=max_prompt_len,
+        max_pages=2, max_prompt_len=max_prompt_len, max_len=max_prompt_len,
+        prefill_batch=asked)
+    assert eng.prefill_batch == rows
+    # still ONE prefill program an engine, built at its first dispatch
+    assert set(eng.executables()) == {"prefill", "decode"}
+    assert eng.executables()["prefill"].compiled is None
+
+
+def burst(n=7, seed=21):
+    """More requests than slots, every budget over one (a budget of one
+    frees its pages at dispatch, which would order frees and allocations
+    differently at one row and at eight)."""
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(4, VOCAB, (int(rng.integers(3, 60)),)).astype(
+        np.int32), 3 + i % 5) for i in range(n)]
+
+
+@pytest.mark.parametrize("kv_quant", ["fp", "int8"])
+def test_burst_at_one_row_serves_what_eight_rows_serve(long_wl_and_params,
+                                                       kv_quant):
+    """At ``max_prompt_len`` 512 the budget gives ONE row. A burst of more
+    requests than slots is then admitted by several dispatches in one tick
+    (four here, where eight rows made one) and serves, token for token,
+    what an explicit ``prefill_batch=8`` serves: same order of admission,
+    same pages, so an int8 pool's pages have equal histories on both
+    sides (PERF.md section 7, 1d). The padding counter follows the shape
+    that ran."""
+    wl, params = long_wl_and_params
+    lp, slots, work = 512, 4, burst()
+    streams, servers = {}, {}
+    for asked in (0, 8):
+        srv = DecodeServer(wl, params, decode_slots=slots, page_size=16,
+                           max_prompt_len=lp, max_len=lp + 16, seed=0,
+                           prefill_batch=asked, kv_quant=kv_quant)
+        reqs = [srv.submit(p, max_new_tokens=m) for p, m in work]
+        srv.step()
+        # the first tick filled every slot, whatever the rows
+        assert srv.free_slots == 0 and len(srv.queue) == len(reqs) - slots
+        first_tick = srv.prefill_steps
+        srv.drain()
+        assert all(r.finished and len(r.tokens) == r.max_new_tokens
+                   for r in reqs)
+        assert srv.free_slots == slots
+        assert srv.mgr.free_pages == srv.mgr.capacity
+        rows = srv.engine.prefill_batch
+        assert srv.prefill_token_slots == srv.prefill_steps * rows * lp
+        assert srv.prompt_tokens_prefilled == sum(
+            r.prompt_len for r in reqs)
+        streams[asked] = [r.tokens for r in reqs]
+        servers[asked] = (rows, first_tick, srv.prefill_steps)
+    assert servers[0][0] == 1 and servers[8][0] == 8
+    assert servers[0][1] == slots and servers[8][1] == 1
+    assert servers[0][2] == len(work)        # a dispatch a request
+    assert streams[0] == streams[8]
+
+
+def test_draft_engine_resolves_the_targets_rows(long_wl_and_params):
+    """The speculative server mirrors every admission into its draft
+    engine with the target's arrays, so both must resolve the same rows
+    from the same arguments; a burst over several dispatches a tick keeps
+    the greedy stream of the non-speculative server."""
+    wl, params = long_wl_and_params
+    kw = dict(decode_slots=4, page_size=16, max_prompt_len=256,
+              max_len=288, seed=0)
+    plain = DecodeServer(wl, params, **kw)
+    spec = DecodeServer(wl, params, spec_tokens=2, spec_draft="model",
+                        draft_layers=1, **kw)
+    assert plain.engine.prefill_batch == 2       # 512 // 256, not min(4, 8)
+    assert spec.engine.prefill_batch == 2
+    assert spec._draft_engine.prefill_batch == 2
+    got = {}
+    for name, srv in (("plain", plain), ("spec", spec)):
+        reqs = [srv.submit(p, max_new_tokens=m) for p, m in burst(n=6)]
+        srv.drain()
+        assert srv.prefill_steps >= 3            # six requests, two rows
+        assert srv.prefill_token_slots == srv.prefill_steps * 2 * 256
+        assert srv.mgr.free_pages == srv.mgr.capacity
+        got[name] = [r.tokens for r in reqs]
+    assert got["spec"] == got["plain"]
+
+
 def test_engine_rejects_unsupported_models(wl_and_params):
     wl, params = wl_and_params
     scan_wl = tiny_workload(scan_layers=True)
