@@ -1,0 +1,101 @@
+"""Layers of the dots3-note-prev share: the whole step and the prefill
+program against the chip's bf16 peak, the decode program against the memory
+roofline, what selection and window together leave of a full cache's reads,
+and the two prefill kernels at THIS model's head counts and widths, both
+layer kinds. Work is counted by harness/work_dots3_note.py from the
+program's own counters, read as readers/deepseek_v32.py reads them (the
+``serve.fetch`` spans' arguments, by the program that counted).
+
+A program without the window counters (a commit before them, another family)
+reads as nothing."""
+
+from harness import work_dots3_note as work
+
+from readers import deepseek_v32 as base
+from readers.deepseek_v32 import (ATTEND_KERNEL, DECODE_PROGRAM,
+                                  INDEX_KERNEL, PREFILL_PROGRAM)
+
+WINDOW = "window_rows_attended"
+
+
+def _counted(program):
+    c = base.counted(program)
+    return c if c is not None and WINDOW in c else None
+
+
+def step_mfu_serve_mixed(ctx):
+    """Needed FLOPs of everything the traced window processed (prompt
+    tokens through the layers, decode slot-steps through layers and head)
+    over its wall time and the peak."""
+    t, pre, dec = base._traced(ctx), _counted("prefill"), _counted("decode")
+    if t is None or pre is None or dec is None:
+        return None
+    cfg = ctx["config"]
+    need = (work.flops_needed(cfg, tokens=t["prompt_tokens"],
+                              head_tokens=0, counted=pre)
+            + work.flops_needed(cfg, tokens=t["slot_steps_active"],
+                                head_tokens=t["slot_steps_active"],
+                                counted=dec))
+    return 100.0 * need / (t["t"] * ctx["peaks"]["flops_bf16"])
+
+
+def prefill_mfu_serve_mixed(ctx):
+    t, pre = base._traced(ctx), _counted("prefill")
+    if t is None or pre is None or ctx["trace"] is None:
+        return None
+    seconds, count = ctx["trace"].module_seconds(PREFILL_PROGRAM)
+    if count == 0 or seconds <= 0:
+        return None
+    need = work.flops_needed(ctx["config"], tokens=t["prompt_tokens"],
+                             head_tokens=0, counted=pre)
+    return 100.0 * need / (seconds * ctx["peaks"]["flops_bf16"])
+
+
+def decode_hbm_roofline_mixed_latent(ctx):
+    t, dec = base._traced(ctx), _counted("decode")
+    if t is None or dec is None or not t.get("decode_steps") \
+            or ctx["trace"] is None:
+        return None
+    seconds, count = ctx["trace"].module_seconds(DECODE_PROGRAM)
+    if count == 0 or seconds <= 0:
+        return None
+    need = work.decode_bytes_needed(ctx["config"], steps=t["decode_steps"],
+                                    counted=dec)
+    return 100.0 * (need / ctx["peaks"]["hbm_bytes_s"]) / seconds
+
+
+def attended_kv_share_mixed(ctx):
+    """Rows attention read (the full layers' selected rows and the sliding
+    layers' windows) over the rows a cache that never frees would hold
+    live, prefill chunks and decode steps together."""
+    pre, dec = _counted("prefill"), _counted("decode")
+    if pre is None or dec is None:
+        return None
+
+    def total(*names):
+        return sum(c[n] for c in (pre, dec) for n in names)
+    live = total("kv_rows_live", "window_rows_live")
+    if live <= 0:
+        return None
+    return 100.0 * total("kv_rows_attended", WINDOW) / live
+
+
+def mla_block_attend_roofline_mixed(ctx):
+    """The prefill attention kernel, both layer kinds: the least time the
+    chip could take for the (query, key) pairs the chunks' attention had to
+    compute (counters) over the kernel's device time."""
+    pre, n = _counted("prefill"), base._chunk_tokens(ctx)
+    return base._kernel_share(ctx, ATTEND_KERNEL, pre and n and (
+        work.prefill_attention_needed(
+            ctx["config"], attended_rows=pre["kv_rows_attended"],
+            window_rows=pre[WINDOW], chunk_tokens=n)))
+
+
+def lightning_index_scores_roofline_mixed(ctx):
+    """The full layers' indexer kernel, likewise, for the pairs it had to
+    score."""
+    pre, n = _counted("prefill"), base._chunk_tokens(ctx)
+    return base._kernel_share(ctx, INDEX_KERNEL, pre and n and (
+        work.index_scores_needed(
+            ctx["config"], scored_rows=pre["index_rows_scored"],
+            chunk_tokens=n)))

@@ -10,7 +10,11 @@ entry point makes (``run/train.py:71`` passes ``**args.dict()``):
 * ``deepseek_v32`` — DeepSeek-V3.2-Exp as one chip's share of an
   expert-parallel deployment (models/deepseek_v32.py): served through
   ``DecodeServer``'s chunked prefill; its ``arch`` flag carries the source's
-  ``config.json`` keys and the cut.
+  ``config.json`` keys and the cut;
+* ``dots3_note`` — dots3-note-prev likewise (models/dots3_note.py: the
+  configuration; the layers are ``deepseek_v32``'s, told their kind): full
+  latent-attention layers with the indexer beside window layers whose cache
+  is a ring a slot, headwise output gates.
 
 The factory returns a :class:`Workload`: the flax module plus pure
 ``init_params`` / ``compute_losses`` functions — the reference's user-hook
@@ -30,9 +34,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from .backbone import TransformerBackbone
-from .deepseek_v32 import DeepseekV32Config, DeepseekV32Model
+from .deepseek_v32 import DeepseekV32Config, LatentMoEModel
 from .diffuseq import DiffuSeqModel, diffuseq_losses
 from .diffusion import DiffusionSchedule, make_schedule
+from .dots3_note import Dots3NoteConfig
 from .gpt2 import GPT2Model, gpt2_losses
 
 __all__ = [
@@ -56,7 +61,10 @@ PRESETS: Dict[str, Dict[str, Tuple[int, int, int]]] = {
     },
     # the published model; a deployment's cut comes through `arch`
     "deepseek_v32": {"base": (7168, 61, 128)},
+    "dots3_note": {"base": (5120, 46, 128)},
 }
+# the families LatentMoEModel runs, by the class that reads their `arch`
+LATENT_MOE = {"deepseek_v32": DeepseekV32Config, "dots3_note": Dots3NoteConfig}
 DIFFUSEQ_EMB_DIM = 128  # DiffuSeq uses a low-dim embedding space
 
 
@@ -118,8 +126,9 @@ def _example_batch_fn(seq_len: int) -> Callable[[int], Dict[str, np.ndarray]]:
 
 def _served_not_trained(params, batch, rng):
     raise NotImplementedError(
-        "the deepseek_v32 share is served, not trained: it has no loss, no "
-        "partition rules and forward-only kernels (ROADMAP R3)")
+        "a latent-attention share (deepseek_v32, dots3_note) is served, not "
+        "trained: it has no loss, no partition rules and forward-only "
+        "kernels (ROADMAP R3)")
 
 
 def create_model_from_config(*, model_family: str = "diffuseq",
@@ -161,15 +170,15 @@ def create_model_from_config(*, model_family: str = "diffuseq",
     heads = num_heads or preset[2]
     jdtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
 
-    if model_family == "deepseek_v32":
+    if model_family in LATENT_MOE:
         # explicit size flags win over `arch` (a dict of the source's
         # keys); the presets' zeros leave it alone
-        cfg = DeepseekV32Config.from_arch(
+        cfg = LATENT_MOE[model_family].from_arch(
             arch or {}, vocab_size=vocab_size, hidden_size=hidden_size,
             n_layers=num_layers, num_attention_heads=num_heads)
-        model = DeepseekV32Model(cfg=cfg, seq_len=seq_len, dtype=jdtype)
+        model = LatentMoEModel(cfg=cfg, seq_len=seq_len, dtype=jdtype)
         return Workload(
-            model=model, family="deepseek_v32", seq_len=seq_len,
+            model=model, family=model_family, seq_len=seq_len,
             hidden_size=cfg.hidden_size, num_layers=cfg.n_layers,
             compute_losses=_served_not_trained,
             example_batch=_example_batch_fn(seq_len))

@@ -78,6 +78,12 @@ def _slot_picker(temperature: float, top_k: int, top_p: float):
     return pick
 
 
+def _is_window(path) -> bool:
+    """A window layer's ring pool in a chunked-prefill model's cache:
+    ``cache_shapes`` names that leaf "window"."""
+    return getattr(path[-1], "key", None) == "window"
+
+
 class DecodeEngine:
     """Device half of the serving stack: paged-cache decode state plus the
     two AOT executables that advance it.
@@ -92,7 +98,12 @@ class DecodeEngine:
         ``cache_shapes``, ``prefill_chunk``, ``decode_step``:
         models/deepseek_v32.py); for the latter the prefill executable
         takes ONE chunk of one prompt (``prefill_chunk`` tokens) and the
-        scheduler walks a prompt chunk by chunk, a chunk a tick.
+        scheduler walks a prompt chunk by chunk, a chunk a tick. Such a
+        model may also say that some of its layers keep only a window of
+        rows (``window_rows(chunk)`` > 0): those layers' rows live in a
+        second pool where every slot owns a RING of
+        ``window_pages_per_slot`` pages, addressed through a second block
+        table (``set_block_tables(table, window)``).
     decode_slots : compiled decode batch size S. Decode ALWAYS runs at S
         (inactive slots write to the trash page and their outputs are
         ignored) — the executable never re-specializes to occupancy.
@@ -167,6 +178,15 @@ class DecodeEngine:
         # and a token took as long, PERF.md PR 29)
         self.prefill_chunk = min(max_prompt_len, max(page_size, min(
             1024, -(-max_prompt_len // 16)))) if self.chunked else 0
+        # a model whose window layers keep `window_rows` rows a slot,
+        # whatever the slot's length: a ring of pages a slot in a pool of
+        # full residency beside the paged pool (never more than a slot's
+        # whole length)
+        window_rows = (model.window_rows(self.prefill_chunk)
+                       if hasattr(model, "window_rows") else 0)
+        self.window_pages_per_slot = min(
+            self.pages_per_slot, -(-window_rows // page_size))
+        self.max_window_pages = 1 + decode_slots * self.window_pages_per_slot
         # int32 counters a program returns behind its tokens (one fetch)
         self.n_counters = len(getattr(model, "counters", ()))
         if decode_span < 1:
@@ -194,11 +214,10 @@ class DecodeEngine:
         if self.chunked:
             dm = None
 
-            def slot_logits(p, cache, tokens, positions, block_table,
-                            active):
+            def slot_logits(p, cache, tokens, positions, tables, active):
                 cache, logits, counted, _ = model.decode_step(
-                    p["params"], cache, tokens, positions, block_table,
-                    active)
+                    p["params"], cache, tokens, positions, tables[0],
+                    active, *tables[1:])
                 return logits, cache, counted
         else:
             # decode=True + paged_pages selects the paged attention branch;
@@ -245,18 +264,19 @@ class DecodeEngine:
             positions = positions.at[safe].set(prompt_lens, mode="drop")
             return mvars["cache"], tokens, positions
 
-        def prefill_chunk_fn(p, cache, ids, meta, table_row, tokens,
+        def prefill_chunk_fn(p, cache, ids, meta, table_rows, tokens,
                              positions, key):
             """One chunk of one prompt: ``ids`` [C] (zero-padded), ``meta``
             = (first position, valid tokens, target slot, 1 on the
-            prompt's last chunk), ``table_row`` the slot's pages. Writes
+            prompt's last chunk), ``table_rows`` the slot's pages (and,
+            behind them, its ring in the window pool). Writes
             the chunk's cache rows; on the last chunk picks the request's
             first token and merges token/position into the decode state at
             the slot (same fold as above). Returns the state and, for the
             one fetch, the tokens with the chunk's counters behind them."""
             start, n_valid, slot, is_last = meta[0], meta[1], meta[2], meta[3]
             cache, logits, counted = model.prefill_chunk(
-                p["params"], cache, ids, start, n_valid, table_row)
+                p["params"], cache, ids, start, n_valid, *table_rows)
             prompt_len = start + n_valid
             first = pick(logits[None], prompt_len[None], slot[None], key)[0]
             safe = jnp.where(is_last > 0, slot, s)        # s = out of bounds
@@ -350,7 +370,8 @@ class DecodeEngine:
         # first-call (variable-creating) apply, then zero-fill. Every real
         # prefill/decode then shares one with-cache signature.
         if self.chunked:
-            cache_abs = model.cache_shapes(max_pages, page_size)
+            cache_abs = model.cache_shapes(max_pages, page_size,
+                                           self.max_window_pages)
         else:
             ids0 = jax.ShapeDtypeStruct((bp, max_prompt_len), jnp.int32)
             pad0 = jax.ShapeDtypeStruct((bp, max_prompt_len), jnp.int32)
@@ -406,8 +427,10 @@ class DecodeEngine:
             lambda a: jnp.zeros(a.shape, a.dtype), cache_abs)
         self.tokens = self._put(np.zeros((s,), np.int32))
         self.positions = self._put(np.zeros((s,), np.int32))
-        self._block_table = self._put(
-            np.zeros((s, self.pages_per_slot), np.int32))
+        self.set_block_tables(
+            np.zeros((s, self.pages_per_slot), np.int32),
+            np.zeros((s, self.window_pages_per_slot), np.int32)
+            if self.window_pages_per_slot else None)
         self._active = self._put(np.zeros((s,), np.int32))
         key = rng if rng is not None else jax.random.PRNGKey(seed)
         self._key = self._put_key(key)
@@ -466,10 +489,19 @@ class DecodeEngine:
         """Swap the sampling key (a dispatch ARGUMENT, so no recompile)."""
         self._key = self._put_key(key)
 
-    def set_block_tables(self, table: np.ndarray) -> None:
+    def set_block_tables(self, table: np.ndarray,
+                         window: Optional[np.ndarray] = None) -> None:
         """Refresh the device block-table mirror (admission/free changed
-        the host copy). Shape must stay [S, pages_per_slot]."""
+        the host copy). Shape must stay [S, pages_per_slot]; ``window``
+        [S, window_pages_per_slot] the slots' rings, of a model with
+        window layers. The decode program takes the flax backbone's table
+        as an array; of a chunked-prefill model a tuple, the rings behind
+        the pages."""
         self._block_table = self._put(np.ascontiguousarray(table, np.int32))
+        if self.chunked:
+            self._block_table = (self._block_table,) + (
+                () if window is None else (
+                    self._put(np.ascontiguousarray(window, np.int32)),))
 
     def set_active(self, active: np.ndarray) -> None:
         self._active = self._put(np.ascontiguousarray(active, np.int32))
@@ -488,20 +520,29 @@ class DecodeEngine:
         extract/ingest wire format stable across a StageLink."""
         flat, _ = jax.tree_util.tree_flatten_with_path(self.cache)
         return [(jax.tree_util.keystr(path), leaf) for path, leaf in flat
-                if (getattr(leaf, "ndim", 0) == 3
-                    and leaf.shape[0] == self.max_pages
-                    and leaf.shape[1] == self.page_size)
-                # int8 pools: the [P] per-page scale sidecars are page
-                # state too — they ride the same extract/ingest wire
-                or (getattr(leaf, "ndim", 0) == 1
-                    and leaf.shape[0] == self.max_pages)]
+                if not _is_window(path) and (
+                    (getattr(leaf, "ndim", 0) == 3
+                     and leaf.shape[0] == self.max_pages
+                     and leaf.shape[1] == self.page_size)
+                    # int8 pools: the [P] per-page scale sidecars are page
+                    # state too — they ride the same extract/ingest wire
+                    or (getattr(leaf, "ndim", 0) == 1
+                        and leaf.shape[0] == self.max_pages))]
 
     def kv_pool_bytes(self) -> int:
         """Device bytes the paged KV pool holds (pages + scale sidecars,
-        every layer) — the ledger's page-pool gauge: the int8 arm must
-        land at <= 0.55x the fp arm at equal geometry (ISSUE 20)."""
+        every layer; the window pool's rings too) — the ledger's page-pool
+        gauge: the int8 arm must land at <= 0.55x the fp arm at equal
+        geometry (ISSUE 20)."""
+        leaves = [leaf for _, leaf in self._pool_leaves()]
+        # a window layer's rings (its cache entry's one leaf, "window") do
+        # not migrate, so they are no `_pool_leaves`; they are pool all the
+        # same
+        leaves += [leaf for path, leaf in
+                   jax.tree_util.tree_flatten_with_path(self.cache)[0]
+                   if _is_window(path)]
         return int(sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
-                       for _, leaf in self._pool_leaves()))
+                       for leaf in leaves))
 
     def extract_pages(self, page_ids: np.ndarray) -> Dict[str, np.ndarray]:
         """Pull the contents of ``page_ids`` out of every pool leaf as
@@ -579,13 +620,17 @@ class DecodeEngine:
         return self.tokens
 
     def prefill_one_chunk(self, ids: np.ndarray, start: int, n_valid: int,
-                          slot: int, table_row: np.ndarray,
-                          is_last: bool) -> jax.Array:
+                          slot: int, table_row: np.ndarray, is_last: bool,
+                          window_row: Optional[np.ndarray] = None
+                          ) -> jax.Array:
         """Run the chunked-prefill executable for ONE chunk of one prompt
         (``ids`` [prefill_chunk], zero-padded past ``n_valid``; positions
-        ``start ..`` of the request bound for ``slot``). Returns the
+        ``start ..`` of the request bound for ``slot``; ``window_row`` the
+        slot's ring, of a model with window layers). Returns the
         fetchable handle: the post-merge tokens [S] with the chunk's
         counters behind them."""
+        rows = (table_row,) if window_row is None else (table_row,
+                                                        window_row)
         with self._ctx():
             (self.cache, self.tokens, self.positions,
              out) = self._prefill_step(
@@ -593,7 +638,8 @@ class DecodeEngine:
                 self._put(np.ascontiguousarray(ids, np.int32)),
                 self._put(np.array([start, n_valid, slot, int(is_last)],
                                    np.int32)),
-                self._put(np.ascontiguousarray(table_row, np.int32)),
+                tuple(self._put(np.ascontiguousarray(r, np.int32))
+                      for r in rows),
                 self.tokens, self.positions, self._key)
         return out
 

@@ -89,6 +89,8 @@ class _SlotState:
 
     req: Request
     pages: np.ndarray               # page ids reserved for this request
+    # its ring in the window pool (a model with window layers), else None
+    window_pages: Optional[np.ndarray] = None
     generated: int = 1              # prefill produced token #1
     position: int = 0               # index of the token currently in state
     # (chunked prefill: prompt rows written so far, until the slot decodes)
@@ -202,6 +204,13 @@ class DecodeServer:
             self._recompiles.uninstall()  # failed build must not leak the
             raise                         # process-global 'jax' log handler
         self.mgr = PageManager(max_pages, page_size)
+        # a model with window layers keeps their rows in a pool of its own,
+        # a ring of pages a slot, whatever the request's length: a second
+        # allocator and a second block table, one a kind of cache
+        wpps = self.engine.window_pages_per_slot
+        self.window_mgr = PageManager(self.engine.max_window_pages,
+                                      page_size) if wpps else None
+        self.window_tables = np.zeros((decode_slots, wpps), np.int32)
         # Shared-prefix page reuse (ISSUE 11 satellite): requests whose
         # prompts open with the same token run share the pages holding
         # that prefix's K/V (refcounted — see PrefixCache for why replay/
@@ -581,6 +590,9 @@ class DecodeServer:
                 self.mgr.free(to_free)
         else:
             self.mgr.free(st.pages)
+        if st.window_pages is not None:
+            self.window_mgr.free(st.window_pages)
+            self.window_tables[slot, :] = TRASH_PAGE
         self.block_tables[slot, :] = TRASH_PAGE
         self.active[slot] = 0
         self.slots[slot] = None
@@ -619,6 +631,15 @@ class DecodeServer:
         self.prefix.publish(req.prompt, pages, n_acquired=len(shared))
         return pages
 
+    def _reserve_window(self, req: Request) -> Optional[np.ndarray]:
+        """The request's ring in the window pool: as many pages as its
+        whole length needs, at most a slot's ring (None: the pool cannot
+        cover them). The pool has a ring a slot, so with a free slot this
+        succeeds; the check keeps a smaller pool honest."""
+        n = min(self.engine.window_pages_per_slot,
+                self.window_mgr.pages_for(req.prompt_len + req.g_max))
+        return self.window_mgr.alloc(n)
+
     def _admit(self) -> bool:
         """Admit queued requests into free slots, up to one prefill batch.
         All-or-nothing page reservation per request (worst case: prompt +
@@ -632,8 +653,15 @@ class DecodeServer:
             if tr.enabled:
                 # known only now: in the ring and the shard, not in the
                 # xplane (an annotation takes its arguments when it opens)
+                # (a budget of one has left its slot again by now)
+                held = [st for st in (self.slots[slot] for slot, _ in batch)
+                        if st is not None]
                 sp.args = {"n": len(batch), "prompt_tokens": sum(
-                    req.prompt_len for _, req in batch)}
+                    req.prompt_len for _, req in batch),
+                    # pages reserved, by the kind of cache
+                    "pages_full": sum(len(st.pages) for st in held),
+                    "pages_window": sum(len(st.window_pages) for st in held
+                                        if st.window_pages is not None)}
             return bool(batch)
 
     def _admit_batch(self) -> List[tuple]:
@@ -645,6 +673,12 @@ class DecodeServer:
             pages = self._reserve_pages(req)
             if pages is None:
                 break  # pool exhausted: wait for completions to free pages
+            window_pages = None
+            if self.window_mgr is not None:
+                window_pages = self._reserve_window(req)
+                if window_pages is None:
+                    self.mgr.free(pages)      # all or nothing, both kinds
+                    break
             slot = free.pop(0)
             self.queue.popleft()
             req.admit_t = time.perf_counter()
@@ -653,7 +687,8 @@ class DecodeServer:
                 # the slot is held but decodes nothing yet: its table row
                 # stays trash (a decode step writes every slot's row)
                 # until the last chunk of its prompt is dispatched
-                self.slots[slot] = _SlotState(req=req, pages=pages)
+                self.slots[slot] = _SlotState(req=req, pages=pages,
+                                              window_pages=window_pages)
                 self._prefilling.append(slot)
                 batch.append((slot, req))
                 continue
@@ -713,11 +748,16 @@ class DecodeServer:
         ids[:n_valid] = req.prompt[start:start + n_valid]
         table_row = np.zeros((self.engine.pages_per_slot,), np.int32)
         table_row[:len(st.pages)] = st.pages
+        window_row = None
+        if st.window_pages is not None:
+            window_row = np.zeros((self.engine.window_pages_per_slot,),
+                                  np.int32)
+            window_row[:len(st.window_pages)] = st.window_pages
         tr = self.tracer
         with tr.span("serve.prefill_chunk", "serve", args={
                 "tokens": n_valid, "slot": slot} if tr.enabled else None):
-            out = self.engine.prefill_one_chunk(ids, start, n_valid, slot,
-                                                table_row, is_last)
+            out = self.engine.prefill_one_chunk(
+                ids, start, n_valid, slot, table_row, is_last, window_row)
         self.prefill_steps += 1
         self.prompt_tokens_prefilled += n_valid
         self.prefill_token_slots += size
@@ -731,6 +771,8 @@ class DecodeServer:
             return
         self.block_tables[slot, :] = TRASH_PAGE
         self.block_tables[slot, :len(st.pages)] = st.pages
+        if window_row is not None:
+            self.window_tables[slot] = window_row
         self.active[slot] = 1
         self._dirty = True
 
@@ -793,7 +835,9 @@ class DecodeServer:
         if self.active.any():
             with self.tracer.span("serve.decode_dispatch", "serve"):
                 if self._dirty:
-                    self.engine.set_block_tables(self.block_tables)
+                    self.engine.set_block_tables(
+                        self.block_tables, self.window_tables
+                        if self.window_mgr is not None else None)
                     self.engine.set_active(self.active)
                     self._dirty = False
                 snap = [(s, st.req) for s, st in enumerate(self.slots)

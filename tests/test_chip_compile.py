@@ -377,15 +377,19 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
                            exact=True) == []
 
 
-def test_mla_block_attend_compiles_at_published_widths(one_chip):
+@pytest.mark.parametrize("h, dq", [(128, 192), (64, 256)],
+                         ids=["full_h128_dq192", "window_h64_dq256"])
+def test_mla_block_attend_compiles_at_published_widths(one_chip, h, dq):
     """The chunked MLA prefill's attention block (ops/mla_attention.py) at
-    DeepSeek-V3.2-Exp's widths (128 heads, 192-wide queries and keys, 128-
-    wide values), a chunk of 1024 queries against a block of 512 keys: the
-    192-long contraction, the [1, queries] statistics rows and the carry
-    updated in place are what interpret mode cannot refuse."""
+    the widths of DeepSeek-V3.2-Exp's layers and dots3-note-prev's full
+    layers (128 heads, 192-wide queries and keys, 128-wide values) and of
+    dots3-note-prev's window layers (64 heads, 256-wide), a chunk of 1024
+    queries against a block of 512 keys: the contraction, the [1, queries]
+    statistics rows and the carry updated in place are what interpret mode
+    cannot refuse."""
     from distributed_pipeline_tpu.ops import mla_attention as ma
 
-    h, dq, dv, c, k = 128, 192, 128, 1024, 512
+    dv, c, k = 128, 1024, 512
     carry = (sds((h, 1, c), jnp.float32, one_chip),
              sds((h, 1, c), jnp.float32, one_chip),
              sds((h, dv, c), jnp.float32, one_chip))
