@@ -110,7 +110,10 @@ class Workload:
         return {k: v for k, v in variables.items() if k != "losses"}
 
     def param_count(self, params: Any) -> int:
-        return sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))
+        """Parameters of the ``params`` collection: a server's variables
+        carry derived copies beside it (GPT2Model.serving_variables)."""
+        return sum(int(np.prod(p.shape)) for p in
+                   jax.tree_util.tree_leaves(params.get("params", params)))
 
 
 def _example_batch_fn(seq_len: int) -> Callable[[int], Dict[str, np.ndarray]]:

@@ -26,7 +26,8 @@ from flax import linen as nn
 
 from ..ops import dot_product_attention
 
-__all__ = ["TransformerBackbone", "Block", "Mlp", "SelfAttention"]
+__all__ = ["TransformerBackbone", "Block", "Mlp", "SelfAttention",
+           "as_dtype", "serving_blocks"]
 
 # Logical axis names; parallel/sharding.py maps them onto mesh axes
 # ("embed" -> fsdp, "mlp"/"heads"/"kv" -> tensor, etc.).
@@ -38,6 +39,41 @@ KV = "kv"
 
 def _dense_init(fan_in: int):
     return nn.initializers.normal(stddev=fan_in ** -0.5)
+
+
+def as_dtype(leaf, dtype):
+    """``leaf`` in ``dtype``: the SAME object where it already is (a tree
+    that is right is never copied), a described leaf (``ShapeDtypeStruct``)
+    re-described on its sharding, an array cast — round to nearest even,
+    what ``.astype`` inside a program does. A flax metadata box keeps its
+    box."""
+    def one(x):
+        if x.dtype == dtype:
+            return x
+        if isinstance(x, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(x.shape, dtype, sharding=x.sharding)
+        return x.astype(dtype)
+    return jax.tree_util.tree_map(one, leaf)
+
+
+def serving_blocks(backbone, dtype):
+    """A named-blocks backbone's parameters as a server holds them: every
+    matrix its module casts to the compute dtype before its only use
+    (``MATMUL_PARAMS``, declared beside those uses) already in ``dtype``,
+    so that a serving program reads half the bytes and casts nothing. The
+    modules keep their ``.astype(self.dtype)`` — a no-op on this tree, and
+    what training on float32 masters needs. LayerNorm leaves and an MoE
+    router are used in float32 and stay as they are. Stacked
+    (``scan_layers``) weights have no ``block_<i>`` entry and pass
+    through: the engine does not serve them."""
+    from .moe import MoEMlp  # function-level: moe imports backbone
+    cast = {"attn": SelfAttention.MATMUL_PARAMS, "mlp": Mlp.MATMUL_PARAMS,
+            "moe": MoEMlp.MATMUL_PARAMS}
+    return {name: sub if not name.startswith("block_") else {
+                mod: {k: as_dtype(v, dtype) if k in cast.get(mod, ()) else v
+                      for k, v in leaves.items()}
+                for mod, leaves in sub.items()}
+            for name, sub in backbone.items()}
 
 
 class SelfAttention(nn.Module):
@@ -80,6 +116,9 @@ class SelfAttention(nn.Module):
     # fp k/v, so prefill logits are unchanged; decode logits carry the
     # documented quantization divergence instead of bit-identity.
     kv_quant: str = "fp"
+
+    # cast to ``dtype`` before their only use (the two einsums below)
+    MATMUL_PARAMS = ("qkv", "out")
 
     @nn.compact
     def __call__(self, x: jnp.ndarray,
@@ -243,6 +282,8 @@ class Mlp(nn.Module):
 
     dtype: jnp.dtype = jnp.bfloat16
     expand: int = 4
+
+    MATMUL_PARAMS = ("wi", "wo")  # cast to ``dtype`` before their only use
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:

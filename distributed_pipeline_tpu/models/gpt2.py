@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops.xent import token_cross_entropy
-from .backbone import EMBED, TransformerBackbone
+from .backbone import EMBED, TransformerBackbone, as_dtype, serving_blocks
 
 __all__ = ["GPT2Model", "gpt2_losses"]
 
@@ -113,8 +113,31 @@ class GPT2Model(nn.Module):
                                                  block_table)
         # Tied LM head in compute dtype: bf16 [B, L, V] logits cost half the
         # HBM traffic of f32; softmax stats go to f32 downstream (ops/xent.py).
-        return jnp.einsum("bld,vd->blv", h,
-                          word_emb.embedding.astype(self.dtype))
+        # A server brings the cast table with it (serving_variables): the
+        # lookup above keeps the float32 one, whose sum rounds once.
+        head = (self.get_variable("serving", "head")
+                if self.has_variable("serving", "head")
+                else word_emb.embedding)
+        return jnp.einsum("bld,vd->blv", h, head.astype(self.dtype))
+
+    def serving_variables(self, variables):
+        """The variables a server holds, from what training leaves: the
+        block matrices in the compute dtype (backbone.serving_blocks) and,
+        in a ``serving`` collection of its own, the tied head's copy of
+        the table in that dtype. The cast is the one each use makes inside
+        a program, made once, so logits come out bit for bit; a leaf that
+        is right already is the same object (a served tree comes back
+        with every leaf its own), and a described tree
+        (``ShapeDtypeStruct`` leaves) is re-described. ``params`` keeps
+        the float32 table, position embedding and LayerNorm leaves, and
+        every parameter once."""
+        p = variables["params"]
+        head = variables.get("serving", {}).get(
+            "head", nn.meta.unbox(p["word_emb"]["embedding"]))
+        return {**variables,
+                "params": {**p, "backbone": serving_blocks(p["backbone"],
+                                                           self.dtype)},
+                "serving": {"head": as_dtype(head, self.dtype)}}
 
 
 def gpt2_losses(model: GPT2Model, params, batch: Dict[str, jnp.ndarray],

@@ -72,6 +72,10 @@ class MoEMlp(nn.Module):
     expand: int = 4
     no_drop: bool = False
 
+    # cast to ``dtype`` before their only use (moe_mlp_fwd's expert
+    # einsums); the router multiplies in float32 and is not among them
+    MATMUL_PARAMS = ("wi", "wo")
+
     @nn.compact
     def __call__(self, x: jnp.ndarray,
                  pad_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:

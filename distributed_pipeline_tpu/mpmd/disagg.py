@@ -188,9 +188,6 @@ def serve_disagg_inprocess(workload, params,
     max_len = max_len or workload.seq_len
     max_prompt_len = max_prompt_len or max(2, max_len // 2)
     page_size = page_size or 16
-    pre = PrefillClient(workload, params, page_size=page_size,
-                        max_prompt_len=max_prompt_len, max_len=max_len,
-                        mesh=mesh)
     if server is None:
         server = DecodeServer(
             workload, params, decode_slots=decode_slots,
@@ -198,6 +195,10 @@ def serve_disagg_inprocess(workload, params,
             max_prompt_len=max_prompt_len, max_len=max_len,
             decode_span=decode_span, mesh=mesh,
             eos_id=eos_id)
+        params = server.engine.params  # one serving copy for both roles
+    pre = PrefillClient(workload, params, page_size=page_size,
+                        max_prompt_len=max_prompt_len, max_len=max_len,
+                        mesh=mesh)
     if link is None:
         link = MemStageLink(capacity=len(pairs) + 1)
 

@@ -65,6 +65,11 @@ def render(payload: Dict[str, Any]) -> str:
                               f"({_fmt_bytes(coll['bytes'].get(op, 0))})"
                               for op, n in coll["counts"].items())
             lines.append(f"  collectives:       {parts}")
+        if "bytes_serving" in row:   # what the engine holds of its tree
+            lines.append(f"  weights:           "
+                         f"{_fmt_bytes(row.get('bytes_in'))} given, "
+                         f"{_fmt_bytes(row['bytes_serving'])} held, "
+                         f"{row.get('leaves_cast', 0)} leaves cast")
         if "mfu" not in row:
             if "padding_waste_frac" in row:
                 lines.append(f"  padding waste:     "

@@ -194,6 +194,9 @@ def _serve_single(settings: ServeSettings) -> dict:
         spec_tokens=settings.spec_tokens,
         spec_draft=settings.spec_draft,
         draft_layers=settings.draft_layers)
+    # the engine holds its serving form of the tree (the matrices in the
+    # compute dtype); let the float32 masters go with this name
+    del params
 
     pending = _load_requests(settings, max_prompt_len, wl.model.vocab_size)
     logger.info(f"serving {len(pending)} requests on {settings.decode_slots} "
@@ -380,6 +383,7 @@ def _fleet_worker_main(settings: ServeSettings) -> dict:
         # the scheduler's own serve.* spans and each request's life, in
         # this replica's shard under the router's trace id
         tracer=proto.tracer)
+    del params  # the engine holds its serving form; let the masters go
 
     def _restore_params(target: str):
         # the abstract target's shardings place the tree during restore;
@@ -614,6 +618,7 @@ def _disagg_prefill_main(settings: ServeSettings) -> dict:
         max_prompt_len=max_prompt_len, max_len=max_len,
         temperature=settings.temperature, top_k=settings.top_k,
         top_p=settings.top_p, seed=settings.seed, mesh=mesh)
+    del params  # the engine holds its serving form; let the masters go
     kv_link = FileStageLink(
         os.path.join(settings.disagg_links, f"kv_{rid}"),
         capacity=8, tracer=proto.tracer)
@@ -749,6 +754,7 @@ def _disagg_decode_main(settings: ServeSettings) -> dict:
         eos_id=settings.eos_id if settings.eos_id >= 0 else None,
         mesh=mesh, sanitize=settings.sanitize,
         decode_impl=settings.decode_impl)
+    del params  # the engine holds its serving form; let the masters go
     n_peers = max(1, settings.disagg_peers)
     kv_links = [FileStageLink(
         os.path.join(settings.disagg_links, f"kv_{i}"),

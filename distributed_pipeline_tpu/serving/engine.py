@@ -38,8 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.sampling import _truncate_logits
+from ..obs import trace as trace_lib
 from ..parallel.sharding import replicated
-from ..utils.perf import AOTStep
+from ..utils.perf import AOTStep, tree_bytes
 
 __all__ = ["DecodeEngine"]
 
@@ -90,9 +91,21 @@ class DecodeEngine:
 
     Parameters
     ----------
-    workload, params : the model and its live parameter tree (passed
-        through untouched — whatever sharding they carry is what the
-        executables compile against). Either the named-blocks flax causal
+    workload, params : the model and its parameter tree, as training left
+        it or as a server already holds it. The engine keeps the model's
+        SERVING FORM of the tree (``model.serving_variables``, made once
+        here and at each :meth:`set_params`; ``self.params``), and every
+        executable compiles against and is called with that: of the flax
+        causal LM the block matrices and the tied head's table in the
+        compute dtype — the cast each use makes inside a program, made
+        once, so a decode step reads half the bytes of float32 masters and
+        logits are bit for bit the same — beside float32 embeddings and
+        LayerNorm leaves. A leaf that already has its dtype is the
+        caller's own array (never copied; its sharding is what the
+        executables compile against), and a model that declares no
+        serving form is held untouched. ``weights`` says what was done:
+        ``bytes_in``, ``bytes_serving``, ``leaves_cast``. Either the
+        named-blocks flax causal
         LM, whose backbone holds the paged K/V branch, or a model that
         brings its own paged-cache functions (``chunked_prefill``,
         ``cache_shapes``, ``prefill_chunk``, ``decode_step``:
@@ -136,7 +149,8 @@ class DecodeEngine:
                  mesh=None, transfer_guard: bool = False,
                  decode_impl: str = "auto", kv_quant: str = "fp",
                  spec_tokens: int = 0,
-                 on_compile: Optional[Callable[[str, float], None]] = None):
+                 on_compile: Optional[Callable[[str, float], None]] = None,
+                 tracer: Any = None):
         model = workload.model
         # by what the model can do, not by its family's name: either it
         # brings its own paged-cache functions and a chunked prefill
@@ -203,7 +217,9 @@ class DecodeEngine:
                              f"page), got {max_pages}")
         self.mesh = mesh
         self._guard = transfer_guard
-        self.params = params
+        self._serving_form = getattr(model, "serving_variables", None)
+        self._tracer = tracer if tracer is not None else trace_lib.FOLLOW
+        self.set_params(params)
         self.compile_time_s = 0.0
         self.compile_times: dict = {}  # per program: serve_prefill, ...
         self._on_compile = on_compile
@@ -379,7 +395,7 @@ class DecodeEngine:
             cache_abs = jax.eval_shape(
                 lambda p, i, m, bt: dm.apply(p, i, m, block_table=bt,
                                              mutable=["cache"])[1]["cache"],
-                params, ids0, pad0, bt0)
+                self.params, ids0, pad0, bt0)
 
         okw_p: dict = {}
         okw_d: dict = {}
@@ -485,6 +501,24 @@ class DecodeEngine:
         if self._on_compile is not None:
             self._on_compile(name, seconds)
 
+    def set_params(self, params) -> None:
+        """Hold ``params`` in the model's serving form (class docstring):
+        construction and the hot swap come through here, so a swapped
+        tree meets the executables' pinned signature with the dtypes the
+        first one had. Leaf by leaf and outside any jit: what is right
+        already stays the caller's buffer."""
+        with self._tracer.span("serve.weights", "serve") as sp:
+            held = (params if self._serving_form is None
+                    else self._serving_form(params))
+            given = {id(x): x for x in jax.tree_util.tree_leaves(params)}
+            kept = {id(x): x for x in jax.tree_util.tree_leaves(held)}
+            self.weights = {
+                "bytes_in": tree_bytes(list(given.values())),
+                "bytes_serving": tree_bytes(list(kept.values())),
+                "leaves_cast": len(kept.keys() - given.keys())}
+            sp.args = dict(self.weights)
+            self.params = held
+
     def set_rng(self, key: jax.Array) -> None:
         """Swap the sampling key (a dispatch ARGUMENT, so no recompile)."""
         self._key = self._put_key(key)
@@ -541,8 +575,7 @@ class DecodeEngine:
         leaves += [leaf for path, leaf in
                    jax.tree_util.tree_flatten_with_path(self.cache)[0]
                    if _is_window(path)]
-        return int(sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
-                       for leaf in leaves))
+        return tree_bytes(leaves)
 
     def extract_pages(self, page_ids: np.ndarray) -> Dict[str, np.ndarray]:
         """Pull the contents of ``page_ids`` out of every pool leaf as

@@ -168,7 +168,8 @@ class DecodeServer:
                 temperature=temperature,
                 top_k=top_k, top_p=top_p, rng=rng, seed=seed, mesh=mesh,
                 transfer_guard=sanitize, decode_impl=decode_impl,
-                kv_quant=kv_quant, spec_tokens=spec_tokens)
+                kv_quant=kv_quant, spec_tokens=spec_tokens,
+                tracer=self.tracer)
             if self.engine.chunked and prefix_cache:
                 raise NotImplementedError(
                     "the prefix cache skips whole prompt prefills; a "
@@ -181,8 +182,10 @@ class DecodeServer:
                 # pages [1 + s*pps, 1 + (s+1)*pps) forever, so the draft
                 # needs no allocator and rollback is just the host state
                 # push each round (accepted draft K/V is valid by the
-                # acceptance rule: d_j == g_{j-1}).
-                dwl, dparams = truncated_draft(workload, params,
+                # acceptance rule: d_j == g_{j-1}). Views of the leaves
+                # the TARGET holds: one serving copy of the weights for
+                # both (the draft engine's own pass finds nothing to cast).
+                dwl, dparams = truncated_draft(workload, self.engine.params,
                                                draft_layers)
                 pps = self.engine.pages_per_slot
                 self._draft_engine = DecodeEngine(
@@ -193,7 +196,7 @@ class DecodeServer:
                     prefill_batch=prefill_batch, decode_span=1,
                     temperature=0.0, seed=seed, mesh=mesh,
                     transfer_guard=sanitize, decode_impl=decode_impl,
-                    kv_quant=kv_quant)
+                    kv_quant=kv_quant, tracer=self.tracer)
                 self._draft_tables = np.arange(
                     1, 1 + decode_slots * pps,
                     dtype=np.int32).reshape(decode_slots, pps)
@@ -295,18 +298,21 @@ class DecodeServer:
         return self.sanitize_report.write(out_dir)
 
     def set_params(self, params) -> None:
-        """Hot-swap surface: replace the target's weights AND rebuild the
-        model draft's early-exit views from the swapped tree (the draft
-        leaves are references into ``params``, so this is re-indexing,
-        not a second restore). Callers that poke ``engine.params``
-        directly would leave a model draft proposing from stale weights —
-        harmless for correctness (every token is target-verified) but a
-        silent accept-rate regression."""
-        self.engine.params = params
+        """Hot-swap surface: replace the target's weights — through the
+        engine's own ``set_params``, so the swapped tree is held in the
+        same serving form (and dtypes) the executables were compiled
+        against — AND rebuild the model draft's early-exit views from
+        what the target now holds (the draft leaves are references into
+        that tree, so this is re-indexing, not a second restore or cast).
+        Callers that poke ``engine.params`` directly would leave a model
+        draft proposing from stale weights — harmless for correctness
+        (every token is target-verified) but a silent accept-rate
+        regression."""
+        self.engine.set_params(params)
         if self._draft_engine is not None:
-            _, dparams = truncated_draft(self.workload, params,
+            _, dparams = truncated_draft(self.workload, self.engine.params,
                                          self._draft_layers)
-            self._draft_engine.params = dparams
+            self._draft_engine.set_params(dparams)
 
     @property
     def free_slots(self) -> int:
@@ -359,7 +365,8 @@ class DecodeServer:
         [prefill_batch, max_prompt_len] shape regardless of actual
         prompt lengths; the rows are the engine's token budget unless
         the caller named them, so a lone admission pads one row of 512
-        and not eight). ``n_devices`` defaults to 1: decode state is
+        and not eight). A ``weights`` row says what the engine holds of
+        the tree it was given. ``n_devices`` defaults to 1: decode state is
         replicated, so the service rate IS the per-chip rate (the
         measure_decode rationale)."""
         from ..obs import ledger as ledger_lib
@@ -427,6 +434,9 @@ class DecodeServer:
                     / self.prefill_token_slots
                     if self.prefill_token_slots > 0 else 0.0)
             rows[f"serve_{name}"] = row
+        # what the engine holds of the tree it was given (its serving
+        # form): bytes in, bytes held, leaves cast
+        rows["weights"] = dict(self.engine.weights)
         return rows
 
     # ------------------------------------------------------------ lifecycle

@@ -300,6 +300,21 @@ def results_of_size(text, n_elements, op=None, exact=False):
     return found
 
 
+def entry_parameters(text):
+    """(dtype, dims) of the compiled program's own arguments: the
+    ``parameter`` lines of its ENTRY computation (a fusion's body has
+    parameters of its own)."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    found = []
+    for ln in entry.splitlines():
+        m = _HLO_OP.search(ln)
+        if m and m.group(3) == "parameter":
+            found.append((m.group(1), tuple(
+                int(d) for d in m.group(2).split(",") if d)))
+    return found
+
+
 @pytest.mark.parametrize("heads", [12, 20])
 @pytest.mark.parametrize("kv_quant", ["fp", "int8"], ids=["bf16", "int8"])
 @pytest.mark.parametrize("program", ["decode", "prefill", "prefill1",
@@ -320,7 +335,14 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
     under 1024) and holds no kernel; it is held at eight rows (what a
     caller may still ask for) and, as ``prefill1``, at the ONE row the
     engine's token budget resolves for ``max_prompt_len`` 512 (PR 32: the
-    shape the serve cell runs)."""
+    shape the serve cell runs).
+
+    Since PR 34 the engine, built on a described float32 tree, holds and
+    compiles against the model's serving form: no program takes a block
+    matrix or the head's table in float32 or converts one (3.09 GB read
+    and cast in every decode step of GPT-2-large, PERF.md PR 34); the
+    float32 arguments left are the LayerNorm vectors, an int8 pool's
+    scales, and the two embedding tables the lookup gathers from."""
     from flax import linen as nn
 
     from distributed_pipeline_tpu.models import create_model_from_config
@@ -365,8 +387,20 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
     else:
         step, args = eng._verify_step, (
             i32(span, slots), *state, i32(slots, n), i32(slots), key)
-    text = step._jitted.lower(params, cache, *args).compile().as_text()
+    assert eng.weights["leaves_cast"] == 4 * layers + 1
+    text = step._jitted.lower(eng.params, cache, *args).compile().as_text()
     assert results_of_size(text, pools[0].size, op="copy") == []
+    d, vocab = 64 * heads, 1000
+    given = entry_parameters(text)
+    matrices = {(d, 3, heads, 64), (heads, 64, d), (d, 4 * d), (4 * d, d)}
+    assert {dims for dt, dims in given if dt == "bf16"} >= matrices | {
+        (vocab, d)}
+    assert {dims for dt, dims in given if dt == "f32" and len(dims) > 1} \
+        == {(vocab, d), (n * ps, d)}
+    assert {dims for dt, dims in given if dt == "f32" and len(dims) == 1} \
+        <= {(d,), (1 + slots * n,)}
+    for size in (3 * d * d, d * d, 4 * d * d, vocab * d):
+        assert results_of_size(text, size, op="convert", exact=True) == []
     if program.startswith("prefill"):
         assert "tpu_custom_call" not in text
         return

@@ -331,6 +331,11 @@ def test_serving_ledger_rows_and_padding_hand_count():
     assert pre["padding_waste_frac"] == pytest.approx(1 - 15 / 32)
     # decode occupancy waste: dispatches with one empty slot accrue it
     assert 0 <= dec["padding_waste_frac"] < 1
+    # what the engine holds of its tree (a float32 model: as given)
+    from distributed_pipeline_tpu.run.perf_report import render
+    assert rows["weights"] == server.engine.weights
+    assert rows["weights"]["leaves_cast"] == 0
+    assert "0 leaves cast" in render({"programs": rows})
 
 
 # ----------------------------------------------------------------- GL010

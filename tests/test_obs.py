@@ -197,7 +197,8 @@ TRAIN_SPANS = ("train.next_batch", "data.host_wait", "data.h2d",
                "train.log")
 SERVE_SPANS = ("serve.step", "serve.sweep", "serve.admit",
                "serve.prefill_dispatch", "serve.decode_dispatch",
-               "serve.fetch", "serve.fetch_wait", "serve.spec_round")
+               "serve.fetch", "serve.fetch_wait", "serve.spec_round",
+               "serve.weights")
 REQUEST_SPANS = ("request.queue", "request.first_token", "request.decode")
 PARENTS = {   # span -> the spans it may lie directly beneath
     "data.host_wait": {"train.next_batch"},
@@ -211,6 +212,7 @@ PARENTS = {   # span -> the spans it may lie directly beneath
     "serve.fetch": {"serve.step", None},              # None: drain's last
     "serve.fetch_wait": {"serve.fetch", "serve.spec_round"},
     "serve.spec_round": {"serve.step"},
+    "serve.weights": {None},          # construction and the hot swap
 }
 
 
@@ -269,6 +271,7 @@ def profiled_session(tmp_path_factory):
         reqs.append(spec.submit(      # its own counter: ids would collide
             prompt, 6, trace_id=trace_lib.request_trace_id(4243)))
         spec.drain()
+        server.set_params(params)     # the hot swap: one serve.weights
         jax.profiler.stop_trace()
         for loop in (fed, eager):
             loop.run_step(loop.next_batch())   # after it: ring untouched
@@ -328,12 +331,58 @@ def test_span_arguments_reach_both_sinks(profiled_session):
     admits = [e["args"] for e in ring if e["name"] == "serve.admit"]
     assert sum(a["n"] for a in admits) == 4
     assert sum(a["prompt_tokens"] for a in admits) == 5 + 3 + 4 + 5
+    (held,) = [e["args"] for e in ring if e["name"] == "serve.weights"]
+    # a float32 model's tree is right as it is: nothing cast, same bytes
+    assert held["leaves_cast"] == 0 < held["bytes_in"] \
+        == held["bytes_serving"]
     fetched = sum(e["args"]["n_tokens"] for e in ring
                   if e["name"] == "serve.fetch")
     # the plain server's tokens, and the one token the speculative server
     # fetches from its prefill (its rounds fetch inside serve.spec_round)
     assert fetched == 1 + sum(len(r.tokens)
                               for r in profiled_session["reqs"][:3])
+
+
+def test_serve_weights_span_says_what_the_engine_holds(tmp_path):
+    """``serve.weights`` (cat ``serve``) lies round the serving copy of
+    the weights, at construction and at each ``set_params``, target and
+    draft engine each: bytes given, bytes held, leaves cast — the engine's
+    own ``weights`` and the cost ledger's ``weights`` row."""
+    import jax
+
+    from distributed_pipeline_tpu.models import create_model_from_config
+    from distributed_pipeline_tpu.serving import DecodeServer
+
+    wl = create_model_from_config(
+        model_family="gpt2", vocab_size=64, seq_len=16, hidden_size=32,
+        num_layers=2, num_heads=2, dtype="bfloat16")
+    params = wl.init_params(jax.random.PRNGKey(3))
+    tr = trace_lib.tracer_for(str(tmp_path), 0, armed=True, proc="r0.rank0")
+    server = DecodeServer(wl, params, decode_slots=2, page_size=4,
+                          max_prompt_len=8, max_len=16, spec_tokens=2,
+                          spec_draft="model", draft_layers=1, tracer=tr)
+    server.set_params(wl.init_params(jax.random.PRNGKey(4)))
+    req = server.submit(np.arange(1, 6, dtype=np.int32), 3)
+    server.drain()
+    tr.close()
+    spans = [e for e in trace_lib.read_trace(
+        trace_lib.trace_path(str(tmp_path), 0))
+        if e["name"] == "serve.weights"]
+    assert [e["cat"] for e in spans] == ["serve"] * 4
+    n_params = wl.param_count(params)
+    cast = {"bytes_in": 4 * n_params, "leaves_cast": 2 * 4 + 1,
+            "bytes_serving": server.engine.weights["bytes_serving"]}
+    # target then draft, twice; the draft is views of what the target
+    # holds (one block of two, nothing left to cast)
+    assert spans[0]["args"] == spans[2]["args"] == cast
+    assert spans[1]["args"] == spans[3]["args"] \
+        == server._draft_engine.weights
+    assert spans[1]["args"]["leaves_cast"] == 0
+    assert 2 * n_params < cast["bytes_serving"] < 3 * n_params
+    assert server.engine.weights == cast and req.finished
+    rows = server.cost_ledger(wall_s=1.0, n_devices=1)
+    assert rows["weights"] == cast
+    assert set(rows) == {"serve_prefill", "serve_verify", "weights"}
 
 
 def test_children_lie_inside_parents(profiled_session):

@@ -689,6 +689,239 @@ def test_engine_rejects_unsupported_models(wl_and_params):
                      max_prompt_len=SEQ + 1)
 
 
+# ------------------------- the serving form of the weights (ISSUE 34)
+
+def _leaves(tree):
+    return jax.tree_util.tree_leaves(tree)
+
+
+def _by_path(tree):
+    return {jax.tree_util.keystr(path): leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+MATMUL_LEAVES = ("['attn']['qkv']", "['attn']['out']", "['mlp']['wi']",
+                 "['mlp']['wo']", "['moe']['wi']", "['moe']['wo']")
+
+
+class AsTrained:
+    """The model with no serving form declared: an engine holds such a
+    model's float32 tree untouched and every program casts it at each use,
+    as every engine did before ISSUE 34. The twin the served tokens are
+    held to."""
+
+    def __init__(self, model):
+        self._model = model
+
+    def __getattr__(self, name):
+        if name == "serving_variables":
+            raise AttributeError(name)
+        return getattr(self._model, name)
+
+
+def as_trained(wl):
+    import dataclasses
+    return dataclasses.replace(wl, model=AsTrained(wl.model))
+
+
+@pytest.fixture(scope="module", params=["dense", "moe"])
+def bf16_wl_and_params(request):
+    """bfloat16 compute over float32 masters, dense and with experts in
+    every second block."""
+    wl = tiny_workload(dtype="bfloat16",
+                       moe_experts=4 if request.param == "moe" else 0)
+    return wl, wl.init_params(jax.random.PRNGKey(3))
+
+
+@pytest.mark.parametrize("shape", ["prefill", "decode"])
+def test_serving_form_logits_are_bit_identical(bf16_wl_and_params, shape):
+    """float32 -> bfloat16 is the same rounding outside a program as
+    inside it: the logits of the serving form ARE the float32 tree's, for
+    a whole-prompt call and for a one-token call over a cache."""
+    wl, params = bf16_wl_and_params
+    served = wl.model.serving_variables(params)
+    dm = wl.model.clone(decode=True, moe_no_drop=True)
+    ids = jnp.asarray(prompt_ids(seed=6))
+
+    @jax.jit
+    def prefill(p):
+        logits, mvars = dm.apply(p, ids, None, mutable=["cache"])
+        return logits, mvars["cache"]
+
+    @jax.jit
+    def decode(p, cache):
+        return dm.apply({**p, "cache": cache}, ids[:, 5:6], None,
+                        cache_index=jnp.asarray(5, jnp.int32),
+                        mutable=["cache"])[0]
+
+    want, cache = prefill(params)
+    got, cache_s = prefill(served)
+    if shape == "decode":
+        want, got = decode(params, cache), decode(served, cache_s)
+    assert want.dtype == jnp.bfloat16 and want.shape[-1] == VOCAB
+    assert float(jnp.max(jnp.abs(want.astype(jnp.float32)))) > 0.1
+    assert np.array_equal(np.asarray(want.astype(jnp.float32)),
+                          np.asarray(got.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("kw", [
+    dict(), dict(kv_quant="int8"),
+    dict(spec_tokens=2, spec_draft="model", draft_layers=1)],
+    ids=["fp", "int8", "spec"])
+def test_server_on_the_serving_form_serves_the_float32_trees_tokens(
+        bf16_wl_and_params, kw):
+    """A server casts once what each program cast at each use: token for
+    token what the twin that still casts in its programs serves, from the
+    fp pool, the int8 pool and through the speculative verify; and what
+    the dense decode of the float32 tree picks (held on the first token,
+    which the prefill's logits decide: test_merged_head_axis_pool's note
+    on bfloat16 near-ties between different CPU programs)."""
+    wl, params = bf16_wl_and_params
+    geometry = dict(decode_slots=4, page_size=4, max_prompt_len=SEQ,
+                    max_len=SEQ, prefill_batch=4, seed=0)
+    ids = prompt_ids(seed=8)
+    plen = SEQ // 2
+    srv = DecodeServer(wl, params, **geometry, **kw)
+    assert srv.engine.weights["leaves_cast"] > 0
+    twin = DecodeServer(as_trained(wl), params, **geometry, **kw)
+    assert twin.engine.weights["leaves_cast"] == 0
+    assert all(a is b for a, b in zip(_leaves(twin.engine.params),
+                                      _leaves(params)))
+    got = one_shot_decode(wl, params, ids, plen, server=srv)
+    want = one_shot_decode(wl, params, ids, plen, server=twin)
+    np.testing.assert_array_equal(got, want)
+    dense = np.asarray(gpt2_decode(wl, params, jnp.asarray(ids), plen,
+                                   use_cache=True))
+    np.testing.assert_array_equal(got[:, :plen + 1], dense[:, :plen + 1])
+    if kw.get("spec_tokens"):
+        assert srv.spec_rounds > 0 and srv.draft_proposed > 0
+
+
+def test_engine_holds_matrices_in_the_compute_dtype(bf16_wl_and_params):
+    """Every matrix a module casts before its one use is bfloat16 in
+    ``engine.params``; LayerNorm leaves, the router and BOTH embedding
+    tables stay float32 (the lookup's sum rounds once), the tied head
+    reads a bfloat16 copy carried in a collection of its own, and the
+    parameter count is the tree's."""
+    from flax import linen as nn
+    wl, params = bf16_wl_and_params
+    srv = DecodeServer(wl, params, decode_slots=2, page_size=4,
+                       max_prompt_len=8, spec_tokens=2, spec_draft="model",
+                       draft_layers=1)
+    held = srv.engine.params
+    assert set(held) == {"params", "serving"}
+    leaves = _by_path(nn.meta.unbox(held["params"]))
+    given = _by_path(nn.meta.unbox(params["params"]))
+    assert set(leaves) == set(given)
+    cast = [k for k in leaves if k.endswith(MATMUL_LEAVES)]
+    assert len(cast) == 2 * 4              # two blocks: qkv, out, wi, wo
+    for key, leaf in leaves.items():
+        assert leaf.shape == given[key].shape
+        assert leaf.dtype == (jnp.bfloat16 if key in cast else jnp.float32)
+    assert leaves["['word_emb']['embedding']"] is given[
+        "['word_emb']['embedding']"]
+    assert leaves["['pos_emb']"] is given["['pos_emb']"]
+    head = held["serving"]["head"]
+    assert head.dtype == jnp.bfloat16 and head.shape == (VOCAB, 32)
+    assert wl.param_count(held) == wl.param_count(params)
+    w = srv.engine.weights
+    assert w["leaves_cast"] == len(cast) + 1          # and the head
+    assert w["bytes_in"] == 4 * wl.param_count(params)
+    n_cast = sum(leaves[k].size for k in cast)
+    assert w["bytes_serving"] == w["bytes_in"] - 2 * n_cast + 2 * head.size
+    # the draft's blocks and head ARE the target's: one copy for both
+    draft = srv._draft_engine
+    assert draft.weights["leaves_cast"] == 0
+    mine = {id(x) for x in _leaves(held)}
+    assert all(id(x) in mine for x in _leaves(draft.params))
+    assert draft.params["serving"]["head"] is head
+
+
+@pytest.mark.parametrize("case", ["float32_model", "served_tree",
+                                  "described_tree", "chunked_family"])
+def test_a_tree_that_is_right_is_never_copied(case):
+    """A leaf already in its dtype is the caller's own array in
+    ``engine.params``, whatever the tree's size (an 11 GB bfloat16 tree on
+    a 16 GB chip cannot be copied); a described tree is re-described; a
+    model that declares no serving form (the chunked family) is held
+    untouched."""
+    from distributed_pipeline_tpu.serving.engine import DecodeEngine
+    geometry = dict(decode_slots=2, page_size=4, max_pages=9,
+                    max_prompt_len=8)
+    if case == "chunked_family":
+        from tests.test_deepseek_v32 import TINY, build
+        wl, _, tree = build(dict(TINY, dtype="bfloat16",
+                                 param_dtype="bfloat16"))
+        eng = DecodeEngine(wl, tree, max_len=32, **geometry)
+        assert eng.chunked and eng.params is tree
+    elif case == "described_tree":
+        from flax import linen as nn
+        wl = tiny_workload(dtype="bfloat16")
+        tree = nn.meta.unbox(jax.eval_shape(wl.init_params,
+                                            jax.random.PRNGKey(0)))
+        eng = DecodeEngine(wl, tree, **geometry)
+        want = jax.eval_shape(wl.model.serving_variables, tree)
+        assert jax.tree_util.tree_structure(eng.params) == \
+            jax.tree_util.tree_structure(want)
+        assert all(isinstance(a, jax.ShapeDtypeStruct)
+                   and (a.shape, a.dtype) == (b.shape, b.dtype)
+                   for a, b in zip(_leaves(eng.params), _leaves(want)))
+        assert eng.weights["leaves_cast"] == 2 * 4 + 1
+        return
+    else:
+        wl = tiny_workload(
+            dtype="float32" if case == "float32_model" else "bfloat16")
+        tree = wl.init_params(jax.random.PRNGKey(3))
+        if case == "served_tree":
+            tree = wl.model.serving_variables(tree)
+        eng = DecodeEngine(wl, tree, **geometry)
+        assert wl.param_count(eng.params) == wl.param_count(tree)
+    given = {id(x) for x in _leaves(tree)}
+    assert all(id(x) in given for x in _leaves(eng.params))
+    assert eng.weights["leaves_cast"] == 0
+    assert eng.weights["bytes_serving"] == eng.weights["bytes_in"]
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+def test_hot_swap_recasts_and_recompiles_nothing(spec):
+    """``set_params`` with a float32 tree after the first token: the
+    engine holds the new tree's serving form (the dtypes the executables
+    were compiled against), new tokens follow the new weights, the model
+    draft's views follow, and nothing compiles in steady state."""
+    wl = tiny_workload(dtype="bfloat16")
+    old = wl.init_params(jax.random.PRNGKey(3))
+    new = wl.init_params(jax.random.PRNGKey(4))
+    kw = dict(decode_slots=2, page_size=4, max_prompt_len=8, max_len=SEQ,
+              seed=0)
+    if spec:
+        kw.update(spec_tokens=2, spec_draft="model", draft_layers=1)
+    prompt = np.arange(4, 10, dtype=np.int32)
+    fresh = DecodeServer(wl, new, **kw)
+    want_new = fresh.submit(prompt, max_new_tokens=6)
+    fresh.drain()
+    srv = DecodeServer(wl, old, sanitize=True, **kw)
+    try:
+        was = srv.submit(prompt, max_new_tokens=6)
+        srv.drain()
+        assert was.tokens != want_new.tokens
+        steady = srv.recompile_count
+        dtypes = [x.dtype for x in _leaves(srv.engine.params)]
+        srv.set_params(new)
+        assert [x.dtype for x in _leaves(srv.engine.params)] == dtypes
+        assert srv.engine.weights["leaves_cast"] == 2 * 4 + 1
+        got = srv.submit(prompt, max_new_tokens=6)
+        srv.drain()
+        assert got.tokens == want_new.tokens
+        assert srv.recompile_count == steady
+        if spec:
+            held = {id(x) for x in _leaves(srv.engine.params)}
+            assert all(id(x) in held
+                       for x in _leaves(srv._draft_engine.params))
+            assert srv._draft_engine.weights["leaves_cast"] == 0
+    finally:
+        srv.stop_sanitizer()
+
+
 # ------------------------------------------------------- entry wiring
 
 def _train_tiny_gpt2_run(tmp_path):
