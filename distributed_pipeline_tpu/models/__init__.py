@@ -14,7 +14,12 @@ entry point makes (``run/train.py:71`` passes ``**args.dict()``):
 * ``dots3_note`` — dots3-note-prev likewise (models/dots3_note.py: the
   configuration; the layers are ``deepseek_v32``'s, told their kind): full
   latent-attention layers with the indexer beside window layers whose cache
-  is a ring a slot, headwise output gates.
+  is a ring a slot, headwise output gates;
+* ``keye_vl2`` — Keye-VL-2.0's language model as one pipeline stage with
+  every expert held (models/keye_vl2.py): grouped-query attention through
+  the lightning indexer over a paged K/V pool, rotary positions, head
+  norms, an untied head, and a sorted, grouped pass over the experts; on
+  the same chunked-prefill seam.
 
 The factory returns a :class:`Workload`: the flax module plus pure
 ``init_params`` / ``compute_losses`` functions — the reference's user-hook
@@ -39,6 +44,7 @@ from .diffuseq import DiffuSeqModel, diffuseq_losses
 from .diffusion import DiffusionSchedule, make_schedule
 from .dots3_note import Dots3NoteConfig
 from .gpt2 import GPT2Model, gpt2_losses
+from .keye_vl2 import KeyeVL2Config, SparseGQAMoEModel
 
 __all__ = [
     "Workload", "create_model_from_config", "seed_all", "PRESETS",
@@ -62,9 +68,13 @@ PRESETS: Dict[str, Dict[str, Tuple[int, int, int]]] = {
     # the published model; a deployment's cut comes through `arch`
     "deepseek_v32": {"base": (7168, 61, 128)},
     "dots3_note": {"base": (5120, 46, 128)},
+    "keye_vl2": {"base": (2048, 48, 32)},
 }
-# the families LatentMoEModel runs, by the class that reads their `arch`
-LATENT_MOE = {"deepseek_v32": DeepseekV32Config, "dots3_note": Dots3NoteConfig}
+# the served-only families of plain functions on the chunked-prefill seam:
+# (the class that reads their `arch`, the model that runs it)
+CHUNKED = {"deepseek_v32": (DeepseekV32Config, LatentMoEModel),
+           "dots3_note": (Dots3NoteConfig, LatentMoEModel),
+           "keye_vl2": (KeyeVL2Config, SparseGQAMoEModel)}
 DIFFUSEQ_EMB_DIM = 128  # DiffuSeq uses a low-dim embedding space
 
 
@@ -129,9 +139,9 @@ def _example_batch_fn(seq_len: int) -> Callable[[int], Dict[str, np.ndarray]]:
 
 def _served_not_trained(params, batch, rng):
     raise NotImplementedError(
-        "a latent-attention share (deepseek_v32, dots3_note) is served, not "
-        "trained: it has no loss, no partition rules and forward-only "
-        "kernels (ROADMAP R3)")
+        "a chunked-prefill family (deepseek_v32, dots3_note, keye_vl2) is "
+        "served, not trained: it has no loss, no partition rules and "
+        "forward-only kernels (ROADMAP R3)")
 
 
 def create_model_from_config(*, model_family: str = "diffuseq",
@@ -173,13 +183,14 @@ def create_model_from_config(*, model_family: str = "diffuseq",
     heads = num_heads or preset[2]
     jdtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
 
-    if model_family in LATENT_MOE:
+    if model_family in CHUNKED:
         # explicit size flags win over `arch` (a dict of the source's
         # keys); the presets' zeros leave it alone
-        cfg = LATENT_MOE[model_family].from_arch(
+        config_cls, model_cls = CHUNKED[model_family]
+        cfg = config_cls.from_arch(
             arch or {}, vocab_size=vocab_size, hidden_size=hidden_size,
             n_layers=num_layers, num_attention_heads=num_heads)
-        model = LatentMoEModel(cfg=cfg, seq_len=seq_len, dtype=jdtype)
+        model = model_cls(cfg=cfg, seq_len=seq_len, dtype=jdtype)
         return Workload(
             model=model, family=model_family, seq_len=seq_len,
             hidden_size=cfg.hidden_size, num_layers=cfg.n_layers,

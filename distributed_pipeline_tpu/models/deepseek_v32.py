@@ -48,6 +48,12 @@ index scores and the softmax are float32; matmul operands are ``dtype``
 RoPE layouts (the file of the configuration states them too): MLA rotates
 interleaved pairs in place, the indexer rotates split halves; YaRN's
 ``mscale`` enters through the softmax scale, as the source has it.
+
+The prefill's selection (index scores block by block, the radix select for
+each query's k-th score, the tie-break) and the choice of arm of the two
+Pallas-backed pieces live in models/sparse_select.py, shared with
+models/keye_vl2.py, which also takes ``route``, ``_best_first``,
+``rms_norm``, ``layer_norm``, ``rope_halves`` and ``_angles`` from here.
 """
 
 from __future__ import annotations
@@ -61,7 +67,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import mla_attention
+from . import sparse_select
+from .sparse_select import NEG
 
 __all__ = ["DeepseekV32Config", "LatentMoEModel", "LayerKind", "COUNTERS",
            "WINDOW_COUNTERS", "route"]
@@ -76,7 +83,6 @@ COUNTERS = ("expert_assignments_held", "experts_touched",
 # and rows a cache that never frees would hold live for them (context x
 # window layers); the three above then count the full layers only
 WINDOW_COUNTERS = ("window_rows_attended", "window_rows_live")
-NEG = mla_attention.NEG   # "masked" in float32 score space (finite)
 TRASH_PAGE = 0       # serving/paged_kv.py's reserved page
 KV_BLOCK = 512       # rows of context the chunked prefill reads a step
 
@@ -324,10 +330,29 @@ def _best_first(x: jnp.ndarray, k: int) -> jnp.ndarray:
     return jnp.stack(picks, -1)
 
 
-def _sortable(x: jnp.ndarray) -> jnp.ndarray:
-    """float32 -> uint32 with the same order."""
-    u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
-    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
+def init_params(shapes: Dict[str, Any], rng: jax.Array, std: float,
+                dtype: Any, embed_std: Optional[float] = None
+                ) -> Dict[str, Any]:
+    """A parameter tree of ``shapes`` (nested dicts of tuples): normal(0,
+    ``std``) matrices (``embed`` at ``embed_std`` where given), unit norm
+    scales, zero biases (a router's correction bias float32)."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    leaves = []
+    for i, (path, shape) in enumerate(flat):
+        name = path[-1].key
+        if name == "router_bias":
+            leaves.append(jnp.zeros(shape, jnp.float32))
+        elif name.endswith(("norm", "norm_g", "norm_f")):
+            leaves.append(jnp.ones(shape, dtype))
+        elif name.endswith("norm_b"):
+            leaves.append(jnp.zeros(shape, dtype))
+        else:
+            scale = embed_std if name == "embed" and embed_std else std
+            leaves.append((scale * jax.random.normal(
+                jax.random.fold_in(rng, i), shape, jnp.float32)
+            ).astype(dtype))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 # -------------------------------------------------------------- the model
@@ -416,23 +441,9 @@ class LatentMoEModel:
     def init(self, rng: jax.Array, *_example: Any) -> Dict[str, Any]:
         """``{"params": tree}``: normal(0, initializer_range) matrices, unit
         norm scales, zero biases (the router's correction bias float32)."""
-        std = self.cfg.initializer_range
-        flat, treedef = jax.tree_util.tree_flatten_with_path(
-            self.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
-        leaves = []
-        for i, (path, shape) in enumerate(flat):
-            name = path[-1].key
-            if name == "router_bias":
-                leaves.append(jnp.zeros(shape, jnp.float32))
-            elif name.endswith(("norm", "norm_g", "norm_f")):
-                leaves.append(jnp.ones(shape, self.dtype))
-            elif name.endswith("norm_b"):
-                leaves.append(jnp.zeros(shape, self.dtype))
-            else:
-                leaves.append((std * jax.random.normal(
-                    jax.random.fold_in(rng, i), shape, jnp.float32)
-                ).astype(self.dtype))
-        return {"params": jax.tree_util.tree_unflatten(treedef, leaves)}
+        return {"params": init_params(
+            self.param_shapes(), rng, self.cfg.initializer_range,
+            self.dtype)}
 
     # --------------------------------------------------------------- cache
 
@@ -458,17 +469,6 @@ class LatentMoEModel:
     def _mm(self, a: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
         return jnp.dot(a.astype(self.dtype), w.astype(self.dtype),
                        preferred_element_type=jnp.float32)
-
-    def _block_attend(self, q_t, k, v_t, bias, carry, scale: float):
-        """One context block of the prefill's attention
-        (ops/mla_attention.py)."""
-        impl = self._on_chip(q_t.shape[2], k.shape[1])
-        if impl == "xla":
-            return mla_attention.block_attend_xla(
-                q_t, k, v_t, bias, carry, scale=scale)
-        return mla_attention.block_attend(
-            q_t, k, v_t, bias, carry, scale=scale,
-            interpret=impl == "interpret")
 
     def _logits(self, p, hidden: jnp.ndarray) -> jnp.ndarray:
         """The untied head, stored [V, D] like the embedding: its rows are
@@ -581,26 +581,6 @@ class LatentMoEModel:
         return jnp.einsum("shc,chv->shv", o_lat.astype(self.dtype),
                           lp["wv_b"].reshape(k.kv_lora_rank, k.heads, dv),
                           preferred_element_type=jnp.float32)
-
-    def _on_chip(self, *sizes: int) -> str:
-        """Which arm a Pallas-backed piece takes: the kernel on the chip
-        where the sizes are whole tiles, plain XLA elsewhere (the CPU, a
-        tiny test shape); ``kernel_impl`` forces one."""
-        if self.kernel_impl != "auto":
-            return self.kernel_impl
-        aligned = all(n % 128 == 0 for n in sizes)
-        return "pallas" if (aligned and jax.default_backend() == "tpu"
-                            ) else "xla"
-
-    def _index_scores(self, qi_t, wi_t, ki_rows) -> jnp.ndarray:
-        """I[s, t] = sum_j w[t, j] relu(q[t, j] . k[s]) for one block of
-        keys, TRANSPOSED: ``qi_t`` [J, di, T], ``wi_t`` [J, 1, T],
-        ``ki_rows`` [S, di] -> [S, T] float32."""
-        impl = self._on_chip(qi_t.shape[2], ki_rows.shape[0])
-        if impl == "xla":
-            return mla_attention.index_scores_xla(qi_t, wi_t, ki_rows)
-        return mla_attention.index_scores(qi_t, wi_t, ki_rows,
-                                          interpret=impl == "interpret")
 
     def _ffn(self, lp, i: int, h: jnp.ndarray, live: jnp.ndarray,
              decode: bool = False
@@ -745,9 +725,9 @@ class LatentMoEModel:
                 m, l, acc, n_att = carry
                 keys, v_t = self._block_kv(k, wk, wv, block_rows(b))
                 sel = block_mask(b)
-                m, l, acc = self._block_attend(
-                    q_t, keys, v_t, jnp.where(sel, 0.0, NEG), (m, l, acc),
-                    k.softmax_scale)
+                m, l, acc = sparse_select.block_attend(
+                    self.kernel_impl, q_t, keys, v_t,
+                    jnp.where(sel, 0.0, NEG), (m, l, acc), k.softmax_scale)
                 return m, l, acc, n_att + jnp.sum(sel & valid[None, :],
                                                   dtype=jnp.int32)
             _, l, acc, n_att = jax.lax.fori_loop(
@@ -769,62 +749,17 @@ class LatentMoEModel:
             def block_pages(b):
                 return jax.lax.dynamic_slice(table, (b * pb,), (pb,))
 
-            # pass 1: index scores of the live context, block by block,
-            # kept TRANSPOSED ([keys, queries]: a block is whole rows, and
-            # the attention kernel wants its mask that way)
-            qi_t = qi.astype(self.dtype).transpose(1, 2, 0)      # [J, di, n]
-            wi_t = wi.T[:, None, :]                              # [J, 1, n]
-
-            def score_block(b, buf):
-                s = self._index_scores(
-                    qi_t, wi_t, idx[block_pages(b)].reshape(kb, -1))
-                s = jnp.where(key_pos(b)[:, None] <= pos[None, :], s, NEG)
-                return jax.lax.dynamic_update_slice(buf, s, (b * kb, 0))
-            scores = jax.lax.fori_loop(
-                0, n_blocks, score_block,
-                jnp.full((l_max, n), NEG, jnp.float32))
-
-            # each query's k-th largest score, exactly: the sortable keys'
-            # digits from the top, four bits a pass (a radix select over
-            # the live blocks: 8 passes, 15 counts each, one read a block)
-            def kth_key(keys):
-                def digit(j, prefix):
-                    shift = (28 - 4 * j).astype(jnp.uint32)
-                    cands = prefix[None, :] | (
-                        jnp.arange(1, 16, dtype=jnp.uint32)[:, None] << shift)
-
-                    def count(b, acc):
-                        blk = jax.lax.dynamic_slice(
-                            keys, (b * kb, 0), (kb, n))
-                        return acc + jnp.sum(
-                            blk[None] >= cands[:, None, :], 1,
-                            dtype=jnp.int32)
-                    cnt = jax.lax.fori_loop(
-                        0, n_blocks, count, jnp.zeros((15, n), jnp.int32))
-                    # counts fall as the digit rises: as many digits reach
-                    # k as the largest that does
-                    best = jnp.sum(cnt >= k_sel, 0).astype(jnp.uint32)
-                    return prefix | (best << shift)
-                return jax.lax.fori_loop(
-                    0, 8, digit, jnp.zeros((n,), jnp.uint32))
-            keys = _sortable(scores)
-            # with no more live rows than k every causal row is selected
-            threshold = jax.lax.cond(
-                live_len > k_sel, kth_key,
-                lambda keys: jnp.zeros((n,), jnp.uint32), keys)
-            selected = (keys >= threshold[None, :]) & (scores > NEG)
-
-            def break_ties(selected):
-                """Equal scores at the threshold: the earliest positions
-                take the places left, as ``lax.top_k`` (decode) does."""
-                above = (keys > threshold[None, :]) & (scores > NEG)
-                equal = selected & ~above
-                left = k_sel - jnp.sum(above, 0, dtype=jnp.int32)
-                rank = jnp.cumsum(equal, 0, dtype=jnp.int32)
-                return above | (equal & (rank <= left[None, :]))
-            selected = jax.lax.cond(
-                jnp.max(jnp.sum(selected, 0, dtype=jnp.int32)) > k_sel,
-                break_ties, lambda sel: sel, selected)
+            # pass 1: index scores of the live context and each query's
+            # top-k among them (models/sparse_select.py: scored block by
+            # block, a radix select for the k-th score, ties to the earlier
+            # position), kept TRANSPOSED ([keys, queries])
+            scores = sparse_select.score_context(
+                self.kernel_impl, qi.astype(self.dtype).transpose(1, 2, 0),
+                wi.T[:, None, :],
+                lambda b: idx[block_pages(b)].reshape(kb, -1), pos, kb,
+                n_blocks, l_max)
+            selected = sparse_select.select_top_k(
+                scores, live_len, k_sel, kb, n_blocks)
 
             # pass 2: attention over the selected rows
             o, n_att = walk(

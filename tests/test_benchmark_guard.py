@@ -2,7 +2,7 @@
 
 ``benchmark/`` measures the program from outside (``BENCHMARK.json``,
 ``benchmark/run.py``) and imports nothing of it, so nothing in ``tests/``
-ran it: its own tests, two of its three cells' rehearsals, and the names by
+ran it: its own tests, its cells' rehearsals, and the names by
 which its readers find the program's work in a trace. A renamed jitted
 function or ``pallas_call`` moves no end-to-end metric and passes every other
 test, and leaves a per-layer metric ``null`` from then on. Three guards:
@@ -69,7 +69,7 @@ def test_benchmark_own_tests_pass(path):
 def test_accepted_entries_stand_as_their_prs_listed_them():
     """The entry the deselected assertion above looked at, and the order
     the driver reads: what PR 32's benchmark had is a prefix of every list,
-    PR 33's entries lie behind it."""
+    PR 33's entries lie behind it and PR 35's behind those."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
@@ -82,13 +82,19 @@ def test_accepted_entries_stand_as_their_prs_listed_them():
         "step_mfu.serve.mixed", "prefill_mfu.serve.mixed",
         "decode_hbm_roofline.mixed_latent", "attended_kv_share.mixed",
         "mla_block_attend_roofline.mixed",
-        "lightning_index_scores_roofline.mixed"]
+        "lightning_index_scores_roofline.mixed",
+        "step_mfu.serve.sparse_gqa", "prefill_mfu.serve.sparse_gqa",
+        "decode_hbm_roofline.sparse_gqa", "attended_kv_share.sparse_gqa",
+        "expert_rows_needed_share", "mla_block_attend_roofline.sparse_gqa",
+        "lightning_index_scores_roofline.sparse_gqa"]
     assert [c["name"] for c in bench["configs"]] == [
         "gpt2-base", "gpt2-large", "deepseek-v3.2-exp-ep16",
-        "dots3-note-prev-ep16"]
-    assert CELLS[:3] == ["gpt2-base.train.seq1024",
-                         "gpt2-large.serve.closed16",
-                         "deepseek-v3.2-exp-ep16.serve.closed-long16"]
+        "dots3-note-prev-ep16", "keye-vl-2.0-30b-a3b-pp8"]
+    assert CELLS == ["gpt2-base.train.seq1024",
+                     "gpt2-large.serve.closed16",
+                     "deepseek-v3.2-exp-ep16.serve.closed-long16",
+                     "dots3-note-prev-ep16.serve.closed-mixed16",
+                     "keye-vl-2.0-30b-a3b-pp8.serve.closed-long-reason16"]
 
 
 # ------------------------------------------------------- every cell, walked
@@ -173,6 +179,11 @@ def kernel_case(metric, benchmark_side, module, attr):
     kernel_case("lightning_index_scores_roofline.mixed",
                 constant("INDEX_KERNEL"), "mla_attention",
                 "INDEX_KERNEL_NAME"),
+    kernel_case("mla_block_attend_roofline.sparse_gqa",
+                constant("ATTEND_KERNEL"), "mla_attention", "KERNEL_NAME"),
+    kernel_case("lightning_index_scores_roofline.sparse_gqa",
+                constant("INDEX_KERNEL"), "mla_attention",
+                "INDEX_KERNEL_NAME"),
 ])
 def test_kernel_names_the_readers_look_for(bench_run, metric, benchmark_side,
                                            module, attr):
@@ -228,6 +239,13 @@ def windowed_programs():
     return served_programs(wl, tree)
 
 
+@pytest.fixture(scope="module")
+def grouped_programs():
+    from tests.test_keye_vl2 import TINY, build
+    wl, _, tree = build(TINY)
+    return served_programs(wl, tree)
+
+
 @pytest.mark.parametrize("metric, benchmark_side, programs, phase", [
     pytest.param("fused_adamw_ema_time_share", asked, "train_programs",
                  "train", id="jit_train_step"),
@@ -246,6 +264,12 @@ def windowed_programs():
     pytest.param("prefill_mfu.serve.mixed", constant("PREFILL_PROGRAM"),
                  "windowed_programs", "prefill",
                  id="jit_prefill_chunk_fn.mixed"),
+    pytest.param("decode_hbm_roofline.sparse_gqa",
+                 constant("DECODE_PROGRAM"), "grouped_programs", "decode",
+                 id="jit_decode_fn.sparse_gqa"),
+    pytest.param("prefill_mfu.serve.sparse_gqa", constant("PREFILL_PROGRAM"),
+                 "grouped_programs", "prefill",
+                 id="jit_prefill_chunk_fn.sparse_gqa"),
 ])
 def test_program_names_the_readers_look_for(request, bench_run, metric,
                                             benchmark_side, programs, phase):
@@ -253,3 +277,27 @@ def test_program_names_the_readers_look_for(request, bench_run, metric,
     tiny widths of the trainer's and the servers' own tests."""
     reader, _ = bench_run.load_reader(metric)
     assert request.getfixturevalue(programs)[phase] in benchmark_side(reader)
+
+
+def test_counter_names_the_new_readers_sum():
+    """The counters a reader of the grouped family asks ``serve.fetch``
+    for, against the names its programs return them under."""
+    from distributed_pipeline_tpu.models import deepseek_v32, keye_vl2
+    sys.path.insert(0, BENCH)
+    try:
+        from harness import work_keye_vl2
+        from readers import keye_vl2 as reader
+    finally:
+        sys.path.remove(BENCH)
+    names = deepseek_v32.COUNTERS + keye_vl2.GROUPED_COUNTERS
+    assert reader.ROWS in names
+    counted = dict.fromkeys(names, 1.0)
+    with open(os.path.join(BENCH, "configs",
+                           "keye-vl-2.0-30b-a3b-pp8.json")) as f:
+        cfg = json.load(f)
+    assert work_keye_vl2.flops_needed(cfg, tokens=1, head_tokens=1,
+                                      counted=counted) > 0
+    assert work_keye_vl2.decode_bytes_needed(cfg, steps=1,
+                                             counted=counted) > 0
+    assert work_keye_vl2.INDEX_ROW_STORED \
+        == keye_vl2.KeyeVL2Config().index_row

@@ -411,13 +411,16 @@ def test_serving_programs_do_not_relayout_the_pool(one_chip, as_on_tpu,
                            exact=True) == []
 
 
-@pytest.mark.parametrize("h, dq", [(128, 192), (64, 256)],
-                         ids=["full_h128_dq192", "window_h64_dq256"])
+@pytest.mark.parametrize("h, dq", [(128, 192), (64, 256), (32, 128)],
+                         ids=["full_h128_dq192", "window_h64_dq256",
+                              "gqa_h32_dq128"])
 def test_mla_block_attend_compiles_at_published_widths(one_chip, h, dq):
     """The chunked MLA prefill's attention block (ops/mla_attention.py) at
     the widths of DeepSeek-V3.2-Exp's layers and dots3-note-prev's full
-    layers (128 heads, 192-wide queries and keys, 128-wide values) and of
-    dots3-note-prev's window layers (64 heads, 256-wide), a chunk of 1024
+    layers (128 heads, 192-wide queries and keys, 128-wide values), of
+    dots3-note-prev's window layers (64 heads, 256-wide) and of
+    Keye-VL-2.0's grouped-query layers (32 query heads of 128, each on its
+    key head's repeated block), a chunk of 1024
     queries against a block of 512 keys: the contraction, the [1, queries]
     statistics rows and the carry updated in place are what interpret mode
     cannot refuse."""
@@ -436,14 +439,42 @@ def test_mla_block_attend_compiles_at_published_widths(one_chip, h, dq):
     assert_kernel(compiled, ma.KERNEL_NAME)
 
 
-def test_lightning_index_scores_compiles_at_published_widths(one_chip):
-    """The indexer's score block (64 heads of 128, 1024 queries against 512
-    keys): a head a grid step, the weighted sum resident in VMEM."""
+@pytest.mark.parametrize("j, di", [(64, 128), (16, 64)],
+                         ids=["j64_di128", "j16_di64"])
+def test_lightning_index_scores_compiles_at_published_widths(one_chip, j,
+                                                             di):
+    """The indexer's score block (64 heads of 128, the latent families';
+    16 heads of 64, Keye-VL-2.0's; 1024 queries against 512 keys): a head a
+    grid step, the weighted sum resident in VMEM."""
     from distributed_pipeline_tpu.ops import mla_attention as ma
 
-    j, di, c, k = 64, 128, 1024, 512
+    c, k = 1024, 512
     compiled = ma.index_scores.lower(
         sds((j, di, c), jnp.bfloat16, one_chip),
         sds((j, 1, c), jnp.float32, one_chip),
         sds((k, di), jnp.bfloat16, one_chip)).compile()
     assert_kernel(compiled, ma.INDEX_KERNEL_NAME)
+
+
+@pytest.mark.parametrize("rows", [8192, 128], ids=["chunk", "decode_step"])
+def test_grouped_expert_product_is_a_kernel_with_the_counted_row_tile(
+        one_chip, rows):
+    """``lax.ragged_dot`` at Keye-VL-2.0's expert sizes (128 experts of
+    2048 x 768; a chunk's 8,192 assignments, a decode step's 128) compiles
+    to XLA's grouped-matmul kernel, not to a dense product over every
+    expert, and its row tile is the one ``expert_rows_computed`` counts
+    with (models/keye_vl2.py::row_tile)."""
+    from distributed_pipeline_tpu.models import keye_vl2
+
+    compiled = jax.jit(lambda x, w, n: jax.lax.ragged_dot(
+        x, w, n, preferred_element_type=jnp.float32)).lower(
+            sds((rows, 2048), jnp.bfloat16, one_chip),
+            sds((128, 2048, 768), jnp.bfloat16, one_chip),
+            sds((128,), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    tiling = re.search(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
+    assert tiling, "no ragged_dot_tiling on the grouped product"
+    assert int(tiling.group(1)) == keye_vl2.row_tile(rows)
+    # the work follows the rows, not rows x experts
+    assert compiled.cost_analysis()["flops"] == 2.0 * rows * 2048 * 768
