@@ -91,3 +91,19 @@ def index_scores_needed(cfg: Dict[str, Any], *, scored_rows: float,
     return {"flops": scored_rows * s["scored_flops"],
             "bytes": scored_rows / max(chunk_tokens, 1.0) * itemsize
             * s["index_key"]}
+
+
+def grouped_experts_needed(cfg: Dict[str, Any], *, assignments: float,
+                           experts_touched: float, itemsize: int = 2
+                           ) -> Dict[str, float]:
+    """What the expert layer's grouped products need for ``assignments``
+    routed (token, expert) pairs that fell on ``experts_touched`` experts
+    (both summed over layers and calls: the counters): three matrices a
+    pair; an expert's matrices read once a call it is touched in, a row
+    read once (``itemsize``) and its result written once (float32). Tile
+    padding and rows of tiles that hold none count for nothing."""
+    s = sizes(cfg)
+    d = cfg["hidden_size"]
+    return {"flops": 2.0 * assignments * s["expert"],
+            "bytes": itemsize * experts_touched * s["expert"]
+            + assignments * d * (itemsize + 4)}

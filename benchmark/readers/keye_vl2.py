@@ -17,6 +17,7 @@ from readers.deepseek_v32 import (ATTEND_KERNEL, DECODE_PROGRAM,
                                   INDEX_KERNEL, PREFILL_PROGRAM)
 
 ROWS = "expert_rows_computed"
+GROUPED_KERNEL = "grouped_expert_matmul"   # ops/grouped_matmul.py's name
 
 
 def _counted(program):
@@ -110,3 +111,20 @@ def lightning_index_scores_roofline_sparse_gqa(ctx):
         work.index_scores_needed(
             ctx["config"], scored_rows=pre["index_rows_scored"],
             chunk_tokens=n)))
+
+
+def grouped_expert_matmul_roofline(ctx):
+    """The expert layer's kernel, prefill chunks and decode steps together:
+    the least time the chip could take for the assignments' three products
+    and the touched experts' matrices (counters) over the kernel's device
+    time."""
+    pre, dec = _counted("prefill"), _counted("decode")
+    if pre is None or dec is None:
+        return None
+    return base._kernel_share(ctx, GROUPED_KERNEL, (
+        work.grouped_experts_needed(
+            ctx["config"],
+            assignments=pre["expert_assignments_held"]
+            + dec["expert_assignments_held"],
+            experts_touched=pre["experts_touched"]
+            + dec["experts_touched"])))

@@ -41,8 +41,9 @@ def _ctx():
 
         def op_seconds(self, name):
             return {"mla_block_attend": (0.2, 900),
-                    "lightning_index_scores": (0.05, 900)}.get(name,
-                                                               (0.0, 0))
+                    "lightning_index_scores": (0.05, 900),
+                    "grouped_expert_matmul": (0.9, 780)}.get(name,
+                                                             (0.0, 0))
     return {"trace": Trace(), "config": CFG,
             "peaks": {"flops_bf16": 197e12, "hbm_bytes_s": 819e9},
             "counters": {"traced": {
@@ -81,7 +82,8 @@ def all_readers(mod):
             mod.decode_hbm_roofline_sparse_gqa,
             mod.attended_kv_share_sparse_gqa, mod.expert_rows_needed_share,
             mod.mla_block_attend_roofline_sparse_gqa,
-            mod.lightning_index_scores_roofline_sparse_gqa)
+            mod.lightning_index_scores_roofline_sparse_gqa,
+            mod.grouped_expert_matmul_roofline)
 
 
 def test_readers_read_nothing_without_the_grouped_counters(readers):
@@ -128,5 +130,13 @@ def test_readers_against_a_hand_computation(readers):
         100 * (3.4e8 * 2 * 32 * 256 / 197e12) / 0.2)
     assert mod.lightning_index_scores_roofline_sparse_gqa(ctx) \
         == pytest.approx(100 * (1.5e9 * 2 * 16 * 64 / 197e12) / 0.05)
+    # the grouped kernel is memory-bound at 64 rows an expert: the touched
+    # experts' matrices and a row in and out an assignment
+    assignments = 30000 * 48 + 1500 * 48
+    touched = 30 * 6 * 128 + 100 * 6 * 79
+    bytes_ = 2 * touched * s["expert"] + assignments * 2048 * 6
+    assert bytes_ / 819e9 > 2 * assignments * s["expert"] / 197e12
+    assert mod.grouped_expert_matmul_roofline(ctx) == pytest.approx(
+        100 * (bytes_ / 819e9) / 0.9)
     for f in all_readers(mod):
         assert 0 < f(ctx) < 105

@@ -34,14 +34,15 @@ the chip anyway), both through the ONE page table a slot:
   over them in plain XLA.
 
 The expert layer (:func:`grouped_experts`) is dropless and follows the
-assignments: the ``[T, k]`` assignments are flattened and sorted by expert,
-the rows gathered, three grouped products (``lax.ragged_dot``: on the chip
-XLA's own grouped-matmul kernel, whose row tiles visit only the experts that
-have rows) run with the group sizes from a count, and each token takes its
-``k`` weighted rows back through the inverse permutation. No one-hot
-operand, no ``cond`` an expert. It keeps the held-expert contract
-(``n_routed_experts_held`` from ``expert_offset``; assignments to absent
-experts leave the sort), all experts held by default.
+assignments: the ``[T, k]`` assignments are flattened and sorted by expert
+into tile-aligned groups, the rows gathered, the three products run as one
+Pallas kernel a row tile against that tile's expert
+(ops/grouped_matmul.py: ``grouped_expert_matmul``; ``lax.ragged_dot`` over
+the same layout is its XLA arm), and each token takes its ``k`` weighted rows
+back through the rows' destinations. No one-hot operand, no ``cond`` an
+expert; an expert without a row is not read. It keeps the held-expert
+contract (``n_routed_experts_held`` from ``expert_offset``; assignments to
+absent experts leave the sort), all experts held by default.
 
 :meth:`SparseGQAMoEModel.apply` is the prefill chunk over a private one-slot
 cache, so there is one set of layer equations. Arithmetic as the latent
@@ -59,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import grouped_matmul
 from . import sparse_select
 from .deepseek_v32 import (COUNTERS, TRASH_PAGE, _angles, check_held_experts,
                            init_params, layer_norm, rms_norm, rope_halves,
@@ -66,24 +68,15 @@ from .deepseek_v32 import (COUNTERS, TRASH_PAGE, _angles, check_held_experts,
 from .sparse_select import NEG
 
 __all__ = ["KeyeVL2Config", "SparseGQAMoEModel", "GROUPED_COUNTERS",
-           "grouped_experts", "row_tile"]
+           "grouped_experts"]
 
-# behind deepseek_v32.COUNTERS: rows the grouped products multiplied (tile
-# padding and the tiles a group only touches included; summed over layers,
-# the three matrices' common row count once) and K/V rows a decode step
-# copied out of the pool
+# behind deepseek_v32.COUNTERS: rows the grouped products multiplied (whole
+# row tiles, the padding of each expert's last tile included; summed over
+# layers, the three matrices' common row count once) and K/V rows a decode
+# step copied out of the pool
 GROUPED_COUNTERS = ("expert_rows_computed", "kv_rows_gathered")
 KV_BLOCK = 512       # rows of context the chunked prefill reads a step
 LANES = 128
-# rows of a tile of XLA's grouped-matmul kernel on the chip, as its compiled
-# module states them (``ragged_dot_tiling``: 512 for a chunk's 8,192 rows,
-# 128 for a decode step's 128; tests/test_chip_compile.py holds both)
-GROUP_ROW_TILE = 512
-
-
-def row_tile(rows: int) -> int:
-    return min(GROUP_ROW_TILE, rows)
-
 
 @dataclasses.dataclass(frozen=True)
 class KeyeVL2Config:
@@ -188,7 +181,8 @@ class KeyeVL2Config:
 
 def grouped_experts(h: jnp.ndarray, ids: jnp.ndarray, w: jnp.ndarray,
                     live: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
-                    wd: jnp.ndarray, *, offset: int = 0, dtype: Any
+                    wd: jnp.ndarray, *, offset: int = 0, dtype: Any,
+                    kernel_impl: str = "auto"
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The held experts' part of a routed layer, sorted and grouped.
 
@@ -196,45 +190,40 @@ def grouped_experts(h: jnp.ndarray, ids: jnp.ndarray, w: jnp.ndarray,
     experts (of the whole router) and weights, ``live`` [T] the tokens that
     count; ``wg`` / ``wu`` [E_h, D, F] and ``wd`` [E_h, F, D] the experts
     ``offset .. offset + E_h``. An assignment to an absent expert, or of a
-    token that does not count, leaves the sort (it goes behind every group
-    and is multiplied by nothing). Returns (sum over a token's held experts
-    of ``w_e * expert_e(h)`` [T, D] float32, [assignments computed, experts
-    that saw a row, rows the grouped products multiplied] int32)."""
+    token that does not count, leaves the sort (no row holds it and nothing
+    is multiplied for it). The assignments are sorted by expert into
+    tile-aligned groups (ops/grouped_matmul.py), the rows gathered, the
+    three products run tile by tile against each tile's expert, and every
+    token takes its ``k`` rows back, weighted. Returns (sum over a token's
+    held experts of ``w_e * expert_e(h)`` [T, D] float32, [assignments
+    computed, experts that saw a row, rows the products multiplied (whole
+    tiles)] int32)."""
     t, k = ids.shape
-    e_held = wg.shape[0]
+    e_held, d, f = wg.shape
     local = ids - offset
     ok = (local >= 0) & (local < e_held) & live[:, None]
+    tile = grouped_matmul.row_tile(t * k)
     with jax.named_scope("experts.sort"):
-        key = jnp.where(ok, local, e_held).reshape(-1)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        sizes = jnp.bincount(key, length=e_held + 1)[:e_held].astype(
-            jnp.int32)
-        x = h.astype(dtype)[order // k]                      # [T k, D]
+        lay = grouped_matmul.aligned_layout(
+            jnp.where(ok, local, e_held).reshape(-1), e_held, tile)
+        x = h.astype(dtype)[lay["source"] // k]           # [rows padded, D]
     with jax.named_scope("experts.grouped"):
-        # (the CPU's grouped product multiplies no bfloat16: there the
-        # operands, rounded to `dtype`, go in as float32 — the same sums)
-        wide = dtype if jax.default_backend() == "tpu" else jnp.float32
-
-        def grouped(a, m):
-            return jax.lax.ragged_dot(
-                a.astype(wide), m.astype(dtype).astype(wide), sizes,
-                preferred_element_type=jnp.float32)
-        a = jax.nn.silu(grouped(x, wg)) * grouped(x, wu)
-        out = grouped(a.astype(dtype), wd)                   # [T k, D]
-    n_real = jnp.sum(sizes)
-    # rows behind the last group belong to none: whatever lies there
-    out = jnp.where(jnp.arange(t * k)[:, None] < n_real, out, 0.0)
-    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))
-    y = jnp.sum(out[back].reshape(t, k, -1)
-                * jnp.where(ok, w, 0.0)[:, :, None], 1)
-    # the row tiles a group's rows lie in are multiplied whole for it
-    tile = row_tile(t * k)
-    end = jnp.cumsum(sizes)
-    tiles = jnp.where(sizes > 0,
-                      (end - 1) // tile - (end - sizes) // tile + 1, 0)
-    stats = jnp.stack([n_real, jnp.sum(sizes > 0),
-                       tile * jnp.sum(tiles)]).astype(jnp.int32)
+        impl = sparse_select.on_chip(kernel_impl, d, f)
+        args = (x, wg.astype(dtype), wu.astype(dtype), wd.astype(dtype),
+                lay["tile_expert"], lay["tiles_used"])
+        if impl == "xla":
+            out = grouped_matmul.grouped_swiglu_xla(*args, tile=tile)
+        else:
+            out = grouped_matmul.grouped_swiglu(
+                *args, tile=tile, interpret=impl == "interpret")
+    # (`where`, not a zero weight: the row of an assignment that left the
+    # sort is whatever lies at row 0)
+    y = jnp.sum(jnp.where(
+        ok[:, :, None],
+        out[lay["dest"]].reshape(t, k, d) * w[:, :, None], 0.0), 1)
+    sizes = lay["sizes"]
+    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                       tile * lay["tiles_used"][0]]).astype(jnp.int32)
     return y, stats
 
 
@@ -370,7 +359,8 @@ class SparseGQAMoEModel:
         ids, w = route(c, probs, jnp.zeros((c.num_experts,), jnp.float32))
         y, stats = grouped_experts(
             h, ids, w, live, lp["experts_gate"], lp["experts_up"],
-            lp["experts_down"], offset=c.expert_offset, dtype=self.dtype)
+            lp["experts_down"], offset=c.expert_offset, dtype=self.dtype,
+            kernel_impl=self.kernel_impl)
         return y, stats, ids
 
     def _layers(self, p, cache, x, attend, live, collect: bool):

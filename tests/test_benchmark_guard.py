@@ -86,7 +86,8 @@ def test_accepted_entries_stand_as_their_prs_listed_them():
         "step_mfu.serve.sparse_gqa", "prefill_mfu.serve.sparse_gqa",
         "decode_hbm_roofline.sparse_gqa", "attended_kv_share.sparse_gqa",
         "expert_rows_needed_share", "mla_block_attend_roofline.sparse_gqa",
-        "lightning_index_scores_roofline.sparse_gqa"]
+        "lightning_index_scores_roofline.sparse_gqa",
+        "grouped_expert_matmul_roofline"]
     assert [c["name"] for c in bench["configs"]] == [
         "gpt2-base", "gpt2-large", "deepseek-v3.2-exp-ep16",
         "dots3-note-prev-ep16", "keye-vl-2.0-30b-a3b-pp8"]
@@ -184,6 +185,8 @@ def kernel_case(metric, benchmark_side, module, attr):
     kernel_case("lightning_index_scores_roofline.sparse_gqa",
                 constant("INDEX_KERNEL"), "mla_attention",
                 "INDEX_KERNEL_NAME"),
+    kernel_case("grouped_expert_matmul_roofline",
+                constant("GROUPED_KERNEL"), "grouped_matmul", "KERNEL_NAME"),
 ])
 def test_kernel_names_the_readers_look_for(bench_run, metric, benchmark_side,
                                            module, attr):

@@ -457,24 +457,23 @@ def test_lightning_index_scores_compiles_at_published_widths(one_chip, j,
 
 
 @pytest.mark.parametrize("rows", [8192, 128], ids=["chunk", "decode_step"])
-def test_grouped_expert_product_is_a_kernel_with_the_counted_row_tile(
-        one_chip, rows):
-    """``lax.ragged_dot`` at Keye-VL-2.0's expert sizes (128 experts of
-    2048 x 768; a chunk's 8,192 assignments, a decode step's 128) compiles
-    to XLA's grouped-matmul kernel, not to a dense product over every
-    expert, and its row tile is the one ``expert_rows_computed`` counts
-    with (models/keye_vl2.py::row_tile)."""
-    from distributed_pipeline_tpu.models import keye_vl2
+def test_grouped_expert_matmul_compiles_at_published_widths(one_chip, rows):
+    """The expert layer's kernel (ops/grouped_matmul.py) at Keye-VL-2.0's
+    sizes (128 experts of 2048 x 768; a chunk's 8,192 assignments in tiles
+    of 128 rows, a decode step's 128 in tiles of 16): an expert's three
+    matrices double-buffered in VMEM (19 MB), the scalar-prefetched tile
+    table in the weights' index maps."""
+    from distributed_pipeline_tpu.ops import grouped_matmul as gm
 
-    compiled = jax.jit(lambda x, w, n: jax.lax.ragged_dot(
-        x, w, n, preferred_element_type=jnp.float32)).lower(
-            sds((rows, 2048), jnp.bfloat16, one_chip),
-            sds((128, 2048, 768), jnp.bfloat16, one_chip),
-            sds((128,), jnp.int32, one_chip)).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    tiling = re.search(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
-    assert tiling, "no ragged_dot_tiling on the grouped product"
-    assert int(tiling.group(1)) == keye_vl2.row_tile(rows)
-    # the work follows the rows, not rows x experts
-    assert compiled.cost_analysis()["flops"] == 2.0 * rows * 2048 * 768
+    e, d, f = 128, 2048, 768
+    tile = gm.row_tile(rows)
+    padded = gm.padded_rows(rows, e, tile)
+    assert (tile, padded) == {8192: (128, 24448), 128: (16, 2048)}[rows]
+    compiled = gm.grouped_swiglu.lower(
+        sds((padded, d), jnp.bfloat16, one_chip),
+        sds((e, d, f), jnp.bfloat16, one_chip),
+        sds((e, d, f), jnp.bfloat16, one_chip),
+        sds((e, f, d), jnp.bfloat16, one_chip),
+        sds((padded // tile,), jnp.int32, one_chip),
+        sds((1,), jnp.int32, one_chip), tile=tile).compile()
+    assert_kernel(compiled, gm.KERNEL_NAME)

@@ -6,7 +6,7 @@ longer than it, a chunk boundary inside the selection), the family through
 ``DecodeServer`` with short and long requests in one queue, the discrete
 choices compared as sets, the grouped expert layer against the reference's
 expert-at-a-time sum (even, skewed, empty-expert routing), the share test,
-the grouped pass's counters by hand, softmax routing with one group by hand,
+the grouped pass's layout, kernel and counters by hand, softmax routing with one group by hand,
 the two copies of the reference and the configuration file."""
 
 import json
@@ -23,6 +23,7 @@ from distributed_pipeline_tpu.models import deepseek_v32 as latent
 from distributed_pipeline_tpu.models import keye_vl2 as prog
 from distributed_pipeline_tpu.models import reference_keye_vl2 as ref
 from distributed_pipeline_tpu.models.keye_vl2 import KeyeVL2Config
+from distributed_pipeline_tpu.ops import grouped_matmul
 from distributed_pipeline_tpu.serving import DecodeServer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -303,14 +304,12 @@ def routing(kind, t, rng):
 
 
 @pytest.mark.parametrize("kind", ["even", "skewed", "empty_experts"])
-def test_grouped_layer_equals_the_expert_at_a_time_sum(tiny, kind,
-                                                       monkeypatch):
+def test_grouped_layer_equals_the_expert_at_a_time_sum(tiny, kind):
     """Sorted and grouped against an expert at a time, under routing a
     trained router gives, routing that sends every token to one expert, and
     routing that leaves half the experts without a row: nothing dropped,
-    and the three counts by hand (row tiles of 8: a group's rows are
-    multiplied in every tile they lie in)."""
-    monkeypatch.setattr(prog, "GROUP_ROW_TILE", 8)
+    and the three counts by hand (160 assignments take row tiles of 16: an
+    expert's rows are multiplied as whole tiles)."""
     _, w, _ = tiny
     lw = w["layer_1"]
     t = 40
@@ -328,17 +327,58 @@ def test_grouped_layer_equals_the_expert_at_a_time_sum(tiny, kind,
         lw["experts_down"], dtype=jnp.float32))(
             h, jnp.asarray(ids_e), jnp.asarray(w_e), jnp.asarray(live))
     np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    assert grouped_matmul.row_tile(t * 4) == 16
     sizes = np.bincount(ids_e[live].reshape(-1), minlength=16)
-    end = np.cumsum(sizes)
-    tiles = sum((e - 1) // 8 - (e - n) // 8 + 1
-                for e, n in zip(end, sizes) if n)
     assert np.asarray(stats).tolist() == [
-        37 * 4, int((sizes > 0).sum()), 8 * tiles]
+        37 * 4, int((sizes > 0).sum()), 16 * int((-(-sizes // 16)).sum())]
     assert stats[2] >= stats[0]
     if kind == "skewed":
         assert sizes[5] == 37 and sizes[11] == 0
     if kind == "empty_experts":
         assert int((sizes > 0).sum()) == 8
+
+
+def test_aligned_layout_by_hand():
+    """Seven assignments over three held experts (3 = left the sort), tiles
+    of 4 rows: expert 0 has 5 rows (two tiles), expert 1 none, expert 2 one;
+    rows are laid out expert by expert, each group padded to whole tiles;
+    the tiles behind the last used one repeat its expert."""
+    key = jnp.asarray([2, 0, 3, 0, 0, 0, 0], jnp.int32)
+    lay = jax.tree_util.tree_map(
+        np.asarray, grouped_matmul.aligned_layout(key, 3, 4))
+    assert grouped_matmul.padded_rows(7, 3, 4) == 16
+    assert lay["sizes"].tolist() == [5, 0, 1]
+    assert lay["tiles_used"].tolist() == [3]
+    assert lay["tile_expert"].tolist() == [0, 0, 2, 2]
+    # expert 0's rows hold assignments 1, 3, 4, 5, 6; expert 2's tile holds 0
+    assert lay["source"][:5].tolist() == [1, 3, 4, 5, 6]
+    assert lay["source"][8] == 0
+    assert lay["dest"].tolist() == [8, 0, 0, 1, 2, 3, 4]
+
+
+def test_grouped_kernel_equals_its_xla_arm():
+    """ops/grouped_matmul.py's kernel, interpreted, against ``ragged_dot``
+    over the same tile-aligned layout, at a tile-aligned tiny size: skewed
+    groups (one expert with three tiles, one with none), tiles behind the
+    last used one skipped."""
+    e, d, f, tile, m = 4, 128, 128, 16, 72
+    rng = np.random.default_rng(0)
+    key = jnp.asarray(rng.choice([0, 0, 0, 0, 2, 3, 4], m), jnp.int32)
+    lay = grouped_matmul.aligned_layout(key, e, tile)
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    x = jax.random.normal(ks[0], (m, d), jnp.bfloat16)[
+        jnp.minimum(lay["source"], m - 1)]
+    wg, wu = (0.1 * jax.random.normal(k, (e, d, f), jnp.bfloat16)
+              for k in ks[1:3])
+    wd = 0.1 * jax.random.normal(ks[3], (e, f, d), jnp.bfloat16)
+    args = (x, wg, wu, wd, lay["tile_expert"], lay["tiles_used"])
+    want = grouped_matmul.grouped_swiglu_xla(*args, tile=tile)
+    got = grouped_matmul.grouped_swiglu(*args, tile=tile, interpret=True)
+    used = int(lay["tiles_used"][0]) * tile
+    assert 0 < used < x.shape[0]
+    np.testing.assert_allclose(np.asarray(got)[:used],
+                               np.asarray(want)[:used], atol=2e-3)
+    assert float(jnp.max(jnp.abs(want[:used]))) > 0.05
 
 
 def test_shares_add_up_to_the_uncut_layer(tiny):
