@@ -62,9 +62,10 @@ import numpy as np
 
 Weights = Dict[str, Any]
 PRECISIONS = ("float32", "bfloat16", "fp8", "int8")
+# what attention's first piece reads (``wo`` goes to the heads' piece)
 ATTENTION_WEIGHTS = (
-    "attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wo", "idx_wq",
-    "idx_wk", "idx_k_norm_g", "idx_k_norm_b", "idx_w")
+    "attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "idx_wq", "idx_wk",
+    "idx_k_norm_g", "idx_k_norm_b", "idx_w")
 BLOCK = 256          # rows a block (queries, tokens through an expert)
 # served_gaps pads a request to one of these lengths (doubling, then whole
 # steps of the first): few distinct lengths, few compilations
@@ -382,7 +383,7 @@ class _Forward:
         for i in range(cfg["n_layers"]):
             lw = w[f"layer_{i}"]
             # attention's pieces take attention's weights only
-            aw = {k: lw[k] for k in ATTENTION_WEIGHTS if k != "wo"}
+            aw = {k: lw[k] for k in ATTENTION_WEIGHTS}
             q, k, v, q_i, k_i, w_i = self.pre(aw, x)
             sel = self.select(q_i, k_i, w_i)
             for g0 in range(cfg["num_key_value_heads"]):

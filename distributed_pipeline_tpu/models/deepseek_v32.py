@@ -52,8 +52,9 @@ interleaved pairs in place, the indexer rotates split halves; YaRN's
 The prefill's selection (index scores block by block, the radix select for
 each query's k-th score, the tie-break) and the choice of arm of the two
 Pallas-backed pieces live in models/sparse_select.py, shared with
-models/keye_vl2.py, which also takes ``route``, ``_best_first``,
-``rms_norm``, ``layer_norm``, ``rope_halves`` and ``_angles`` from here.
+models/keye_vl2.py, which also takes ``route`` (and with it
+``_best_first``), ``rms_norm``, ``layer_norm``, ``rope_halves``, ``_angles``,
+``init_params`` and ``last_valid_logits`` from here.
 """
 
 from __future__ import annotations
@@ -328,6 +329,17 @@ def _best_first(x: jnp.ndarray, k: int) -> jnp.ndarray:
         picks.append(i)
         x = jnp.where(cols[None, :] == i[:, None], -jnp.inf, x)
     return jnp.stack(picks, -1)
+
+
+def last_valid_logits(hidden: jnp.ndarray, n_valid, logits_fn
+                      ) -> jnp.ndarray:
+    """Logits of a chunk's last valid row: the head (``logits_fn``) over a
+    tile of rows that holds it — a single row would become a float32
+    multiply-and-reduce over the whole head matrix."""
+    rows = min(8, hidden.shape[0])
+    first = jnp.clip(n_valid - rows, 0, hidden.shape[0] - rows)
+    tile = jax.lax.dynamic_slice_in_dim(hidden, first, rows, 0)
+    return logits_fn(tile)[jnp.maximum(n_valid - 1, 0) - first]
 
 
 def init_params(shapes: Dict[str, Any], rng: jax.Array, std: float,
@@ -843,14 +855,8 @@ class LatentMoEModel:
         counters int32)."""
         hidden, cache, counters, _ = self._chunk_hidden(
             p, cache, ids, start, n_valid, table_row, window_row)
-        # the head over a tile of rows that holds the last valid one: a
-        # single row would become a float32 multiply-and-reduce over the
-        # whole head matrix
-        rows = min(8, hidden.shape[0])
-        first = jnp.clip(n_valid - rows, 0, hidden.shape[0] - rows)
-        tile = jax.lax.dynamic_slice_in_dim(hidden, first, rows, 0)
-        logits = self._logits(p, tile)
-        return cache, logits[jnp.maximum(n_valid - 1, 0) - first], counters
+        return cache, last_valid_logits(
+            hidden, n_valid, lambda tile: self._logits(p, tile)), counters
 
     def apply(self, variables, ids, pad_mask=None, *, collect: bool = False):
         """Cache-free forward: ``ids`` [B, T] -> logits [B, T, V] float32

@@ -63,8 +63,8 @@ import numpy as np
 from ..ops import grouped_matmul
 from . import sparse_select
 from .deepseek_v32 import (COUNTERS, TRASH_PAGE, _angles, check_held_experts,
-                           init_params, layer_norm, rms_norm, rope_halves,
-                           route)
+                           init_params, last_valid_logits, layer_norm,
+                           rms_norm, rope_halves, route)
 from .sparse_select import NEG
 
 __all__ = ["KeyeVL2Config", "SparseGQAMoEModel", "GROUPED_COUNTERS",
@@ -126,17 +126,15 @@ class KeyeVL2Config:
         held."""
         flat = dict(arch)
         flat.update(flat.pop("sa_config", None) or {})
-        flat.update({k: v for k, v in (flat.pop("rope_scaling", None)
-                                       or {}).items()
-                     if k == "mrope_section"})
+        sections = (flat.pop("rope_scaling", None) or {}).get(
+            "mrope_section", flat.get("mrope_section"))
+        if sections is not None:
+            flat["mrope_section"] = tuple(sections)
         flat.update({k: v for k, v in over.items() if v})
         flat.setdefault("n_routed_experts_held",
                         flat.get("num_experts", cls.num_experts))
         names = {f.name for f in dataclasses.fields(cls)}
-        flat = {k: v for k, v in flat.items() if k in names}
-        if "mrope_section" in flat:
-            flat["mrope_section"] = tuple(flat["mrope_section"])
-        cfg = cls(**flat)
+        cfg = cls(**{k: v for k, v in flat.items() if k in names})
         check_held_experts(cfg)
         if cfg.num_attention_heads % cfg.num_key_value_heads:
             raise ValueError("query heads must divide by key/value heads")
@@ -486,14 +484,8 @@ class SparseGQAMoEModel:
         counters int32)."""
         hidden, cache, counters, _ = self._chunk_hidden(
             p, cache, ids, start, n_valid, table_row)
-        # the head over a tile of rows that holds the last valid one: a
-        # single row would become a float32 multiply-and-reduce over the
-        # whole head matrix
-        rows = min(8, hidden.shape[0])
-        first = jnp.clip(n_valid - rows, 0, hidden.shape[0] - rows)
-        tile = jax.lax.dynamic_slice_in_dim(hidden, first, rows, 0)
-        logits = self._logits(p, tile)
-        return cache, logits[jnp.maximum(n_valid - 1, 0) - first], counters
+        return cache, last_valid_logits(
+            hidden, n_valid, lambda tile: self._logits(p, tile)), counters
 
     def apply(self, variables, ids, pad_mask=None, *, collect: bool = False):
         """Cache-free forward: ``ids`` [B, T] -> logits [B, T, V] float32

@@ -9,6 +9,7 @@ expert-at-a-time sum (even, skewed, empty-expert routing), the share test,
 the grouped pass's layout, kernel and counters by hand, softmax routing with one group by hand,
 the two copies of the reference and the configuration file."""
 
+import dataclasses
 import json
 import os
 import sys
@@ -456,15 +457,10 @@ def test_sizes_of_the_source():
     np.testing.assert_allclose(
         cfg.indexer_inv_freq, ref.inv_freq(1e7, 64), rtol=1e-7)
     file_cfg = KeyeVL2Config.from_arch(json.load(open(CONFIG_FILE)))
-    assert file_cfg == dataclass_replace(cfg, n_layers=6,
-                                         max_position_embeddings=16896)
+    assert file_cfg == dataclasses.replace(cfg, n_layers=6,
+                                           max_position_embeddings=16896)
     with pytest.raises(ValueError, match="mrope_section"):
         KeyeVL2Config.from_arch({"rope_scaling": {"mrope_section": [8, 8]}})
-
-
-def dataclass_replace(cfg, **kw):
-    import dataclasses
-    return dataclasses.replace(cfg, **kw)
 
 
 # ------------------------------------------------ the copies, the files
@@ -565,7 +561,6 @@ def test_prefill_with_the_kernels_equals_its_xla_arm(monkeypatch):
     the attention's and the indexer's place (8 query heads on 2 repeated
     key heads; the indexer at 4 heads of 16), at a tile-aligned tiny size
     (blocks of 128 rows), against the XLA arm."""
-    import dataclasses
     monkeypatch.setattr(prog, "KV_BLOCK", 128)
     cfg = dict(TINY, max_position_embeddings=512)
     cfg["sa_config"] = dict(TINY["sa_config"], topk=150)
