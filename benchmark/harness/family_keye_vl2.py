@@ -11,11 +11,10 @@ from typing import Any, Dict
 
 ARCH_KEYS = (
     "hidden_size", "n_layers", "num_attention_heads", "num_key_value_heads",
-    "head_dim", "num_experts", "n_routed_experts_held", "expert_offset",
-    "num_experts_per_tok", "moe_intermediate_size", "norm_topk_prob",
-    "sa_config", "rope_scaling", "rope_theta", "rms_norm_eps",
-    "max_position_embeddings", "initializer_range",
-    "embedding_initializer_range")
+    "head_dim", "num_experts", "num_experts_per_tok",
+    "moe_intermediate_size", "norm_topk_prob", "sa_config", "rope_scaling",
+    "rope_theta", "rms_norm_eps", "max_position_embeddings",
+    "initializer_range", "embedding_initializer_range")
 
 
 def program_flags(cfg: Dict[str, Any]) -> Dict[str, Any]:

@@ -6,10 +6,9 @@ It imports nothing of the program and takes nothing the program has made. It
 makes its own weights from the seed (``make_weights``; the driver hands the
 same arrays to the program, in the configuration's ``param_dtype``) and
 computes the forward pass of the layers the configuration file states: every
-width as published, the router over all ``num_experts``, and of the experts
-the ``n_routed_experts_held`` from ``expert_offset`` (all of them, where the
-file gives no such key), a Python loop over them, each over the rows routed
-to it: every token's eight experts are computed an expert at a time.
+width as published, the router over all ``num_experts`` and every one of the
+experts, a Python loop over them, each over the rows routed to it: every
+token's eight experts are computed an expert at a time.
 
 A layer, for a sequence ``x`` [T, D] (all norms RMSNorm, eps
 ``rms_norm_eps``; pre-norm residual blocks; no bias anywhere):
@@ -75,18 +74,12 @@ NEG = -1e30
 
 # ------------------------------------------------------------------ weights
 
-def held(cfg: Dict[str, Any]) -> Tuple[int, int]:
-    """(first expert held here, how many): all of them without the keys."""
-    return (int(cfg.get("expert_offset", 0)),
-            int(cfg.get("n_routed_experts_held", cfg["num_experts"])))
-
-
 def param_shapes(cfg: Dict[str, Any]) -> Dict[str, Any]:
     d, dh = cfg["hidden_size"], cfg["head_dim"]
     h, g = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     sa = cfg["sa_config"]
     j, di = sa["indexer_num_heads"], sa["indexer_head_dim"]
-    e, f = held(cfg)[1], cfg["moe_intermediate_size"]
+    e, f = cfg["num_experts"], cfg["moe_intermediate_size"]
     layer = {
         "attn_norm": (d,), "wq": (d, h * dh), "wk": (d, g * dh),
         "wv": (d, g * dh), "q_norm": (dh,), "k_norm": (dh,),
@@ -379,7 +372,6 @@ class _Forward:
             # reach an earlier position), cut off again below
             ids = jnp.pad(ids, (0, -t % BLOCK))
         x = _keep(w["embed"][ids].astype(jnp.float32), self.precision)
-        off, n_held = held(cfg)
         for i in range(cfg["n_layers"]):
             lw = w[f"layer_{i}"]
             # attention's pieces take attention's weights only
@@ -392,8 +384,8 @@ class _Forward:
             ids_e, w_e = self.routed(x, lw["mlp_norm"], lw["router"])
             y = x
             host_ids, host_w = jax.device_get((ids_e, w_e))
-            for e in range(n_held):
-                hit = host_ids == off + e                        # [T, k]
+            for e in range(cfg["num_experts"]):
+                hit = host_ids == e                              # [T, k]
                 rows = np.nonzero(hit.any(-1))[0]
                 if rows.size == 0:
                     continue

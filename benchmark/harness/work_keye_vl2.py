@@ -12,14 +12,12 @@ query head against its key head's key and value. Rows, assignments and
 touched experts come from the counters, so a program cannot raise a share by
 scoring, attending, routing or padding more than it must: rows a grouped
 product multiplies beyond the assignments, K/V rows gathered beyond the
-attended, and experts read without a row count for nothing."""
+attended, experts read without a row, and the zeros that pad a stored
+indexer key to whole lane tiles count for nothing."""
 
 from __future__ import annotations
 
 from typing import Any, Dict
-
-INDEX_ROW_STORED = 128   # numbers of an indexer key as the pool stores it
-
 
 def sizes(cfg: Dict[str, Any]) -> Dict[str, float]:
     """Matrix parameters by the piece that uses them."""
@@ -54,13 +52,15 @@ def decode_bytes_needed(cfg: Dict[str, Any], *, steps: float,
                         counted: Dict[str, float], itemsize: int = 2
                         ) -> float:
     """Bytes ``steps`` decode steps must read: the matrices every step
-    passes, the experts that saw a token (counter), the stored indexer key
-    of every row scored and the K/V row of every row attended."""
+    passes, the experts that saw a token (counter), the indexer key of
+    every row scored (``indexer_head_dim`` numbers, as the prefill's
+    reader counts it: not the padding the pool stores it with) and the K/V
+    row of every row attended."""
     s = sizes(cfg)
     return itemsize * (
         steps * (s["token"] + s["head"])
         + counted["experts_touched"] * s["expert"]
-        + counted["index_rows_scored"] * INDEX_ROW_STORED
+        + counted["index_rows_scored"] * s["index_key"]
         + counted["kv_rows_attended"] * s["kv_row"])
 
 

@@ -28,7 +28,6 @@ def test_sizes_are_the_issue_s_arithmetic():
     assert s["scored_flops"] == 2 * 16 * 64
     assert s["pair_flops"] == 2 * 32 * (128 + 128)
     assert 2 * s["kv_row"] == 2048 and s["index_key"] == 64
-    assert work.INDEX_ROW_STORED == 128
 
 
 def _ctx():
@@ -55,14 +54,12 @@ COUNTED = {"prefill": {"expert_assignments_held": 30000 * 8 * 6,
                        "experts_touched": 30 * 6 * 128,
                        "index_rows_scored": 1.5e9, "kv_rows_attended": 3.4e8,
                        "kv_rows_live": 1.5e9,
-                       "expert_rows_computed": 30 * 6 * 73000,
-                       "kv_rows_gathered": 0},
+                       "expert_rows_computed": 30 * 6 * 73000},
            "decode": {"expert_assignments_held": 1500 * 8 * 6,
                       "experts_touched": 100 * 6 * 79,
                       "index_rows_scored": 7.5e7, "kv_rows_attended": 1.8e7,
                       "kv_rows_live": 7.5e7,
-                      "expert_rows_computed": 100 * 6 * 79 * 128,
-                      "kv_rows_gathered": 100 * 6 * 16 * 2048}}
+                      "expert_rows_computed": 100 * 6 * 79 * 128}}
 
 
 @pytest.fixture
@@ -95,7 +92,7 @@ def test_readers_read_nothing_without_the_grouped_counters(readers):
         assert f(_ctx()) is None
     state["counted"] = {
         program: {k: v for k, v in group.items()
-                  if k not in ("expert_rows_computed", "kv_rows_gathered")}
+                  if k != "expert_rows_computed"}
         for program, group in COUNTED.items()}
     for f in all_readers(mod):
         assert f(_ctx()) is None
@@ -116,7 +113,7 @@ def test_readers_against_a_hand_computation(readers):
     assert mod.prefill_mfu_serve_sparse_gqa(ctx) == pytest.approx(
         100 * pre / (1.2 * 197e12))
     need = 2 * (100 * (s["token"] + s["head"]) + 100 * 6 * 79 * s["expert"]
-                + 7.5e7 * 128 + 1.8e7 * 1024)
+                + 7.5e7 * 64 + 1.8e7 * 1024)      # the key, not its padding
     assert mod.decode_hbm_roofline_sparse_gqa(ctx) == pytest.approx(
         100 * need / 819e9 / 1.6)
     # a decode step of this reading: 5.9 GB, the experts three quarters

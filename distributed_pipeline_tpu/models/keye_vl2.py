@@ -40,9 +40,8 @@ Pallas kernel a row tile against that tile's expert
 (ops/grouped_matmul.py: ``grouped_expert_matmul``; ``lax.ragged_dot`` over
 the same layout is its XLA arm), and each token takes its ``k`` weighted rows
 back through the rows' destinations. No one-hot operand, no ``cond`` an
-expert; an expert without a row is not read. It keeps the held-expert
-contract (``n_routed_experts_held`` from ``expert_offset``; assignments to
-absent experts leave the sort), all experts held by default.
+expert; an expert without a row is not read. Every expert is held: the
+layer's matrices are ``[num_experts, ...]`` and there is no cut to state.
 
 :meth:`SparseGQAMoEModel.apply` is the prefill chunk over a private one-slot
 cache, so there is one set of layer equations. Arithmetic as the latent
@@ -62,9 +61,9 @@ import numpy as np
 
 from ..ops import grouped_matmul
 from . import sparse_select
-from .deepseek_v32 import (COUNTERS, TRASH_PAGE, _angles, check_held_experts,
-                           init_params, last_valid_logits, layer_norm,
-                           rms_norm, rope_halves, route)
+from .deepseek_v32 import (COUNTERS, TRASH_PAGE, _angles, init_params,
+                           last_valid_logits, layer_norm, rms_norm,
+                           rope_halves, route)
 from .sparse_select import NEG
 
 __all__ = ["KeyeVL2Config", "SparseGQAMoEModel", "GROUPED_COUNTERS",
@@ -72,17 +71,16 @@ __all__ = ["KeyeVL2Config", "SparseGQAMoEModel", "GROUPED_COUNTERS",
 
 # behind deepseek_v32.COUNTERS: rows the grouped products multiplied (whole
 # row tiles, the padding of each expert's last tile included; summed over
-# layers, the three matrices' common row count once) and K/V rows a decode
-# step copied out of the pool
-GROUPED_COUNTERS = ("expert_rows_computed", "kv_rows_gathered")
+# layers, the three matrices' common row count once)
+GROUPED_COUNTERS = ("expert_rows_computed",)
 KV_BLOCK = 512       # rows of context the chunked prefill reads a step
 LANES = 128
 
 @dataclasses.dataclass(frozen=True)
 class KeyeVL2Config:
     """The source's ``config.json`` keys of the language model (same names;
-    ``sa_config`` and ``rope_scaling`` flattened), the cut (``n_layers``,
-    the held experts), and nothing else."""
+    ``sa_config`` and ``rope_scaling`` flattened), the cut (``n_layers``),
+    and nothing else."""
 
     vocab_size: int = 151936
     hidden_size: int = 2048
@@ -91,8 +89,6 @@ class KeyeVL2Config:
     num_key_value_heads: int = 4
     head_dim: int = 128
     num_experts: int = 128
-    n_routed_experts_held: int = 128
-    expert_offset: int = 0
     num_experts_per_tok: int = 8
     moe_intermediate_size: int = 768
     norm_topk_prob: bool = True
@@ -111,19 +107,13 @@ class KeyeVL2Config:
 
     # what `route` reads of a router without groups, bias or scale
     n_group = 1
-    topk_group = 1
     routed_scaling_factor = 1.0
-
-    @property
-    def n_routed_experts(self) -> int:
-        return self.num_experts
 
     @classmethod
     def from_arch(cls, arch: Dict[str, Any], **over: Any) -> "KeyeVL2Config":
         """From a dict of the source's keys (a benchmark configuration file,
         ``training_args.json``'s ``arch``); keys this class does not know
-        are ignored; without ``n_routed_experts_held`` every expert is
-        held."""
+        are ignored."""
         flat = dict(arch)
         flat.update(flat.pop("sa_config", None) or {})
         sections = (flat.pop("rope_scaling", None) or {}).get(
@@ -131,11 +121,8 @@ class KeyeVL2Config:
         if sections is not None:
             flat["mrope_section"] = tuple(sections)
         flat.update({k: v for k, v in over.items() if v})
-        flat.setdefault("n_routed_experts_held",
-                        flat.get("num_experts", cls.num_experts))
         names = {f.name for f in dataclasses.fields(cls)}
         cfg = cls(**{k: v for k, v in flat.items() if k in names})
-        check_held_experts(cfg)
         if cfg.num_attention_heads % cfg.num_key_value_heads:
             raise ValueError("query heads must divide by key/value heads")
         if cfg.indexer_num_kv_heads != 1:
@@ -179,31 +166,29 @@ class KeyeVL2Config:
 
 def grouped_experts(h: jnp.ndarray, ids: jnp.ndarray, w: jnp.ndarray,
                     live: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
-                    wd: jnp.ndarray, *, offset: int = 0, dtype: Any,
+                    wd: jnp.ndarray, *, dtype: Any,
                     kernel_impl: str = "auto"
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The held experts' part of a routed layer, sorted and grouped.
+    """A routed layer over all of its experts, sorted and grouped.
 
     ``h`` [T, D] the normalised rows, ``ids`` / ``w`` [T, k] each token's
-    experts (of the whole router) and weights, ``live`` [T] the tokens that
-    count; ``wg`` / ``wu`` [E_h, D, F] and ``wd`` [E_h, F, D] the experts
-    ``offset .. offset + E_h``. An assignment to an absent expert, or of a
-    token that does not count, leaves the sort (no row holds it and nothing
-    is multiplied for it). The assignments are sorted by expert into
+    experts and weights, ``live`` [T] the tokens that count; ``wg`` / ``wu``
+    [E, D, F] and ``wd`` [E, F, D] every expert's matrices. An assignment of
+    a token that does not count leaves the sort (no row holds it and
+    nothing is multiplied for it). The assignments are sorted by expert into
     tile-aligned groups (ops/grouped_matmul.py), the rows gathered, the
     three products run tile by tile against each tile's expert, and every
     token takes its ``k`` rows back, weighted. Returns (sum over a token's
-    held experts of ``w_e * expert_e(h)`` [T, D] float32, [assignments
+    experts of ``w_e * expert_e(h)`` [T, D] float32, [assignments
     computed, experts that saw a row, rows the products multiplied (whole
     tiles)] int32)."""
     t, k = ids.shape
-    e_held, d, f = wg.shape
-    local = ids - offset
-    ok = (local >= 0) & (local < e_held) & live[:, None]
+    e, d, f = wg.shape
+    ok = jnp.broadcast_to(live[:, None], ids.shape)
     tile = grouped_matmul.row_tile(t * k)
     with jax.named_scope("experts.sort"):
         lay = grouped_matmul.aligned_layout(
-            jnp.where(ok, local, e_held).reshape(-1), e_held, tile)
+            jnp.where(ok, ids, e).reshape(-1), e, tile)
         x = h.astype(dtype)[lay["source"] // k]           # [rows padded, D]
     with jax.named_scope("experts.grouped"):
         impl = sparse_select.on_chip(kernel_impl, d, f)
@@ -253,7 +238,7 @@ class SparseGQAMoEModel:
         d, dh = c.hidden_size, c.head_dim
         h, g = c.num_attention_heads, c.num_key_value_heads
         j, di = c.indexer_num_heads, c.indexer_head_dim
-        e, f = c.n_routed_experts_held, c.moe_intermediate_size
+        e, f = c.num_experts, c.moe_intermediate_size
         layer = {
             "attn_norm": (d,), "wq": (d, h * dh), "wk": (d, g * dh),
             "wv": (d, g * dh), "q_norm": (dh,), "k_norm": (dh,),
@@ -357,7 +342,7 @@ class SparseGQAMoEModel:
         ids, w = route(c, probs, jnp.zeros((c.num_experts,), jnp.float32))
         y, stats = grouped_experts(
             h, ids, w, live, lp["experts_gate"], lp["experts_up"],
-            lp["experts_down"], offset=c.expert_offset, dtype=self.dtype,
+            lp["experts_down"], dtype=self.dtype,
             kernel_impl=self.kernel_impl)
         return y, stats, ids
 
@@ -574,8 +559,7 @@ class SparseGQAMoEModel:
             return (o.reshape(s_n, g * rep, dh), {"kv": kv, "index_k": idx},
                     jnp.where(ok, sel, -1),
                     {"index_rows_scored": n_live, "kv_rows_attended": n_att,
-                     "kv_rows_live": n_live,
-                     "kv_rows_gathered": jnp.int32(s_n * k_sel)})
+                     "kv_rows_live": n_live})
 
         x, cache, counters, aux = self._layers(p, cache, x, attend, live,
                                                collect)

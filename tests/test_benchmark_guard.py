@@ -302,5 +302,5 @@ def test_counter_names_the_new_readers_sum():
                                       counted=counted) > 0
     assert work_keye_vl2.decode_bytes_needed(cfg, steps=1,
                                              counted=counted) > 0
-    assert work_keye_vl2.INDEX_ROW_STORED \
-        == keye_vl2.KeyeVL2Config().index_row
+    assert work_keye_vl2.sizes(cfg)["index_key"] \
+        == keye_vl2.KeyeVL2Config().indexer_head_dim

@@ -169,7 +169,6 @@ def test_chunked_prefill_then_decode_equals_reference(tiny, small_blocks,
         assert counted["kv_rows_attended"] == LAYERS * sum(
             min(t + 1, TOPK) for t in range(start, start + n))
         assert counted["expert_assignments_held"] == n * PER_TOKEN * LAYERS
-        assert counted["kv_rows_gathered"] == 0
     # slot 1 stays inactive (an all-trash table): it must disturb nothing
     tables = jnp.stack([table, jnp.zeros_like(table)])
     decode = jax.jit(m.decode_step)
@@ -183,8 +182,6 @@ def test_chunked_prefill_then_decode_equals_reference(tiny, small_blocks,
         assert counted["kv_rows_attended"] == min(t + 1, TOPK) * LAYERS
         assert counted["expert_assignments_held"] == PER_TOKEN * LAYERS
         assert 1 <= counted["experts_touched"] <= PER_TOKEN * LAYERS
-        # both slots' gathers are made, the idle one's too
-        assert counted["kv_rows_gathered"] == 2 * TOPK * LAYERS
 
 
 def test_served_through_decode_server_equals_reference(tiny, small_blocks):
@@ -194,7 +191,7 @@ def test_served_through_decode_server_equals_reference(tiny, small_blocks):
     after release, over pages other requests wrote). Every served token is
     the reference's pick at its position (float32: a gap above 1e-4 would
     be a wrong row, not rounding); pages return to the free list; the
-    seven counters come back with the tokens."""
+    six counters come back with the tokens."""
     wl, w, tree = tiny
     server = DecodeServer(wl, tree, decode_slots=2, page_size=2,
                           max_prompt_len=64, max_len=POSITIONS)
@@ -225,7 +222,6 @@ def test_served_through_decode_server_equals_reference(tiny, small_blocks):
     pre, dec = server.counted["prefill"], server.counted["decode"]
     assert pre["expert_assignments_held"] == PER_TOKEN * LAYERS * sum(
         n for n, _ in shapes)
-    assert pre["kv_rows_gathered"] == 0 < dec["kv_rows_gathered"]
     assert eng.kv_pool_bytes() == sum(
         leaf.size * leaf.dtype.itemsize
         for leaf in jax.tree_util.tree_leaves(eng.cache))
@@ -383,11 +379,11 @@ def test_grouped_kernel_equals_its_xla_arm():
 
 
 def test_shares_add_up_to_the_uncut_layer(tiny):
-    """What ties the held-expert contract to the model: four shares of the
-    grouped layer with a quarter of the experts each (``expert_offset`` 0,
-    4, 8, 12; assignments to absent experts leave the sort) sum to the
-    uncut layer, which is the reference's expert-at-a-time sum; the shares'
-    assignments add up to all of them (dropless)."""
+    """The model's expert layer is the reference's expert-at-a-time sum, and
+    four shares of it sum to it: each share keeps the assignments that fall
+    on one quarter of the experts (the others' tokens do not count there:
+    an assignment at a time, ``k`` 1) and the shares' assignments add up to
+    all of them (dropless)."""
     wl, w, _ = tiny
     lw = w["layer_2"]
     x = jax.random.normal(jax.random.PRNGKey(6), (40, 64))
@@ -401,24 +397,19 @@ def test_shares_add_up_to_the_uncut_layer(tiny):
             == np.sort(np.asarray(ids_e), -1)).all()
     np.testing.assert_allclose(np.asarray(whole), want, atol=2e-5)
     assert int(stats[0]) == 40 * PER_TOKEN
-    total, held_sum = np.zeros_like(want), 0
+    share = jax.jit(lambda i, w, live: prog.grouped_experts(
+        h, i, w, live, lw["experts_gate"], lw["experts_up"],
+        lw["experts_down"], dtype=jnp.float32))
+    total, computed = np.zeros_like(want), 0
     for rank in range(4):
-        cfg = dict(TINY, n_routed_experts_held=4, expert_offset=4 * rank)
-        model = create_model_from_config(
-            model_family="keye_vl2", vocab_size=VOCAB, seq_len=POSITIONS,
-            dtype="float32", arch=arch_of(cfg)).model
-        assert model.param_shapes()["layer_0"]["experts_up"] == (4, 64, 32)
-        lp = dict(lw, **{k: lw[k][4 * rank:4 * rank + 4] for k in (
-            "experts_gate", "experts_up", "experts_down")})
-        y, stats, _ = jax.jit(model._experts)(lp, h, live)
-        total += np.asarray(y)
-        held_sum += int(stats[0])
-        assert int(stats[1]) <= 4
-    assert held_sum == 40 * PER_TOKEN
+        for j in range(PER_TOKEN):
+            here = ids_e[:, j] // 4 == rank
+            y, stats = share(ids_e[:, j:j + 1], w_e[:, j:j + 1], here)
+            total += np.asarray(y)
+            computed += int(stats[0])
+            assert int(stats[0]) == int(here.sum()) and int(stats[1]) <= 4
+    assert computed == 40 * PER_TOKEN
     np.testing.assert_allclose(total, want, atol=2e-5)
-    with pytest.raises(ValueError, match="held experts"):
-        KeyeVL2Config.from_arch(dict(arch_of(TINY), n_routed_experts_held=4,
-                                     expert_offset=14))
 
 
 # ------------------------------------------- (e) routing, worked by hand
@@ -445,12 +436,12 @@ def test_sizes_of_the_source():
     """The published sizes as the program reads them: 32 query heads on 4
     key heads of 128, a cached row of 1,024 numbers (keys then values) and
     an indexer key of 64 stored as a whole lane tile, rotary frequencies
-    over the whole head at base 1e7, every expert held by default."""
+    over the whole head at base 1e7, every expert's matrices in the tree."""
     cfg = KeyeVL2Config()
     assert (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
             cfg.kv_row, cfg.indexer_head_dim, cfg.index_row, cfg.topk) == (
                 32, 4, 128, 1024, 64, 128, 2048)
-    assert cfg.n_routed_experts == cfg.n_routed_experts_held == 128
+    assert cfg.num_experts == 128 and cfg.num_experts_per_tok == 8
     assert (cfg.n_group, cfg.routed_scaling_factor) == (1, 1.0)
     np.testing.assert_allclose(
         cfg.inv_freq, 1.0 / 1e7 ** (np.arange(0, 128, 2) / 128), rtol=1e-6)
@@ -499,7 +490,6 @@ def test_configuration_file_states_its_cut():
     # bfloat16
     assert ref.param_count(cfg) == 4_374_622_464
     assert cfg["num_experts"] == 128 and cfg["num_experts_per_tok"] == 8
-    assert "n_routed_experts_held" not in cfg       # every expert is here
     assert cfg["n_layers"] == 6 >= 4 and cfg["vocab_size"] == 151936
     assert cfg["sa_config"]["topk"] == 2048
     for key in cfg["reduced"]:
