@@ -14,7 +14,7 @@ ARCH_KEYS = (
     "head_dim", "num_experts", "num_experts_per_tok",
     "moe_intermediate_size", "norm_topk_prob", "sa_config", "rope_scaling",
     "rope_theta", "rms_norm_eps", "max_position_embeddings",
-    "initializer_range", "embedding_initializer_range")
+    "initializer_range", "embedding_initializer_range", "dispatch_lag")
 
 
 def program_flags(cfg: Dict[str, Any]) -> Dict[str, Any]:

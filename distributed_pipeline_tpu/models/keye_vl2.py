@@ -80,7 +80,7 @@ LANES = 128
 class KeyeVL2Config:
     """The source's ``config.json`` keys of the language model (same names;
     ``sa_config`` and ``rope_scaling`` flattened), the cut (``n_layers``),
-    and nothing else."""
+    and the one thing a deployment states of its host (``dispatch_lag``)."""
 
     vocab_size: int = 151936
     hidden_size: int = 2048
@@ -104,6 +104,11 @@ class KeyeVL2Config:
     max_position_embeddings: int = 262144
     initializer_range: float = 0.02
     embedding_initializer_range: float = 1.0
+    # the deployment's, not the source's: decode dispatches the server keeps
+    # in flight before it fetches tokens (DecodeServer's default is one). A
+    # host that stalls for longer than a decode step idles the chip unless
+    # that many steps are queued; each costs a first token one tick
+    dispatch_lag: int = 1
 
     # what `route` reads of a router without groups, bias or scale
     n_group = 1
@@ -226,6 +231,11 @@ class SparseGQAMoEModel:
 
     chunked_prefill = True   # what DecodeEngine asks a model
     counters = COUNTERS + GROUPED_COUNTERS   # behind a program's tokens
+
+    @property
+    def dispatch_lag(self) -> int:
+        """What DecodeServer asks where its caller names no lag."""
+        return self.cfg.dispatch_lag
 
     @property
     def vocab_size(self) -> int:

@@ -107,6 +107,14 @@ class DecodeServer:
     executables compile exactly once) and dispatches run under
     ``jax.transfer_guard("disallow")``.
 
+    Tokens are fetched ``dispatch_lag`` dispatches behind, so that the
+    host's bookkeeping, and a stall of the host shorter than the work queued
+    on the device, cost the chip nothing. Given none, the server asks the
+    model what its deployment states (``model.dispatch_lag``) and fetches
+    one dispatch behind where it states nothing. A budget is spent by count
+    at dispatch, so a deeper queue frees no slot later: it costs a first
+    token, and a client that waits for its answer, that many dispatches.
+
     ``spec_tokens = K > 0`` turns on SPECULATIVE decoding: each round a
     draft (``spec_draft``: host-side "ngram" prompt-lookup, or "model" — an
     early-exit engine over the target's first ``draft_layers`` blocks)
@@ -126,7 +134,7 @@ class DecodeServer:
                  top_k: int = 0, top_p: float = 0.0, seed: int = 0,
                  rng: Optional[jax.Array] = None, eos_id: Optional[int] = None,
                  mesh=None, sanitize: bool = False,
-                 dispatch_lag: int = 1,
+                 dispatch_lag: Optional[int] = None,
                  prefix_cache: bool = False,
                  decode_impl: str = "auto", kv_quant: str = "fp",
                  spec_tokens: int = 0, spec_draft: str = "ngram",
@@ -226,7 +234,11 @@ class DecodeServer:
         self.slots: List[Optional[_SlotState]] = [None] * s
         self.queue: Deque[Request] = collections.deque()
         self.default_eos_id = eos_id
-        self.dispatch_lag = max(0, dispatch_lag)
+        # decode dispatches in flight before the host fetches: the
+        # caller's, else what the model's deployment states, else one
+        if dispatch_lag is None:
+            dispatch_lag = getattr(workload.model, "dispatch_lag", 1)
+        self.dispatch_lag = max(0, int(dispatch_lag))
         # lagged fetch ring: (device tokens handle, [(slot, Request)] whose
         # token in that vector is NEW)
         self._ring: Deque[Any] = collections.deque()

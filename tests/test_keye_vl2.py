@@ -227,6 +227,42 @@ def test_served_through_decode_server_equals_reference(tiny, small_blocks):
         for leaf in jax.tree_util.tree_leaves(eng.cache))
 
 
+@pytest.mark.parametrize("stated, asked, held", [
+    (None, None, 1), (8, None, 8), (8, 2, 2)],
+    ids=["no_lag_stated", "the_deployments_lag", "the_callers_lag_wins"])
+def test_server_keeps_the_deployments_dispatches_in_flight(
+        tiny, small_blocks, stated, asked, held):
+    """DecodeServer, told no ``dispatch_lag``, asks the model what its
+    deployment states (one where it states none; the caller's wins). With
+    8 dispatches in flight the ring holds up to 8 entries behind every busy
+    tick, a first token arrives that many entries after its dispatch, and
+    the tokens are those of a server that fetches one dispatch behind: the
+    budget is spent by count at dispatch, so the lag moves no token."""
+    wl, w, tree = tiny
+    if stated is not None:
+        wl, _, _ = build({**TINY, "dispatch_lag": stated})
+    shapes = [(29, 9), (3, 2), (40, 17), (13, 14), (5, 1)]
+
+    def serve(**kw):
+        server = DecodeServer(wl, tree, decode_slots=2, page_size=2,
+                              max_prompt_len=64, max_len=POSITIONS, **kw)
+        reqs = [server.submit(ids_of(n, seed=30 + i), g)
+                for i, (n, g) in enumerate(shapes)]
+        deepest = 0
+        while server.busy and server.step():
+            deepest = max(deepest, len(server._ring))
+        server.drain()
+        assert server.mgr.free_pages == server.mgr.capacity
+        return server, deepest, [list(r.tokens) for r in reqs]
+
+    server, deepest, tokens = serve(
+        **({} if asked is None else {"dispatch_lag": asked}))
+    assert server.dispatch_lag == deepest == held
+    assert [len(t) for t in tokens] == [g for _, g in shapes]
+    if held != 1:
+        assert tokens == serve(dispatch_lag=1)[2]
+
+
 # ------------------------------- (c) the discrete choices, compared as sets
 
 def test_selected_rows_and_routed_experts_equal_the_reference(tiny,
@@ -448,8 +484,10 @@ def test_sizes_of_the_source():
     np.testing.assert_allclose(
         cfg.indexer_inv_freq, ref.inv_freq(1e7, 64), rtol=1e-7)
     file_cfg = KeyeVL2Config.from_arch(json.load(open(CONFIG_FILE)))
+    assert cfg.dispatch_lag == 1     # the class states no deployment
     assert file_cfg == dataclasses.replace(cfg, n_layers=6,
-                                           max_position_embeddings=16896)
+                                           max_position_embeddings=16896,
+                                           dispatch_lag=8)
     with pytest.raises(ValueError, match="mrope_section"):
         KeyeVL2Config.from_arch({"rope_scaling": {"mrope_section": [8, 8]}})
 
@@ -500,6 +538,8 @@ def test_configuration_file_states_its_cut():
                 "chunk_sizes", "router", "precision", "weights", "ties"):
         assert cfg["assumed"][key]
     assert "8-stage pipeline" in cfg["deployment"]
+    # the deployment keeps 8 dispatches in flight, and says why
+    assert cfg["dispatch_lag"] == 8 and "110 ms" in cfg["dispatch_lag_note"]
     # the program reads the file as the benchmark's adapter hands it over
     sys.path.insert(0, os.path.join(ROOT, "benchmark"))
     try:
@@ -509,6 +549,7 @@ def test_configuration_file_states_its_cut():
     model = create_model_from_config(
         seq_len=fam.dims(cfg)["positions"], **fam.program_flags(cfg)).model
     assert model.param_shapes() == ref.param_shapes(cfg)
+    assert model.dispatch_lag == 8
     shapes = model.cache_shapes(1 + 16 * 264, 64)
     assert shapes["layer_5"]["kv"].shape == (4225, 64, 1024)
     assert shapes["layer_5"]["index_k"].shape == (4225, 64, 128)
