@@ -35,12 +35,15 @@ the chip anyway), both through the ONE page table a slot:
 
 The expert layer (:func:`grouped_experts`) is dropless and follows the
 assignments: the ``[T, k]`` assignments are flattened and sorted by expert
-into tile-aligned groups, the rows gathered, the three products run as one
-Pallas kernel a row tile against that tile's expert
-(ops/grouped_matmul.py: ``grouped_expert_matmul``; ``lax.ragged_dot`` over
-the same layout is its XLA arm), and each token takes its ``k`` weighted rows
-back through the rows' destinations. No one-hot operand, no ``cond`` an
-expert; an expert without a row is not read. Every expert is held: the
+into tile-aligned groups, the three products run as one Pallas kernel a row
+tile against that tile's expert (ops/grouped_matmul.py:
+``grouped_expert_matmul``, which takes the tokens' ``[T, D]`` block and the
+table of the token each padded row holds, and makes a tile's rows in VMEM:
+no padded copy of the rows is written on the way in; ``lax.ragged_dot`` over
+the same layout, behind a gather of the rows, is its XLA arm and what a
+whole sequence takes), and each token takes its ``k`` weighted rows back
+through the rows' destinations. No one-hot operand over experts, no ``cond``
+an expert; an expert without a row is not read. Every expert is held: the
 layer's matrices are ``[num_experts, ...]`` and there is no cut to state.
 
 :meth:`SparseGQAMoEModel.apply` is the prefill chunk over a private one-slot
@@ -181,12 +184,14 @@ def grouped_experts(h: jnp.ndarray, ids: jnp.ndarray, w: jnp.ndarray,
     [E, D, F] and ``wd`` [E, F, D] every expert's matrices. An assignment of
     a token that does not count leaves the sort (no row holds it and
     nothing is multiplied for it). The assignments are sorted by expert into
-    tile-aligned groups (ops/grouped_matmul.py), the rows gathered, the
-    three products run tile by tile against each tile's expert, and every
-    token takes its ``k`` rows back, weighted. Returns (sum over a token's
-    experts of ``w_e * expert_e(h)`` [T, D] float32, [assignments
-    computed, experts that saw a row, rows the products multiplied (whole
-    tiles)] int32)."""
+    tile-aligned groups (ops/grouped_matmul.py), the three products run tile
+    by tile against each tile's expert on ``h`` and the table of the token
+    each padded row holds (the kernel where ``h`` is a chunk's or a decode
+    step's and stays in VMEM; the XLA arm, which gathers the rows, for a
+    whole sequence), and every token takes its ``k`` rows back, weighted.
+    Returns (sum over a token's experts of ``w_e * expert_e(h)`` [T, D]
+    float32, [assignments computed, experts that saw a row, rows the
+    products multiplied (whole tiles)] int32)."""
     t, k = ids.shape
     e, d, f = wg.shape
     ok = jnp.broadcast_to(live[:, None], ids.shape)
@@ -194,11 +199,14 @@ def grouped_experts(h: jnp.ndarray, ids: jnp.ndarray, w: jnp.ndarray,
     with jax.named_scope("experts.sort"):
         lay = grouped_matmul.aligned_layout(
             jnp.where(ok, ids, e).reshape(-1), e, tile)
-        x = h.astype(dtype)[lay["source"] // k]           # [rows padded, D]
+        token = lay["source"] // k       # the token each padded row holds
     with jax.named_scope("experts.grouped"):
         impl = sparse_select.on_chip(kernel_impl, d, f)
-        args = (x, wg.astype(dtype), wu.astype(dtype), wd.astype(dtype),
-                lay["tile_expert"], lay["tiles_used"])
+        if kernel_impl == "auto" and not grouped_matmul.rows_stay_resident(
+                t, d, dtype):
+            impl = "xla"        # a whole sequence (`apply`): gather its rows
+        args = (h.astype(dtype), token, wg.astype(dtype), wu.astype(dtype),
+                wd.astype(dtype), lay["tile_expert"], lay["tiles_used"])
         if impl == "xla":
             out = grouped_matmul.grouped_swiglu_xla(*args, tile=tile)
         else:

@@ -456,21 +456,27 @@ def test_lightning_index_scores_compiles_at_published_widths(one_chip, j,
     assert_kernel(compiled, ma.INDEX_KERNEL_NAME)
 
 
-@pytest.mark.parametrize("rows", [8192, 128], ids=["chunk", "decode_step"])
-def test_grouped_expert_matmul_compiles_at_published_widths(one_chip, rows):
+@pytest.mark.parametrize("tokens", [1024, 16], ids=["chunk", "decode_step"])
+def test_grouped_expert_matmul_compiles_at_published_widths(one_chip,
+                                                            tokens):
     """The expert layer's kernel (ops/grouped_matmul.py) at Keye-VL-2.0's
-    sizes (128 experts of 2048 x 768; a chunk's 8,192 assignments in tiles
-    of 128 rows, a decode step's 128 in tiles of 16): an expert's three
-    matrices double-buffered in VMEM (19 MB), the scalar-prefetched tile
-    table in the weights' index maps."""
+    sizes (128 experts of 2048 x 768, 8 a token; a chunk's 8,192
+    assignments in tiles of 128 rows, a decode step's 128 in tiles of 16):
+    the tokens' own block resident (4 MB a chunk, twice), the row table a
+    ``(tile, 1)`` block a grid step and the tile's rows made from both on
+    the matrix unit, an expert's three matrices double-buffered in VMEM
+    (19 MB), the scalar-prefetched tile table in the weights' index maps."""
     from distributed_pipeline_tpu.ops import grouped_matmul as gm
 
-    e, d, f = 128, 2048, 768
+    e, d, f, k = 128, 2048, 768, 8
+    rows = tokens * k
     tile = gm.row_tile(rows)
     padded = gm.padded_rows(rows, e, tile)
     assert (tile, padded) == {8192: (128, 24448), 128: (16, 2048)}[rows]
+    assert gm.rows_stay_resident(tokens, d, jnp.bfloat16)
     compiled = gm.grouped_swiglu.lower(
-        sds((padded, d), jnp.bfloat16, one_chip),
+        sds((tokens, d), jnp.bfloat16, one_chip),
+        sds((padded,), jnp.int32, one_chip),
         sds((e, d, f), jnp.bfloat16, one_chip),
         sds((e, d, f), jnp.bfloat16, one_chip),
         sds((e, f, d), jnp.bfloat16, one_chip),

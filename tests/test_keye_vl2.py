@@ -6,7 +6,10 @@ longer than it, a chunk boundary inside the selection), the family through
 ``DecodeServer`` with short and long requests in one queue, the discrete
 choices compared as sets, the grouped expert layer against the reference's
 expert-at-a-time sum (even, skewed, empty-expert routing), the share test,
-the grouped pass's layout, kernel and counters by hand, softmax routing with one group by hand,
+the grouped pass's layout (by hand and against a plain loop), its kernel on
+the tokens' block and the row table, its counters by hand, the guard that no
+padded copy of the rows is made in front of the kernel, softmax routing with
+one group by hand,
 the two copies of the reference and the configuration file."""
 
 import dataclasses
@@ -389,29 +392,179 @@ def test_aligned_layout_by_hand():
     assert lay["dest"].tolist() == [8, 0, 0, 1, 2, 3, 4]
 
 
-def test_grouped_kernel_equals_its_xla_arm():
-    """ops/grouped_matmul.py's kernel, interpreted, against ``ragged_dot``
-    over the same tile-aligned layout, at a tile-aligned tiny size: skewed
-    groups (one expert with three tiles, one with none), tiles behind the
-    last used one skipped."""
-    e, d, f, tile, m = 4, 128, 128, 16, 72
-    rng = np.random.default_rng(0)
-    key = jnp.asarray(rng.choice([0, 0, 0, 0, 2, 3, 4], m), jnp.int32)
-    lay = grouped_matmul.aligned_layout(key, e, tile)
+def layout_by_loop(key, experts, tile):
+    """The layout, an expert and a row at a time."""
+    rows = grouped_matmul.padded_rows(len(key), experts, tile)
+    source, dest = np.zeros(rows, int), np.zeros(len(key), int)
+    tile_expert, sizes, at = [], [], 0
+    for e in range(experts):
+        mine = np.nonzero(key == e)[0]          # in the assignments' order
+        sizes.append(len(mine))
+        source[at:at + len(mine)] = mine
+        dest[mine] = at + np.arange(len(mine))
+        n_tiles = -(-len(mine) // tile)
+        tile_expert += [e] * n_tiles
+        at += n_tiles * tile
+    used = len(tile_expert)
+    last = tile_expert[-1] if used else experts - 1
+    return {"sizes": sizes, "tiles_used": [used], "source": source,
+            "dest": dest,
+            "tile_expert": tile_expert + [last] * (rows // tile - used)}
+
+
+@pytest.mark.parametrize("m, experts, tile", [(160, 16, 16), (128, 128, 16),
+                                              (1024, 8, 128)],
+                         ids=["m160_e16_t16", "m128_e128_t16",
+                              "m1024_e8_t128"])
+@pytest.mark.parametrize("kind", ["mixed", "all_left", "one_expert",
+                                  "first_and_last"])
+def test_aligned_layout_equals_a_plain_loop(m, experts, tile, kind):
+    """``aligned_layout`` (sorts, compares and one scatter) against a loop
+    over experts and rows: every output, under routing with assignments
+    that left the sort, with none that stayed, with one expert taking all
+    and with only the first and the last expert taken; a row that holds
+    nothing names assignment 0."""
+    rng = np.random.default_rng(m + len(kind))
+    key = {"mixed": rng.integers(0, experts + 1, m),
+           "all_left": np.full(m, experts),
+           "one_expert": np.full(m, experts // 2),
+           "first_and_last": rng.choice([0, experts - 1, experts], m)}[kind]
+    got = jax.tree_util.tree_map(np.asarray, jax.jit(
+        lambda k: grouped_matmul.aligned_layout(k, experts, tile))(
+            jnp.asarray(key, jnp.int32)))
+    want = layout_by_loop(key, experts, tile)
+    assert sorted(got) == sorted(want)
+    for name, value in want.items():
+        assert got[name].dtype == np.int32, name
+        assert got[name].tolist() == list(value), name
+
+
+KERNEL_EXPERTS = 4
+
+
+def kernel_routing(kind, t, rng):
+    """[T, 2] held experts of ``KERNEL_EXPERTS`` (that number itself: an
+    assignment that left the sort, as one of a token that does not count)."""
+    e = KERNEL_EXPERTS
+    if kind == "skewed":        # PR 35's case: 0 holds most, 1 none, some left
+        return rng.choice([0, 0, 0, 0, 2, 3, e], (t, 2))
+    if kind == "no_row":        # expert 1 sees no row at all
+        return np.stack([rng.permutation([0, 2, 3])[:2] for _ in range(t)])
+    if kind == "past_one_tile":  # expert 0 holds a row of EVERY token
+        return np.stack([np.zeros(t, int), rng.integers(1, e, t)], 1)
+    if kind == "dead_tokens":   # `live` false: a third of them, the tail too
+        ids = np.stack([rng.permutation(e)[:2] for _ in range(t)])
+        dead = rng.random(t) < 0.3
+        dead[-5:] = True
+        return np.where(dead[:, None], e, ids)
+    assert kind == "one_expert"  # every token on expert 2 and nowhere else
+    return np.stack([np.full(t, 2), np.full(t, e)], 1)
+
+
+@pytest.mark.parametrize("kind", ["skewed", "no_row", "past_one_tile",
+                                  "dead_tokens", "one_expert"])
+@pytest.mark.parametrize("tile, t", [(16, 40), (128, 200)],
+                         ids=["tile16", "tile128"])
+def test_grouped_kernel_with_its_row_table(tile, t, kind):
+    """ops/grouped_matmul.py's kernel, interpreted, on the tokens' own
+    ``[T, D]`` block and the row table (bfloat16, widths scaled down), both
+    tiles the program takes: against ``ragged_dot`` behind a gather over the
+    same layout, and against a plain loop, a token and a dense expert at a
+    time; the rows the kernel's one-hot product makes are ``h[source // k]``
+    to the bit; tiles behind the last used one are skipped."""
+    e, d, f, k = KERNEL_EXPERTS, 128, 128, 2
+    ids = kernel_routing(kind, t, np.random.default_rng(tile + len(kind)))
+    lay = grouped_matmul.aligned_layout(
+        jnp.asarray(ids.reshape(-1), jnp.int32), e, tile)
+    token = lay["source"] // k
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
-    x = jax.random.normal(ks[0], (m, d), jnp.bfloat16)[
-        jnp.minimum(lay["source"], m - 1)]
-    wg, wu = (0.1 * jax.random.normal(k, (e, d, f), jnp.bfloat16)
-              for k in ks[1:3])
+    h = jax.random.normal(ks[0], (t, d), jnp.bfloat16)
+    wg, wu = (0.1 * jax.random.normal(key, (e, d, f), jnp.bfloat16)
+              for key in ks[1:3])
     wd = 0.1 * jax.random.normal(ks[3], (e, f, d), jnp.bfloat16)
-    args = (x, wg, wu, wd, lay["tile_expert"], lay["tiles_used"])
+
+    def bits(a):
+        return np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16))
+    assert (bits(grouped_matmul._rows_of(h, token[:, None]))
+            == bits(h[token])).all()
+
+    args = (h, token, wg, wu, wd, lay["tile_expert"], lay["tiles_used"])
     want = grouped_matmul.grouped_swiglu_xla(*args, tile=tile)
-    got = grouped_matmul.grouped_swiglu(*args, tile=tile, interpret=True)
+    got = np.asarray(grouped_matmul.grouped_swiglu(
+        *args, tile=tile, interpret=True))
     used = int(lay["tiles_used"][0]) * tile
-    assert 0 < used < x.shape[0]
-    np.testing.assert_allclose(np.asarray(got)[:used],
-                               np.asarray(want)[:used], atol=2e-3)
-    assert float(jnp.max(jnp.abs(want[:used]))) > 0.05
+    assert 0 < used < token.shape[0]
+    np.testing.assert_allclose(got[:used], np.asarray(want)[:used],
+                               atol=2e-3)
+    sizes = np.bincount(ids.reshape(-1), minlength=e + 1)[:e]
+    assert used == tile * int((-(-sizes // tile)).sum())
+    if kind in ("skewed", "past_one_tile"):
+        assert sizes[0] > tile                  # an expert past one tile
+    if kind in ("skewed", "no_row"):
+        assert sizes[1] == 0
+
+    f32 = jnp.float32
+    dest = np.asarray(lay["dest"]).reshape(t, k)
+    for row in range(t):
+        x = h[row].astype(f32)
+        for j in range(k):
+            if ids[row, j] == e:
+                continue
+            g, u, dn = (m[ids[row, j]].astype(f32) for m in (wg, wu, wd))
+            a = (jax.nn.silu(x @ g) * (x @ u)).astype(jnp.bfloat16)
+            np.testing.assert_allclose(
+                got[dest[row, j]], np.asarray(a.astype(f32) @ dn),
+                atol=3e-3)
+    assert np.abs(got[:used]).max() > 0.05
+
+
+def chunk_jaxpr(t, e=128, d=2048, f=768, k=8):
+    """``grouped_experts`` traced (nothing runs) as the chip would take it
+    at Keye-VL-2.0's widths: -> (every value made outside a kernel's body
+    as (shape, dtype), the kernels' names)."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    jaxpr = jax.make_jaxpr(lambda *a: prog.grouped_experts(
+        *a, dtype=jnp.bfloat16))(
+            sds((t, d), jnp.float32), sds((t, k), jnp.int32),
+            sds((t, k), jnp.float32), sds((t,), bool), sds((e, d, f)),
+            sds((e, d, f)), sds((e, f, d)))
+    values, kernels = [], []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            values.extend((v.aval.shape, v.aval.dtype) for v in eqn.outvars)
+            if eqn.primitive.name == "pallas_call":
+                kernels.append(eqn.params["name"])
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    return values, kernels
+
+
+@pytest.mark.parametrize("t, kernel", [(1024, True), (16, True),
+                                       (16896, False)],
+                         ids=["chunk", "decode_step", "whole_sequence"])
+def test_no_padded_copy_of_the_rows_in_front_of_the_kernel(monkeypatch, t,
+                                                           kernel):
+    """On the chip a prefill chunk (4 MB of rows) and a decode step take
+    the kernel, and nothing of ``[padded rows, D]`` in the operands' type is
+    made outside it: the rows go in as ``h`` and the row table. A whole
+    sequence (``apply``: past the budget stated in ops/grouped_matmul.py)
+    takes the XLA arm, which gathers them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d, k, e = 2048, 8, 128
+    assert grouped_matmul.rows_stay_resident(t, d, jnp.bfloat16) == kernel
+    values, kernels = chunk_jaxpr(t)
+    padded = grouped_matmul.padded_rows(
+        t * k, e, grouped_matmul.row_tile(t * k))
+    copies = [v for v in values if v == ((padded, d), jnp.bfloat16)]
+    if kernel:
+        assert kernels == [grouped_matmul.KERNEL_NAME] and not copies
+        assert ((padded, d), jnp.float32) in values     # the kernel's out
+    else:
+        assert not kernels and copies
 
 
 def test_shares_add_up_to_the_uncut_layer(tiny):
