@@ -30,6 +30,7 @@ from typing import Any, Dict, Iterable, Iterator, Optional
 
 import numpy as np
 
+from ..obs import trace as trace_lib
 from .dataset import (
     BOS_ID,
     EOS_ID,
@@ -213,10 +214,17 @@ def _prefetched(gen_factory, *, num_workers: int, depth: int,
 
     def worker(wid: int) -> None:
         q = queues[wid]
+        tr = trace_lib.FOLLOW   # one is_enabled() a batch outside a session
         try:
-            for batch in gen_factory(worker_id=wid, stride=num_workers):
-                if not _put(q, batch):
-                    return
+            batches = gen_factory(worker_id=wid, stride=num_workers)
+            while True:
+                # the making of one batch on this thread (which of it holds
+                # the GIL against the step loop); the wait in _put is not in
+                with tr.span("data.assemble", "data",
+                             args={"worker": wid} if tr.enabled else None):
+                    batch = next(batches, _END)
+                if batch is _END or not _put(q, batch):
+                    break
             _put(q, _END)
         except BaseException as e:  # propagate to the consumer, don't die silent
             _put(q, e)

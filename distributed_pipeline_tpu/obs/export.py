@@ -579,6 +579,31 @@ def _prom_ledger(p: _Prom, run_dir: str,
                         "speculative decoding")
 
 
+def _prom_ticks(p: _Prom, ticks: Any, lab: Dict[str, Any]) -> None:
+    """The decode server's own account of its ticks since its first token
+    (utils/perf.py::StallBreakdown.summary, off the replica's beacon)."""
+    if not isinstance(ticks, dict):
+        return
+    for kind, row in (ticks.get("kinds") or {}).items():
+        for q, key in (("0.5", "p50_s"), ("0.99", "p99_s"), ("1", "max_s")):
+            p.add("dpt_tick_seconds", row.get(key),
+                  {**lab, "kind": kind, "quantile": q},
+                  help_="one entry of the server's step to the next, by "
+                        "what the tick dispatched")
+    stalls = ticks.get("stalls") or {}
+    p.add("dpt_stalls_total", stalls.get("count"), lab,
+          help_="ticks that ran past max(20 ms, 4x their kind's median)")
+    p.add("dpt_stall_seconds_total", stalls.get("seconds"), lab,
+          help_="seconds the stalled ticks ran over their kind's median")
+    for what, n in (ticks.get("dry") or {}).items():
+        p.add("dpt_dry_dispatches_total", n, {**lab, "dispatch": what},
+              help_="dispatches before which nothing was left in flight: "
+                    "the device was starved")
+        p.add("dpt_dispatches_total",
+              (ticks.get("dispatches") or {}).get(what),
+              {**lab, "dispatch": what})
+
+
 def _prom_fleet(p: _Prom, fleet_dir: str, now: float) -> None:
     from ..serving.fleet import ReplicaPaths, read_json_file
 
@@ -609,6 +634,7 @@ def _prom_fleet(p: _Prom, fleet_dir: str, now: float) -> None:
                           {**lab, "category": cat[:-2]},
                           help_="in-attempt serving-time decomposition "
                                 "from the replica's beacon")
+                _prom_ticks(p, snap.get("ticks"), lab)
             if b.get("accept_rate") is not None:
                 # live speculative gauges off the beacon (no --cost_ledger
                 # needed): same names the ledger path emits per program

@@ -272,7 +272,9 @@ class Tracer:
         self.proc = proc
         self._n = 0
         self._f: Any = None
-        self._stack: list = []
+        # the open spans, innermost last: one stack a THREAD, so that two
+        # threads that open spans at once keep their own parent chains
+        self._local = threading.local()
         self._lock = threading.Lock()
 
     @property
@@ -288,8 +290,16 @@ class Tracer:
         self._n += 1
         return f"{self.proc}:{self._n}"
 
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
     def _parent(self) -> Optional[str]:
-        return self._stack[-1] if self._stack else None
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     # --------------------------------------------------------------- events
 
@@ -308,17 +318,18 @@ class Tracer:
     def _push(self) -> str:
         with self._lock:
             sid = self._next_id()
-            self._stack.append(sid)
+        self._stack().append(sid)
         return sid
 
     def _pop(self, span: _Span, dur_s: float) -> None:
-        with self._lock:
-            if span.sid in self._stack:
-                self._stack.remove(span.sid)
-            parent = self._parent()
+        # a ``with`` block closes on the thread that opened it, innermost
+        # first: the span is the top of its own thread's stack
+        stack = self._stack()
+        if stack and stack[-1] == span.sid:
+            stack.pop()
         self._emit({"ph": "X", "name": span.name, "cat": span.cat,
                     "t": span._t0, "dur": dur_s,
-                    "sid": span.sid, "parent": parent,
+                    "sid": span.sid, "parent": self._parent(),
                     "trace": span.trace_id, "args": span.args},
                    session=span._ann is not None)
 
@@ -333,7 +344,7 @@ class Tracer:
             return ""  # unarmed, no session: as free as NULL's
         with self._lock:
             sid = self._next_id()
-            parent = self._parent()
+        parent = self._parent()
         self._emit({"ph": "X", "name": name, "cat": cat, "t": t0,
                     "dur": max(0.0, dur_s), "sid": sid,
                     "parent": parent, "trace": trace_id,
@@ -348,7 +359,7 @@ class Tracer:
             return ""
         with self._lock:
             sid = self._next_id()
-            parent = self._parent()
+        parent = self._parent()
         self._emit({"ph": "i", "name": name, "cat": cat,
                     "t": time.time() if t is None else t, "sid": sid,
                     "parent": parent, "trace": trace_id,
@@ -363,6 +374,7 @@ class Tracer:
         on (``session``: what the span saw when it opened; None asks
         now), the shard when armed."""
         event = {k: v for k, v in event.items() if v is not None}
+        event["tid"] = threading.get_ident()
         if profiler_on() if session is None else session:
             _RING.append(event)
         if self.path is None:

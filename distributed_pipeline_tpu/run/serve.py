@@ -305,6 +305,10 @@ def _serve_single(settings: ServeSettings) -> dict:
         result["accepted_tokens_per_s"] = result[
             "decode_tokens_per_s_per_chip"]
     result.update(server.prefix_stats())
+    # the server's own account of its ticks since the first token: by
+    # kind, the time between ticks, dry dispatches, stalls (no records)
+    server.ticks.close()
+    result["ticks"] = server.ticks.summary(records=False)
     if settings.cost_ledger:
         # roofline attribution off the live executables (obs/ledger.py);
         # n_devices=1: replicated decode, per-chip == service rate
@@ -471,6 +475,7 @@ def _fleet_worker_main(settings: ServeSettings) -> dict:
                 / max(time.perf_counter() - t_serve0, 1e-9), 1)
         return extra
 
+    proto.tracker.ticks = server.ticks   # rides the beacon's snapshot
     proto.write_beacon(tick)
     proto.announce_ready(step)
     print(f"[serve-worker {rid}] ready at step {step} "

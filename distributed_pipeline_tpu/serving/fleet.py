@@ -65,7 +65,7 @@ import glob
 import os
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from ..chaos import goodput as goodput_lib
 from ..chaos.inject import COMMIT_MARKERS
@@ -139,6 +139,12 @@ class ServingTracker:
         # the hot-swap drain/load windows on the timeline are exactly the
         # ledger's drain_s/swap_s — they can never disagree
         self.tracer = trace_lib.NULL
+        # the decode server's account of its ticks (run/serve.py wires
+        # it): its summary, without the stall records, rides the
+        # snapshot, refreshed once a second at most
+        self.ticks: Any = None
+        self._ticks_snap: Optional[dict] = None
+        self._ticks_at = 0.0
 
     def book(self, category: str, seconds: float) -> None:
         self._cats[category] += max(0.0, seconds)
@@ -159,11 +165,17 @@ class ServingTracker:
     def snapshot(self) -> Dict[str, float]:
         wall = max(0.0, time.time() - self.t_start)
         booked = sum(self._cats.values())
-        return {
+        snap: Dict[str, Any] = {
             "wall_s": round(wall, 6),
             "serving_s": round(max(0.0, wall - booked), 6),
             **{c: round(v, 6) for c, v in self._cats.items()},
         }
+        if self.ticks is not None:
+            if self._ticks_snap is None or wall - self._ticks_at >= 1.0:
+                self._ticks_snap = self.ticks.summary(records=False)
+                self._ticks_at = wall
+            snap["ticks"] = self._ticks_snap
+        return snap
 
 
 class WorkerProtocol:

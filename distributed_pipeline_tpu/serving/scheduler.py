@@ -40,6 +40,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import sys
 import time
 from typing import Any, Deque, List, Optional
 
@@ -48,7 +49,7 @@ import numpy as np
 
 from ..obs import trace as trace_lib
 from ..utils.perf import EventStats, RecompileMonitor, SanitizeReport, \
-    device_peak_flops
+    StallBreakdown, device_peak_flops
 from ..utils.perf import transformer_decode_flops_per_token \
     as decode_flops_per_token
 from .engine import DecodeEngine
@@ -56,6 +57,16 @@ from .paged_kv import TRASH_PAGE, PageManager, PrefixCache
 from .spec import DRAFT_KINDS, ngram_propose, truncated_draft
 
 __all__ = ["Request", "DecodeServer", "one_shot_decode"]
+
+# The server's own account of its ticks (utils/perf.py::StallBreakdown,
+# always on). The phases lie on the boundaries of the serve.* spans of the
+# same names (admit holds the prefill dispatch; fetch_host is serve.fetch
+# less its fetch_wait); a dispatch's bit says what kind of tick it makes.
+TICK_PHASES = ("sweep", "admit", "prefill_chunk", "decode_dispatch",
+               "fetch_wait", "fetch_host")
+TICK_DISPATCHES = (("prefill", 1), ("chunk", 1), ("decode", 2), ("spec", 4))
+_PREFILL, _CHUNK, _DECODE, _SPEC = range(4)
+TICK_KINDS = ("idle", "prefill", "decode", "prefill+decode") + ("spec",) * 4
 
 
 @dataclasses.dataclass
@@ -274,6 +285,13 @@ class DecodeServer:
         names = tuple(getattr(workload.model, "counters", ()))
         self.counted = {"prefill": dict.fromkeys(names, 0),
                         "decode": dict.fromkeys(names, 0)}
+        # every tick, booked whether or not anything traces; found again
+        # as perf.tick_account("serve") once the server is gone, and not
+        # cleared by reset_stats (its steady point is the first token)
+        self.ticks = StallBreakdown(
+            "serve", phases=TICK_PHASES, waits=("fetch_wait",),
+            dispatches=TICK_DISPATCHES, kinds=TICK_KINDS,
+            tracer=self.tracer)
 
     # ----------------------------------------------------------- gauges etc.
 
@@ -297,6 +315,9 @@ class DecodeServer:
             if self._recompiles_at_first_token is not None:
                 self.sanitize_report.note_recompiles(
                     self._recompiles, self._recompiles_at_first_token)
+            # where an untraced run shows what its ticks were
+            self.ticks.close()
+            print(self.ticks.report_line(), file=sys.stderr, flush=True)
         return self._recompiles.count
 
     def write_sanitize_report(self, out_dir: str) -> str:
@@ -589,6 +610,7 @@ class DecodeServer:
     def _book_first_token(self, req: Request, now: float) -> None:
         req.ttft_s = now - req.submit_t
         self.ttft.add(req.ttft_s)
+        self.ticks.mark_steady()    # (the first one counts)
         if self.tracer.enabled:
             self._request_span("request.first_token", req, req.admit_t, now)
 
@@ -670,6 +692,7 @@ class DecodeServer:
         if not self.queue:
             return False  # hot path: nothing to admit, skip the slot scan
         tr = self.tracer
+        t0 = time.perf_counter()
         with tr.span("serve.admit", "serve") as sp:
             batch = self._admit_batch()
             if tr.enabled:
@@ -684,7 +707,8 @@ class DecodeServer:
                     "pages_full": sum(len(st.pages) for st in held),
                     "pages_window": sum(len(st.window_pages) for st in held
                                         if st.window_pages is not None)}
-            return bool(batch)
+        self.ticks.phase("admit", time.perf_counter() - t0)
+        return bool(batch)
 
     def _admit_batch(self) -> List[tuple]:
         free = [s for s in range(len(self.slots)) if self.slots[s] is None]
@@ -733,6 +757,7 @@ class DecodeServer:
             lens[i] = req.prompt_len
             smap[i] = slot
             stables[i] = self.block_tables[slot]
+        self.ticks.dispatched(_PREFILL, self._newest())
         with self.tracer.span("serve.prefill_dispatch", "serve"):
             toks = self.engine.prefill(ids, lens, smap, stables)
         if self._draft_engine is not None:
@@ -776,10 +801,13 @@ class DecodeServer:
                                   np.int32)
             window_row[:len(st.window_pages)] = st.window_pages
         tr = self.tracer
+        self.ticks.dispatched(_CHUNK, self._newest())
+        t0 = time.perf_counter()
         with tr.span("serve.prefill_chunk", "serve", args={
                 "tokens": n_valid, "slot": slot} if tr.enabled else None):
             out = self.engine.prefill_one_chunk(
                 ids, start, n_valid, slot, table_row, is_last, window_row)
+        self.ticks.phase("prefill_chunk", time.perf_counter() - t0)
         self.prefill_steps += 1
         self.prompt_tokens_prefilled += n_valid
         self.prefill_token_slots += size
@@ -806,12 +834,23 @@ class DecodeServer:
         guard still raises on an implicit transfer, but the trip's site
         lands in the report on the way out."""
         tr = self.tracer
-        with (self.sanitize_report.watch() if self.sanitize
-              else contextlib.nullcontext()), \
-            tr.span("serve.step", "serve", args={
-                "queued": len(self.queue),
-                "active": int(self.active.sum())} if tr.enabled else None):
-            return self._step_inner()
+        on = tr.enabled
+        queued, active = len(self.queue), int(np.count_nonzero(self.active))
+        self.ticks.begin(queued, active, len(self._ring),
+                         self._recompiles.count, on)
+        try:
+            with (self.sanitize_report.watch() if self.sanitize
+                  else contextlib.nullcontext()), \
+                tr.span("serve.step", "serve", args={
+                    "queued": queued, "active": active} if on else None):
+                return self._step_inner()
+        finally:
+            self.ticks.end()
+
+    def _newest(self) -> Any:
+        """The newest result still in flight (None: nothing is): what the
+        account asks ``is_ready()`` of before a dispatch."""
+        return self._ring[-1][0] if self._ring else None
 
     def _sweep(self) -> None:
         """EOS sweep: requests finished by content (observed at fetch, one
@@ -820,11 +859,13 @@ class DecodeServer:
         release inline at dispatch time."""
         if not self._needs_sweep:
             return
+        t0 = time.perf_counter()
         with self.tracer.span("serve.sweep", "serve"):
             for slot, st in enumerate(self.slots):
                 if st is not None and st.req.finished:
                     self._release(slot)
             self._needs_sweep = False
+        self.ticks.phase("sweep", time.perf_counter() - t0)
 
     def _step_inner(self) -> bool:
         self._sweep()
@@ -855,6 +896,8 @@ class DecodeServer:
                 self._recompiles_at_first_token = self._recompiles.count
             return dispatched
         if self.active.any():
+            self.ticks.dispatched(_DECODE, self._newest())
+            t0 = time.perf_counter()
             with self.tracer.span("serve.decode_dispatch", "serve"):
                 if self._dirty:
                     self.engine.set_block_tables(
@@ -865,6 +908,7 @@ class DecodeServer:
                 snap = [(s, st.req) for s, st in enumerate(self.slots)
                         if st is not None and self.active[s]]
                 toks = self.engine.decode()
+            self.ticks.phase("decode_dispatch", time.perf_counter() - t0)
             span = self.engine.decode_span
             self.decode_steps += 1
             # occupancy accounting: active vs compiled slot-steps this
@@ -927,18 +971,24 @@ class DecodeServer:
             # target will verify
             self._draft_engine.set_decode_state(cur_tok, cur_pos)
             handles = [self._draft_engine.decode() for _ in range(K)]
+            t0 = time.perf_counter()
             with self.tracer.span("serve.fetch_wait", "serve"):
                 for j, h in enumerate(handles):
                     draft[j] = np.asarray(jax.device_get(h))
+            self.ticks.phase("fetch_wait", time.perf_counter() - t0)
         else:
             for s, st in snap:
                 hist = np.concatenate(
                     [st.req.prompt, np.asarray(st.req.tokens, np.int32)])
                 draft[:, s] = ngram_propose(hist, K)
+        # (a round is synchronous: nothing is in flight, every one is dry)
+        self.ticks.dispatched(_SPEC, self._newest())
         verified = self.engine.verify(draft, cur_tok, cur_pos)
+        t0 = time.perf_counter()
         with self.tracer.span("serve.fetch_wait", "serve"):
             seq = np.asarray(jax.device_get(verified))
         now = time.perf_counter()
+        self.ticks.phase("fetch_wait", now - t0)
         self.decode_steps += 1
         self.spec_rounds += 1
         self.slot_steps_active += len(snap) * (K + 1)
@@ -982,12 +1032,16 @@ class DecodeServer:
         n_counted = self.engine.n_counters
         counted0 = ({k: dict(v) for k, v in self.counted.items()}
                     if n_counted and tr.enabled else None)
+        t_in = time.perf_counter()
+        waited = 0.0
         with tr.span("serve.fetch", "serve") as sp:
             while len(self._ring) > lag:
                 toks_dev, snap, program = self._ring.popleft()
+                t0 = time.perf_counter()
                 with tr.span("serve.fetch_wait", "serve"):
                     # the host WAITING for the device, and nothing else
                     arr = np.asarray(jax.device_get(toks_dev))
+                waited += time.perf_counter() - t0
                 rows = arr if arr.ndim == 2 else arr[None]  # [span|1, S]
                 if n_counted:
                     # the program's counters came with its tokens
@@ -1018,6 +1072,8 @@ class DecodeServer:
                         program: {k: v - counted0[program][k]
                                   for k, v in group.items()}
                         for program, group in self.counted.items()})
+        self.ticks.phase("fetch_wait", waited)
+        self.ticks.phase("fetch_host", time.perf_counter() - t_in - waited)
 
     def drain(self) -> None:
         """Run until every submitted request has completed and every token

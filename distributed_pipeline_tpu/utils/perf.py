@@ -9,13 +9,16 @@ first-class gauges, and nothing in the hot path blocks on the device.
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import json
 import logging
+import math
 import os
 import time
 import traceback
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
 
@@ -25,7 +28,7 @@ __all__ = ["device_peak_flops", "transformer_train_flops_per_token",
            "AOTStep", "RecompileMonitor",
            "device_summary", "tpu_kernel_census",
            "SanitizeReport", "SANITIZE_REPORT_NAME",
-           "StallBreakdown", "EventStats", "GoodputTracker",
+           "StallBreakdown", "tick_account", "EventStats", "GoodputTracker",
            "tree_bytes", "tree_bytes_per_replica", "peak_live_bytes"]
 
 # Peak dense bf16 FLOP/s per chip (public spec sheets), matched IN ORDER
@@ -484,11 +487,145 @@ class SanitizeReport:
             return ""
 
 
-class StallBreakdown:
-    """Per-step stall accounting: WHERE the host loop's wall time goes,
-    so "is the input pipeline the bottleneck" is a number, not a guess.
+# ---- the step loops' own account of every tick (always on)
 
-    Four gauges, attributed by the trainer / device-prefetch wrapper:
+# A tick is STALLED when its whole period (tick part + between part) passes
+# max(STALL_FLOOR_S, STALL_FACTOR x the running median of its kind).
+STALL_FLOOR_S = 0.020
+STALL_FACTOR = 4.0
+
+# Log-spaced buckets of a tick's period: four an octave from 10 us up
+# (the last holds everything past ~2.8 min), so p50 / p99 need no list of
+# samples and read to within a bucket's width (19 %).
+_BUCKET0_S = 1e-5
+_N_BUCKETS = 96
+_BUCKET_MID_S = tuple(_BUCKET0_S * 2.0 ** ((i + 0.5) / 4.0)
+                      for i in range(_N_BUCKETS))
+
+# Seconds the cyclic collector has run in this process, and the start of
+# the collection that is running: a gc.callbacks hook, installed with the
+# first account, that costs two clock readings a COLLECTION and nothing a
+# tick.
+_GC = [0.0, 0.0]
+_ACCOUNTS: Dict[str, "StallBreakdown"] = {}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC[1] = time.perf_counter()
+    else:
+        _GC[0] += time.perf_counter() - _GC[1]
+
+
+def tick_account(name: str) -> Optional["StallBreakdown"]:
+    """The newest account a loop of this name (``serve``, ``train``) made
+    in this process, held strongly: a reader finds it after the loop's
+    owner is gone (plain Python, no device memory)."""
+    return _ACCOUNTS.get(name)
+
+
+class _KindStats:
+    """What the account keeps of one kind of tick since the steady point."""
+
+    __slots__ = ("count", "tick_s", "cpu_s", "between_s", "phase_s",
+                 "buckets", "med", "below", "max_s")
+
+    def __init__(self, n_phases: int) -> None:
+        self.count = 0
+        self.tick_s = self.cpu_s = self.between_s = self.max_s = 0.0
+        self.phase_s = [0.0] * n_phases
+        self.buckets = [0] * _N_BUCKETS
+        self.med = 0      # bucket of the lower median, kept as ticks come
+        self.below = 0    # ticks in the buckets under it
+
+    def add(self, period: float, tick: float, cpu: float, between: float,
+            phases: List[float]) -> None:
+        b = (min(_N_BUCKETS - 1, int(4.0 * math.log2(period / _BUCKET0_S)))
+             if period > _BUCKET0_S else 0)
+        buckets = self.buckets
+        buckets[b] += 1
+        if not self.count:
+            self.med = b
+        elif b < self.med:
+            self.below += 1
+        self.count += 1
+        # the lower median has rank (count - 1) // 2: move the bucket it
+        # lies in by the one tick that came (amortised O(1), no sort)
+        rank = (self.count - 1) // 2
+        while self.below > rank:
+            self.med -= 1
+            self.below -= buckets[self.med]
+        while self.below + buckets[self.med] <= rank:
+            self.below += buckets[self.med]
+            self.med += 1
+        self.tick_s += tick
+        self.cpu_s += cpu
+        self.between_s += between
+        if period > self.max_s:
+            self.max_s = period
+        mine = self.phase_s
+        for i, s in enumerate(phases):
+            mine[i] += s
+
+    def median_s(self) -> float:
+        return _BUCKET_MID_S[self.med] if self.count else 0.0
+
+    def quantile_s(self, q: float) -> float:
+        """Nearest-rank quantile of the period, to a bucket's middle."""
+        # rank ceil(q x count) - 1, in whole thousandths (no float ceil)
+        rank = max(0, -(-round(q * 1000) * self.count // 1000) - 1)
+        seen = 0
+        for b, n in enumerate(self.buckets):
+            seen += n
+            if seen > rank:
+                return min(_BUCKET_MID_S[b], self.max_s)
+        return self.max_s
+
+
+class StallBreakdown:
+    """A step loop's own account of every tick, always on: WHERE the host
+    loop's wall time goes, whether or not anything traces.
+
+    A TICK runs from one entry of the loop's step to the next entry
+    (``DecodeServer.step``; ``TrainLoop.next_batch`` + ``run_step``). It
+    has a tick part (``begin`` -> ``end``) and a between part (``end`` ->
+    the next ``begin``: the caller's time); the two make its PERIOD. For
+    every tick the account books
+
+    * its ``kind``, by what it dispatched (``dispatched``: the kinds are
+      indexed by the OR of the dispatches' bits);
+    * wall seconds of both parts, and CPU seconds of the loop's own thread
+      over the tick part (``time.thread_time``): wall far above CPU is a
+      wait (the GIL, a blocked runtime call, a descheduled process), wall
+      equal to CPU is the loop's own Python or a compile;
+    * wall seconds by phase (``phase``), at the boundaries the loop's spans
+      mark; what no phase covers is ``other``;
+    * DRY dispatches: ``dispatched`` is told, just before a dispatch, the
+      newest result still in flight; if that is ready already
+      (``is_ready()``: no transfer, no wait) the device had nothing
+      queued. The program's own reading of a starved device;
+    * by kind: count, sums, log-spaced bucket counts of the period, and a
+      running median from them;
+    * a STALL RECORD for a tick whose period passes ``max(STALL_FLOOR_S,
+      STALL_FACTOR x its kind's running median)`` (a kind's first tick has
+      nothing to be compared with): the newest 64 are kept, their count
+      and seconds over the median for ever; with a ``tracer`` that is
+      enabled the record is also an instant ``<name>.stall``;
+    * one small tuple a tick in a bounded ring (``ticks``): entry time
+      (``perf_counter``), kind, tick seconds, CPU seconds, between
+      seconds, dry dispatches.
+
+    Everything is of the STEADY part: ``mark_steady`` (the first fetched
+    token; the first completed step) drops what was booked up to and with
+    the tick that called it. A tick is booked when the next begins;
+    ``close`` books the last one, with no between part.
+
+    A normal tick costs two ``thread_time`` readings, one ``perf_counter``
+    reading a boundary and one tuple; it takes no lock and formats nothing.
+    Everything else happens in the stall branch or in ``summary``.
+
+    The train loop's four GAUGES stay what they were (``add`` / ``lap`` /
+    ``sums``, attributed by the trainer / device-prefetch wrapper):
 
     * ``data_wait_s``   — blocked on the host iterator (batch assembly;
       the thread-prefetch queue was empty when the loop asked);
@@ -502,22 +639,77 @@ class StallBreakdown:
       metrics fetch blocks on a k-steps-old output (``dispatch_lag``;
       an upper bound on device execution — it includes queue wait).
 
-    ``add`` accumulates; ``lap`` returns the window's per-step means and
-    resets it (the ``log_interval`` cadence); ``totals`` is cumulative.
-    Gauges with no samples report 0.0 so every sink/bench row carries
-    all four keys.
+    The first three ARE phases of the tick (``add`` books both from the
+    same seconds); ``device_step_s`` spans ticks, and the part of it the
+    loop stood waiting is the phase ``metrics_wait``. ``lap`` returns the
+    window's per-step means and resets it (the ``log_interval`` cadence);
+    ``sums`` is cumulative since construction. Gauges with no samples
+    report 0.0 so every sink/bench row carries all four keys.
     """
 
     GAUGES = ("data_wait_s", "h2d_wait_s", "dispatch_s", "device_step_s")
+    _GAUGE_PHASE = {"data_wait_s": "data_wait", "h2d_wait_s": "h2d",
+                    "dispatch_s": "dispatch"}
+    KEPT_STALLS = 64
+    KEPT_TICKS = 65536
 
-    def __init__(self) -> None:
+    def __init__(self, name: str = "train", *,
+                 phases: Tuple[str, ...] = ("data_wait", "h2d", "dispatch",
+                                            "metrics_wait", "log"),
+                 waits: Tuple[str, ...] = ("data_wait", "metrics_wait"),
+                 dispatches: Tuple[Tuple[str, int], ...] = (("step", 1),),
+                 kinds: Tuple[str, ...] = ("idle", "step"),
+                 tracer: Any = None) -> None:
         self._win = {g: [0.0, 0] for g in self.GAUGES}   # [sum, count]
         self._tot = {g: [0.0, 0] for g in self.GAUGES}
+        self.name = name
+        self.phases = tuple(phases)
+        self._ix = {p: i for i, p in enumerate(self.phases)}
+        self._wait_ix = tuple(self._ix[p] for p in waits)
+        self._gauge_ix = {g: self._ix[p]
+                          for g, p in self._GAUGE_PHASE.items()
+                          if p in self._ix}
+        self.dispatches = tuple(d for d, _ in dispatches)
+        self._bit = tuple(b for _, b in dispatches)
+        self.kinds = tuple(kinds)
+        self.tracer = tracer
+        # the open tick (begin .. end), then pending until the next begin
+        self._open = self._pending = False
+        self._cur = [0.0] * len(self.phases)
+        self._t0 = self._c0 = self._t1 = self._tick_s = self._cpu_s = 0.0
+        self._bits = self._dry = 0
+        self._queued = self._active = self._inflight = 0
+        self._recompiles = 0
+        self._traced = False
+        self._gc0 = 0.0
+        self.n_ticks = 0              # since construction: a tick's number
+        self._booked_until = 0.0      # where the last tick booked ended
+        self.steady_t: Optional[float] = None
+        self._steady_due = False
+        self._reset()
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+        _ACCOUNTS[name] = self
+
+    def _reset(self) -> None:
+        self._kinds: Dict[str, _KindStats] = {}
+        self.n_dispatched = [0] * len(self.dispatches)
+        self.n_dry = [0] * len(self.dispatches)
+        self.stall_count = 0
+        self.stall_seconds = 0.0
+        self.stalls: Deque[Dict[str, Any]] = collections.deque(
+            maxlen=self.KEPT_STALLS)
+        self.ticks: Deque[tuple] = collections.deque(maxlen=self.KEPT_TICKS)
+
+    # ------------------------------------------------------- the four gauges
 
     def add(self, gauge: str, seconds: float) -> None:
         for acc in (self._win[gauge], self._tot[gauge]):
             acc[0] += seconds
             acc[1] += 1
+        i = self._gauge_ix.get(gauge)
+        if i is not None and self._open:
+            self._cur[i] += seconds
 
     @staticmethod
     def _means(accs) -> dict:
@@ -529,14 +721,186 @@ class StallBreakdown:
         self._win = {g: [0.0, 0] for g in self.GAUGES}
         return out
 
-    def totals(self) -> dict:
-        """Cumulative per-step means since construction."""
-        return self._means(self._tot)
-
     def sums(self) -> dict:
         """Cumulative SECONDS per gauge since construction (not means) —
         the goodput decomposition needs absolute time, not rates."""
         return {g: s for g, (s, _) in self._tot.items()}
+
+    # ------------------------------------------------------------- one tick
+
+    def begin(self, queued: int = 0, active: int = 0, inflight: int = 0,
+              recompiles: int = 0, traced: bool = False) -> None:
+        """A tick's entry: books the tick before it (its between part ends
+        here). The arguments are the loop's state at entry, kept for a
+        stall record: requests queued, slots active, results in flight,
+        the recompile count, whether a profiler session is on. A tick that
+        is open already (a step that raised, a batch pulled twice) goes
+        on."""
+        if self._open:
+            return
+        now = time.perf_counter()
+        if self._pending:
+            self._book(now - self._t1, traced, recompiles)
+        if self._steady_due:
+            self._steady_due = False
+            self.steady_t = now
+            self._reset()
+        self._open = True
+        self._queued, self._active, self._inflight = queued, active, inflight
+        self._recompiles, self._traced = recompiles, traced
+        self._gc0 = _GC[0]
+        self._t0 = now
+        self._c0 = time.thread_time()
+
+    def phase(self, name: str, seconds: float) -> None:
+        """Seconds of the open tick spent in one of the loop's phases (a
+        drain or a flush outside any tick books nothing)."""
+        if self._open:
+            self._cur[self._ix[name]] += seconds
+
+    def dispatched(self, what: int, newest: Any = None) -> None:
+        """Called just BEFORE dispatch number ``what`` (an index into
+        ``dispatches``), with the newest result still in flight: dry if
+        there is none or it is ready already."""
+        if not self._open:
+            return
+        self._bits |= self._bit[what]
+        self.n_dispatched[what] += 1
+        if newest is None or newest.is_ready():
+            self.n_dry[what] += 1
+            self._dry += 1
+
+    def end(self) -> None:
+        """The tick part ends (the step returns)."""
+        if not self._open:
+            return
+        self._cpu_s = time.thread_time() - self._c0
+        self._t1 = time.perf_counter()
+        self._tick_s = self._t1 - self._t0
+        self._open = False
+        self._pending = True
+
+    def mark_steady(self) -> None:
+        """The loop is warm (both programs compiled, the first token
+        fetched; the first step complete): the account starts again at the
+        next tick's entry. Only the first call counts."""
+        if self.steady_t is None:
+            self._steady_due = True
+
+    def close(self) -> None:
+        """The loop is over: book the last tick, which has no between
+        part."""
+        if self._pending:
+            self._book(0.0, self._traced, self._recompiles)
+
+    def _book(self, between: float, traced: bool, recompiles: int) -> None:
+        """One whole tick into its kind's sums, ``traced`` and
+        ``recompiles`` being the loop's state as the period ended."""
+        self._pending = False
+        self.n_ticks += 1
+        tick, cpu, cur = self._tick_s, self._cpu_s, self._cur
+        period = tick + between
+        kind = self.kinds[self._bits]
+        stats = self._kinds.get(kind)
+        if stats is None:
+            stats = self._kinds[kind] = _KindStats(len(cur))
+        elif period > STALL_FLOOR_S:
+            median = stats.median_s()
+            if period > STALL_FACTOR * median:
+                self._stalled(kind, period, median, between, traced,
+                              recompiles)
+        stats.add(period, tick, cpu, between, cur)
+        self.ticks.append((self._t0, kind, tick, cpu, between, self._dry))
+        self._booked_until = self._t1 + between
+        for i in range(len(cur)):
+            cur[i] = 0.0
+        self._bits = self._dry = 0
+
+    def _stalled(self, kind: str, period: float, median: float,
+                 between: float, traced: bool, recompiles: int) -> None:
+        """The rare branch: keep the whole of a tick that stalled."""
+        tick = self._tick_s
+        phases = {p: round(s, 6) for p, s in zip(self.phases, self._cur)}
+        phases["other"] = round(tick - sum(self._cur), 6)
+        record = {
+            "t": round(self._t0 + (time.time() - time.perf_counter()), 6),
+            "tick": self.n_ticks, "kind": kind,
+            "wall_s": round(tick, 6), "cpu_s": round(self._cpu_s, 6),
+            "between_s": round(between, 6), "median_s": round(median, 6),
+            "excess_s": round(period - median, 6), "phases": phases,
+            "queued": self._queued, "active": self._active,
+            "inflight": self._inflight,
+            "gc_s": round(_GC[0] - self._gc0, 6),
+            "recompiles": recompiles - self._recompiles,
+            # a profiler session started or stopped inside it: the
+            # caller's own doing, which a reader may set aside
+            "session_edge": bool(traced != self._traced),
+        }
+        self.stall_count += 1
+        self.stall_seconds += period - median
+        self.stalls.append(record)
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            tr.instant(f"{self.name}.stall", self.name, t=record["t"],
+                       args=record)
+
+    # ------------------------------------------------------------ read it
+
+    def summary(self, records: bool = True) -> Dict[str, Any]:
+        """The steady part so far (the ticks booked: an open or pending
+        one is not in yet). By kind: count, seconds, the period's p50 /
+        p99 / max, mean seconds of the tick part, the between part, the
+        CPU and each phase, and the CPU's share of the tick part less its
+        waits. Then the between part's share of all, dispatches and dry
+        dispatches, and the stalls (``records=False``: without the
+        records). ``span_s`` is the steady point to the end of the last
+        tick booked, on two clock readings: ``seconds`` sums to it."""
+        kinds: Dict[str, Any] = {}
+        n = 0
+        seconds = between_s = 0.0
+        for kind, st in self._kinds.items():
+            c = st.count
+            waits = sum(st.phase_s[i] for i in self._wait_ix)
+            phases = {p: round(s / c, 6)
+                      for p, s in zip(self.phases, st.phase_s)}
+            phases["other"] = round((st.tick_s - sum(st.phase_s)) / c, 6)
+            kinds[kind] = {
+                "count": c,
+                "seconds": round(st.tick_s + st.between_s, 6),
+                "p50_s": round(st.quantile_s(0.5), 6),
+                "p99_s": round(st.quantile_s(0.99), 6),
+                "max_s": round(st.max_s, 6),
+                "tick_s": round(st.tick_s / c, 6),
+                "between_s": round(st.between_s / c, 6),
+                "cpu_s": round(st.cpu_s / c, 6),
+                "cpu_share": round(st.cpu_s / (st.tick_s - waits), 4)
+                if st.tick_s > waits else 0.0,
+                "phases": phases}
+            n += c
+            seconds += st.tick_s + st.between_s
+            between_s += st.between_s
+        stalls: Dict[str, Any] = {"count": self.stall_count,
+                                  "seconds": round(self.stall_seconds, 6)}
+        if records:
+            stalls["records"] = list(self.stalls)
+        return {
+            "name": self.name, "steady": self.steady_t is not None,
+            "ticks": n, "seconds": round(seconds, 6),
+            "span_s": round(self._booked_until - self.steady_t, 6)
+            if n and self.steady_t is not None else None,
+            "between_s": round(between_s, 6),
+            "between_share": round(between_s / seconds, 6) if seconds else 0.0,
+            "kinds": kinds,
+            "dispatches": dict(zip(self.dispatches, self.n_dispatched)),
+            "dry": dict(zip(self.dispatches, self.n_dry)),
+            "stalls": stalls}
+
+    def report_line(self) -> str:
+        """``ticks <name> <json of summary()>``: what a loop run with
+        ``sanitize`` leaves on standard error as it stops, so that an
+        untraced run shows where its ticks went."""
+        return (f"ticks {self.name} "
+                + json.dumps(self.summary(), separators=(",", ":")))
 
 
 class GoodputTracker:

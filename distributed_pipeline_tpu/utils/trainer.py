@@ -33,6 +33,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import os
+import sys
 import time
 from typing import Any, Callable, Dict, Iterator, Optional, Sequence, Tuple, \
     Union
@@ -238,7 +239,11 @@ class TrainLoop:
         # eager semantics tests rely on stay the default API behavior.
         self.prefetch_depth = prefetch_depth
         self.dispatch_lag = dispatch_lag
+        # every step booked as one tick (next_batch + run_step, always
+        # on), beside the four gauges; perf.tick_account("train") finds it
         self.stalls = StallBreakdown()
+        self._newest_loss: Any = None  # the last dispatched step's, for
+        # the account's dry-dispatch probe (is_ready(): no transfer)
         # (loop step idx, dispatch-return timestamp, device metrics tree)
         self._inflight: "collections.deque" = collections.deque()
 
@@ -339,6 +344,7 @@ class TrainLoop:
         # goodput tracker, so the trace and the ledger can never disagree.
         self.tracer = trace_lib.tracer_for(
             self.checkpoint_dir, jax.process_index(), armed=self._trace)
+        self.stalls.tracer = self.tracer   # a stalled step is an instant
         # global batch = per-host batch x hosts (reference trainer.py:89)
         self.global_batch = batch_size * jax.process_count()
         dpf = (self.mesh.shape["data"] * self.mesh.shape["fsdp"]
@@ -791,6 +797,9 @@ class TrainLoop:
                 self.sanitize_report.note_recompiles(
                     self._recompiles, self._recompiles_at_first_step)
             self.sanitize_report.write(self.checkpoint_dir)
+            # where an untraced run shows what its steps were
+            self.stalls.close()
+            print(self.stalls.report_line(), file=sys.stderr, flush=True)
         return self._recompiles.count
 
     def _sanitize_guard(self):
@@ -828,7 +837,9 @@ class TrainLoop:
         """Pull the next training batch, attributing host-iterator wait to
         the ``data_wait_s`` stall gauge. With device prefetch on, the
         wrapper attributes its own waits internally (this call returns a
-        buffered :class:`DeviceBatch` without double counting)."""
+        buffered :class:`DeviceBatch` without double counting). A tick of
+        the loop's account begins here and ends with ``run_step``."""
+        self._begin_tick()
         with self.tracer.span("train.next_batch", "train"):
             if self.chaos is not None:
                 # An injected iterator stall is exactly the failure the
@@ -846,6 +857,12 @@ class TrainLoop:
             self.stalls.add("data_wait_s", time.perf_counter() - t0)
             return batch
 
+    def _begin_tick(self) -> None:
+        # (goes on with the open tick when next_batch began it already)
+        self.stalls.begin(inflight=len(self._inflight),
+                          recompiles=self._recompiles.count,
+                          traced=self.tracer.enabled)
+
     def run_step(self, batch: Union[Dict[str, np.ndarray], DeviceBatch]
                  ) -> Dict[str, Any]:
         """One optimizer step (reference run_step, trainer.py:198-201).
@@ -858,9 +875,14 @@ class TrainLoop:
         are fetched/logged while step N runs, so the host never blocks on
         the step it just enqueued (flush_metrics drains the tail)."""
         tr = self.tracer
-        with tr.span("train.run_step", "train",
-                     args={"step": self.step + 1} if tr.enabled else None):
-            return self._run_step(batch)
+        self._begin_tick()
+        try:
+            with tr.span("train.run_step", "train",
+                         args={"step": self.step + 1} if tr.enabled
+                         else None):
+                return self._run_step(batch)
+        finally:
+            self.stalls.end()
 
     def _run_step(self, batch: Union[Dict[str, np.ndarray], DeviceBatch]
                   ) -> Dict[str, Any]:
@@ -880,6 +902,7 @@ class TrainLoop:
                 prepared = self._prepare(batch)
             self.stalls.add("h2d_wait_s", time.perf_counter() - t0)
             n_items = self.get_batch_length(batch)
+        self.stalls.dispatched(0, self._newest_loss)
         t0 = time.perf_counter()
         try:
             with tr.span("train.dispatch", "train"), self.mesh, \
@@ -899,6 +922,7 @@ class TrainLoop:
                 f"(--debug_nans): {e}") from e
         dispatched = time.perf_counter()
         self.stalls.add("dispatch_s", dispatched - t0)
+        self._newest_loss = metrics["loss"]
         if first:
             # Block once so "time to first step" means a COMPLETED step
             # (async dispatch would otherwise stop the clock at enqueue).
@@ -918,6 +942,7 @@ class TrainLoop:
             self._ledger_watch = trace_lib.Stopwatch()
             self._ledger_step0 = self.step + 1
             self._ledger_stall0 = self.stalls.sums()
+            self.stalls.mark_steady()   # the account's, at the same boundary
         self.step += 1
         self._samples += n_items * jax.process_count()
         self._timer.tick()
@@ -946,16 +971,20 @@ class TrainLoop:
         self._g_prev_stall = self._stall_sum()
         self._g_prev_compile = self.goodput.get("compile_s")
         if self.progress_file:
+            t0 = time.perf_counter()
             with tr.span("train.log", "train"):
                 self._write_beacon()
+            self.stalls.phase("log", time.perf_counter() - t0)
         if self.dispatch_lag > 0:
             self._inflight.append((self.step, dispatched, metrics))
             while len(self._inflight) > self.dispatch_lag:
                 self._emit_lagged()
         else:
             logger.logkvs_mean(metrics)
+        t0 = time.perf_counter()
         with tr.span("train.log", "train"):
             self.log_step()
+        self.stalls.phase("log", time.perf_counter() - t0)
         return metrics
 
     def _emit_lagged(self) -> None:
@@ -967,10 +996,13 @@ class TrainLoop:
         just late."""
         step_idx, dispatched, metrics = self._inflight.popleft()
         tr = self.tracer
+        t0 = time.perf_counter()
         with tr.span("train.metrics_wait", "train",
                      args={"step": step_idx} if tr.enabled else None):
             jax.block_until_ready(metrics["loss"])
-        self.stalls.add("device_step_s", time.perf_counter() - dispatched)
+        now = time.perf_counter()
+        self.stalls.phase("metrics_wait", now - t0)
+        self.stalls.add("device_step_s", now - dispatched)
         logger.logkvs_mean(metrics)
 
     def flush_metrics(self) -> None:

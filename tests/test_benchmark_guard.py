@@ -69,7 +69,8 @@ def test_benchmark_own_tests_pass(path):
 def test_accepted_entries_stand_as_their_prs_listed_them():
     """The entry the deselected assertion above looked at, and the order
     the driver reads: what PR 32's benchmark had is a prefix of every list,
-    PR 33's entries lie behind it and PR 35's behind those."""
+    PR 33's entries lie behind it, PR 35's behind those and PR 37's six
+    (the loops' own account of their ticks) last."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
@@ -87,7 +88,10 @@ def test_accepted_entries_stand_as_their_prs_listed_them():
         "decode_hbm_roofline.sparse_gqa", "attended_kv_share.sparse_gqa",
         "expert_rows_needed_share", "mla_block_attend_roofline.sparse_gqa",
         "lightning_index_scores_roofline.sparse_gqa",
-        "grouped_expert_matmul_roofline"]
+        "grouped_expert_matmul_roofline",
+        "tick_stall_share.serve", "device_dry_dispatch_share.serve",
+        "tick_between_share.serve", "decode_tick_device_share.serve",
+        "step_stall_share.train", "device_dry_dispatch_share.train"]
     assert [c["name"] for c in bench["configs"]] == [
         "gpt2-base", "gpt2-large", "deepseek-v3.2-exp-ep16",
         "dots3-note-prev-ep16", "keye-vl-2.0-30b-a3b-pp8"]
@@ -273,6 +277,9 @@ def grouped_programs():
     pytest.param("prefill_mfu.serve.sparse_gqa", constant("PREFILL_PROGRAM"),
                  "grouped_programs", "prefill",
                  id="jit_prefill_chunk_fn.sparse_gqa"),
+    pytest.param("decode_tick_device_share.serve",
+                 constant("DECODE_PROGRAM"), "gpt2_programs", "decode",
+                 id="jit_decode_fn.tick"),
 ])
 def test_program_names_the_readers_look_for(request, bench_run, metric,
                                             benchmark_side, programs, phase):

@@ -78,7 +78,7 @@ def test_prefetch_attributes_stalls():
     stats = StallBreakdown()
     list(prefetch_to_device(host_batches(4), put=lambda b: b, depth=2,
                             stats=stats))
-    totals = stats.totals()
+    totals = stats.sums()
     assert set(totals) == set(StallBreakdown.GAUGES)
     assert totals["data_wait_s"] >= 0.0 and totals["h2d_wait_s"] >= 0.0
 
@@ -142,7 +142,7 @@ def test_sanitizer_and_stalls_clean_under_prefetch(tmp_path):
         loop.flush_metrics()
         assert loop.step == 5
         assert loop.recompile_count == base  # frozen: no silent retrace
-        totals = loop.stalls.totals()
+        totals = loop.stalls.sums()
         assert set(totals) == set(StallBreakdown.GAUGES)
         assert totals["dispatch_s"] > 0.0
         assert totals["device_step_s"] > 0.0  # the lagged fetch observed it

@@ -47,6 +47,84 @@ def test_tracer_roundtrip_nested_spans_and_explicit_ids(tmp_path):
     assert by["step"]["ph"] == "X" and by["mark"]["ph"] == "i"
 
 
+def test_spans_of_two_threads_keep_their_own_parent_chains(tmp_path):
+    """One parent stack a thread: two threads that open nested spans at
+    once (a shortened switch interval interleaves them) each point their
+    ``parent`` links into their own thread, and every event carries the
+    thread's id."""
+    import sys
+    import threading
+
+    tr = trace_lib.tracer_for(str(tmp_path), 0, armed=True)
+    start = threading.Barrier(2)
+
+    def work(label):
+        start.wait(timeout=10)
+        for i in range(200):
+            with tr.span(f"{label}.outer", label, args={"i": i}):
+                with tr.span(f"{label}.inner", label):
+                    tr.complete(f"{label}.booked", label, time.time(), 0.0)
+                tr.instant(f"{label}.mark", label)
+
+    threads = [threading.Thread(target=work, args=(label,))
+               for label in ("a", "b")]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    tr.close()
+    events = trace_lib.read_trace(trace_lib.trace_path(str(tmp_path), 0))
+    assert len(events) == 2 * 200 * 4
+    by_sid = {e["sid"]: e for e in events}
+    assert len(by_sid) == len(events)           # ids stay unique
+    tids = {}
+    for e in events:
+        tids.setdefault(e["cat"], set()).add(e["tid"])
+        kind = e["name"].split(".")[1]
+        if kind == "outer":
+            assert "parent" not in e
+            continue
+        parent = by_sid[e["parent"]]
+        assert parent["cat"] == e["cat"] and parent["tid"] == e["tid"]
+        assert parent["name"].split(".")[1] == {
+            "inner": "outer", "booked": "inner", "mark": "outer"}[kind]
+    assert len(tids["a"]) == len(tids["b"]) == 1 and tids["a"] != tids["b"]
+
+
+def test_data_workers_book_data_assemble_on_their_own_threads(tmp_path,
+                                                              monkeypatch):
+    """``data.assemble`` lies round the making of one batch in a prefetch
+    worker (through FOLLOW: one ``is_enabled()`` a batch outside a
+    session), under the worker's thread id and no parent of the loop's."""
+    import threading
+
+    from distributed_pipeline_tpu.data import load_data_from_args
+
+    tr = trace_lib.tracer_for(str(tmp_path), 0, armed=True)
+    monkeypatch.setattr(trace_lib, "FOLLOW", tr)
+    data = load_data_from_args("train", batch_size=4, dataset="synthetic-lm",
+                               seq_len=16, vocab_size=64, seed=0,
+                               data_loader_workers=2)
+    with tr.span("train.next_batch", "train"):
+        batches = [next(data) for _ in range(6)]
+    data.close()
+    tr.close()
+    assert all(b["input_ids"].shape == (4, 16) for b in batches)
+    events = trace_lib.read_trace(trace_lib.trace_path(str(tmp_path), 0))
+    made = [e for e in events if e["name"] == "data.assemble"]
+    assert len(made) >= 6 and all(e["cat"] == "data" for e in made)
+    assert {e["args"]["worker"] for e in made} == {0, 1}
+    assert all("parent" not in e for e in made)
+    assert threading.get_ident() not in {e["tid"] for e in made}
+    assert len({e["tid"] for e in made}) == 2
+
+
 def test_second_session_appending_to_shard_keeps_ids_unique(tmp_path,
                                                             monkeypatch):
     """A manual (launcher-less) resume appends a SECOND session to the
@@ -98,14 +176,15 @@ def _tiny_gpt2():
         num_layers=2, num_heads=2, dtype="float32")
 
 
-def _tiny_loop(wl, ckpt_dir, **kw):
+def _tiny_loop(wl, ckpt_dir, workers=0, **kw):
     from distributed_pipeline_tpu.data import load_data_from_args
     from distributed_pipeline_tpu.parallel import make_mesh
     from distributed_pipeline_tpu.utils.trainer import TrainLoop
 
     data = load_data_from_args("train", batch_size=8,
                                dataset="synthetic-lm", seq_len=16,
-                               vocab_size=64, seed=0)
+                               vocab_size=64, seed=0,
+                               data_loader_workers=workers)
     return TrainLoop(model=wl, data=data, batch_size=8, lr=1e-3,
                      learning_steps=100, log_interval=10 ** 9,
                      save_interval=10 ** 9, mesh=make_mesh(dp=8),
@@ -239,7 +318,7 @@ def profiled_session(tmp_path_factory):
     prompt = np.arange(1, 6, dtype=np.int32)
     trace_lib.clear_recorded()
     with logger.scoped_configure(format_strs=[]):
-        fed = _tiny_loop(wl, str(tmp / "a"), prefetch_depth=2,
+        fed = _tiny_loop(wl, str(tmp / "a"), workers=2, prefetch_depth=2,
                          dispatch_lag=1)
         eager = _tiny_loop(wl, str(tmp / "b"))
         server = DecodeServer(wl, params, **kw)
@@ -406,6 +485,19 @@ def test_children_lie_inside_parents(profiled_session):
     assert ("data.h2d", "train.next_batch") in seen
     assert ("data.h2d", "train.run_step") in seen
     assert ("serve.fetch_wait", "serve.spec_round") in seen
+
+
+def test_data_workers_spans_lie_on_their_own_threads(profiled_session):
+    """Inside a session the fed loop's two data workers book
+    ``data.assemble`` through FOLLOW: in the ring under the worker's thread
+    id, with no parent of the step loop's, whose own spans all share one."""
+    ring = profiled_session["ring"]
+    made = [e for e in ring if e["name"] == "data.assemble"]
+    loop_tids = {e["tid"] for e in ring if e["name"] in TRAIN_SPANS}
+    assert made and len(loop_tids) == 1
+    assert all("parent" not in e and e["cat"] == "data" for e in made)
+    assert not {e["tid"] for e in made} & loop_tids
+    assert {e["args"]["worker"] for e in made} <= {0, 1}
 
 
 def test_request_spans_are_the_requests_own_stamps(profiled_session):
