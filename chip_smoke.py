@@ -678,6 +678,11 @@ def _decode_child(spec_path: str) -> None:
                     seam = fd.paged_decode_attention
                 args = (jnp.asarray(q, jnp.bfloat16), pools[0], pools[1],
                         table, jnp.asarray(pos, jnp.int32))
+                # the kernel's page census (the span form runs a slot's
+                # links as pseudo-slots on repeated table rows)
+                census = fd.decode_page_census(
+                    np.repeat(np.asarray(table), max(span, 1), axis=0),
+                    pos.reshape(-1), ps)
                 arms = {impl: jax.jit(
                     lambda *a, impl=impl: seam(
                         *a, impl=impl, scales_k=scales[0],
@@ -691,6 +696,7 @@ def _decode_child(spec_path: str) -> None:
                         np.abs(got["pallas"] - got["xla"]).max()),
                     "max_abs_xla": float(np.abs(got["xla"]).max()),
                     "live_tokens": int(depth.sum()),
+                    "pages_live": census[0], "pages_copied": census[1],
                     "call_ms": {impl: call_ms(fn, args)
                                 for impl, fn in arms.items()}}
     with open(spec["result"], "w") as f:
@@ -720,7 +726,13 @@ def phase_decode(sizes: Sizes, seed: int) -> Dict[str, Any]:
             f"{c['max_abs_xla']:.4g}; a call (host clock, "
             f"{c['live_tokens']} live tokens): pallas "
             f"{c['call_ms']['pallas']:.3f} ms, xla "
-            f"{c['call_ms']['xla']:.3f} ms")
+            f"{c['call_ms']['xla']:.3f} ms; the kernel's schedule copies "
+            f"{c['pages_copied']} distinct pages a pool, "
+            f"{c['pages_live']} are live")
+        if c["pages_copied"] != c["pages_live"]:
+            res["failures"].append(
+                f"{name}: the schedule copies {c['pages_copied']} pages "
+                f"where {c['pages_live']} hold a live position")
         if not (c["finite"] and c["max_abs_diff_vs_xla"]
                 <= sizes.decode_tol * c["max_abs_xla"]):
             res["failures"].append(

@@ -6,11 +6,12 @@ slot's page RESERVATION in HBM — dead tail pages included — splits its
 heads (at ``Dh`` 64, half a lane tile, a relayout that moves every byte)
 and runs masked softmax attention over all of it: its cost follows slots x
 reservation, not live tokens (PERF.md PR 28, PR 30). This kernel removes
-the copy and the split: each grid step DMAs a BLOCK of ``G`` consecutive
-live pages ``[page_size, H * Dh]`` directly from the pool through the
-slot's block table, folds it into online-softmax scratch in VMEM, and
-writes only a ``[1, H * Dh]`` output row a slot. Dead pages never enter
-the schedule.
+the copy and the split: each grid step attends a BLOCK of ``G`` consecutive
+pages ``[page_size, H * Dh]`` of a slot, copied straight from the pool
+through the slot's block table — the pages that hold a live position and
+no other — folds it into online-softmax scratch in VMEM, and writes only a
+``[1, H * Dh]`` output row a slot. Dead pages never enter the schedule, and
+the dead entries of a slot's last block are never copied.
 
 No head is split, of the pool or of the query. A slot's query row
 ``[1, H * Dh]`` is spread once into a block-diagonal matrix
@@ -32,21 +33,53 @@ products to stay exact and took 3.7 x the kernel time: PERF.md PR 30).
 
 Step table (computed ON DEVICE inside the jitted decode step — positions
 and block tables are data, so the table costs no recompile and no host
-sync): a static worst-case ``[5 + G, B * ceil(n / G)]`` int32 array, one
-column of ``(slot, first, last, base, pos)`` + ``G`` page ids per step
-(steps along the minor dimension: SMEM pads that one to 128 words). ``G``
-comes from ``page_size`` alone (a block of ``BLOCK_ROWS`` positions: 16
-pages of 16) and the pages a slot has. The columns cover each slot's
+sync): a static worst-case ``[6 + G, B * ceil(n / G)]`` int32 array, one
+column of ``(slot, first, last, base, pos, live)`` + ``G`` page ids per
+step (steps along the minor dimension: SMEM pads that one to 128 words).
+``G`` comes from ``page_size`` alone (a block of ``BLOCK_ROWS`` positions:
+16 pages of 16) and the pages a slot has. The columns cover each slot's
 ``ceil((pos // page_size + 1) / G)`` live blocks in slot-major order (a
 contiguous accumulation run per slot, found by comparing the column's
 index with the running sum of the slots' block counts: no sort); their
 number is the kernel's GRID, a traced scalar, so no dead step runs (a
 static grid of ``B * ceil(n / G)`` steps spent half the kernel's time on
-its dead tail, 0.5 us a step). Entries of a slot's last block past its
-last live page name the trash page and are masked by ``position <= pos``
-(the mask is vacuous elsewhere and applied everywhere: one form). A slot
-with no live position (``pos`` < 0) still gets one block, all masked, and
-reads zeros.
+its dead tail). ``live`` is the count of the column's entries that hold a
+live position, ``min(G, n_live - blk * G)``: ``G`` but in a slot's last
+block. A slot with no live position (``pos`` < 0) still gets one block,
+``live`` 0, all masked, and reads zeros.
+
+The copies are the kernel's own (PR 38) where a step's ``2 G`` pages are
+more than ``HIDDEN_STEP_BYTES`` (GPT-2-large's bfloat16 pool: 1.3 MB).
+The pools stay in HBM (``memory_space=pl.ANY``) and a step starts one copy
+a pool for each LIVE entry of a column, each page straight to its
+``page_size`` rows of the column's buffer ``[G, page_size, H * Dh]`` (a
+ring of ``MAX_RING`` buffers a pool where ``RING_BYTES`` hold them, else
+of two), for the column ``ring - 1`` steps ahead of the one it computes,
+across slot boundaries; then it waits for its own column's. The rows of an
+entry past the live ones hold what an earlier step left there (the V
+buffers start a call zeroed, so it is finite) and are masked by
+``position <= pos`` — the mask is vacuous elsewhere and applied
+everywhere: one form. Before, the pools were ``2 G`` pipeline operands of
+one page each, a dead entry named the trash page, and the pipeline copied
+all ``2 G`` at every step, whatever was live and whatever an operand had
+held the step before (naming the same page again does not stop it): a
+grid step was its 1.3 MB at four fifths of the HBM peak, 2.1-2.3 us, with
+the arithmetic hidden under it (the chip, PERF.md PR 38).
+
+Where the copies already hide, they stay those pipeline operands, a dead
+entry naming the slot's last live page (read again under the mask), not
+the trash page. A step's own arithmetic is about 0.9 us, and the kernel's
+own copies are started and awaited by the scalar core in loops that
+arithmetic cannot overlap, about 37 ns a page, so they win where the bytes
+bound the step and lose where they do not (the chip, PERF.md PR 38, at
+the dense serve cell's depth): an int8 pool (0.66 MB a step: 30.8 us a
+call as pipeline operands, 42.8 with the kernel's own copies, where the
+bfloat16 pool went 61.5 -> 44.4); GPT-2-base's bfloat16 pool (0.79 MB:
+47.8 against 48.5); and the span form at any width, whose pseudo-slots
+list the same pages ``L`` times in a row — the chip reads a page again
+within microseconds far under the HBM's price (a 4-link span at
+GPT-2-large's width: 139.9 us as pipeline operands, 233.6 with the
+kernel's own copies), so ``paged_span_attention`` says ``revisits``.
 
 Page-layout contract (what TP layouts must keep to ride this kernel):
 
@@ -55,15 +88,17 @@ Page-layout contract (what TP layouts must keep to ride this kernel):
   says why: with ``Dh`` alone in the lanes the TPU stored the pool
   page-minor and every program relaid it). Nothing reshapes the POOL; the
   XLA arm splits the heads of its gathered view, this kernel splits
-  nothing. Page 0 is the trash page — read only under the position mask;
+  nothing. Page 0 is the trash page — this kernel does not read it (a
+  slot whose table lists it under a live position, a released slot with a
+  stale position, reads it as that slot's page);
 * a block-table row lists a slot's pages head-first; entries past the live
   prefix may be anything (trash, stale, shared) — the schedule never
-  visits them;
+  copies them;
 * positions are absolute token indices; the row at ``pos % page_size`` of
   page ``pos // page_size`` must already hold the current token's K/V
   (the caller writes via ``write_token_kv`` BEFORE attending);
 * page sharing (serving/paged_kv.py ``PrefixCache``) is invisible here:
-  two slots listing the same page id just schedule two DMAs of it;
+  two slots listing the same page id just start two copies of it;
 * on real TPU a row must be whole lane tiles, ``(H * Dh) % 128 == 0``, and
   a page block whole sublane tiles of the pool's type (``page_size`` a
   multiple of 16; of 8 for a float32 pool); other shapes dispatch to the
@@ -74,8 +109,10 @@ Page-layout contract (what TP layouts must keep to ride this kernel):
   its step's column (``2 G`` more rows), so they arrive with the scalar
   prefetch; the page is cast to the query's type (exact) and its scale
   multiplies the page's score columns (K) and weight columns (V) — no
-  second gather, no extra HBM traffic beyond 8 bytes a page. The chip's
-  compiler takes a 16-row int8 block (half its (32, 128) tile).
+  second gather, no extra HBM traffic beyond 8 bytes a page (an entry
+  past the live ones carries the slot's last live page's pair: finite,
+  under a zero weight). The chip's compiler takes a 16-row int8 page
+  (half its (32, 128) tile) as a copy's destination.
 
 Dispatch: ``impl="auto"`` -> this kernel on TPU (layout permitting), the
 XLA gather path elsewhere; ``"pallas"`` forces the kernel (interpreter
@@ -86,22 +123,23 @@ scores in float32 where the XLA arm rounds them to the activation type, so
 outputs match the XLA path to float tolerance, not bitwise — the serving
 contract is greedy-token identity (tests/test_kernels.py).
 
-Chip status (PR 30): 'auto' selects the kernel for GPT-2-large
+Chip status (PR 30, PR 38): 'auto' selects the kernel for GPT-2-large
 (``H20 / Dh64``), GPT-2-base (``H12 / Dh64``) and ``H16 / Dh128``, bf16 and
 int8 pools; it compiles for a described v5e at those shapes, decode and
 4-link span (tests/test_chip_compile.py), and RUNS on the chip inside the
-served model (``gpt2-large.serve.closed16``, PERF.md PR 30) and alone
-against the XLA arm (``python chip_smoke.py --only decode``: both
-geometries, bf16 and int8, decode and span, with each arm's time a call).
+served model (``gpt2-large.serve.closed16``, PERF.md PR 30, PR 38) and
+alone against the XLA arm (``python chip_smoke.py --only decode``: both
+geometries, bf16 and int8, decode and span, with each arm's time a call
+and the schedule's page census).
 The span form runs B * L pseudo-slots through the kernel and so reads a
 slot's pages L times (the XLA span arm gathers them once).
 
-HBM accounting: :func:`decode_hbm_bytes` reproduces the schedule's DMA
-traffic (distinct pages named, the step table, a q and an output row a
-slot) — the kernel-arm number the ``gpt2-serve-decode-kernel`` bench leg
-lands next to the XLA twin's cost-analysis bytes, because interpreter-mode
-emulation (scan + full-array updates) does not share the kernel's memory
-profile and cannot be cost-analyzed faithfully off-TPU.
+HBM accounting: :func:`decode_hbm_bytes` prices the schedule's traffic
+(the distinct pages that hold a live position — the trash page is not
+among them — the step table, a q and an output row a slot), because
+interpreter-mode emulation (scan + full-array updates) does not share the
+kernel's memory profile and cannot be cost-analyzed faithfully off-TPU;
+:func:`decode_page_census` is its page count beside the live pages'.
 """
 
 from __future__ import annotations
@@ -118,17 +156,21 @@ from jax.experimental.pallas import tpu as pltpu
 _VMEM = pltpu.VMEM
 
 __all__ = ["flash_decode", "paged_decode_attention", "paged_span_attention",
-           "resolve_decode_impl", "decode_hbm_bytes", "xla_paged_decode",
+           "resolve_decode_impl", "decode_hbm_bytes", "decode_page_census",
+           "xla_paged_decode",
            "xla_paged_span_decode"]
 
 KERNEL_NAME = "flash_decode"  # stable: traces and HLO text find it
 NEG_INF = -1e9
 LANES = 128
-TRASH_PAGE = 0  # mirrors serving/paged_kv.py (leaf module, no import cycle)
 BLOCK_ROWS = 256        # positions a grid step attends: G pages of page_size
+HIDDEN_STEP_BYTES = 1 << 20  # a step's 2 G pages up to this many bytes hide
+#                              under its arithmetic as pipeline operands
+MAX_RING = 4            # buffers a pool for the kernel's own copies (the step
+RING_BYTES = 8 << 20    # computed + 3 ahead) where this much VMEM holds them
 # step-table rows (one column a grid step); G page ids follow, then for an
 # int8 pool G K-scale words and G V-scale words
-_SLOT, _FIRST, _LAST, _BASE, _POS, _PAGE0 = range(6)
+_SLOT, _FIRST, _LAST, _BASE, _POS, _LIVE, _PAGE0 = range(7)
 
 
 def _interpret() -> bool:
@@ -176,51 +218,113 @@ def _live_counts(positions, page_size: int, n_pages: int, g: int, xp):
     return n_live, xp.maximum(-(-n_live // g), 1)
 
 
-def _build_steps(block_table: jnp.ndarray, positions: jnp.ndarray,
-                 page_size: int, g: int, scales_k=None, scales_v=None):
-    """Traced step table ``[5 + G, B * ceil(n / G)]`` (module docstring)
-    and the number of its columns that are steps — the kernel's grid, a
-    traced scalar. One COLUMN per step — SMEM pads the minor dimension to
-    128 words, so the steps have to lie along it. Step ``t`` belongs to the
+def _schedule(block_table, positions, page_size: int, g: int, xp):
+    """The kernel's schedule ([T] = every column of the step table; ``xp``
+    as in :func:`_live_counts`): each column's slot, its block of the slot,
+    the slots' live-block counts, the grid (the columns that are steps),
+    the LIVE entries a column — ``min(G, n_live - blk * G)``, the pages the
+    kernel copies — and the ``[T, G]`` page ids. Step ``t`` belongs to the
     slot whose run of live blocks holds it (a compare against the running
-    sum of the slots' live-block counts: no sort); the columns past the
-    last run are never visited. With int8 scales the table grows by
-    ``2 G`` rows: each page's K and V scale as bitcast int32, gathered
-    through the block table."""
+    sum of the slots' live-block counts: no sort). An entry past the live
+    ones repeats the slot's last live page: it is never copied, and names a
+    page only so that an int8 pool's scale words there are a live page's."""
     B, n = block_table.shape
     nb = -(-n // g)
-    pos = positions.astype(jnp.int32)
-    n_live, nb_live = _live_counts(pos, page_size, n, g, jnp)
-    ends = jnp.cumsum(nb_live)
-    t = jnp.arange(B * nb, dtype=jnp.int32)
-    slot = jnp.minimum(
-        jnp.sum(t[:, None] >= ends[None, :], axis=1), B - 1).astype(jnp.int32)
+    n_live, nb_live = _live_counts(positions, page_size, n, g, xp)
+    ends = xp.cumsum(nb_live)
+    t = xp.arange(B * nb, dtype=xp.int32)
+    slot = xp.minimum(
+        xp.sum(t[:, None] >= ends[None, :], axis=1), B - 1).astype(xp.int32)
     blk = t - (ends - nb_live)[slot]
-    j = blk[:, None] * g + jnp.arange(g, dtype=jnp.int32)[None, :]  # [T, G]
-    # entries of a slot's last block past its live prefix name the trash
-    # page (the table's own entries there may be anything) and are masked
-    # by position
-    pages = jnp.where(j < n_live[slot][:, None],
-                      block_table[slot[:, None], jnp.minimum(j, n - 1)],
-                      TRASH_PAGE).astype(jnp.int32)
+    live = xp.clip(n_live[slot] - blk * g, 0, g).astype(xp.int32)
+    j = xp.minimum(blk[:, None] * g + xp.arange(g, dtype=xp.int32)[None, :],
+                   xp.maximum(n_live[slot] - 1, 0)[:, None])        # [T, G]
+    pages = block_table[slot[:, None], j].astype(xp.int32)
+    return slot, blk, nb_live, ends[-1], live, pages
+
+
+def _build_steps(block_table: jnp.ndarray, positions: jnp.ndarray,
+                 page_size: int, g: int, scales_k=None, scales_v=None):
+    """Traced step table ``[6 + G, B * ceil(n / G)]`` (module docstring)
+    and the number of its columns that are steps — the kernel's grid, a
+    traced scalar. One COLUMN per step — SMEM pads the minor dimension to
+    128 words, so the steps have to lie along it; the columns past the
+    last slot's run are never visited. With int8 scales the table grows by
+    ``2 G`` rows: each named page's K and V scale as bitcast int32,
+    gathered through the page ids."""
+    pos = positions.astype(jnp.int32)
+    slot, blk, nb_live, n_steps, live, pages = _schedule(
+        block_table, pos, page_size, g, jnp)
     rows = [slot, blk == 0, blk == nb_live[slot] - 1,
-            blk * (g * page_size), pos[slot]]
+            blk * (g * page_size), pos[slot], live]
     rows += list(pages.T)
     if scales_k is not None:
         for sc in (scales_k, scales_v):
             bits = jax.lax.bitcast_convert_type(
                 sc.astype(jnp.float32), jnp.int32)[pages]         # [T, G]
             rows += list(bits.T)
-    return jnp.stack([r.astype(jnp.int32) for r in rows], axis=0), ends[-1]
+    return jnp.stack([r.astype(jnp.int32) for r in rows], axis=0), n_steps
+
+
+def _fetch_live_pages(steps_ref, k_hbm, v_hbm, kbuf, vbuf, sems):
+    """The kernel's own copies (a step of over ``HIDDEN_STEP_BYTES``):
+    start the LIVE entries of the column ``ring - 1`` steps ahead, a page
+    of each pool straight to its rows of that column's buffers — no other
+    page leaves HBM — then wait for this step's own. The copies of later
+    steps fly while this one is computed, across slot boundaries."""
+    t = pl.program_id(0)
+    n_steps = pl.num_programs(0)           # the traced grid
+    ring = kbuf.shape[0]
+
+    def copies(col, i):
+        page, to = steps_ref[_PAGE0 + i, col], col % ring
+        return (pltpu.make_async_copy(k_hbm.at[page], kbuf.at[to, i],
+                                      sems.at[to, 0]),
+                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[to, i],
+                                      sems.at[to, 1]))
+
+    def start(col):
+        def one(i, carry):
+            for copy in copies(col, i):
+                copy.start()
+            return carry
+        jax.lax.fori_loop(0, steps_ref[_LIVE, col], one, 0)
+
+    @pl.when(t == 0)
+    def _prologue():
+        # rows of entries never copied lie under a zero weight and have to
+        # be finite: what a buffer held before this call need not be
+        vbuf[:] = jnp.zeros_like(vbuf)
+        for col in range(ring - 1):
+            pl.when(col < n_steps)(functools.partial(start, col))
+
+    pl.when(t + ring - 1 < n_steps)(functools.partial(start, t + ring - 1))
+
+    def land(i, carry):
+        for copy in copies(t, i):
+            copy.wait()
+        return carry
+    jax.lax.fori_loop(0, steps_ref[_LIVE, t], land, 0)
 
 
 def _decode_kernel(steps_ref, q_ref, *refs, scale: float, g: int,
-                   head_dim: int, quant: bool):
-    k_refs, v_refs = refs[:g], refs[g:2 * g]
-    o_ref, qd_ref, acc_ref, m_ref, l_ref = refs[2 * g:]
+                   head_dim: int, quant: bool, own_copies: bool):
     t = pl.program_id(0)
+    if own_copies:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sems = refs[:6]
+        _fetch_live_pages(steps_ref, k_hbm, v_hbm, kbuf, vbuf, sems)
+        here = t % kbuf.shape[0]           # the buffers this step reads
+        k_pages = [kbuf.at[here, i] for i in range(g)]
+        v_pages = [vbuf.at[here, i] for i in range(g)]
+        refs = refs[6:]
+    else:
+        # 2 G pipeline operands of a page, copied at every step
+        k_pages = [ref.at[0] for ref in refs[:g]]
+        v_pages = [ref.at[0] for ref in refs[g:2 * g]]
+        o_ref, refs = refs[2 * g], refs[2 * g + 1:]
+    qd_ref, acc_ref, m_ref, l_ref = refs
     heads, width = qd_ref.shape            # heads: H padded to 16 rows
-    page_size = k_refs[0].shape[1]
+    page_size = k_pages[0].shape[0]
     n_rows = g * page_size
     dtype = qd_ref.dtype                   # the products' operand type
     exact = None if dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
@@ -247,7 +351,7 @@ def _decode_kernel(steps_ref, q_ref, *refs, scale: float, g: int,
     def block(page_refs):
         """The step's G pages as one [G * page_size, H * Dh] block, rows in
         position order, as they lie in the pool (int8: cast, exact)."""
-        pages = [ref[0].astype(dtype) for ref in page_refs]
+        pages = [ref[...].astype(dtype) for ref in page_refs]
         return pages[0] if g == 1 else jnp.concatenate(pages, axis=0)
 
     def page_scales(row):
@@ -265,7 +369,7 @@ def _decode_kernel(steps_ref, q_ref, *refs, scale: float, g: int,
     # positions along the lanes — flash attention's own layout, with
     # free dimensions on both sides of both products (MXU).
     s = jax.lax.dot_general(
-        qd_ref[:], block(k_refs), (((1,), (1,)), ((), ())),
+        qd_ref[:], block(k_pages), (((1,), (1,)), ((), ())),
         precision=exact, preferred_element_type=jnp.float32) * scale
     if quant:
         s = s * page_scales(_PAGE0 + g)
@@ -277,7 +381,7 @@ def _decode_kernel(steps_ref, q_ref, *refs, scale: float, g: int,
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     # exact zeros for masked positions (a block with no live position
-    # would otherwise softmax over the raw trash scores)
+    # would otherwise softmax over whatever its rows hold)
     p = jnp.where(live, jnp.exp(s - m_new[:, :1]), 0.0)
     l_new = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
     m_ref[:] = m_new
@@ -287,7 +391,7 @@ def _decode_kernel(steps_ref, q_ref, *refs, scale: float, g: int,
     # [heads, H * Dh]: row h is head h's weights over EVERY head's
     # lanes; only its own Dh lanes are kept, at the slot's last block
     acc = alpha[:, :1] * acc_ref[:] + jnp.dot(
-        p.astype(dtype), block(v_refs), precision=exact,
+        p.astype(dtype), block(v_pages), precision=exact,
         preferred_element_type=jnp.float32)
     acc_ref[:] = acc
 
@@ -302,24 +406,33 @@ def _decode_kernel(steps_ref, q_ref, *refs, scale: float, g: int,
 
 def flash_decode(q: jnp.ndarray, pages_k: jnp.ndarray, pages_v: jnp.ndarray,
                  block_table: jnp.ndarray, positions: jnp.ndarray,
-                 scales_k=None, scales_v=None) -> jnp.ndarray:
+                 scales_k=None, scales_v=None,
+                 revisits: bool = False) -> jnp.ndarray:
     """Paged single-query attention: ``q`` [B, H, Dh], pool
     ``[P, page_size, H * Dh]``, ``block_table`` [B, n_pages], ``positions``
     [B] -> [B, H, Dh]. Attends positions ``0..positions[b]`` of each slot
     through its block table; everything later is skipped at schedule level.
     ``scales_k``/``scales_v`` ([P] fp32) flag an int8 pool: the kernel
     scales each page's scores and weights with its pair from the step
-    table."""
-    return _flash_decode(q, pages_k, pages_v, block_table, positions,
-                         scales_k, scales_v, interpret=_interpret())
+    table. ``revisits``: consecutive slots list the same pages (the span
+    form's pseudo-slots). The kernel copies the live pages itself where a
+    step's pages are more bytes than hide under its arithmetic and are not
+    such revisits, which the chip reads again cheaply (module docstring)."""
+    page_size, width = pages_k.shape[1:]
+    g = _pages_per_block(page_size, block_table.shape[1])
+    step_bytes = 2 * g * page_size * width * pages_k.dtype.itemsize
+    return _flash_decode(
+        q, pages_k, pages_v, block_table, positions, scales_k, scales_v,
+        interpret=_interpret(),
+        own_copies=not revisits and step_bytes > HIDDEN_STEP_BYTES)
 
 
 # jitted, so that a model's layers share ONE trace of the kernel and its
 # table (traced a layer, 36 layers of 33 block specs cost the serve cell
 # 12 s of set-up); where it runs is part of the cache's key
-@functools.partial(jax.jit, static_argnames="interpret")
+@functools.partial(jax.jit, static_argnames=("interpret", "own_copies"))
 def _flash_decode(q, pages_k, pages_v, block_table, positions, scales_k,
-                  scales_v, *, interpret: bool):
+                  scales_v, *, interpret: bool, own_copies: bool):
     B, H, Dh = q.shape
     page_size, width = pages_k.shape[1:]
     g = _pages_per_block(page_size, block_table.shape[1])
@@ -328,35 +441,48 @@ def _flash_decode(q, pages_k, pages_v, block_table, positions, scales_k,
     steps, n_steps = _build_steps(block_table, positions, page_size, g,
                                   scales_k, scales_v)
 
-    def page_spec(i):
-        # the SAME pool operand is named G times; spec i reads page id i
-        # of the step's column (the pipeline's own DMAs, nothing manual)
-        return pl.BlockSpec((1, page_size, width),
-                            lambda t, s: (s[_PAGE0 + i, t], 0, 0),
-                            memory_space=_VMEM)
-
     row_spec = pl.BlockSpec((1, 1, width), lambda t, s: (s[_SLOT, t], 0, 0),
                             memory_space=_VMEM)
     heads = -(-H // 16) * 16        # whole sublane tiles of either type
+    scratch = [
+        _VMEM((heads, width), dtype),         # block-diagonal query
+        _VMEM((heads, width), jnp.float32),   # acc
+        _VMEM((heads, LANES), jnp.float32),   # running max
+        _VMEM((heads, LANES), jnp.float32),   # running normaliser
+    ]
+    if own_copies:
+        # the pools stay in HBM and the kernel copies the live pages
+        # itself, into a ring of buffers: the one computed and the steps
+        # ahead of it (a power of two: the step's buffer is t % ring)
+        block_bytes = g * page_size * width * pages_k.dtype.itemsize
+        ring = MAX_RING if 2 * MAX_RING * block_bytes <= RING_BYTES else 2
+        pools = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        operands = [pages_k, pages_v]
+        scratch = [_VMEM((ring, g, page_size, width), pages_k.dtype),
+                   _VMEM((ring, g, page_size, width), pages_v.dtype),
+                   pltpu.SemaphoreType.DMA((ring, 2))] + scratch
+    else:
+        # copies that hide under the step's arithmetic as they are, where
+        # the kernel's own would not (module docstring). The SAME pool
+        # operand is named G times; spec i reads page id i of the step's
+        # column (the pipeline's own DMAs)
+        pools = [pl.BlockSpec((1, page_size, width),
+                              lambda t, s, i=i: (s[_PAGE0 + i, t], 0, 0),
+                              memory_space=_VMEM) for i in range(g)] * 2
+        operands = [pages_k] * g + [pages_v] * g
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_steps,),   # traced: the live blocks, no dead step runs
-        in_specs=[row_spec] + [page_spec(i) for i in range(g)] * 2,
+        in_specs=[row_spec] + pools,
         out_specs=row_spec,
-        scratch_shapes=[
-            _VMEM((heads, width), dtype),         # block-diagonal query
-            _VMEM((heads, width), jnp.float32),   # acc
-            _VMEM((heads, LANES), jnp.float32),   # running max
-            _VMEM((heads, LANES), jnp.float32),   # running normaliser
-        ])
+        scratch_shapes=scratch)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=Dh ** -0.5, g=g,
-                          head_dim=Dh, quant=quant),
+                          head_dim=Dh, quant=quant, own_copies=own_copies),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, width), q.dtype),
         name=KERNEL_NAME,
-        interpret=interpret)(
-            steps, q.reshape(B, 1, width), *[pages_k] * g, *[pages_v] * g)
+        interpret=interpret)(steps, q.reshape(B, 1, width), *operands)
     return out.reshape(B, H, Dh)
 
 
@@ -364,9 +490,10 @@ def xla_paged_decode(q: jnp.ndarray, pages_k: jnp.ndarray,
                      pages_v: jnp.ndarray, block_table: jnp.ndarray,
                      positions: jnp.ndarray, scales_k=None,
                      scales_v=None) -> jnp.ndarray:
-    """The gather-path twin ([B, H, Dh] in/out), kept callable standalone so
-    the bench leg can cost-analyze the seam it replaces. int8 pools
-    (``scales_*`` given) are dequantized right after the gather."""
+    """The gather-path twin ([B, H, Dh] in/out), callable standalone: the
+    tests' reference and the arm ``chip_smoke.py --only decode`` times the
+    kernel against. int8 pools (``scales_*`` given) are dequantized right
+    after the gather."""
     from ..serving.paged_kv import dequant_gathered, gather_kv
     from .attention import dot_product_attention
     h = q.shape[1]
@@ -448,44 +575,58 @@ def paged_span_attention(q, pages_k, pages_v, block_table, positions,
         qf = q.transpose(0, 2, 1, 3).reshape(B * L, H, Dh)
         bt = jnp.repeat(block_table, L, axis=0)
         o = flash_decode(qf, pages_k, pages_v, bt, positions.reshape(-1),
-                         scales_k, scales_v)
+                         scales_k, scales_v, revisits=True)
         return o.reshape(B, L, H, Dh).transpose(0, 2, 1, 3)
     return xla_paged_span_decode(q, pages_k, pages_v, block_table,
                                  positions, scales_k, scales_v)
+
+
+def decode_page_census(block_table: np.ndarray, positions: np.ndarray,
+                       page_size: int):
+    """``(pages_live, pages_copied)`` of one kernel invocation a pool, both
+    distinct page ids: the pages that hold a live position of some slot,
+    by the block table and the positions alone, and the pages the kernel
+    starts a copy for — the live entries of its schedule's steps
+    (:func:`_schedule` itself, in numpy). They are equal. The mechanism's
+    counter — ``chip_smoke.py --only decode`` prints it; the serving tick
+    does not compute it."""
+    bt = np.asarray(block_table)
+    pos = np.asarray(positions).astype(np.int32)
+    n = bt.shape[1]
+    g = _pages_per_block(page_size, n)
+    n_live, _ = _live_counts(pos, page_size, n, g, np)
+    holds = {int(p) for b, k in enumerate(n_live) for p in bt[b, :int(k)]}
+    *_, n_steps, live, pages = _schedule(bt, pos, page_size, g, np)
+    copied = np.arange(g)[None, :] < live[:int(n_steps), None]
+    return len(holds), len({int(p) for p in pages[:int(n_steps)][copied]})
 
 
 def decode_hbm_bytes(block_table: np.ndarray, positions: np.ndarray,
                      page_size: int, n_heads: int, head_dim: int,
                      dtype_bytes: int = 4, kv_dtype_bytes=None,
                      quantized: bool = False) -> int:
-    """Exact HBM bytes one kernel invocation DMAs, from its own schedule.
+    """HBM bytes one kernel invocation has to move, from its own schedule.
 
     Counts each DISTINCT page's K and V blocks once across the whole
-    schedule — the schedule visits pages slot-major, so a page shared by
-    many slots (PrefixCache) or revisited consecutively is fetched once;
-    dedup is by page-id set. The trash page is one more page of that set:
-    the schedule names it for the entries of a slot's last block past its
-    live prefix, so it is counted once when any slot has such entries (the
-    table's columns past the last live block are not steps: the grid ends
-    there). Adds one q read and one output write per slot and the SMEM
-    step table, ``[5 + G, B * ceil(n / G)]`` words with ``G`` the kernel's
-    own (:func:`_pages_per_block`). ``kv_dtype_bytes`` prices the pool
+    schedule (:func:`decode_page_census`'s ``pages_copied``: the live
+    pages and no other — no copy is started for the entries of a slot's
+    last block past its live ones; a page shared by many slots
+    (PrefixCache) is priced once, dedup is by page-id set; the table's
+    columns past the last live block are not steps: the grid ends there).
+    Adds one q read and one output write per slot and the SMEM step table,
+    ``[6 + G, B * ceil(n / G)]`` words with ``G`` the kernel's own
+    (:func:`_pages_per_block`). ``kv_dtype_bytes`` prices the pool
     separately from q/out (int8 pools: 1 vs 4); ``quantized`` adds the
     table's ``2 G`` scale rows — the per-page scale pair rides it, so it
     costs table bytes, not extra page traffic."""
-    bt = np.asarray(block_table)
-    pos = np.asarray(positions)
-    B, n = bt.shape
+    B, n = np.asarray(block_table).shape
     if kv_dtype_bytes is None:
         kv_dtype_bytes = 1 if quantized else dtype_bytes
     width = n_heads * head_dim
     g = _pages_per_block(page_size, n)
     nb = -(-n // g)
-    n_live, nb_live = _live_counts(pos, page_size, n, g, np)
-    seen = {int(p) for b in range(B) for p in bt[b, :int(n_live[b])]}
-    if (nb_live * g > n_live).any():
-        seen.add(TRASH_PAGE)
-    total = len(seen) * 2 * page_size * width * kv_dtype_bytes  # K and V
+    _, copied = decode_page_census(block_table, positions, page_size)
+    total = copied * 2 * page_size * width * kv_dtype_bytes  # K and V
     total += B * 2 * width * dtype_bytes           # q read + out write
-    total += (B * nb) * (5 + (3 if quantized else 1) * g) * 4  # step table
+    total += (B * nb) * (_PAGE0 + (3 if quantized else 1) * g) * 4  # table
     return int(total)

@@ -41,6 +41,11 @@ tier-1 tests. Page 0 is reserved as the TRASH page: every write that must
 not land anywhere (padded prompt tail, inactive slot, out-of-range
 position) is redirected there, and no read ever sees it (reads are masked
 to each slot's live prefix, which only spans pages the allocator assigned).
+The XLA arm's gather still MOVES it, for every table entry that names it,
+under that mask; the flash-decode kernel does not: it copies the pages
+that hold a live position of a slot and starts no copy for any other entry
+(ops/flash_decode.py; before PR 38 its schedule named the trash page for
+the dead entries of a slot's last block and copied it at every such step).
 
 Host side: :class:`PageManager` owns the free list and the block tables as
 plain numpy — allocation policy is host code (the scheduler reserves a

@@ -19,9 +19,13 @@ import pytest
 from distributed_pipeline_tpu.data import load_data_from_args
 from distributed_pipeline_tpu.models import create_model_from_config
 from distributed_pipeline_tpu.ops.flash_decode import (
+    _pages_per_block,
+    _schedule,
     decode_hbm_bytes,
+    decode_page_census,
     flash_decode,
     paged_decode_attention,
+    paged_span_attention,
     resolve_decode_impl,
     xla_paged_decode,
 )
@@ -35,6 +39,17 @@ from distributed_pipeline_tpu.serving import TRASH_PAGE, DecodeServer
 from distributed_pipeline_tpu.utils.trainer import TrainLoop
 
 # ----------------------------------------------------------- flash-decode
+
+
+@pytest.fixture(params=["own_copies", "pipeline_operands"])
+def fetch(request, monkeypatch):
+    """Both ways a step's pages reach the kernel, whatever the pool's size
+    here: the kernel's own copies of the live pages (a step of more than
+    ``HIDDEN_STEP_BYTES``) and 2 G pipeline operands (the rest)."""
+    from distributed_pipeline_tpu.ops import flash_decode as fd
+    monkeypatch.setattr(fd, "HIDDEN_STEP_BYTES",
+                        -1 if request.param == "own_copies" else 1 << 60)
+    return request.param
 
 
 def paged_case(rng, *, slots, n_pages, page_size, n_heads, head_dim,
@@ -80,7 +95,7 @@ def dense_reference(q, k_pool, v_pool, table, positions):
     (8, 2, [2, 5, 8, 12]),      # partial first page / spilled second
 ])
 def test_flash_decode_matches_xla_across_geometries(page_size, n_pages,
-                                                    positions):
+                                                    positions, fetch):
     rng = np.random.default_rng(7)
     q, k, v, bt, pos = paged_case(
         rng, slots=4, n_pages=n_pages, page_size=page_size, n_heads=2,
@@ -92,7 +107,7 @@ def test_flash_decode_matches_xla_across_geometries(page_size, n_pages,
                                rtol=2e-5, atol=2e-6)
 
 
-def test_flash_decode_ignores_dead_pages_and_garbage_tails():
+def test_flash_decode_ignores_dead_pages_and_garbage_tails(fetch):
     """Entries past the live prefix of a block-table row may be anything
     (contract): point them at the garbage trash page and poison the dead
     rows of each last live page — the output must not move."""
@@ -114,7 +129,7 @@ def test_flash_decode_ignores_dead_pages_and_garbage_tails():
     np.testing.assert_array_equal(got, clean)
 
 
-def test_flash_decode_prefix_cache_shared_pages():
+def test_flash_decode_prefix_cache_shared_pages(fetch):
     """Two slots listing the SAME physical page (PrefixCache sharing) just
     schedule two reads of it — parity must hold with divergent tails."""
     rng = np.random.default_rng(13)
@@ -141,8 +156,7 @@ BLOCK_CASES = {
 
 @pytest.mark.parametrize("positions", list(BLOCK_CASES.values()),
                          ids=list(BLOCK_CASES))
-def test_flash_decode_blocks_of_pages(positions):
-    from distributed_pipeline_tpu.ops.flash_decode import _pages_per_block
+def test_flash_decode_blocks_of_pages(positions, fetch):
     ps, n, H, Dh = 16, 40, 2, 8
     assert _pages_per_block(ps, n) == 16 and n % 16 != 0
     rng = np.random.default_rng(23)
@@ -155,7 +169,7 @@ def test_flash_decode_blocks_of_pages(positions):
     np.testing.assert_allclose(got, dense_reference(q, k, v, bt, pos),
                                rtol=2e-5, atol=2e-6)
     # table entries past the live prefix may be anything: the schedule
-    # names the trash page in their place, also INSIDE a live block
+    # never names them, also INSIDE a live block
     btp = np.asarray(bt).copy()
     for b, p in enumerate(positions):
         btp[b, p // ps + 1:] = 1 + (7 * b) % (len(positions) * n)
@@ -163,7 +177,156 @@ def test_flash_decode_blocks_of_pages(positions):
     np.testing.assert_array_equal(moved, got)
 
 
-def test_flash_decode_inactive_slot_and_shared_prefix_in_blocks():
+def block_table_cases():
+    """(id, block table, positions) at 16-row pages, 40 pages a slot: the
+    geometries of ``BLOCK_CASES``, then slots that share their first twenty
+    pages beside a released slot (table all trash, a stale position) —
+    first, in the middle and last."""
+    n = 40
+    for name, positions in BLOCK_CASES.items():
+        yield name, 1 + np.arange(len(positions) * n).reshape(-1, n), positions
+    for name, released in [("released_first", 0), ("released_between", 1),
+                           ("released_last", 2)]:
+        table = 1 + np.arange(3 * n).reshape(3, n)
+        live = [b for b in range(3) if b != released]
+        table[live[1], :20] = table[live[0], :20]
+        table[released, :] = TRASH_PAGE
+        positions = np.asarray([340, 500, 5])
+        positions[released] = 40
+        yield name, table, positions.tolist()
+
+
+TABLE_CASES = list(block_table_cases())
+
+
+@pytest.mark.parametrize("table,positions", [c[1:] for c in TABLE_CASES],
+                         ids=[c[0] for c in TABLE_CASES])
+def test_flash_decode_schedule_copies_live_pages_only(table, positions):
+    """The step table carries the count of live entries a column,
+    ``min(G, n_live - blk * G)``: the kernel starts a copy for those and for
+    no other. The live entries are the block table's own; no entry of a
+    slot that lists none names the trash page (a released slot's stale
+    position lists it). The traced table and the census's numpy one are
+    the same function."""
+    ps, n = 16, table.shape[1]
+    g = _pages_per_block(ps, n)
+    pos = np.asarray(positions, np.int32)
+    got = _schedule(table, pos, ps, g, np)
+    traced = _schedule(jnp.asarray(table, jnp.int32), jnp.asarray(pos), ps,
+                       g, jnp)
+    for a, b in zip(got, traced):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    slot, blk, nb_live, n_steps, live, pages = got
+    active = (table != TRASH_PAGE).any(axis=1)    # a released row is trash
+    n_live = np.clip(pos // ps + 1, 0, n)
+    assert n_steps == nb_live.sum() == np.maximum(-(-n_live // g), 1).sum()
+    assert (live[int(n_steps):] == 0).all()       # no step, no copy
+    for t in range(int(n_steps)):
+        b = slot[t]
+        assert live[t] == min(g, n_live[b] - blk[t] * g)
+        assert (live[t] == g) or blk[t] == nb_live[b] - 1   # last block
+        j = blk[t] * g + np.arange(live[t])
+        np.testing.assert_array_equal(pages[t, :live[t]], table[b, j])
+        if active[b]:
+            assert (pages[t] != TRASH_PAGE).all(), (t, pages[t])
+    # the page census: what is copied is what is live, a shared page once
+    listed = {int(p) for b in range(len(pos)) for p in table[b, :n_live[b]]}
+    assert decode_page_census(table, pos, ps) == (len(listed), len(listed))
+    assert (TRASH_PAGE in listed) == (not active.all())
+    # ... and a released slot whose position says so copies nothing at all
+    assert decode_page_census(table, np.where(active, pos, -1), ps) == (
+        len(listed - {TRASH_PAGE}),) * 2
+
+
+@pytest.mark.parametrize("span", [0, 3], ids=["decode", "span3"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_flash_decode_reads_no_trash_and_no_unlisted_page(kv, span, fetch):
+    """A pool whose trash page and whose pages outside every slot's live
+    prefix are poisoned (large finite values, their int8 scales too) gives
+    the outputs of a clean pool, bit for bit: the kernel copies the pages
+    that hold a live position and no other."""
+    ps, n, H, Dh, B = 16, 40, 2, 8, 4
+    rng = np.random.default_rng(37)
+    P = 1 + B * n
+    table = 1 + rng.permutation(B * n).reshape(B, n)
+    depth = np.asarray([5, 300, 256, 640])          # live tokens a slot
+    listed = np.zeros(P, bool)
+    for b in range(B):
+        listed[table[b, :-(-depth[b] // ps)]] = True
+    assert not listed[TRASH_PAGE] and (~listed).sum() > B
+    if kv == "int8":
+        clean = [rng.integers(-127, 128, (P, ps, H * Dh)).astype(np.int8)
+                 for _ in range(2)]
+        scales = [(rng.uniform(0.1, 3.0, (P,)) / 127.0).astype(np.float32)
+                  for _ in range(2)]
+        bad, bad_scale = 127, 1e4
+    else:
+        clean = [np.asarray(jnp.asarray(
+            rng.standard_normal((P, ps, H * Dh)), jnp.bfloat16))
+            for _ in range(2)]
+        scales, bad, bad_scale = [None, None], 3e4, None
+    if span:
+        q = rng.standard_normal((B, H, span, Dh))
+        pos = depth[:, None] - span + np.arange(span)[None, :]
+        seam = paged_span_attention
+    else:
+        q = rng.standard_normal((B, H, Dh))
+        pos = depth - 1
+        seam = paged_decode_attention
+
+    def run(pools, scales):
+        return np.asarray(seam(
+            jnp.asarray(q, jnp.float32), *map(jnp.asarray, pools),
+            jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32),
+            impl="pallas",
+            scales_k=None if scales[0] is None else jnp.asarray(scales[0]),
+            scales_v=None if scales[1] is None else jnp.asarray(scales[1])))
+
+    want = run(clean, scales)
+    poisoned, poisoned_scales = [], []
+    for pool, sc, sign in zip(clean, scales, (1, -1)):
+        pool = pool.copy()
+        pool[~listed] = sign * bad
+        poisoned.append(pool)
+        if sc is not None:
+            sc = sc.copy()
+            sc[~listed] = bad_scale
+        poisoned_scales.append(sc)
+    got = run(poisoned, poisoned_scales)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+
+
+def test_flash_decode_ring_of_buffers(monkeypatch):
+    """The kernel's own copies fly ``ring - 1`` steps ahead: the ring of
+    two a wide pool's buffers leave room for (``RING_BYTES``) gives the
+    outputs of the default four, bit for bit — also where the grid is
+    shorter than the ring, and across a slot with nothing live."""
+    from distributed_pipeline_tpu.ops import flash_decode as fd
+    ps, n, H, Dh = 16, 40, 2, 8
+    rng = np.random.default_rng(41)
+    block_bytes = 16 * ps * H * Dh * 4
+    monkeypatch.setattr(fd, "HIDDEN_STEP_BYTES", -1)    # its own copies
+    rooms = (fd.RING_BYTES, 2 * fd.MAX_RING * block_bytes - 1)  # 4, then 2
+    assert rooms[1] < rooms[0] and fd.MAX_RING == 4
+    for positions in ([70], [639, -1, 3, 300, 255]):
+        q, k, v, bt, pos = paged_case(
+            rng, slots=len(positions), n_pages=n, page_size=ps, n_heads=H,
+            head_dim=Dh, positions=positions)
+        outs = []
+        for room in rooms:
+            monkeypatch.setattr(fd, "RING_BYTES", room)
+            fd._flash_decode.clear_cache()
+            outs.append(np.asarray(flash_decode(q, k, v, bt, pos)))
+        np.testing.assert_array_equal(outs[1], outs[0])
+        some = np.asarray(positions) >= 0      # nothing live reads zeros
+        np.testing.assert_array_equal(outs[1][~some], 0.0)
+        np.testing.assert_allclose(outs[1][some], np.asarray(
+            xla_paged_decode(q, k, v, bt, pos))[some], rtol=2e-5, atol=2e-5)
+    fd._flash_decode.clear_cache()
+
+
+def test_flash_decode_inactive_slot_and_shared_prefix_in_blocks(fetch):
     """A released slot (table all trash, a stale position) beside two
     slots that share their first twenty pages (more than one block): the
     inactive row attends the trash page like the XLA arm, the others are
@@ -187,7 +350,7 @@ def test_flash_decode_inactive_slot_and_shared_prefix_in_blocks():
     np.testing.assert_allclose(none[0], got[0], rtol=1e-6)
 
 
-def test_flash_decode_int8_scales_a_page_of_a_block():
+def test_flash_decode_int8_scales_a_page_of_a_block(fetch):
     """Each page of a block carries its own K and V scale (a factor of 30
     apart here) through the step table."""
     ps, n, H, Dh, B = 16, 40, 2, 8, 3
@@ -305,26 +468,27 @@ def test_resolve_decode_impl_dispatch(monkeypatch):
 
 
 def test_decode_hbm_bytes_counts_live_pages_only():
-    """The byte model is the schedule: distinct pages x (K+V) — the trash
-    page among them once, named for a last block's entries past the live
-    prefix — q/out per slot, the step table; and it must scale with
-    POSITION, not the page reservation."""
+    """The byte model is the schedule: distinct LIVE pages x (K+V) — no
+    copy is started for a last block's entries past the live prefix —
+    q/out per slot, the step table; and it must scale with POSITION, not
+    the page reservation."""
     ps, H, Dh = 4, 2, 8
     bt = np.asarray([[1, 2, 3], [4, 5, 6]])
     page = ps * H * Dh * 4
     qo = H * Dh * 4
 
     def tab(slots, n, quantized=False):  # G = n at these sizes: one block
-        return slots * (5 + (3 if quantized else 1) * n) * 4
+        return slots * (6 + (3 if quantized else 1) * n) * 4
 
     got = decode_hbm_bytes(bt, np.asarray([0, 5]), ps, H, Dh)
-    # slot 0: 1 live page; slot 1: 2 live pages; + the trash page
-    assert got == (3 + 1) * 2 * page + 2 * 2 * qo + tab(2, 3)
+    # slot 0: 1 live page; slot 1: 2 live pages; no trash page
+    assert got == 3 * 2 * page + 2 * 2 * qo + tab(2, 3)
+    assert decode_page_census(bt, np.asarray([0, 5]), ps) == (3, 3)
     # growing the reservation (dead tail) must not move the number
     bt_wide = np.concatenate([bt, np.full((2, 5), TRASH_PAGE)], 1)
     wide = decode_hbm_bytes(bt_wide, np.asarray([0, 5]), ps, H, Dh)
     assert wide == got - tab(2, 3) + tab(2, 8)     # only the table grows
-    # a repeated page is fetched once; a full slot names no trash page
+    # a repeated page is fetched once
     shared = decode_hbm_bytes(np.asarray([[1, 1]]), np.asarray([7]),
                               ps, H, Dh)
     assert shared == 1 * 2 * page + 2 * qo + tab(1, 2)
@@ -341,29 +505,30 @@ def test_decode_hbm_bytes_dedups_shared_pages_across_slots():
     qo = H * Dh * 4
     bt = np.asarray([[1, 2], [1, 3]])
     got = decode_hbm_bytes(bt, np.asarray([7, 7]), ps, H, Dh)
-    assert got == 3 * 2 * page + 2 * 2 * qo + 2 * (5 + 2) * 4
+    assert got == 3 * 2 * page + 2 * 2 * qo + 2 * (6 + 2) * 4
     # int8 pool: pages priced at 1 byte/elt, q/out stay fp, the table
     # gains 2 G scale rows for the per-page scale pairs
     q8 = decode_hbm_bytes(bt, np.asarray([7, 7]), ps, H, Dh,
                           quantized=True)
-    assert q8 == 3 * 2 * (ps * H * Dh) + 2 * 2 * qo + 2 * (5 + 3 * 2) * 4
+    assert q8 == 3 * 2 * (ps * H * Dh) + 2 * 2 * qo + 2 * (6 + 3 * 2) * 4
 
 
 def test_decode_hbm_bytes_follows_blocks_of_pages():
     """At 16-row pages a block is 16 pages: 40 pages a slot make 3 block
-    columns a slot, each 5 + 16 words; a slot 260 positions deep has 17
-    live pages in 2 live blocks, the second padded with the trash page (its
-    third column is no step); a slot at the end of its reservation names
-    all 40 of its pages and, 40 being no multiple of 16, the trash page
-    for the rest of its third block."""
+    columns a slot, each 6 + 16 words; a slot 260 positions deep has 17
+    live pages in 2 live blocks, the second with ONE live entry (its third
+    column is no step); a slot at the end of its reservation copies all 40
+    of its pages, eight of them in its third block (40 is no multiple of
+    16), and nothing for that block's other eight entries."""
     ps, H, Dh, n = 16, 2, 8, 40
     bt = 1 + np.arange(2 * n).reshape(2, n)
     page = ps * H * Dh * 4
     got = decode_hbm_bytes(bt, np.asarray([259, 639]), ps, H, Dh)
-    assert got == (17 + 40 + 1) * 2 * page + 2 * 2 * H * Dh * 4 + (
-        2 * 3 * (5 + 16) * 4)
+    assert got == (17 + 40) * 2 * page + 2 * 2 * H * Dh * 4 + (
+        2 * 3 * (6 + 16) * 4)
     full = decode_hbm_bytes(bt, np.asarray([639, 639]), ps, H, Dh)
-    assert full == (80 + 1) * 2 * page + 2 * 2 * H * Dh * 4 + 2 * 3 * 21 * 4
+    assert full == 80 * 2 * page + 2 * 2 * H * Dh * 4 + 2 * 3 * 22 * 4
+    assert decode_page_census(bt, np.asarray([639, 639]), ps) == (80, 80)
 
 
 # ------------------------------------------- DecodeServer token identity
